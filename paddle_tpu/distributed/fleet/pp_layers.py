@@ -59,7 +59,7 @@ import warnings
 import numpy as np
 import jax
 import jax.numpy as jnp
-from ..ring_attention import shard_map  # jax-version shim (check_vma)
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ...nn.layer.layers import Layer

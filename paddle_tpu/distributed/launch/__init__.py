@@ -32,6 +32,24 @@ def find_free_port():
         return s.getsockname()[1]
 
 
+def _refuse_if_holding_chip(what):
+    """A chip belongs to one process: a parent that has touched JAX on
+    an accelerator holds it, and a child that needs it then fails or
+    hangs. Start workers from a process that has not run anything on
+    the device yet (importing paddle_tpu does not). Reads jax's backend
+    registry (`jax._src.xla_bridge`, checked against jax 0.9.0): there
+    is no public way to ask without initializing the backend."""
+    import jax
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized() and \
+            jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"{what}: this process already holds the "
+            f"{jax.default_backend()} device(s); worker processes could "
+            f"not open them. Launch before the first device query or "
+            f"computation in the parent.")
+
+
 def _rank_env(master, nnodes, nproc_per_node, node_rank, local_rank,
               extra=None):
     """Only the vars the launcher injects (merged over os.environ by the
@@ -67,6 +85,7 @@ def launch(script, script_args=(), nproc_per_node=1, nnodes=1,
     and watch them; on any failure terminate the pod (reference:
     controller.py:66 run/watch). Returns the first nonzero exit code, or
     0."""
+    _refuse_if_holding_chip("launch")
     if master is None:
         if nnodes > 1:
             # each node inventing its own local coordinator can never
@@ -134,6 +153,7 @@ def spawn(func, args=(), nprocs=-1, join=True, daemon=False,
     rendezvous env set. nprocs=-1 -> one per local device group (1 on a
     single host)."""
     import multiprocessing as mp
+    _refuse_if_holding_chip("spawn")
     if nprocs <= 0:
         nprocs = int(os.getenv("PADDLE_NPROCS", "1"))
     master = f"127.0.0.1:{find_free_port()}"

@@ -17,25 +17,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # jax<0.5: not yet promoted out of experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-def shard_map(*args, check_vma=None, **kw):
-    """jax-version shim: newer jax spells the replication check
-    `check_vma`, jax<=0.4.x spells it `check_rep`. Accept the new
-    spelling everywhere and translate when the installed shard_map
-    predates it (ulysses/pp_layers import this shim too)."""
-    import inspect
-    params = inspect.signature(_shard_map).parameters
-    if check_vma is not None:
-        if "check_vma" in params:
-            kw["check_vma"] = check_vma
-        elif "check_rep" in params:
-            kw["check_rep"] = check_vma
-    return _shard_map(*args, **kw)
-
+from jax import shard_map
 
 __all__ = ["ring_attention", "ring_attention_sharded"]
 

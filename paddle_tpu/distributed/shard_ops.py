@@ -26,9 +26,7 @@ ppermute = jax.lax.ppermute
 axis_index = jax.lax.axis_index
 
 
-def axis_size(axis_name):
-    return jax.lax.axis_size(axis_name) if hasattr(jax.lax, "axis_size") \
-        else jax.lax.psum(1, axis_name)
+axis_size = jax.lax.axis_size
 
 
 def all_gather(x, axis_name, axis=0, tiled=True):

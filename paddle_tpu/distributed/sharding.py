@@ -115,20 +115,11 @@ def shard_optimizer_states(optimizer, axis="sharding", offload=False):
     dev0 = jax.devices()[0]
 
     def _sharding(shape, kind):
-        # "device" is the default memory kind; NAMING it trips
-        # backends whose PJRT memory-space list predates the spelling
-        # (CPU on jax 0.4.x only knows "unpinned_host") — omit it and
-        # only pin the explicit pinned_host offload kind
-        mk = None if kind == "device" else kind
         if jax_mesh is not None:
-            spec = _spec_for(tuple(shape), axis)
-            if mk is None:
-                return NamedSharding(jax_mesh, spec)
-            return NamedSharding(jax_mesh, spec, memory_kind=mk)
+            return NamedSharding(jax_mesh, _spec_for(tuple(shape), axis),
+                                 memory_kind=kind)
         from jax.sharding import SingleDeviceSharding
-        if mk is None:
-            return SingleDeviceSharding(dev0)
-        return SingleDeviceSharding(dev0, memory_kind=mk)
+        return SingleDeviceSharding(dev0, memory_kind=kind)
 
     orig = optimizer._accumulator_specs
 

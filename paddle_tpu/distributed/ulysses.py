@@ -18,7 +18,7 @@ from __future__ import annotations
 import math
 
 import jax
-from .ring_attention import shard_map  # jax-version shim (check_vma)
+from jax import shard_map
 from jax.sharding import PartitionSpec, NamedSharding
 
 __all__ = ["ulysses_attention", "ulysses_attention_sharded"]

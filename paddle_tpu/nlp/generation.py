@@ -23,6 +23,7 @@ is a length-L write at pos 0, decode a length-1 write at pos L+i.
 from __future__ import annotations
 
 import os
+import threading
 
 import numpy as np
 import jax
@@ -32,6 +33,7 @@ from ..core.dispatch import register_op
 from ..core.tensor import Tensor
 from ..core import dtype as dtypes
 from ..ops._helpers import apply_op, as_tensor
+from ..ops.pallas import kernel_mesh
 from ..ops.pallas.paged_attention import (dequantize_paged_q8,
                                           gqa_attend_reference,
                                           paged_decode_attention,
@@ -758,6 +760,59 @@ def _is_zero_pos(pos):
     return int(np.asarray(v)) == 0
 
 
+# Tracing an engine program swaps TRACERS into the model's tensors (the
+# weights are the program's first argument) until the trace ends.
+# Replicas may share one model, each tracing from its own pump thread,
+# and the solo `CompiledGenerator` reads the same tensors: this one
+# process-wide lock makes every swap -> restore window exclusive, so
+# none of them ever reads, or restores, another's tracers. Compiled
+# steps never take it. (Running the model EAGERLY from another thread
+# while an engine compiles is not covered.)
+_STATE_SWAP_LOCK = threading.RLock()
+
+
+def _swap_state(tensors, vals):
+    """Trace-time only: bind `vals` into `tensors`, returning the
+    originals. Takes _STATE_SWAP_LOCK, which `_restore_state` (the
+    caller's `finally`) gives back."""
+    _STATE_SWAP_LOCK.acquire()
+    originals = [t._value for t in tensors]
+    for t, v in zip(tensors, vals):
+        t._value = v
+    return originals
+
+
+def _restore_state(tensors, originals):
+    for t, v in zip(tensors, originals):
+        t._value = v
+    _STATE_SWAP_LOCK.release()
+
+
+class _StepProgram:
+    """One jitted engine program whose leading operand is the
+    engine's weight list. Callers pass the remaining operands; `lower`
+    and `_cache_size` (the retrace probes' view) go to the one
+    underlying `jax.jit`."""
+
+    def __init__(self, fn, state_vals, mesh=None):
+        self._jit = jax.jit(fn)
+        self._state_vals = state_vals
+        # the tensor-parallel replica's device mesh: named while the
+        # program is traced, so its Pallas kernels run per device
+        self._mesh = mesh
+
+    def __call__(self, *args):
+        with kernel_mesh(self._mesh, "mp"):
+            return self._jit(self._state_vals, *args)
+
+    def lower(self, *args):
+        with kernel_mesh(self._mesh, "mp"):
+            return self._jit.lower(self._state_vals, *args)
+
+    def _cache_size(self):
+        return self._jit._cache_size()
+
+
 def _pack_caches(caches):
     """DecodeCache list -> loop-carry pytree: per layer
     (k, v, k_scale|None, v_scale|None). None entries keep the pytree
@@ -1162,6 +1217,12 @@ class CompiledGenerator:
 
     def __call__(self, input_ids, max_new_tokens=16,
                  return_scores=False):
+        # reads the model's tensors and may trace: see _STATE_SWAP_LOCK
+        with _STATE_SWAP_LOCK:
+            return self._generate(input_ids, max_new_tokens,
+                                  return_scores)
+
+    def _generate(self, input_ids, max_new_tokens, return_scores):
         from ..core import random as random_mod
         ids = as_tensor(input_ids)
         beam = self.decode_strategy == "beam_search"

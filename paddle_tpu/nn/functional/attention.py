@@ -28,11 +28,8 @@ __all__ = ["scaled_dot_product_attention", "flash_attention",
 
 def _use_pallas(q_len, head_dim):
     import jax
-    try:
-        plat = jax.devices()[0].platform
-    except Exception:
-        plat = "cpu"
-    return plat == "tpu" and q_len >= 128 and head_dim in (64, 128, 256)
+    return (jax.devices()[0].platform == "tpu" and q_len >= 128
+            and head_dim in (64, 128, 256))
 
 
 def _sdpa_ref(q, k, v, mask, causal, scale, dropout_p, key):

@@ -11,8 +11,10 @@ from __future__ import annotations
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ...core.dispatch import register_op
+from ...ops.pallas import per_device
 from ...ops._helpers import as_tensor, apply_op
 
 __all__ = ["batch_norm", "layer_norm", "instance_norm", "group_norm",
@@ -152,10 +154,7 @@ def _use_pallas_ln():
         return False  # escape hatch
     if os.environ.get("PADDLE_TPU_PALLAS_INTERPRET", "0") == "1":
         return True
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 def _ln_fwd(x, w, b, n_norm_axes, epsilon):
@@ -165,7 +164,12 @@ def _ln_fwd(x, w, b, n_norm_axes, epsilon):
         # 25 LN sites; reference fuses in layer_norm_kernel.cu)
         from ...ops.pallas import layer_norm as pln
         if pln.supported(x, w, b, n_norm_axes) and _use_pallas_ln():
-            return pln.layer_norm_fused(x, w, b, float(epsilon))
+            # under a kernel mesh (the tensor-parallel serving replica)
+            # activations are replicated: every device normalizes them
+            return per_device(
+                lambda x, w, b: pln.layer_norm_fused(x, w, b,
+                                                     float(epsilon)),
+                (P(), P(), P()), P())(x, w, b)
     axes = tuple(range(x.ndim - n_norm_axes, x.ndim))
     dt = x.dtype
     xf = x.astype(jnp.float32) if dt in (jnp.bfloat16, jnp.float16) else x

@@ -15,3 +15,62 @@ Kernels:
   engine's paged KV pool (scalar-prefetched page-table walk, streams
   only live pages)
 """
+import contextlib as _contextlib
+import contextvars as _contextvars
+
+import jax as _jax
+from jax.sharding import PartitionSpec as _P
+
+
+def trace32():
+    """Context manager: trace the enclosed `pallas_call` in 32-bit mode.
+    The package turns `jax_enable_x64` on globally (paddle int64 parity)
+    and Mosaic cannot legalize i64 index arithmetic."""
+    return _jax.enable_x64(False)
+
+
+# A Mosaic kernel cannot be partitioned by GSPMD ("Mosaic kernels cannot
+# be automatically partitioned. Please wrap the call in a shard_map"), on
+# real chips only: off-TPU the jnp references partition like any XLA op,
+# which is all a virtual CPU mesh ever exercised. A program that spans a
+# mesh therefore names it while it is traced (`kernel_mesh`), and every
+# kernel wrapper runs its `pallas_call` per device (`per_device`).
+_KERNEL_MESH = _contextvars.ContextVar("paddle_tpu_kernel_mesh",
+                                       default=None)
+
+
+@_contextlib.contextmanager
+def kernel_mesh(mesh, head_axis=None):
+    """While tracing a program over `mesh` (None: one device, a no-op):
+    kernels run per device under `shard_map`. `head_axis` names the mesh
+    axis the attention heads (and the KV pools' head dimension) are
+    sharded over; everything a kernel wrapper does not declare sharded
+    is replicated."""
+    token = _KERNEL_MESH.set(None if mesh is None or mesh.size == 1
+                             else (mesh, head_axis))
+    try:
+        yield
+    finally:
+        _KERNEL_MESH.reset(token)
+
+
+def per_device(fn, in_specs, out_specs):
+    """`fn` under `shard_map` over the current kernel mesh. Specs are
+    PartitionSpecs written with the placeholder axis "heads", which is
+    replaced by the mesh's head axis (or dropped without one). With no
+    kernel mesh this is `fn` itself."""
+    cur = _KERNEL_MESH.get()
+    if cur is None:
+        return fn
+    mesh, head_axis = cur
+
+    def named(spec):
+        return _P(*(head_axis if a == "heads" else a for a in spec))
+
+    return _jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=_jax.tree.map(named, in_specs,
+                               is_leaf=lambda x: isinstance(x, _P)),
+        out_specs=_jax.tree.map(named, out_specs,
+                                is_leaf=lambda x: isinstance(x, _P)),
+        check_vma=False)

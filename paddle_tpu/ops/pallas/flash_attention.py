@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.experimental import disable_x64 as _disable_x64
+from . import trace32 as _trace32
 
 import os
 
@@ -491,7 +491,7 @@ def _flash_fwd_bhld(q, k, v, bias, kvec, seeds, h, causal, scale,
     mask_ops, mask_specs = _mask_specs(bias, kvec, h, block_q, block_k)
     # Mosaic rejects i64 index arithmetic; trace the kernel in 32-bit
     # mode regardless of the global jax_enable_x64 (paddle int64 parity)
-    with _disable_x64():
+    with _trace32():
         out, lse = pl.pallas_call(
             kernel,
             grid=(bh, n_q, n_k),
@@ -515,7 +515,7 @@ def _flash_fwd_bhld(q, k, v, bias, kvec, seeds, h, causal, scale,
                 pltpu.VMEM((block_q, _LANES), jnp.float32),
                 pltpu.VMEM((block_q, d), jnp.float32),
             ],
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_INTERPRET,
         )(*seed_ops, qp, kp, vp, *mask_ops)
@@ -566,7 +566,7 @@ def _flash_bwd_bhld(q, k, v, o, lse, do, bias, kvec, seeds, h, causal,
     mask_ops, mask_specs = _mask_specs(bias, kvec, h, block_q, block_k)
 
     dq_kernel = functools.partial(_fa_dq_kernel, **statics)
-    with _disable_x64():
+    with _trace32():
         dq = pl.pallas_call(
             dq_kernel,
             grid=(bh, n_q, n_k),
@@ -577,7 +577,7 @@ def _flash_bwd_bhld(q, k, v, o, lse, do, bias, kvec, seeds, h, causal,
                                    lambda b, i, j: (b, i, 0)),
             out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_INTERPRET,
         )(*seed_ops, qp, kp, vp, dop, lse, di, *mask_ops)
@@ -601,7 +601,7 @@ def _flash_bwd_bhld(q, k, v, o, lse, do, bias, kvec, seeds, h, causal,
                                          block_k, transpose=True)
 
     dkv_kernel = functools.partial(_fa_dkv_kernel, **statics)
-    with _disable_x64():
+    with _trace32():
         dk, dv = pl.pallas_call(
             dkv_kernel,
             grid=(bh, n_k, n_q),
@@ -620,7 +620,7 @@ def _flash_bwd_bhld(q, k, v, o, lse, do, bias, kvec, seeds, h, causal,
                 pltpu.VMEM((block_k, d), jnp.float32),
                 pltpu.VMEM((block_k, d), jnp.float32),
             ],
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_INTERPRET,
         )(*seed_ops, kp, vp, qp, dop, lse, di, *mask_ops2)

@@ -26,7 +26,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental import disable_x64 as _disable_x64
+from . import trace32 as _trace32
 
 _INTERPRET = os.environ.get("PADDLE_TPU_PALLAS_INTERPRET", "0") == "1"
 
@@ -110,7 +110,7 @@ def _ln_fwd(x, w, b, eps, block_r):
     # 32-bit trace inside the kernel regardless of the global
     # jax_enable_x64 (paddle int64 parity): Mosaic cannot legalize the
     # i64 index-map constants x64 mode would produce
-    with _disable_x64():
+    with _trace32():
         y = _fwd_call(x2, w, b, br, c, n, eps)
     if pad:
         y = y[:r]
@@ -137,7 +137,7 @@ def _ln_bwd(dy, x, w, eps, block_r):
     dy2, pad = _pad_rows(dy.reshape(r, c), br)
     x2, _ = _pad_rows(x.reshape(r, c), br)
     n = dy2.shape[0] // br
-    with _disable_x64():
+    with _trace32():
         dx, dw_p, db_p = _bwd_call(dy2, x2, w, br, c, n, eps)
     if pad:
         dx = dx[:r]

@@ -9,24 +9,31 @@ and XLA cannot fuse a data-dependent gather into the attention reads
 "Ragged Paged Attention" (PAPERS.md): walk the page table and stream
 ONLY the pages a row actually occupies.
 
-Structure — grid (batch_row, kv_head, page):
+Structure — grid (batch_row, q_block, page):
 
-- `page_table` [B, max_pages] and `pos` [B] ride in as SCALAR-PREFETCH
-  operands (pltpu.PrefetchScalarGridSpec), so the K/V BlockSpec index
-  maps can chase the page table: grid step (b, g, p) DMAs pool page
-  `page_table[b, p]` for kv head g. Steps past the row's last live
-  page (`pos[b] // page_size`) clamp their index to that page — the
+- `page_table` [B, max_pages], `pos` [B] and `q_len` [B] ride in as
+  SCALAR-PREFETCH operands (pltpu.PrefetchScalarGridSpec), so the K/V
+  BlockSpec index maps can chase the page table: grid step (b, t, p)
+  DMAs pool page `page_table[b, p]` — the WHOLE page, all kv heads
+  (block (1, page_size, H_kv, D): Mosaic wants a block's two minor
+  dimensions (8, 128)-divisible or whole, so a block cannot take one
+  head out of [H_kv, D]; see `_ragged_attention_local`). Steps past
+  the row's last live page clamp their index to that page — the
   pipeline skips the re-fetch of an unchanged block, so HBM traffic is
   O(pages actually used) per row, and compute there is predicated off.
 - Flash-style online softmax across page blocks: running (m, l, acc)
   scratch in VMEM, exactly the flash_attention.py recurrence with
-  page_size-wide key blocks. The partial tail page is handled by
+  page_size-wide key blocks, the kv heads as the batch dimension of
+  both dots (`_attend_page`). The partial tail page is handled by
   in-page masking (position > pos[b] -> -inf), which also covers
   trash-page rows: a retired/free slot's page-table row points at the
   reserved page 0 and every position past `pos` contributes -inf.
-- GQA without materialization: queries are grouped [B, H_kv, rep, D]
-  so kv head g serves its `rep = H // H_kv` query heads from ONE
-  streamed copy of K/V — no `repeat_interleave` of the cache.
+- GQA without materialization: queries are grouped
+  [B, n_qblk, H_kv, qblk * rep, D] so kv head g serves its
+  `rep = H // H_kv` query heads from ONE streamed copy of K/V — no
+  `repeat_interleave` of the cache.
+- The single-token decode op (`paged_decode_attention`) is this walk
+  at q_len 1.
 
 Off-TPU the op runs `paged_attention_reference` — the same math as the
 gather path (gather pages -> masked grouped softmax), kept around both
@@ -44,10 +51,10 @@ group), `group_leader` [B] (group -> a representative row) and
 next to `page_table`/`pos`/`q_len` and drive a TWO-PHASE kernel:
 
 - phase 1 walks each group's shared pages via the LEADER's page table
-  (grid (kv_head, q_block, group x page)), streaming every shared
-  page from HBM ONCE PER GROUP while updating the online-softmax
-  partials (m, l, acc) of EVERY member row in VMEM (non-member rows
-  are masked out of the update, so their partials stay bit-exact);
+  (grid (q_block, group x page)), streaming every shared page from
+  HBM ONCE PER GROUP while updating the online-softmax partials
+  (m, l, acc) of every MEMBER row in VMEM (non-member rows are
+  predicated off, so their partials stay bit-exact);
 - phase 2 is exactly the per-row walk above, except each row STARTS
   from its phase-1 partials and its page sweep clamps to
   [group_cnt[group_id[b]], last_live] — private tail pages stream
@@ -76,7 +83,7 @@ exactly like fp pages.
 
 RAGGED GENERALIZATION (`ragged_paged_attention`): the same walk, but
 every row carries its own query length — grid
-(batch_row, kv_head, q_block, page), with `q_len` [B] riding next to
+(batch_row, q_block, page), with `q_len` [B] riding next to
 `page_table`/`pos` as a third scalar-prefetch operand. Row b's query
 token i sits at global position pos[b] + i and attends keys
 j <= pos[b] + i (the causal window of the chunk being written), so ONE
@@ -159,6 +166,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from . import per_device as _per_device, trace32 as _trace32
 
 __all__ = ["paged_decode_attention", "paged_attention_reference",
            "gqa_attend_reference", "ragged_paged_attention",
@@ -196,11 +206,7 @@ def _prec(dt):
 
 
 def _use_kernel():
-    try:
-        plat = jax.devices()[0].platform
-    except Exception:
-        plat = "cpu"
-    return plat == "tpu" or _INTERPRET
+    return _INTERPRET or jax.devices()[0].platform == "tpu"
 
 
 # the decode-megakernel gate (see module doc): opt-in because the
@@ -240,521 +246,250 @@ def _mask_to_additive(mask, b, h, lmax, lq=1):
     return out.reshape(b, h, lmax) if lq == 1 else out
 
 
-def _pa_kernel(tab_ref, pos_ref, q_ref, k_ref, v_ref, *rest, ps, rep,
-               scale, has_mask, fp8=False):
+def _attend_page(q, k, v, ks, vs, live, mask, m_ref, l_ref, acc_ref, *,
+                 scale, fp8):
+    """Fold ONE streamed page into the online-softmax partials of one
+    row's query block, for every kv head at once. q [H_kv, R, D] with
+    R = qblk * rep query rows per kv head; k/v [ps, H_kv, D] exactly as
+    the page sits in the pool (the page block carries ALL kv heads —
+    see `_ragged_attention_kernel`); ks/vs the int8 lane's rowwise
+    scales [ps, H_kv] f32 or None; live bool [R, ps]; mask additive f32
+    [H_kv, R, ps] or None. m/l [H_kv, R, 128] and acc [H_kv, R, D] are
+    refs updated in place. The head axis is a batch dimension of both
+    dots, so per head this is the flash_attention.py recurrence with
+    page_size-wide key blocks."""
+    if ks is not None:
+        # fused in-VMEM dequant: int8 codes x rowwise scale — the
+        # dequantized page never round-trips through HBM
+        q = q.astype(jnp.float32)
+        k = k.astype(jnp.float32) * ks[:, :, None]
+        v = v.astype(jnp.float32) * vs[:, :, None]
+    elif fp8:
+        # pure-convert fp8 lane: the e4m3 value IS the number —
+        # upconvert in VMEM, no scale operand exists
+        q = q.astype(jnp.float32)
+        k = k.astype(jnp.float32)
+        v = v.astype(jnp.float32)
+    prec = _prec(q.dtype)
+    k = jnp.swapaxes(k, 0, 1)                      # [H_kv, ps, D]
+    v = jnp.swapaxes(v, 0, 1)
+    s = jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+        precision=prec) * jnp.float32(scale)       # [H_kv, R, ps]
+    s = jnp.where(live[None], s, jnp.float32(_NEG_INF))
+    if mask is not None:
+        s = s + mask
+    m_prev = m_ref[:, :, :1]
+    l_prev = l_ref[:, :, :1]
+    m_cur = jnp.max(s, axis=2, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    alpha = jnp.exp(m_prev - m_new)
+    pexp = jnp.exp(s - m_new)
+    l_ref[...] = jnp.broadcast_to(
+        alpha * l_prev + jnp.sum(pexp, axis=2, keepdims=True),
+        l_ref.shape)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        pexp.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+        precision=prec)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+
+
+def _live_window(t, p, pos_b, qlen_b, *, ps, qblk, rep):
+    """bool [qblk * rep, ps]: query t*qblk + i (live iff < q_len)
+    attends key position p*ps + j iff it is <= pos + query index.
+    Masks the partial tail page AND trash-page positions."""
+    shape = (qblk, rep, ps)
+    qi = t * qblk + jax.lax.broadcasted_iota(
+        jnp.int32, shape, 0).reshape(qblk * rep, ps)
+    k_pos = p * ps + jax.lax.broadcasted_iota(
+        jnp.int32, shape, 2).reshape(qblk * rep, ps)
+    return (qi < qlen_b) & (k_pos <= pos_b + qi)
+
+
+def _ragged_kernel(*refs, ps, qblk, rep, scale, has_mask, has_scale,
+                   fp8, grouped):
+    """The per-row page walk — grid (batch_row, q_block, page). With
+    `grouped` it is phase 2 of the grouped walk: each row initializes
+    from its phase-1 partials and skips pages below its group's shared
+    span (their contribution is already folded in), so private tail
+    pages stream once per row and shared pages are never re-read. The
+    merge IS the online-softmax recurrence continuing where phase 1
+    stopped, so the page order per row matches the ungrouped walk."""
+    refs = list(refs)
+    n_pre = 6 if grouped else 3
+    pre, refs = refs[:n_pre], refs[n_pre:]
+    pos_ref, qlen_ref = pre[1], pre[2]
+    q_ref, k_ref, v_ref = refs[:3]
+    refs = refs[3:]
+    ks_ref = vs_ref = mask_ref = None
+    if has_scale:
+        # int8 lane: rowwise dequant scales ride next to the code
+        # pages — one [ps, H_kv] f32 block per streamed K/V page
+        ks_ref, vs_ref = refs[:2]
+        refs = refs[2:]
     if has_mask:
-        mask_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        mask_ref = None
-        o_ref, m_ref, l_ref, acc_ref = rest
+        mask_ref, refs = refs[0], refs[1:]
+    if grouped:
+        m_in, l_in, acc_in = refs[:3]
+        refs = refs[3:]
+    o_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
+    t = pl.program_id(1)
     p = pl.program_id(2)
     n_p = pl.num_programs(2)
     pos_b = pos_ref[b]
-    prec = _prec(jnp.float32 if fp8 else q_ref.dtype)
-    scale32 = jnp.float32(scale)
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, jnp.float32(_NEG_INF))
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    # a page contributes iff it holds at least one valid position
-    # (j <= pos); fully-dead pages are exactly zero under the online
-    # softmax, so skipping them is not an approximation
-    @pl.when(p * ps <= pos_b)
-    def _compute():
-        q = q_ref[0, 0]                     # [rep, D]
-        k = k_ref[0, :, 0, :]               # [ps, D]
-        if fp8:
-            # pure-convert fp8 lane: the e4m3 value IS the number —
-            # upconvert in VMEM, no scale operand exists
-            q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=prec) * scale32       # [rep, ps]
-        # in-page validity: global position p*ps + local <= pos[b]
-        # (masks the partial tail page AND trash-page positions)
-        k_pos = p * ps + jax.lax.broadcasted_iota(
-            jnp.int32, (q_ref.shape[2], ps), 1)
-        s = jnp.where(k_pos <= pos_b, s, jnp.float32(_NEG_INF))
-        if has_mask:
-            s = s + mask_ref[0]             # additive f32 [rep, ps]
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.exp(s - m_new)
-        l_ref[:] = jnp.broadcast_to(
-            alpha * l_prev + jnp.sum(pexp, axis=1, keepdims=True),
-            l_ref.shape)
-        v = v_ref[0, :, 0, :]               # [ps, D]
-        if fp8:
-            v = v.astype(jnp.float32)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=prec)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-
-    @pl.when(p == n_p - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, :1], jnp.float32(1e-30))
-        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
-
-
-def _paged_attention_kernel(q, k_pool, v_pool, page_table, pos, mask):
-    """q [B, 1, H, D]; pools [P, ps, H_kv, D]; page_table [B, max_pages]
-    int32; pos [B] int32; mask None | additive f32 [B, H, lmax]."""
-    b, l, h, d = q.shape
-    p_total, ps, hkv, _ = k_pool.shape
-    mp = page_table.shape[1]
-    rep = h // hkv
-    scale = 1.0 / math.sqrt(d)
-    q4 = q.reshape(b, hkv, rep, d)
-
-    def last_live(posr, bi):
-        # index of the row's last live page (pos -> ceil((pos+1)/ps)-1)
-        return jnp.minimum(posr[bi] // ps, mp - 1)
-
-    def kv_idx(bi, g, p, tab, posr):
-        # dead steps re-fetch the previous (clamped) page: the pipeline
-        # skips the DMA of an unchanged block index, so only live pages
-        # ever stream from HBM
-        return (tab[bi, jnp.minimum(p, last_live(posr, bi))], 0, g, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, rep, d), lambda bi, g, p, tab, posr:
-                     (bi, g, 0, 0)),
-        pl.BlockSpec((1, ps, 1, d), kv_idx),
-        pl.BlockSpec((1, ps, 1, d), kv_idx),
-    ]
-    ops = [q4, k_pool, v_pool]
-    if mask is not None:
-        ops.append(mask.reshape(b * hkv, rep, mp * ps))
-        in_specs.append(pl.BlockSpec(
-            (1, rep, ps),
-            lambda bi, g, p, tab, posr: (bi * hkv + g, 0, p)))
-
-    kernel = functools.partial(_pa_kernel, ps=ps, rep=rep, scale=scale,
-                               has_mask=mask is not None,
-                               fp8=_is_fp8(k_pool.dtype))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, mp),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rep, d), lambda bi, g, p, tab,
-                               posr: (bi, g, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rep, _LANES), jnp.float32),
-            pltpu.VMEM((rep, _LANES), jnp.float32),
-            pltpu.VMEM((rep, d), jnp.float32),
-        ],
-    )
-    # Mosaic rejects i64 index arithmetic; trace in 32-bit mode
-    # (jax.experimental.disable_x64 — the bare jax.enable_x64 alias was
-    # removed in jax 0.4.37)
-    from jax.experimental import disable_x64
-    with disable_x64():
-        out = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
-            compiler_params=pltpu.TPUCompilerParams(
-                dimension_semantics=("parallel", "parallel",
-                                     "arbitrary")),
-            interpret=_INTERPRET,
-        )(page_table, pos, *ops)
-    return out.reshape(b, l, h, d)
-
-
-def _ragged_kernel(tab_ref, pos_ref, qlen_ref, q_ref, k_ref, v_ref,
-                   *rest, ps, qblk, rep, scale, has_mask,
-                   has_scale=False, fp8=False):
-    rest = list(rest)
-    if has_scale:
-        # int8 lane: rowwise dequant scales ride next to the code
-        # pages — one (ps,)-wide f32 block per streamed K/V page
-        ks_ref, vs_ref = rest[0], rest[1]
-        rest = rest[2:]
-    else:
-        ks_ref = vs_ref = None
-    if has_mask:
-        mask_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        mask_ref = None
-        o_ref, m_ref, l_ref, acc_ref = rest
-    b = pl.program_id(0)
-    t = pl.program_id(2)
-    p = pl.program_id(3)
-    n_p = pl.num_programs(3)
-    pos_b = pos_ref[b]
     qlen_b = qlen_ref[b]
-    prec = _prec(jnp.float32 if (has_scale or fp8) else q_ref.dtype)
-    scale32 = jnp.float32(scale)
     # last valid query of THIS block (block-dead when t*qblk >= q_len)
     last_qi = jnp.minimum((t + 1) * qblk, qlen_b) - 1
 
     @pl.when(p == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, jnp.float32(_NEG_INF))
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        if grouped:
+            m_ref[...] = m_in[0, 0]
+            l_ref[...] = l_in[0, 0]
+            acc_ref[...] = acc_in[0, 0]
+        else:
+            m_ref[...] = jnp.full_like(m_ref, jnp.float32(_NEG_INF))
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # a page contributes iff it holds a position some live query of the
-    # block attends (j <= pos + last_qi); dead blocks skip every page
-    @pl.when((t * qblk < qlen_b) & (p * ps <= pos_b + last_qi))
+    # block attends (j <= pos + last_qi); dead blocks skip every page —
+    # fully-dead pages are exactly zero under the online softmax, so
+    # skipping them is not an approximation
+    go = (t * qblk < qlen_b) & (p * ps <= pos_b + last_qi)
+    if grouped:
+        gid_ref, gcnt_ref = pre[3], pre[5]
+        go = go & (p >= gcnt_ref[gid_ref[b]])
+
+    @pl.when(go)
     def _compute():
-        q = q_ref[0, 0, :, 0].reshape(qblk * rep, q_ref.shape[-1])
-        k = k_ref[0, :, 0, :]                      # [ps, D]
-        if has_scale:
-            # fused in-VMEM dequant: int8 codes x rowwise scale — the
-            # dequantized page never round-trips through HBM
-            q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32) * ks_ref[0, :, 0][:, None]
-        elif fp8:
-            # pure-convert fp8 lane: upconvert in VMEM, no scales
-            q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=prec) * scale32              # [qblk*rep, ps]
-        # per-query causal window: query t*qblk + i (live iff < q_len)
-        # attends key position p*ps + j iff j_pos <= pos + q_pos
-        qi = t * qblk + jax.lax.broadcasted_iota(
-            jnp.int32, (qblk, rep, ps), 0).reshape(qblk * rep, ps)
-        k_pos = p * ps + jax.lax.broadcasted_iota(
-            jnp.int32, (qblk, rep, ps), 2).reshape(qblk * rep, ps)
-        live = (qi < qlen_b) & (k_pos <= pos_b + qi)
-        s = jnp.where(live, s, jnp.float32(_NEG_INF))
-        if has_mask:
-            s = s + mask_ref[0].reshape(qblk * rep, ps)
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.exp(s - m_new)
-        l_ref[:] = jnp.broadcast_to(
-            alpha * l_prev + jnp.sum(pexp, axis=1, keepdims=True),
-            l_ref.shape)
-        v = v_ref[0, :, 0, :]                      # [ps, D]
-        if has_scale:
-            v = v.astype(jnp.float32) * vs_ref[0, :, 0][:, None]
-        elif fp8:
-            v = v.astype(jnp.float32)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=prec)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        _attend_page(
+            q_ref[0, 0], k_ref[0], v_ref[0],
+            ks_ref[0] if has_scale else None,
+            vs_ref[0] if has_scale else None,
+            _live_window(t, p, pos_b, qlen_b, ps=ps, qblk=qblk,
+                         rep=rep),
+            mask_ref[0, 0, 0] if has_mask else None,
+            m_ref, l_ref, acc_ref, scale=scale, fp8=fp8)
 
     @pl.when(p == n_p - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:, :1], jnp.float32(1e-30))
-        d = o_ref.shape[-1]
-        o_ref[0, 0, :, 0] = (acc_ref[:] / l).reshape(
-            qblk, rep, d).astype(o_ref.dtype)
-
-
-def _ragged_attention_kernel(q, k_pool, v_pool, page_table, pos, q_len,
-                             mask, k_scale=None, v_scale=None):
-    """q [B, lq, H, D]; pools [P, ps, H_kv, D]; page_table
-    [B, max_pages] int32; pos/q_len [B] int32; mask None | additive f32
-    [B, H, lq, lmax]. lq is padded up to a multiple of the query block
-    so the grid tiles evenly; padded queries are dead by q_len.
-    k_scale/v_scale (int8 lane): rowwise dequant scale pages
-    [P, ps, H_kv] f32 streamed next to the int8 code pools — dequant
-    fuses into the in-VMEM compute."""
-    b, lq, h, d = q.shape
-    _, ps, hkv, _ = k_pool.shape
-    mp = page_table.shape[1]
-    rep = h // hkv
-    scale = 1.0 / math.sqrt(d)
-    qblk = min(lq, 8)
-    nqb = -(-lq // qblk)
-    lq_pad = nqb * qblk
-    if lq_pad != lq:
-        padq = jnp.zeros((b, lq_pad - lq, h, d), q.dtype)
-        q = jnp.concatenate([q, padq], axis=1)
-        if mask is not None:
-            padm = jnp.zeros((b, h, lq_pad - lq, mp * ps), jnp.float32)
-            mask = jnp.concatenate([mask, padm], axis=2)
-    q6 = q.reshape(b, nqb, qblk, hkv, rep, d)
-
-    def kv_idx(bi, g, t, p, tab, posr, qlr):
-        # clamp dead steps (block-dead rows and pages past the block's
-        # causal horizon) to the last live page: unchanged block index,
-        # no re-fetch, compute predicated off in-kernel
-        last_qi = jnp.minimum((t + 1) * qblk, qlr[bi]) - 1
-        lp = jnp.clip((posr[bi] + last_qi) // ps, 0, mp - 1)
-        return (tab[bi, jnp.minimum(p, lp)], 0, g, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, qblk, 1, rep, d),
-                     lambda bi, g, t, p, tab, posr, qlr:
-                     (bi, t, 0, g, 0, 0)),
-        pl.BlockSpec((1, ps, 1, d), kv_idx),
-        pl.BlockSpec((1, ps, 1, d), kv_idx),
-    ]
-    ops = [q6, k_pool, v_pool]
-    has_scale = k_scale is not None
-    if has_scale:
-        # int8 lane: the scale pages chase the SAME clamped page-table
-        # walk as the code pages, so dead grid steps skip their DMA too
-        def ks_idx(bi, g, t, p, tab, posr, qlr):
-            last_qi = jnp.minimum((t + 1) * qblk, qlr[bi]) - 1
-            lp = jnp.clip((posr[bi] + last_qi) // ps, 0, mp - 1)
-            return (tab[bi, jnp.minimum(p, lp)], 0, g)
-
-        ops.extend([k_scale, v_scale])
-        in_specs.extend([pl.BlockSpec((1, ps, 1), ks_idx),
-                         pl.BlockSpec((1, ps, 1), ks_idx)])
-    if mask is not None:
-        # [B, H, lq, lmax] -> [B*hkv, lq, rep, lmax]: block rows match
-        # the kernel's (qblk, rep) score layout
-        m5 = mask.reshape(b, hkv, rep, lq_pad, mp * ps)
-        ops.append(m5.transpose(0, 1, 3, 2, 4)
-                   .reshape(b * hkv, lq_pad, rep, mp * ps))
-        in_specs.append(pl.BlockSpec(
-            (1, qblk, rep, ps),
-            lambda bi, g, t, p, tab, posr, qlr:
-            (bi * hkv + g, t, 0, p)))
-
-    kernel = functools.partial(_ragged_kernel, ps=ps, qblk=qblk,
-                               rep=rep, scale=scale,
-                               has_mask=mask is not None,
-                               has_scale=has_scale,
-                               fp8=_is_fp8(k_pool.dtype))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, hkv, nqb, mp),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, qblk, 1, rep, d),
-                               lambda bi, g, t, p, tab, posr, qlr:
-                               (bi, t, 0, g, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((qblk * rep, _LANES), jnp.float32),
-            pltpu.VMEM((qblk * rep, _LANES), jnp.float32),
-            pltpu.VMEM((qblk * rep, d), jnp.float32),
-        ],
-    )
-    from jax.experimental import disable_x64
-    with disable_x64():
-        out = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, nqb, qblk, hkv, rep, d),
-                                           q.dtype),
-            compiler_params=pltpu.TPUCompilerParams(
-                dimension_semantics=("parallel", "parallel",
-                                     "arbitrary", "arbitrary")),
-            interpret=_INTERPRET,
-        )(page_table, pos, q_len, *ops)
-    return out.reshape(b, lq_pad, h, d)[:, :lq]
+        l = jnp.maximum(l_ref[:, :, :1], jnp.float32(1e-30))
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def _grouped_phase1_kernel(tab_ref, pos_ref, qlen_ref, gid_ref,
                            gldr_ref, gcnt_ref, q_ref, k_ref, v_ref,
                            *rest, b, mp, ps, qblk, rep, scale,
                            has_scale, fp8):
-    """Phase 1 of the grouped walk — grid (kv_head, q_block,
-    group x shared_page): each grid step streams ONE shared page of
-    ONE group (via the group leader's page table; the index map clamps
-    dead steps so their DMA is skipped) and folds it into the
-    online-softmax partials of EVERY member row at once. Non-member
-    rows (and groups with no shared span) are masked out of the
-    update, so their partials leave this phase exactly as they
-    entered: (-inf, 0, 0) — the virgin state phase 2 would have
-    initialized anyway."""
-    rest = list(rest)
+    """Phase 1 of the grouped walk — grid (q_block, group x
+    shared_page): each grid step streams ONE shared page of ONE group
+    (via the group leader's page table; the index map clamps dead
+    steps so their DMA is skipped) and folds it into the
+    online-softmax partials of every MEMBER row. Non-member rows (and
+    groups with no shared span) are predicated off, so their partials
+    leave this phase exactly as they entered: (-inf, 0, 0) — the
+    virgin state phase 2 would have initialized anyway. The partials
+    accumulate in the output blocks, which stay resident in VMEM
+    across the whole (group x page) sweep of one q_block."""
+    del tab_ref, gldr_ref
     if has_scale:
-        ks_ref, vs_ref = rest[0], rest[1]
-        rest = rest[2:]
+        ks_ref, vs_ref, m_out, l_out, acc_out = rest
     else:
         ks_ref = vs_ref = None
-    meta_ref, m_out, l_out, acc_out, m_sc, l_sc, acc_sc = rest
-    t = pl.program_id(1)
-    u = pl.program_id(2)
-    n_u = pl.num_programs(2)
+        m_out, l_out, acc_out = rest
+    t = pl.program_id(0)
+    u = pl.program_id(1)
     grp = u // mp
     sp = u % mp
-    cnt = gcnt_ref[grp]
-    prec = _prec(jnp.float32 if (has_scale or fp8) else q_ref.dtype)
-    scale32 = jnp.float32(scale)
 
     @pl.when(u == 0)
     def _init():
-        m_sc[:] = jnp.full_like(m_sc, jnp.float32(_NEG_INF))
-        l_sc[:] = jnp.zeros_like(l_sc)
-        acc_sc[:] = jnp.zeros_like(acc_sc)
+        m_out[...] = jnp.full_like(m_out, jnp.float32(_NEG_INF))
+        l_out[...] = jnp.zeros_like(l_out)
+        acc_out[...] = jnp.zeros_like(acc_out)
 
     # a step is live iff its group really has this shared page
-    @pl.when(sp < cnt)
-    def _compute():
-        d = q_ref.shape[-1]
-        q = q_ref[:, 0, :, 0].reshape(b * qblk * rep, d)
-        k = k_ref[0, :, 0, :]                      # [ps, D]
-        if has_scale:
-            q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32) * ks_ref[0, :, 0][:, None]
-        elif fp8:
-            q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=prec) * scale32              # [b*qblk*rep, ps]
-        # per-(row, query, key) liveness: the row must belong to THIS
-        # group, the query must be live (i < q_len) and the key within
-        # its causal window (j <= pos + i). meta rows: (pos, q_len,
-        # group_id) — a VMEM mirror of the scalar operands so the mask
-        # builds from plain vector reads.
-        pos4 = meta_ref[0, :][:, None, None, None]
-        qlen4 = meta_ref[1, :][:, None, None, None]
-        member4 = (meta_ref[2, :][:, None, None, None] == grp)
-        qi = t * qblk + jax.lax.broadcasted_iota(
-            jnp.int32, (b, qblk, rep, ps), 1)
-        k_pos = sp * ps + jax.lax.broadcasted_iota(
-            jnp.int32, (b, qblk, rep, ps), 3)
-        live = member4 & (qi < qlen4) & (k_pos <= pos4 + qi)
-        s = jnp.where(live.reshape(b * qblk * rep, ps), s,
-                      jnp.float32(_NEG_INF))
-        member = jnp.broadcast_to(member4, (b, qblk, rep, 1)) \
-            .reshape(b * qblk * rep, 1)
-        m_prev = m_sc[:, :1]
-        l_prev = l_sc[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        # NON-member rows take the no-op branch of every update below:
-        # their partials must stay BIT-exact through a phase that
-        # computes garbage scores for them
-        m_new = jnp.where(member, jnp.maximum(m_prev, m_cur), m_prev)
-        alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.exp(s - m_new)
-        l_sc[:] = jnp.broadcast_to(
-            jnp.where(member,
-                      alpha * l_prev + jnp.sum(pexp, axis=1,
-                                               keepdims=True),
-                      l_prev), l_sc.shape)
-        v = v_ref[0, :, 0, :]                      # [ps, D]
-        if has_scale:
-            v = v.astype(jnp.float32) * vs_ref[0, :, 0][:, None]
-        elif fp8:
-            v = v.astype(jnp.float32)
-        upd = acc_sc[:] * alpha + jax.lax.dot_general(
-            pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=prec)
-        acc_sc[:] = jnp.where(member, upd, acc_sc[:])
-        m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
+    @pl.when(sp < gcnt_ref[grp])
+    def _page():
+        k, v = k_ref[0], v_ref[0]
+        ks = ks_ref[0] if has_scale else None
+        vs = vs_ref[0] if has_scale else None
+        for bi in range(b):
+            pos_b = pos_ref[bi]
+            qlen_b = qlen_ref[bi]
 
-    @pl.when(u == n_u - 1)
-    def _flush():
-        m_out[0, 0] = m_sc[:]
-        l_out[0, 0] = l_sc[:]
-        acc_out[0, 0] = acc_sc[:]
+            @pl.when((gid_ref[bi] == grp) & (t * qblk < qlen_b))
+            def _member(bi=bi, pos_b=pos_b, qlen_b=qlen_b):
+                _attend_page(
+                    q_ref[bi, 0], k, v, ks, vs,
+                    _live_window(t, sp, pos_b, qlen_b, ps=ps,
+                                 qblk=qblk, rep=rep),
+                    None, m_out.at[0, bi], l_out.at[0, bi],
+                    acc_out.at[0, bi], scale=scale, fp8=fp8)
 
 
-def _grouped_phase2_kernel(tab_ref, pos_ref, qlen_ref, gid_ref,
-                           gldr_ref, gcnt_ref, q_ref, k_ref, v_ref,
-                           *rest, ps, qblk, rep, scale, has_scale,
-                           fp8):
-    """Phase 2 of the grouped walk: the per-row page sweep of
-    `_ragged_kernel`, except each row initializes from its phase-1
-    partials and skips pages below its group's shared span (their
-    contribution is already folded in) — private tail pages stream
-    once per row, shared pages are never re-read. The merge IS the
-    online-softmax recurrence continuing where phase 1 stopped, so the
-    page order per row matches the ungrouped kernel exactly."""
-    rest = list(rest)
-    if has_scale:
-        ks_ref, vs_ref = rest[0], rest[1]
-        rest = rest[2:]
-    else:
-        ks_ref = vs_ref = None
-    m_in, l_in, acc_in, o_ref, m_ref, l_ref, acc_ref = rest
-    b = pl.program_id(0)
-    t = pl.program_id(2)
-    p = pl.program_id(3)
-    n_p = pl.num_programs(3)
-    pos_b = pos_ref[b]
-    qlen_b = qlen_ref[b]
-    shared_b = gcnt_ref[gid_ref[b]]
-    prec = _prec(jnp.float32 if (has_scale or fp8) else q_ref.dtype)
-    scale32 = jnp.float32(scale)
-    last_qi = jnp.minimum((t + 1) * qblk, qlen_b) - 1
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[:] = m_in[0, 0]
-        l_ref[:] = l_in[0, 0]
-        acc_ref[:] = acc_in[0, 0]
-
-    @pl.when((t * qblk < qlen_b) & (p * ps <= pos_b + last_qi)
-             & (p >= shared_b))
-    def _compute():
-        q = q_ref[0, 0, :, 0].reshape(qblk * rep, q_ref.shape[-1])
-        k = k_ref[0, :, 0, :]                      # [ps, D]
-        if has_scale:
-            q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32) * ks_ref[0, :, 0][:, None]
-        elif fp8:
-            q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=prec) * scale32              # [qblk*rep, ps]
-        qi = t * qblk + jax.lax.broadcasted_iota(
-            jnp.int32, (qblk, rep, ps), 0).reshape(qblk * rep, ps)
-        k_pos = p * ps + jax.lax.broadcasted_iota(
-            jnp.int32, (qblk, rep, ps), 2).reshape(qblk * rep, ps)
-        live = (qi < qlen_b) & (k_pos <= pos_b + qi)
-        s = jnp.where(live, s, jnp.float32(_NEG_INF))
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.exp(s - m_new)
-        l_ref[:] = jnp.broadcast_to(
-            alpha * l_prev + jnp.sum(pexp, axis=1, keepdims=True),
-            l_ref.shape)
-        v = v_ref[0, :, 0, :]                      # [ps, D]
-        if has_scale:
-            v = v.astype(jnp.float32) * vs_ref[0, :, 0][:, None]
-        elif fp8:
-            v = v.astype(jnp.float32)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=prec)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-
-    @pl.when(p == n_p - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, :1], jnp.float32(1e-30))
-        d = o_ref.shape[-1]
-        o_ref[0, 0, :, 0] = (acc_ref[:] / l).reshape(
-            qblk, rep, d).astype(o_ref.dtype)
+def _ragged_attention_kernel(q, k_pool, v_pool, page_table, pos, q_len,
+                             mask, k_scale=None, v_scale=None,
+                             group=None):
+    """`_ragged_attention_local` on every device of the kernel mesh
+    (ops/pallas/__init__.py): under the tensor-parallel serving
+    replica q, the pools, the scale pools and a user mask arrive
+    sharded over their HEAD dimension and each device walks the pages
+    of its own kv heads — no cross-device traffic; page tables and
+    row operands are replicated. On one device it is the local call."""
+    heads = P(None, None, "heads", None)
+    rows = P()
+    ops = dict(q=q, k_pool=k_pool, v_pool=v_pool, page_table=page_table,
+               pos=pos, q_len=q_len)
+    specs = dict(q=heads, k_pool=heads, v_pool=heads, page_table=rows,
+                 pos=rows, q_len=rows)
+    if mask is not None:
+        ops["mask"], specs["mask"] = mask, P(None, "heads", None, None)
+    if k_scale is not None:
+        ops["k_scale"], ops["v_scale"] = k_scale, v_scale
+        specs["k_scale"] = specs["v_scale"] = P(None, None, "heads")
+    if group is not None:
+        ops["group"], specs["group"] = tuple(group), (rows, rows, rows)
+    return _per_device(
+        lambda o: _ragged_attention_local(**{"mask": None, **o}),
+        (specs,), heads)(ops)
 
 
-def _grouped_attention_kernel(q, k_pool, v_pool, page_table, pos,
-                              q_len, group_id, group_leader,
-                              group_cnt, k_scale=None, v_scale=None):
-    """The grouped two-phase page walk (see the module doc). Operand
-    contract (engine-enforced, host side): rows of one group carry
-    IDENTICAL page-table entries for indices [0, group_cnt) — the
-    physically shared prefix — and every member's pos already covers
-    the span (shared pages hold committed KV). group_leader[g] names a
-    member row whose table phase 1 walks; singleton rows ride with
-    group_cnt 0 and take phase 2 only, which is exactly the ungrouped
-    walk."""
+def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
+                            mask, k_scale=None, v_scale=None,
+                            group=None):
+    """q [B, lq, H, D]; pools [P, ps, H_kv, D]; page_table
+    [B, max_pages] int32; pos/q_len [B] int32; mask None | additive f32
+    [B, H, lq, lmax]. lq is padded up to a multiple of the query block
+    so the grid tiles evenly; padded queries are dead by q_len.
+    k_scale/v_scale (int8 lane): rowwise dequant scale pages
+    [P, ps, H_kv] f32 streamed next to the int8 code pools — dequant
+    fuses into the in-VMEM compute.
+
+    POOL LAYOUT AND BLOCKS. Mosaic requires the last two dimensions of
+    a block to be (8, 128)-divisible or to span the array's, so a page
+    block cannot take one head out of the pool's [H_kv, D] minor
+    dimensions. The pool keeps its [P, ps, H_kv, D] layout (one
+    decision for the fp, int8, fp8 and grouped lanes, the scatter
+    write, COW/swap/PKVF frames and the tensor-parallel head shard)
+    and a grid step streams the WHOLE page — block (1, ps, H_kv, D),
+    scale block (1, ps, H_kv) — with the kv heads as the batch
+    dimension of the in-kernel dots. Queries are regrouped outside the
+    kernel to [B, n_qblk, H_kv, qblk * rep, D] so a block's minor
+    dimensions are whole too.
+
+    group = (group_id, group_leader, group_cnt) selects the grouped
+    two-phase walk (see the module doc). Operand contract
+    (engine-enforced, host side): rows of one group carry IDENTICAL
+    page-table entries for indices [0, group_cnt) — the physically
+    shared prefix — and every member's pos already covers the span
+    (shared pages hold committed KV). group_leader[g] names a member
+    row whose table phase 1 walks; singleton rows ride with group_cnt
+    0 and take phase 2 only, which is exactly the ungrouped walk."""
     b, lq, h, d = q.shape
     _, ps, hkv, _ = k_pool.shape
     mp = page_table.shape[1]
@@ -763,149 +498,146 @@ def _grouped_attention_kernel(q, k_pool, v_pool, page_table, pos,
     qblk = min(lq, 8)
     nqb = -(-lq // qblk)
     lq_pad = nqb * qblk
+    rows = qblk * rep
     if lq_pad != lq:
         padq = jnp.zeros((b, lq_pad - lq, h, d), q.dtype)
         q = jnp.concatenate([q, padq], axis=1)
-    q6 = q.reshape(b, nqb, qblk, hkv, rep, d)
+        if mask is not None:
+            padm = jnp.zeros((b, h, lq_pad - lq, mp * ps), jnp.float32)
+            mask = jnp.concatenate([mask, padm], axis=2)
+    q5 = q.reshape(b, nqb, qblk, hkv, rep, d) \
+        .transpose(0, 1, 3, 2, 4, 5).reshape(b, nqb, hkv, rows, d)
     has_scale = k_scale is not None
+    grouped = group is not None
     fp8 = _is_fp8(k_pool.dtype)
-    rows = b * qblk * rep
-    # VMEM mirror of (pos, q_len, group_id): the phase-1 mask builds
-    # from plain vector reads instead of per-row SMEM gathers
-    meta = jnp.stack([pos, q_len, group_id]).astype(jnp.int32)
+    prefetch = (page_table, pos, q_len) + (tuple(group) if grouped
+                                           else ())
 
-    def kv1(g, t, u, tab, posr, qlr, gid, gld, gcn):
+    def live_page(bi, t, p, tab, posr, qlr, *grp):
+        # clamp dead steps (block-dead rows, pages past the block's
+        # causal horizon and — grouped — pages below the row's shared
+        # span, which is phase-1 territory) to a live page: unchanged
+        # block index, no re-fetch, compute predicated off in-kernel
+        last_qi = jnp.minimum((t + 1) * qblk, qlr[bi]) - 1
+        lp = jnp.clip((posr[bi] + last_qi) // ps, 0, mp - 1)
+        lo = 0
+        if grouped:
+            gid, _, gcn = grp
+            lo = jnp.minimum(gcn[gid[bi]], lp)
+        return tab[bi, jnp.clip(p, lo, lp)]
+
+    def kv_idx(bi, t, p, *pre):
+        return (live_page(bi, t, p, *pre), 0, 0, 0)
+
+    def sc_idx(bi, t, p, *pre):
+        # int8 lane: the scale pages chase the SAME clamped page-table
+        # walk as the code pages, so dead grid steps skip their DMA too
+        return (live_page(bi, t, p, *pre), 0, 0)
+
+    q_spec = pl.BlockSpec((1, 1, hkv, rows, d),
+                          lambda bi, t, p, *_: (bi, t, 0, 0, 0))
+    in_specs = [q_spec,
+                pl.BlockSpec((1, ps, hkv, d), kv_idx),
+                pl.BlockSpec((1, ps, hkv, d), kv_idx)]
+    ops = [q5, k_pool, v_pool]
+    if has_scale:
+        ops.extend([k_scale, v_scale])
+        in_specs.extend([pl.BlockSpec((1, ps, hkv), sc_idx),
+                         pl.BlockSpec((1, ps, hkv), sc_idx)])
+    if mask is not None:
+        # [B, H, lq, lmax] -> [B, n_qblk, max_pages, H_kv, rows, ps]:
+        # one block per (row, q_block, page), its minor dims whole and
+        # its rows in the kernel's (qblk, rep) score order
+        m7 = mask.reshape(b, hkv, rep, nqb, qblk, mp, ps)
+        ops.append(m7.transpose(0, 3, 5, 1, 4, 2, 6)
+                   .reshape(b, nqb, mp, hkv, rows, ps))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, 1, hkv, rows, ps),
+            lambda bi, t, p, *_: (bi, t, p, 0, 0, 0)))
+    with _trace32():
+        if grouped:
+            ops.extend(_grouped_phase1(
+                prefetch, ops, b=b, mp=mp, ps=ps, hkv=hkv, d=d,
+                qblk=qblk, nqb=nqb, rep=rep, scale=scale,
+                has_scale=has_scale, fp8=fp8))
+            in_specs.extend(
+                pl.BlockSpec((1, 1, hkv, rows, w),
+                             lambda bi, t, p, *_: (t, bi, 0, 0, 0))
+                for w in (_LANES, _LANES, d))
+        kernel = functools.partial(
+            _ragged_kernel, ps=ps, qblk=qblk, rep=rep, scale=scale,
+            has_mask=mask is not None, has_scale=has_scale, fp8=fp8,
+            grouped=grouped)
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(prefetch),
+                grid=(b, nqb, mp),
+                in_specs=in_specs,
+                out_specs=q_spec,
+                scratch_shapes=[
+                    pltpu.VMEM((hkv, rows, _LANES), jnp.float32),
+                    pltpu.VMEM((hkv, rows, _LANES), jnp.float32),
+                    pltpu.VMEM((hkv, rows, d), jnp.float32),
+                ]),
+            out_shape=jax.ShapeDtypeStruct(q5.shape, q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary",
+                                     "arbitrary")),
+            interpret=_INTERPRET,
+        )(*prefetch, *ops)
+    return out.reshape(b, nqb, hkv, qblk, rep, d) \
+        .transpose(0, 1, 3, 2, 4, 5).reshape(b, lq_pad, h, d)[:, :lq]
+
+
+def _grouped_phase1(prefetch, ops, *, b, mp, ps, hkv, d, qblk, nqb, rep,
+                    scale, has_scale, fp8):
+    """Run phase 1 of the grouped walk over `ops` (q5, pools and, on
+    the int8 lane, scale pools — the operands phase 2 takes too) and
+    return the per-row partials (m, l, acc), each
+    [n_qblk, B, H_kv, qblk * rep, 128 | D] f32."""
+    rows = qblk * rep
+
+    def shared_page(t, u, tab, posr, qlr, gid, gld, gcn):
         # shared page sp of group grp via the LEADER's page table;
         # dead steps (groups with fewer shared pages, or none) clamp
         # to the last live shared page — unchanged block index, DMA
         # skipped — and empty groups to the trash page 0
         grp = u // mp
-        sp = u % mp
         cnt = gcn[grp]
-        live = jnp.clip(sp, 0, jnp.maximum(cnt - 1, 0))
-        return (jnp.where(cnt > 0, tab[gld[grp], live], 0), 0, g, 0)
+        live = jnp.clip(u % mp, 0, jnp.maximum(cnt - 1, 0))
+        return jnp.where(cnt > 0, tab[gld[grp], live], 0)
 
-    def ks1(g, t, u, tab, posr, qlr, gid, gld, gcn):
-        grp = u // mp
-        sp = u % mp
-        cnt = gcn[grp]
-        live = jnp.clip(sp, 0, jnp.maximum(cnt - 1, 0))
-        return (jnp.where(cnt > 0, tab[gld[grp], live], 0), 0, g)
-
-    p1_in = [
-        pl.BlockSpec((b, 1, qblk, 1, rep, d),
-                     lambda g, t, u, *_: (0, t, 0, g, 0, 0)),
-        pl.BlockSpec((1, ps, 1, d), kv1),
-        pl.BlockSpec((1, ps, 1, d), kv1),
-    ]
-    p1_ops = [q6, k_pool, v_pool]
+    kv_spec = pl.BlockSpec(
+        (1, ps, hkv, d), lambda t, u, *pre: (shared_page(t, u, *pre),
+                                             0, 0, 0))
+    in_specs = [pl.BlockSpec((b, 1, hkv, rows, d),
+                             lambda t, u, *_: (0, t, 0, 0, 0)),
+                kv_spec, kv_spec]
     if has_scale:
-        p1_ops.extend([k_scale, v_scale])
-        p1_in.extend([pl.BlockSpec((1, ps, 1), ks1),
-                      pl.BlockSpec((1, ps, 1), ks1)])
-    p1_ops.append(meta)
-    p1_in.append(pl.BlockSpec((3, b), lambda g, t, u, *_: (0, 0)))
+        sc_spec = pl.BlockSpec(
+            (1, ps, hkv), lambda t, u, *pre: (shared_page(t, u, *pre),
+                                              0, 0))
+        in_specs.extend([sc_spec, sc_spec])
+    widths = (_LANES, _LANES, d)
+    return pl.pallas_call(
+        functools.partial(
+            _grouped_phase1_kernel, b=b, mp=mp, ps=ps, qblk=qblk,
+            rep=rep, scale=scale, has_scale=has_scale, fp8=fp8),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(nqb, b * mp),
+            in_specs=in_specs,
+            out_specs=[pl.BlockSpec((1, b, hkv, rows, w),
+                                    lambda t, u, *_: (t, 0, 0, 0, 0))
+                       for w in widths]),
+        out_shape=[jax.ShapeDtypeStruct((nqb, b, hkv, rows, w),
+                                        jnp.float32) for w in widths],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=_INTERPRET,
+    )(*prefetch, *ops)
 
-    kernel1 = functools.partial(
-        _grouped_phase1_kernel, b=b, mp=mp, ps=ps, qblk=qblk, rep=rep,
-        scale=scale, has_scale=has_scale, fp8=fp8)
-    grid1 = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(hkv, nqb, b * mp),
-        in_specs=p1_in,
-        out_specs=[
-            pl.BlockSpec((1, 1, rows, _LANES),
-                         lambda g, t, u, *_: (g, t, 0, 0)),
-            pl.BlockSpec((1, 1, rows, _LANES),
-                         lambda g, t, u, *_: (g, t, 0, 0)),
-            pl.BlockSpec((1, 1, rows, d),
-                         lambda g, t, u, *_: (g, t, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((rows, _LANES), jnp.float32),
-            pltpu.VMEM((rows, _LANES), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.float32),
-        ],
-    )
-
-    def kv2(bi, g, t, p, tab, posr, qlr, gid, gld, gcn):
-        # per-row private sweep: clamp into [shared span, last live] —
-        # steps below the span (phase-1 territory) and past the
-        # horizon re-fetch nothing
-        last_qi = jnp.minimum((t + 1) * qblk, qlr[bi]) - 1
-        lp = jnp.clip((posr[bi] + last_qi) // ps, 0, mp - 1)
-        s0 = jnp.minimum(gcn[gid[bi]], lp)
-        return (tab[bi, jnp.clip(p, s0, lp)], 0, g, 0)
-
-    def ks2(bi, g, t, p, tab, posr, qlr, gid, gld, gcn):
-        last_qi = jnp.minimum((t + 1) * qblk, qlr[bi]) - 1
-        lp = jnp.clip((posr[bi] + last_qi) // ps, 0, mp - 1)
-        s0 = jnp.minimum(gcn[gid[bi]], lp)
-        return (tab[bi, jnp.clip(p, s0, lp)], 0, g)
-
-    p2_in = [
-        pl.BlockSpec((1, 1, qblk, 1, rep, d),
-                     lambda bi, g, t, p, *_: (bi, t, 0, g, 0, 0)),
-        pl.BlockSpec((1, ps, 1, d), kv2),
-        pl.BlockSpec((1, ps, 1, d), kv2),
-    ]
-    if has_scale:
-        p2_in.extend([pl.BlockSpec((1, ps, 1), ks2),
-                      pl.BlockSpec((1, ps, 1), ks2)])
-    p2_in.extend([
-        pl.BlockSpec((1, 1, qblk * rep, _LANES),
-                     lambda bi, g, t, p, *_: (g, t, bi, 0)),
-        pl.BlockSpec((1, 1, qblk * rep, _LANES),
-                     lambda bi, g, t, p, *_: (g, t, bi, 0)),
-        pl.BlockSpec((1, 1, qblk * rep, d),
-                     lambda bi, g, t, p, *_: (g, t, bi, 0)),
-    ])
-    kernel2 = functools.partial(
-        _grouped_phase2_kernel, ps=ps, qblk=qblk, rep=rep, scale=scale,
-        has_scale=has_scale, fp8=fp8)
-    grid2 = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(b, hkv, nqb, mp),
-        in_specs=p2_in,
-        out_specs=pl.BlockSpec((1, 1, qblk, 1, rep, d),
-                               lambda bi, g, t, p, *_:
-                               (bi, t, 0, g, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((qblk * rep, _LANES), jnp.float32),
-            pltpu.VMEM((qblk * rep, _LANES), jnp.float32),
-            pltpu.VMEM((qblk * rep, d), jnp.float32),
-        ],
-    )
-    from jax.experimental import disable_x64
-    with disable_x64():
-        prefetch = (page_table, pos, q_len, group_id, group_leader,
-                    group_cnt)
-        m1, l1, a1 = pl.pallas_call(
-            kernel1,
-            grid_spec=grid1,
-            out_shape=[
-                jax.ShapeDtypeStruct((hkv, nqb, rows, _LANES),
-                                     jnp.float32),
-                jax.ShapeDtypeStruct((hkv, nqb, rows, _LANES),
-                                     jnp.float32),
-                jax.ShapeDtypeStruct((hkv, nqb, rows, d), jnp.float32),
-            ],
-            compiler_params=pltpu.TPUCompilerParams(
-                dimension_semantics=("parallel", "arbitrary",
-                                     "arbitrary")),
-            interpret=_INTERPRET,
-        )(*prefetch, *p1_ops)
-        out = pl.pallas_call(
-            kernel2,
-            grid_spec=grid2,
-            out_shape=jax.ShapeDtypeStruct((b, nqb, qblk, hkv, rep, d),
-                                           q.dtype),
-            compiler_params=pltpu.TPUCompilerParams(
-                dimension_semantics=("parallel", "parallel",
-                                     "arbitrary", "arbitrary")),
-            interpret=_INTERPRET,
-        )(*prefetch, q6, *p1_ops[1:-1], m1, l1, a1)
-    return out.reshape(b, lq_pad, h, d)[:, :lq]
 
 
 def gqa_attend_reference(q, k, v, mask):
@@ -990,9 +722,13 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, pos,
     if mask is not None:
         mask = _mask_to_additive(mask, b, h, lmax)
     if _use_kernel():
-        return _paged_attention_kernel(
+        # the ragged walk at q_len 1: identical attend window (query 0
+        # sees keys j <= pos) and page order
+        if mask is not None:
+            mask = mask.reshape(b, h, 1, lmax)
+        return _ragged_attention_kernel(
             q, k_pool, v_pool, page_table.astype(jnp.int32), posv,
-            mask)
+            jnp.ones((b,), jnp.int32), mask)
     return paged_attention_reference(q, k_pool, v_pool, page_table,
                                      posv, mask)
 
@@ -1165,9 +901,9 @@ def ragged_paged_attention_grouped(q, k_pool, v_pool, page_table, pos,
     posv, qlv, gid, gld, gcn = _grouped_operands(
         b, pos, q_len, group_id, group_leader, group_cnt)
     if _use_kernel() and mask is None:
-        return _grouped_attention_kernel(
+        return _ragged_attention_kernel(
             q, k_pool, v_pool, page_table.astype(jnp.int32), posv, qlv,
-            gid, gld, gcn)
+            None, group=(gid, gld, gcn))
     return ragged_paged_attention(q, k_pool, v_pool, page_table, posv,
                                   qlv, mask)
 
@@ -1187,9 +923,9 @@ def ragged_paged_attention_grouped_q8(q, k_pool, v_pool, k_scale,
     ks = k_scale.astype(jnp.float32)
     vs = v_scale.astype(jnp.float32)
     if _use_kernel() and mask is None:
-        return _grouped_attention_kernel(
+        return _ragged_attention_kernel(
             q, k_pool, v_pool, page_table.astype(jnp.int32), posv, qlv,
-            gid, gld, gcn, k_scale=ks, v_scale=vs)
+            None, k_scale=ks, v_scale=vs, group=(gid, gld, gcn))
     return ragged_paged_attention_q8(q, k_pool, v_pool, ks, vs,
                                      page_table, posv, qlv, mask)
 
@@ -1323,8 +1059,7 @@ def _paged_scatter_kernel(pool, upd, pos, page_table):
         ],
         out_specs=pl.BlockSpec((1, h, d), lambda i, f: (f[i], 0, 0)),
     )
-    from jax.experimental import disable_x64
-    with disable_x64():
+    with _trace32():
         out = pl.pallas_call(
             _scatter_write_kernel,
             grid_spec=grid_spec,
@@ -1333,68 +1068,71 @@ def _paged_scatter_kernel(pool, upd, pos, page_table):
             # flattened-input indices COUNT the scalar-prefetch leaf:
             # flat=0, upd=1, pool=2 (the jax megablox gmm convention)
             input_output_aliases={2: 0},
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=_INTERPRET,
         )(flat.reshape(-1), upd.reshape(b * l, h, d), flat_pool)
     return out.reshape(pool.shape)
 
 
-def _scatter_q8_write_kernel(flat_ref, upd_ref, pool_ref, sc_pool_ref,
-                             code_ref, sc_ref):
+def _scatter_q8_write_kernel(flat_ref, upd_ref, pool_ref, code_ref,
+                             sc_ref):
     # quantize-on-write: the SAME expressions as quantize_kv_rowwise,
     # applied to this grid step's [1, H, D] tile while it is still in
-    # VMEM — codes and rowwise scales leave through the aliased pools
-    del flat_ref, pool_ref, sc_pool_ref
+    # VMEM — the codes leave through the aliased pool, the rowwise
+    # scales as a dense [1, 1, H] row of the step's own output
+    del flat_ref, pool_ref
     uf = upd_ref[...].astype(jnp.float32)
     amax = jnp.max(jnp.abs(uf), axis=-1)
     scale = jnp.maximum(amax, jnp.float32(1e-8)) \
         * jnp.float32(1.0 / 127.0)
     code_ref[...] = jnp.clip(jnp.round(uf / scale[..., None]),
                              -127, 127).astype(code_ref.dtype)
-    sc_ref[...] = scale.astype(sc_ref.dtype)
+    sc_ref[...] = scale[:, None, :].astype(sc_ref.dtype)
 
 
 def _paged_scatter_q8_kernel(pool, scale_pool, upd, pos, page_table):
     """Pallas quantize-then-scatter (the megakernel's q8 write stage):
     same prefetched-slot routing as _paged_scatter_kernel, with the
     rowwise int8 quantization fused into the write so the new token's
-    f32 K/V never round-trips HBM between projection and pool. Codes
-    and scales alias their pools; slot semantics as the fp kernel."""
+    f32 K/V never round-trips HBM between projection and pool. The
+    codes alias their pool; slot semantics as the fp kernel. A token's
+    H scales are one sub-tile row of the [P * ps, H] scale pool, which
+    no legal block can address, so the kernel returns the step's
+    scales densely ([B * l, 1, H], 1/D of the code bytes) and the same
+    XLA scatter as `paged_scatter_q8` lands them at the same slots."""
     b, l, h, d = upd.shape
     flat = _paged_flat_slots(pool.shape[1], pos, page_table, l)
     flat_pool = pool.reshape((-1,) + pool.shape[2:])
-    flat_sc = scale_pool.reshape((-1,) + scale_pool.shape[2:])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b * l,),
         in_specs=[
             pl.BlockSpec((1, h, d), lambda i, f: (i, 0, 0)),
             pl.BlockSpec((1, h, d), lambda i, f: (f[i], 0, 0)),
-            pl.BlockSpec((1, h), lambda i, f: (f[i], 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, h, d), lambda i, f: (f[i], 0, 0)),
-            pl.BlockSpec((1, h), lambda i, f: (f[i], 0)),
+            pl.BlockSpec((1, 1, h), lambda i, f: (i, 0, 0)),
         ],
     )
-    from jax.experimental import disable_x64
-    with disable_x64():
+    with _trace32():
         codes, scales = pl.pallas_call(
             _scatter_q8_write_kernel,
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct(flat_pool.shape, pool.dtype),
-                jax.ShapeDtypeStruct(flat_sc.shape, scale_pool.dtype),
+                jax.ShapeDtypeStruct((b * l, 1, h), scale_pool.dtype),
             ],
-            input_output_aliases={2: 0, 3: 1},
-            compiler_params=pltpu.TPUCompilerParams(
+            input_output_aliases={2: 0},
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=_INTERPRET,
-        )(flat.reshape(-1), upd.reshape(b * l, h, d), flat_pool,
-          flat_sc)
+        )(flat.reshape(-1), upd.reshape(b * l, h, d), flat_pool)
+    flat_sc = scale_pool.reshape((-1,) + scale_pool.shape[2:])
+    flat_sc = flat_sc.at[flat.reshape(-1)].set(scales.reshape(b * l, h))
     return (codes.reshape(pool.shape),
-            scales.reshape(scale_pool.shape))
+            flat_sc.reshape(scale_pool.shape))
 
 
 def lora_delta(x, a, b, scale):
@@ -1416,13 +1154,17 @@ def _lora_paged_kernel(page_ref, x_ref, a_ref, b_ref, s_ref, o_ref):
     x = x_ref[...]                                # [1, W, IN]
     a = a_ref[...].astype(x.dtype)                # [1, IN, R]
     bw = b_ref[...].astype(x.dtype)               # [1, R, OUT]
+    # Mosaic's matmul accumulates in 32 bits; round to x's dtype after
+    # each dot, as the reference einsums do
     t = jax.lax.dot_general(
         x[0], a[0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
         precision=_prec(x.dtype)).astype(x.dtype)
     d = jax.lax.dot_general(
         t, bw[0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
         precision=_prec(x.dtype)).astype(x.dtype)
-    s = s_ref[0, 0].astype(x.dtype)
+    s = s_ref[0, 0, 0].astype(x.dtype)
     o_ref[...] = (d * s).astype(o_ref.dtype)[None]
 
 
@@ -1438,8 +1180,9 @@ def lora_delta_paged(x, a_pool, b_pool, apage, ascale):
     adapter page streams through VMEM ONCE, the same trick the page
     walk plays with `page_table`, instead of XLA materializing a
     gathered [B, in, R] copy in HBM per projection. ascale rides as a
-    [B, 1] f32 VMEM operand (f32 can't share the int32 scalar-prefetch
-    lane). Off-TPU the forward IS gather + `lora_delta` — bit-identical
+    [B, 1, 1] f32 VMEM operand (f32 can't share the int32
+    scalar-prefetch lane; the two unit minor dims make a one-row block
+    legal). Off-TPU the forward IS gather + `lora_delta` — bit-identical
     to the unfused in-trace path by construction."""
     ap = apage.astype(jnp.int32)
     sc = ascale.astype(jnp.float32)
@@ -1456,20 +1199,19 @@ def lora_delta_paged(x, a_pool, b_pool, apage, ascale):
             pl.BlockSpec((1, w, cin), lambda i, p: (i, 0, 0)),
             pl.BlockSpec((1, cin, r), lambda i, p: (p[i], 0, 0)),
             pl.BlockSpec((1, r, cout), lambda i, p: (p[i], 0, 0)),
-            pl.BlockSpec((1, 1), lambda i, p: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, p: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, w, cout), lambda i, p: (i, 0, 0)),
     )
-    from jax.experimental import disable_x64
-    with disable_x64():
+    with _trace32():
         out = pl.pallas_call(
             _lora_paged_kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((bsz, w, cout), x.dtype),
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=_INTERPRET,
-        )(ap, x, a_pool, b_pool, sc.reshape(bsz, 1))
+        )(ap, x, a_pool, b_pool, sc.reshape(bsz, 1, 1))
     return out
 
 
@@ -1558,12 +1300,15 @@ def megakernel_decode_q8(q, k_new, v_new, k_pool, v_pool,
     return out, k_pool, v_pool, k_scale_pool, v_scale_pool
 
 
+_ARGMAX_ROWS = 8
+
+
 def _argmax_epilogue_kernel(x_ref, o_ref):
-    # one grid step per batch row; the whole vocab row rides one VMEM
-    # block (V f32 « VMEM), so the reduction never leaves the tile.
+    # one grid step per sublane tile of 8 rows; each whole vocab row
+    # rides the VMEM block, so the reduction never leaves the tile.
     # first-max tie-breaking == jnp.argmax: min index among positions
     # equal to the row max
-    x = x_ref[...].astype(jnp.float32)               # [1, V]
+    x = x_ref[...].astype(jnp.float32)               # [8, V]
     m = jnp.max(x, axis=1, keepdims=True)
     idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     first = jnp.min(jnp.where(x == m, idx, x.shape[1]), axis=1)
@@ -1577,19 +1322,22 @@ def decode_greedy_argmax(logits):
     [B] (gated with the megakernel): on TPU/interpret the argmax
     reduces on-tile in a Pallas kernel (first-occurrence tie-breaking,
     bit-identical to jnp.argmax); off-TPU it IS jnp.argmax — the
-    exact expression the unfused sampler computes."""
+    exact expression the unfused sampler computes. Rows go through in
+    blocks of 8 (a ragged last block reads padding whose results are
+    dropped on the write)."""
     if not _use_kernel():
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     b, v = logits.shape
-    from jax.experimental import disable_x64
-    with disable_x64():
+    with _trace32():
         out = pl.pallas_call(
             _argmax_epilogue_kernel,
-            grid=(b,),
-            in_specs=[pl.BlockSpec((1, v), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((1, _LANES), lambda i: (i, 0)),
+            grid=(pl.cdiv(b, _ARGMAX_ROWS),),
+            in_specs=[pl.BlockSpec((_ARGMAX_ROWS, v),
+                                   lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((_ARGMAX_ROWS, _LANES),
+                                   lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((b, _LANES), jnp.int32),
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=_INTERPRET,
         )(logits)
@@ -1636,8 +1384,10 @@ def count_page_block_reads(page_table, pos, q_len, group_id=None,
     Tensor-parallel serving (ServingEngine(mesh=...)): pass the
     model's `n_kv` and the mesh's `mp` degree and the counts become
     what ONE CHIP issues per layer — each of the mp shards walks only
-    its n_kv/mp local heads (the kernel's kv_head grid axis is what
-    shards), and each block read moves a 1/mp page slice, so per-chip
+    its n_kv/mp local heads (the heads are the batch dimension of
+    the in-kernel dots; the count stays per head though one block now
+    carries a page's local heads together), and each block read moves
+    a 1/mp page slice, so per-chip
     reads (and the grouped walk's per-chip reads SAVED) drop by mp.
     The defaults (n_kv=1, mp=1) keep the single-walk numbers every
     pre-mesh pin was written against.

@@ -71,7 +71,9 @@ import jax.numpy as jnp
 
 from ..core import dtype as dtypes
 from ..core.tensor import Tensor
-from ..nlp.generation import _pack_caches, _unpack_caches
+from ..nlp.generation import (_StepProgram, _pack_caches,
+                              _restore_state, _swap_state,
+                              _unpack_caches)
 from .paging import PagePool, TRASH_PAGE, pages_needed
 
 __all__ = ["DraftConfig", "DraftEngine", "make_draft_model"]
@@ -256,18 +258,13 @@ class DraftEngine:
             finally:
                 self._restore_state(originals)
 
-        return jax.jit(lambda ct, pos, pt, tokens, q_len: dstep(
-            state_vals, ct, pos, pt, tokens, q_len))
+        return _StepProgram(dstep, state_vals)
 
     def _swap_state(self, state_vals):
-        originals = [t._value for t in self._state_tensors]
-        for t, v in zip(self._state_tensors, state_vals):
-            t._value = v
-        return originals
+        return _swap_state(self._state_tensors, state_vals)
 
     def _restore_state(self, originals):
-        for t, v in zip(self._state_tensors, originals):
-            t._value = v
+        _restore_state(self._state_tensors, originals)
 
     def _micro_step(self, tokens: np.ndarray,
                     q_len: np.ndarray) -> np.ndarray:

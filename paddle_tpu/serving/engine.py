@@ -170,9 +170,15 @@ preempt-swap-resume cycles. (With kv_dtype="int8" the oracle is the
 int8 engine itself: feature gates stay token-identical, fp drift is
 bounded, not zero.)
 
-Weights enter both programs as closed-over constants (the measured
-layout win of generation.py's _build); construct the engine AFTER any
-weight rebinding (quantization etc.) — it snapshots model state.
+Weights enter every compiled program as its first ARGUMENT
+(`_StepProgram`), never as closed-over constants: at 1.3B constants
+put 2.6 GB of literals into the executable — a second copy of the
+weights in device memory, a compile of 291 s against 34 s, and an HLO
+module past protobuf's 2 GiB limit, so `as_text()` (the collective
+and cost censuses) fails (compiles for a described v5e, PR 23) — and
+an ahead-of-time compile could not be handed shapes. Construct the
+engine AFTER any weight rebinding (quantization etc.) — it snapshots
+model state.
 """
 from __future__ import annotations
 
@@ -192,7 +198,8 @@ from ..core import tensor as tensor_mod
 from ..core.dispatch import get_op
 from ..core.tensor import Tensor, set_dispatch_probe
 from ..profiler import RecordEvent
-from ..nlp.generation import (_pack_caches, _top_p_filter,
+from ..nlp.generation import (_StepProgram, _pack_caches,
+                              _restore_state, _swap_state, _top_p_filter,
                               _unpack_caches, decode_model_step,
                               resolve_paged_attn_impl, FP8_DTYPE)
 from ..ops.pallas.paged_attention import (count_page_block_reads,
@@ -577,12 +584,12 @@ class ServingEngine:
         self._clock = clock
         self._id_counter = itertools.count()
         self._requests: Dict[str, Request] = {}
-        # model-state snapshot: weights are constants in the compiled
-        # programs (see module doc)
+        # model-state snapshot: weights are the compiled programs'
+        # first operand (see module doc)
         params = list(model.parameters())
         buffers = [b for _, b in model.named_buffers()]
         self._state_tensors = params + buffers
-        # the weight values the compiled programs close over: on a
+        # the weight values the compiled programs take: on a
         # mesh, the engine's OWN sharded copies (QKV projections
         # column-parallel over heads, the rest replicated) — the
         # model's tensors are never rebound, so oracles and other
@@ -727,6 +734,7 @@ class ServingEngine:
         # through _unpack_caches (see serving/tp.py): replicate — the
         # single per-layer all-gather point
         self._out_shard = None if self.tp is None else self.tp.rep
+        self._mesh = None if self.tp is None else self.tp.mesh
         self._pos = jnp.zeros((self.num_slots,), jnp.int32)
         if self.tp is not None:
             self._pos = self.tp.replicate(self._pos)
@@ -995,14 +1003,10 @@ class ServingEngine:
 
     # -- compiled programs -------------------------------------------------
     def _swap_state(self, state_vals):
-        originals = [t._value for t in self._state_tensors]
-        for t, v in zip(self._state_tensors, state_vals):
-            t._value = v
-        return originals
+        return _swap_state(self._state_tensors, state_vals)
 
     def _restore_state(self, originals):
-        for t, v in zip(self._state_tensors, originals):
-            t._value = v
+        _restore_state(self._state_tensors, originals)
 
     def _build_prefill(self, bucket: int):
         """Compiled once per chunk BUCKET (not per prompt length): a
@@ -1039,10 +1043,7 @@ class ServingEngine:
             finally:
                 self._restore_state(originals)
 
-        return jax.jit(
-            lambda ct, pos, ll, pt, tokens, slot, start, new_pos,
-            last_idx: prefill(state_vals, ct, pos, ll, pt, tokens, slot,
-                              start, new_pos, last_idx))
+        return _StepProgram(prefill, state_vals, self._mesh)
 
     def _build_decode(self):
         """ONE fixed-shape step for all slots: sample from held logits
@@ -1071,8 +1072,7 @@ class ServingEngine:
             finally:
                 self._restore_state(originals)
 
-        return jax.jit(lambda ct, pos, ll, pt, key, t, k, p, g, a: step(
-            state_vals, ct, pos, ll, pt, key, t, k, p, g, a))
+        return _StepProgram(step, state_vals, self._mesh)
 
     def _build_unified(self):
         """THE one compiled ragged prefill+decode+verify step: a
@@ -1230,7 +1230,7 @@ class ServingEngine:
         gram_on = self.grammar_on
         gram_ver = self.grammar_on and self.spec is not None
 
-        def call(ct, *args):
+        def call(state_vals, ct, *args):
             base, rest = args[:11], args[11:]
             i = 0
             lora = None
@@ -1249,7 +1249,7 @@ class ServingEngine:
                 gver = rest[i]
             return ustep(state_vals, ct, *base, group=group,
                          lora=lora, gsamp=gsamp, gver=gver)
-        return jax.jit(call)
+        return _StepProgram(call, state_vals, self._mesh)
 
     def _build_embed(self):
         """Embeddings-lane epilogue: ONE jitted batched single-token
@@ -1278,8 +1278,7 @@ class ServingEngine:
             finally:
                 self._restore_state(originals)
 
-        return jax.jit(lambda ct, pos, pt, tokens: estep(
-            state_vals, ct, pos, pt, tokens))
+        return _StepProgram(estep, state_vals, self._mesh)
 
     def _model_backbone(self):
         """The hidden-state trunk under the causal-LM wrapper (GPT:
@@ -3223,13 +3222,21 @@ class ServingEngine:
             raise ValueError(
                 "collective_counts() needs a mesh engine "
                 "(ServingEngine(mesh=...) / PADDLE_TPU_MESH)")
+        return collective_counts(
+            self.lowered_unified_step().compile().as_text())
+
+    def lowered_unified_step(self):
+        """The ONE unified step, lowered against the exact operands
+        (shapes, shardings) its last dispatch used: `.as_text()` is
+        the program as handed to the compiler (a Pallas kernel shows
+        as `tpu_custom_call`), `.compile().as_text()` the optimized
+        HLO. Requires that at least one unified step has run."""
         if self._unified_fn is None or self._unified_args_tail is None:
             raise ValueError(
-                "collective_counts(): no unified step has run yet — "
-                "serve at least one request first")
-        txt = self._unified_fn.lower(
-            self._ct, *self._unified_args_tail).compile().as_text()
-        return collective_counts(txt)
+                "lowered_unified_step(): no unified step has run yet "
+                "— serve at least one request first")
+        return self._unified_fn.lower(self._ct,
+                                      *self._unified_args_tail)
 
     # -- conveniences ------------------------------------------------------
     @property

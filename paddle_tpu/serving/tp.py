@@ -22,8 +22,10 @@ unified ragged step is sharded with GSPMD, not rewritten:
   REPLICATED and completely unchanged: sharding is pure data-plane.
 
 The ragged paged-attention walk treats `kv_head` as an independent
-axis (the Pallas kernel iterates it as its own grid dimension), so
-each chip's page walk needs NO cross-chip traffic: scatter writes land
+axis (the batch dimension of the Pallas kernel's dots; on a mesh the
+kernel runs per device over its own heads — GSPMD cannot partition a
+Mosaic kernel, see `ops/pallas.kernel_mesh`), so each chip's page
+walk needs NO cross-chip traffic: scatter writes land
 on the chip that owns the head slice, each shard's online softmax
 folds only its own heads, and the one place shards meet is the
 attention OUTPUT — `DecodeCache.out_shard` constrains it back to
@@ -114,8 +116,10 @@ class ServingTP:
         # paged KV pools [num_pages, page_size, H_kv, D] and the int8
         # lane's scale pools [num_pages, page_size, H_kv]: shard the
         # KV-HEAD axis — each chip owns a 1/mp slice of EVERY page
-        self.pool_shard = NamedSharding(self.mesh,
-                                        P(None, None, "mp", None))
+        # (no trailing None: it is the spelling the compiled step's
+        # outputs come back with, so the pools a step returns hit the
+        # same jit cache entry as the pools it was first given)
+        self.pool_shard = NamedSharding(self.mesh, P(None, None, "mp"))
         self.scale_shard = NamedSharding(self.mesh, P(None, None, "mp"))
         self._col = NamedSharding(self.mesh, P(None, "mp"))
         self._vec = NamedSharding(self.mesh, P("mp"))

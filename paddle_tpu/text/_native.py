@@ -1,8 +1,9 @@
 """Build + bind the native tokenizer core (ctypes, no pybind11).
 
 Compiles _fast_tokenizer.c with the system compiler on first use and
-caches the .so under ~/.cache/paddle_tpu, keyed by the source hash
-(atomic publish, safe for concurrent builders). Import never fails:
+keeps the .so next to the source (git ignores `*.so`), keyed by the
+source hash (atomic publish, safe for concurrent builders). Nothing
+outside the checkout is read or written. Import never fails:
 callers check `available()` and fall back to the pure-Python path.
 """
 from __future__ import annotations
@@ -16,11 +17,10 @@ import tempfile
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "_fast_tokenizer.c")
-# cache in a user-writable dir (read-only site-packages installs can't
-# take a .so next to the source; binaries also stay out of the repo).
-# The filename is keyed by the SOURCE HASH so different checkouts/
-# versions sharing the cache dir never load each other's binaries.
-_CACHE = os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu")
+# built inside the checkout, from the tracked source. The filename is
+# keyed by the SOURCE HASH so a stale binary is never loaded after the
+# source changes.
+_CACHE = _DIR
 
 
 def _so_path():

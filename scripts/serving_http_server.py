@@ -99,7 +99,11 @@ def main():
     from paddle_tpu.serving import ServingEngine
     from paddle_tpu.serving.http import serve
 
-    on_tpu = jax.devices()[0].platform == "tpu"
+    # sized by what the caller ASKED for, never by what JAX finds
+    import bench
+    from paddle_tpu.utils.compile_cache import use_compile_cache
+    use_compile_cache()
+    on_tpu = bench.tpu_expected()
     model, cfg = build_model(on_tpu)
     max_len = args.max_len or (1024 if on_tpu else 128)
     chunk = args.chunk or (128 if on_tpu else 32)

@@ -3,10 +3,8 @@
 Mirrors the reference's strategy of testing device-independent plumbing on
 fake backends (SURVEY.md §4: fake_cpu_device.h, ProcessGroupGloo): all
 sharding/parallelism tests run on 8 virtual CPU devices so no TPU pod is
-needed.
-
-Note: the env var JAX_PLATFORMS is not enough on machines where an
-accelerator PJRT plugin overrides it — jax.config.update is authoritative.
+needed. The platform is pinned with jax.config.update, so the suite
+stays on the CPU whatever JAX_PLATFORMS says and never takes a chip.
 """
 import os
 
@@ -14,6 +12,10 @@ flags = os.environ.get("XLA_FLAGS", "")
 if "host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
         flags + " --xla_force_host_platform_device_count=8").strip()
+
+# the suite asks for the CPU, in the way the entry scripts look for
+# (bench.cpu_requested) as well as in JAX's own
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax  # noqa: E402
 
@@ -27,20 +29,12 @@ jax.config.update("jax_platforms", "cpu")
 # the in-memory jit trace counts the retrace probes assert on are
 # untouched). Opt out with PADDLE_TPU_TEST_NO_COMPILE_CACHE=1.
 if not os.environ.get("PADDLE_TPU_TEST_NO_COMPILE_CACHE"):
-    import tempfile
-
-    _cache_dir = os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(tempfile.gettempdir(), "paddle_tpu_t1_xla_cache"))
-    try:
-        jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        # Only executables that took >= 1s to compile are persisted:
-        # that captures every serving unified-step program (the whales)
-        # while skipping the long tail of tiny layer/RNN executables.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          1.0)
-    except Exception:  # older jax without the knobs: cache is a bonus
-        pass
+    from paddle_tpu.utils.compile_cache import use_compile_cache
+    use_compile_cache()
+    # Only executables that took >= 1s to compile are persisted:
+    # that captures every serving unified-step program (the whales)
+    # while skipping the long tail of tiny layer/RNN executables.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 import pytest  # noqa: E402
 

@@ -1,6 +1,6 @@
 """Launcher payload: every eager collective primitive exercised with
 DIVERGENT per-rank values, results checked against numpy on both ranks
-(VERDICT r2 item 1 — reference semantics:
+(review r2 item 1 — reference semantics:
 python/paddle/distributed/collective.py:174, ProcessGroup.h:52)."""
 import os
 import re

@@ -1,7 +1,7 @@
 """Pallas flash-attention kernel parity (interpret mode on CPU; the
 same kernels compile under Mosaic on TPU).
 
-Covers VERDICT r2 item 3: additive bias masks, key-padding vector
+Covers review r2 item 3: additive bias masks, key-padding vector
 masks (the BERT path), and in-kernel dropout — forward AND backward —
 against a plain-jnp oracle that shares the kernel's position-hash keep
 mask (reference semantics: fused_attention_op.cu / fmha_ref.h

@@ -638,6 +638,58 @@ class TestDrainAndAbort:
         assert list(r.stream()) == r.output_tokens
 
 
+def test_replicas_sharing_a_model_trace_concurrently():
+    """Engine programs take the weights as arguments, so tracing one
+    swaps TRACERS into the model's tensors. Replicas that share a
+    model trace their first step from their own threads, next to the
+    solo generator reading the same tensors: without the process-wide
+    swap lock one thread restores another's tracers into the model
+    (UnexpectedTracerError, or a poisoned request). More threads than
+    the race needs, a short switch interval, every join bounded."""
+    import sys
+    import threading
+    model = tiny_gpt()
+    prompts = [np.arange(3 + i, 9 + i) for i in range(4)]
+    engines = [ServingEngine(model, num_slots=2, max_len=64,
+                             page_size=8, chunk_len=16)
+               for _ in prompts]
+    got, errors = [None] * len(prompts), []
+
+    def first_step(i):
+        try:
+            out = engines[i].generate(
+                [prompts[i]], SamplingParams(max_new_tokens=6))
+            got[i] = list(out[0].token_ids)
+        except Exception as e:  # noqa: BLE001 - reported below
+            errors.append(repr(e))
+
+    def solo(i):
+        try:
+            got.append(list(oracle_greedy(model, prompts[i], 6)))
+        except Exception as e:  # noqa: BLE001 - reported below
+            errors.append(repr(e))
+
+    threads = [threading.Thread(target=first_step, args=(i,))
+               for i in range(len(prompts))] + \
+        [threading.Thread(target=solo, args=(0,))]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors
+    import jax
+    assert not any(isinstance(p._value, jax.core.Tracer)
+                   for p in model.parameters())
+    for i, p in enumerate(prompts):
+        assert got[i] == list(oracle_greedy(model, p, 6)), i
+
+
 def test_serving_bench_smoke_writes_stable_schema(tmp_path,
                                                   monkeypatch):
     """`serving_bench.py --smoke` in-process: one JSON line + a

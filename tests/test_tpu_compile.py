@@ -1,0 +1,267 @@
+"""Ahead-of-time compiles for a described TPU v5e — no chip attached.
+
+Interpret mode never checks what Mosaic checks: block shapes against the
+(8, 128) tiling, VMEM, 32-bit matmul accumulators. Every `pallas_call` the
+server and the trainer reach is compiled here at real widths (GPT-3 1.3B
+serving: 16 heads x 128, vocab 50304, page_size 16, 2048 pages, 8 slots x
+chunk 128 and decode rows; GPT-124M training: bs 16 x 1024, 12 x 64), and
+must come out as a `tpu_custom_call`.
+
+This is the ONE file that describes a topology, and it does so inside a
+module-scoped fixture: only one process may load the TPU library, the
+driver runs several xdist workers, and every worker imports every test
+file. Never describe a topology at import, in a `skipif`, in
+`parametrize` or in conftest.py (on-chip-measurement guide, section 2).
+"""
+import os
+
+import pytest
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import layer_norm as pln
+from paddle_tpu.ops.pallas import paged_attention as pa
+
+B, H, D, PS, PAGES, MP, CHUNK, VOCAB = 8, 16, 128, 16, 2048, 128, 128, 50304
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def real_kernels(monkeypatch):
+    """Not interpret mode, whatever an earlier test file asked for
+    (test_pallas_layer_norm.py sets PADDLE_TPU_PALLAS_INTERPRET at
+    import, and the kernel modules read it when THEY are imported)."""
+    for mod in (fa, pln, pa):
+        monkeypatch.setattr(mod, "_INTERPRET", False)
+
+
+@pytest.fixture
+def on_tpu_branch(monkeypatch):
+    """The public ops pick their kernel branch from `jax.devices()`,
+    which is the CPU here: steer them as the chip would."""
+    monkeypatch.setattr(pa, "_use_kernel", lambda: True)
+
+
+def _compiles_to_kernel(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _q(lq):
+    return ((B, lq, H, D), BF16)
+
+
+def _pool(dt):
+    return ((PAGES, PS, H, D), dt)
+
+
+SCALES = ((PAGES, PS, H), F32)
+TABLE = ((B, MP), I32)
+ROW = ((B,), I32)
+
+
+@pytest.mark.parametrize("lq", [CHUNK, 1])
+def test_paged_walk_bf16(one_chip, lq):
+    _compiles_to_kernel(
+        lambda q, k, v, t, p, n: pa._ragged_attention_kernel(
+            q, k, v, t, p, n, None),
+        one_chip, _q(lq), _pool(BF16), _pool(BF16), TABLE, ROW, ROW)
+
+
+@pytest.mark.parametrize("lq", [CHUNK, 1])
+def test_paged_walk_int8(one_chip, lq):
+    _compiles_to_kernel(
+        lambda q, k, v, ks, vs, t, p, n: pa._ragged_attention_kernel(
+            q, k, v, t, p, n, None, k_scale=ks, v_scale=vs),
+        one_chip, _q(lq), _pool(I8), _pool(I8), SCALES, SCALES, TABLE,
+        ROW, ROW)
+
+
+@pytest.mark.parametrize("lq", [CHUNK, 1])
+def test_paged_walk_fp8(one_chip, lq):
+    _compiles_to_kernel(
+        lambda q, k, v, t, p, n: pa._ragged_attention_kernel(
+            q, k, v, t, p, n, None),
+        one_chip, _q(lq), _pool(pa.FP8_DTYPE), _pool(pa.FP8_DTYPE),
+        TABLE, ROW, ROW)
+
+
+@pytest.mark.parametrize("lq", [CHUNK, 1])
+def test_paged_walk_user_mask(one_chip, lq):
+    _compiles_to_kernel(
+        lambda q, k, v, t, p, n, m: pa._ragged_attention_kernel(
+            q, k, v, t, p, n, m),
+        one_chip, _q(lq), _pool(BF16), _pool(BF16), TABLE, ROW, ROW,
+        ((B, H, lq, MP * PS), F32))
+
+
+@pytest.mark.parametrize("lq", [CHUNK, 1])
+def test_grouped_walk_bf16(one_chip, on_tpu_branch, lq):
+    # through the public op: both phases are in the program
+    text = _compiles_to_kernel(
+        pa.ragged_paged_attention_grouped, one_chip, _q(lq), _pool(BF16),
+        _pool(BF16), TABLE, ROW, ROW, ROW, ROW, ROW)
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_grouped_walk_int8(one_chip, on_tpu_branch):
+    _compiles_to_kernel(
+        pa.ragged_paged_attention_grouped_q8, one_chip, _q(CHUNK),
+        _pool(I8), _pool(I8), SCALES, SCALES, TABLE, ROW, ROW, ROW, ROW,
+        ROW)
+
+
+def test_paged_decode_attention(one_chip, on_tpu_branch):
+    # the l == 1 decode op: the ragged walk at q_len 1
+    _compiles_to_kernel(pa.paged_decode_attention, one_chip, _q(1),
+                        _pool(BF16), _pool(BF16), TABLE, ROW)
+
+
+@pytest.mark.parametrize("l", [CHUNK, 1])
+@pytest.mark.parametrize("dt", [BF16, pa.FP8_DTYPE], ids=["bf16", "fp8"])
+def test_paged_scatter(one_chip, l, dt):
+    _compiles_to_kernel(pa._paged_scatter_kernel, one_chip, _pool(dt),
+                        ((B, l, H, D), BF16), ROW, TABLE)
+
+
+@pytest.mark.parametrize("l", [CHUNK, 1])
+def test_paged_scatter_q8(one_chip, l):
+    _compiles_to_kernel(pa._paged_scatter_q8_kernel, one_chip, _pool(I8),
+                        SCALES, ((B, l, H, D), BF16), ROW, TABLE)
+
+
+@pytest.mark.parametrize("rows", [B, B * 5, 5],
+                         ids=["slots", "verify_BxW", "ragged_rows"])
+@pytest.mark.parametrize("dt", [F32, BF16], ids=["f32", "bf16"])
+def test_argmax_epilogue(one_chip, on_tpu_branch, rows, dt):
+    _compiles_to_kernel(pa.decode_greedy_argmax, one_chip,
+                        ((rows, VOCAB), dt))
+
+
+def test_spec_verify_accept(one_chip, on_tpu_branch):
+    _compiles_to_kernel(pa.spec_verify_accept, one_chip,
+                        ((B, 5, VOCAB), F32), ((B, 5), I32), ROW,
+                        ((B,), jnp.bool_))
+
+
+@pytest.mark.parametrize("rank,out", [(16, 2048), (64, 2048), (64, 6144)])
+def test_lora_delta_paged(one_chip, on_tpu_branch, rank, out):
+    _compiles_to_kernel(
+        pa.lora_delta_paged, one_chip, ((B, CHUNK, 2048), BF16),
+        ((33, 2048, rank), BF16), ((33, rank, out), BF16), ROW,
+        ((B,), F32))
+
+
+def _flash_loss(q, k, v):
+    return fa.flash_attention_blhd(q, k, v, causal=True) \
+        .astype(F32).sum()
+
+
+@pytest.mark.parametrize("b,l,h,d", [(16, 1024, 12, 64),
+                                     (2, 2048, 16, 128)],
+                         ids=["gpt124m_12x64", "gpt1p3b_16x128"])
+def test_flash_attention_fwd_bwd(one_chip, b, l, h, d):
+    qkv = ((b, l, h, d), BF16)
+    _compiles_to_kernel(
+        lambda q, k, v: fa.flash_attention_blhd(q, k, v, causal=True),
+        one_chip, qkv, qkv, qkv)
+    text = _compiles_to_kernel(jax.grad(_flash_loss, argnums=(0, 1, 2)),
+                               one_chip, qkv, qkv, qkv)
+    assert text.count("tpu_custom_call") >= 3      # fwd, dq, dkv
+
+
+def test_flash_attention_mask_dropout(one_chip):
+    b, l, h, d = 4, 1024, 12, 64
+    qkv = ((b, l, h, d), BF16)
+
+    def loss(q, k, v, kvec, seeds):
+        return fa.flash_attention_blhd(
+            q, k, v, None, kvec, seeds, dropout_p=0.1).astype(F32).sum()
+
+    _compiles_to_kernel(jax.grad(loss, argnums=(0, 1, 2)), one_chip, qkv,
+                        qkv, qkv, ((b, l), F32), ((2,), I32))
+
+
+@pytest.mark.parametrize("rows,c", [(16 * 1024, 768), (8 * CHUNK, 2048)],
+                         ids=["gpt124m_768", "gpt1p3b_2048"])
+def test_fused_layer_norm_fwd_bwd(one_chip, rows, c):
+    shapes = (((rows, c), BF16), ((c,), BF16), ((c,), BF16))
+    _compiles_to_kernel(pln.layer_norm_fused, one_chip, *shapes)
+    _compiles_to_kernel(
+        jax.grad(lambda x, w, b: pln.layer_norm_fused(x, w, b)
+                 .astype(F32).sum(), argnums=(0, 1, 2)),
+        one_chip, *shapes)
+
+
+# -- four chips: the tensor-parallel serving replica's mesh ----------------
+# GSPMD cannot partition a Mosaic kernel, so under a mesh every kernel
+# wrapper runs per device (ops/pallas `kernel_mesh` / `per_device`). A
+# virtual CPU mesh never shows this: off-TPU the jnp references run.
+
+@pytest.mark.parametrize("dp,mp", [(1, 4), (2, 2)])
+def test_kernels_under_serving_mesh(topo, on_tpu_branch, monkeypatch,
+                                    dp, mp):
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle_tpu.nn.functional import norm as fnorm
+    from paddle_tpu.ops.pallas import kernel_mesh
+    monkeypatch.setattr(fnorm, "_use_pallas_ln", lambda: True)
+    mesh = Mesh(np.asarray(topo.devices).reshape(dp, mp), ("dp", "mp"))
+
+    def shaped(shape_dtype, spec):
+        return jax.ShapeDtypeStruct(*shape_dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    heads, whole = P(None, None, "mp", None), P()
+    row = shaped(ROW, whole)
+    walk_args = [shaped(_q(CHUNK), heads), shaped(_pool(BF16), heads),
+                 shaped(_pool(BF16), heads), shaped(TABLE, whole)] \
+        + [row] * 5
+    with kernel_mesh(mesh, "mp"):
+        # GSPMD alone refuses the kernel ...
+        with pytest.raises(Exception, match="shard_map"):
+            jax.jit(pa._ragged_attention_local).lower(
+                *walk_args[:6], None).compile()
+        # ... per device it compiles, and the one collective is the
+        # all-gather of the head-sharded output
+        text = jax.jit(lambda *a: jax.lax.with_sharding_constraint(
+            pa.ragged_paged_attention_grouped(*a),
+            NamedSharding(mesh, whole))).lower(*walk_args) \
+            .compile().as_text()
+        assert text.count("tpu_custom_call") >= 2
+        assert "all-gather" in text and "all-reduce" not in text
+        text = jax.jit(lambda x, w, b: fnorm._ln_fwd(x, w, b, 1, 1e-5)) \
+            .lower(shaped(((B, CHUNK, 2048), BF16), whole),
+                   shaped(((2048,), BF16), whole),
+                   shaped(((2048,), BF16), whole)).compile().as_text()
+        assert "tpu_custom_call" in text
