@@ -21,8 +21,14 @@ import jax.numpy as jnp
 from ..core.tensor import Tensor, Parameter
 from ..core import random as random_mod
 from ..core import dtype as dtypes
+from ..profiler import RecordEvent
 
 __all__ = ["compile_train_step", "CompiledTrainStep"]
+
+# host span of one call (`profiler.RecordEvent`: in a JAX profiler trace
+# it sits on the clock of the device's `XLA Ops`): the launch of the one
+# compiled program, for an XProf capture against a running job
+SPAN_STEP = "train::step"
 
 
 class CompiledTrainStep:
@@ -237,9 +243,10 @@ class CompiledTrainStep:
         p_vals = [p._value for p in self.params]
         b_vals = [b._value for b in self.buffers]
         try:
-            loss, new_p, new_b, new_s, new_g = self._step(
-                p_vals, b_vals, self.states, self.gstate, lr, key,
-                *batch_vals)
+            with RecordEvent(SPAN_STEP):
+                loss, new_p, new_b, new_s, new_g = self._step(
+                    p_vals, b_vals, self.states, self.gstate, lr, key,
+                    *batch_vals)
         except NotImplementedError as e:
             if "Mosaic kernels cannot be automatically partitioned" \
                     in str(e):

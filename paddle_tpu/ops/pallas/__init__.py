@@ -29,6 +29,25 @@ def trace32():
     return _jax.enable_x64(False)
 
 
+# The device trace names a Mosaic call by its HLO text, not by the Python
+# function behind it, so every `pallas_call` site carries a fixed name:
+# `name=` puts it into the instruction's name scope and `kernel_name`,
+# `metadata=` into `frontend_attributes={kernel_metadata=...}` of the
+# compiled instruction, which is the text a trace reader searches for
+# `ptk:<name>`. Names live in one `KERNELS` table at the top of each
+# kernel file; none may contain another (a reader's needle is a
+# substring). `fn` keeps the kernel function's own name in the lowered
+# text, where start-up checks look for it.
+KERNEL_TAG = "ptk:"
+
+
+def kernel_id(name, fn):
+    """The `name=` / `metadata=` keywords of one `pallas_call` site:
+    `name` is the site's trace name, `fn` the kernel function's name."""
+    return {"name": name,
+            "metadata": {"kernel": KERNEL_TAG + name, "fn": fn}}
+
+
 # A Mosaic kernel cannot be partitioned by GSPMD ("Mosaic kernels cannot
 # be automatically partitioned. Please wrap the call in a shard_map"), on
 # real chips only: off-TPU the jnp references partition like any XLA op,

@@ -39,12 +39,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from . import trace32 as _trace32
+from . import kernel_id as _kernel_id, trace32 as _trace32
 
 import os
 
 # interpret mode: run kernels on CPU for testing (conftest sets this)
 _INTERPRET = os.environ.get("PADDLE_TPU_PALLAS_INTERPRET", "0") == "1"
+
+# trace name -> pallas_call keywords, one entry per call site in this
+# file (ops/pallas/__init__.py `kernel_id`); no name contains another
+KERNELS = {name: _kernel_id(name, fn) for name, fn in (
+    ("flash_fwd", "_fa_kernel"),
+    ("flash_dq", "_fa_dq_kernel"),
+    ("flash_dkv", "_fa_dkv_kernel"),
+)}
 
 def _prec(dt):
     # 'highest' (the package-wide default) is invalid for bf16 operands
@@ -518,6 +526,7 @@ def _flash_fwd_bhld(q, k, v, bias, kvec, seeds, h, causal, scale,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_INTERPRET,
+            **KERNELS["flash_fwd"],
         )(*seed_ops, qp, kp, vp, *mask_ops)
     return out[:, :lq], lse
 
@@ -580,6 +589,7 @@ def _flash_bwd_bhld(q, k, v, o, lse, do, bias, kvec, seeds, h, causal,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_INTERPRET,
+            **KERNELS["flash_dq"],
         )(*seed_ops, qp, kp, vp, dop, lse, di, *mask_ops)
 
     # dkv grid: (bh, n_k, n_q) — q is the sequential (accumulated) axis
@@ -623,6 +633,7 @@ def _flash_bwd_bhld(q, k, v, o, lse, do, bias, kvec, seeds, h, causal,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_INTERPRET,
+            **KERNELS["flash_dkv"],
         )(*seed_ops, kp, vp, qp, dop, lse, di, *mask_ops2)
 
     return dq[:, :lq], dk[:, :lk], dv[:, :lk]

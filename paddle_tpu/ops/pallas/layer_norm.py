@@ -26,9 +26,16 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from . import trace32 as _trace32
+from . import kernel_id as _kernel_id, trace32 as _trace32
 
 _INTERPRET = os.environ.get("PADDLE_TPU_PALLAS_INTERPRET", "0") == "1"
+
+# trace name -> pallas_call keywords, one entry per call site in this
+# file (ops/pallas/__init__.py `kernel_id`); no name contains another
+KERNELS = {name: _kernel_id(name, fn) for name, fn in (
+    ("layer_norm_fwd", "_ln_fwd_kernel"),
+    ("layer_norm_bwd", "_ln_bwd_kernel"),
+)}
 
 DEFAULT_BLOCK_R = 256
 
@@ -127,6 +134,7 @@ def _fwd_call(x2, w, b, br, c, n, eps):
         out_specs=pl.BlockSpec((br, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x2.dtype),
         interpret=_INTERPRET,
+        **KERNELS["layer_norm_fwd"],
     )(x2, w.reshape(1, c), b.reshape(1, c))
 
 
@@ -160,6 +168,7 @@ def _bwd_call(dy2, x2, w, br, c, n, eps):
                    jax.ShapeDtypeStruct((8 * n, c), jnp.float32),
                    jax.ShapeDtypeStruct((8 * n, c), jnp.float32)],
         interpret=_INTERPRET,
+        **KERNELS["layer_norm_bwd"],
     )(dy2, x2, w.reshape(1, c))
 
 

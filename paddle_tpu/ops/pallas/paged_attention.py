@@ -168,7 +168,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from . import per_device as _per_device, trace32 as _trace32
+from . import (kernel_id as _kernel_id, per_device as _per_device,
+               trace32 as _trace32)
 
 __all__ = ["paged_decode_attention", "paged_attention_reference",
            "gqa_attend_reference", "ragged_paged_attention",
@@ -185,6 +186,17 @@ __all__ = ["paged_decode_attention", "paged_attention_reference",
 
 # interpret mode: run the kernel on CPU for testing (tests set this)
 _INTERPRET = os.environ.get("PADDLE_TPU_PALLAS_INTERPRET", "0") == "1"
+
+# trace name -> pallas_call keywords, one entry per call site in this
+# file (ops/pallas/__init__.py `kernel_id`); no name contains another
+KERNELS = {name: _kernel_id(name, fn) for name, fn in (
+    ("ragged_walk", "_ragged_kernel"),
+    ("grouped_phase1", "_grouped_phase1_kernel"),
+    ("scatter_write", "_scatter_write_kernel"),
+    ("scatter_q8_write", "_scatter_q8_write_kernel"),
+    ("lora_paged", "_lora_paged_kernel"),
+    ("argmax_epilogue", "_argmax_epilogue_kernel"),
+)}
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -585,6 +597,7 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
                 dimension_semantics=("parallel", "arbitrary",
                                      "arbitrary")),
             interpret=_INTERPRET,
+            **KERNELS["ragged_walk"],
         )(*prefetch, *ops)
     return out.reshape(b, nqb, hkv, qblk, rep, d) \
         .transpose(0, 1, 3, 2, 4, 5).reshape(b, lq_pad, h, d)[:, :lq]
@@ -636,6 +649,7 @@ def _grouped_phase1(prefetch, ops, *, b, mp, ps, hkv, d, qblk, nqb, rep,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_INTERPRET,
+        **KERNELS["grouped_phase1"],
     )(*prefetch, *ops)
 
 
@@ -1071,6 +1085,7 @@ def _paged_scatter_kernel(pool, upd, pos, page_table):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=_INTERPRET,
+            **KERNELS["scatter_write"],
         )(flat.reshape(-1), upd.reshape(b * l, h, d), flat_pool)
     return out.reshape(pool.shape)
 
@@ -1128,6 +1143,7 @@ def _paged_scatter_q8_kernel(pool, scale_pool, upd, pos, page_table):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=_INTERPRET,
+            **KERNELS["scatter_q8_write"],
         )(flat.reshape(-1), upd.reshape(b * l, h, d), flat_pool)
     flat_sc = scale_pool.reshape((-1,) + scale_pool.shape[2:])
     flat_sc = flat_sc.at[flat.reshape(-1)].set(scales.reshape(b * l, h))
@@ -1211,6 +1227,7 @@ def lora_delta_paged(x, a_pool, b_pool, apage, ascale):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=_INTERPRET,
+            **KERNELS["lora_paged"],
         )(ap, x, a_pool, b_pool, sc.reshape(bsz, 1, 1))
     return out
 
@@ -1340,6 +1357,7 @@ def decode_greedy_argmax(logits):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=_INTERPRET,
+            **KERNELS["argmax_epilogue"],
         )(logits)
     return out[:, 0]
 

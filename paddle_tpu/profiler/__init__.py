@@ -20,14 +20,16 @@ import time
 from enum import Enum
 from typing import Callable, Optional
 
+from jax.profiler import TraceAnnotation as _trace_annotation
+
 __all__ = ["Profiler", "RecordEvent", "ProfilerState", "ProfilerTarget",
            "make_scheduler", "export_chrome_tracing", "chrome_trace",
            "SortedKeys", "SummaryView"]
 
 
 def chrome_trace(events, pid: int = None) -> dict:
-    """THE chrome-tracing writer: `(name, tid, t0_ns, t1_ns)` span
-    tuples -> the Chrome-trace JSON dict (openable in Perfetto /
+    """THE chrome-tracing writer: `(name, tid, t0_ns, t1_ns[, args])`
+    span tuples -> the Chrome-trace JSON dict (openable in Perfetto /
     chrome://tracing; reference: chrometracing_logger.cc). Shared by
     `Profiler.export` (host op/RecordEvent spans) and the serving
     observability layer (request-lifecycle timelines,
@@ -39,8 +41,9 @@ def chrome_trace(events, pid: int = None) -> dict:
         "traceEvents": [
             {"name": name, "ph": "X", "cat": "host",
              "ts": (t0 - base) / 1e3, "dur": (t1 - t0) / 1e3,
-             "pid": os.getpid() if pid is None else pid, "tid": tid}
-            for name, tid, t0, t1 in events
+             "pid": os.getpid() if pid is None else pid, "tid": tid,
+             **({"args": rest[0]} if rest and rest[0] else {})}
+            for name, tid, t0, t1, *rest in events
         ],
         "displayTimeUnit": "ms",
     }
@@ -101,24 +104,49 @@ def _now_ns():
 class RecordEvent:
     """Host span (reference: profiler/utils.py RecordEvent over
     platform/profiler/event_tracing.h). Usable as context manager or via
-    begin()/end()."""
+    begin()/end(), both on one thread.
 
-    def __init__(self, name: str, event_type=None):
+    One span, three readers. While a `Profiler` records, the span lands
+    in its Python list (`Profiler.export`). While a JAX profiler session
+    runs (`jax.profiler.start_trace`, XProf against a live server, the
+    benchmark's `--trace 1`), it is also a `jax.profiler.TraceAnnotation`
+    of the same name: an event on its thread's line of the `/host:`
+    plane of the same `.xplane.pb` that holds the device's `XLA Ops`, on
+    the profiler's clock; with neither, the annotation is inert. And
+    `elapsed_s` holds the span's own two clock reads after `end()`, so a
+    counter fed from it agrees with the span. Keyword arguments (a page,
+    a step index) become the annotation's arguments and the chrome
+    event's `args`: ids belong there, never in `name`, which is a
+    constant of the calling module."""
+
+    __slots__ = ("name", "event_type", "args", "elapsed_s", "_t0",
+                 "_annotation")
+
+    def __init__(self, name: str, event_type=None, **args):
         self.name = name
         self.event_type = event_type
+        self.args = args
+        self.elapsed_s = 0.0
         self._t0 = None
+        self._annotation = None
 
     def begin(self):
+        self._annotation = _trace_annotation(self.name, **self.args)
+        self._annotation.__enter__()
         self._t0 = _now_ns()
 
     def end(self):
         if self._t0 is None:
             return
+        t0, t1 = self._t0, _now_ns()
+        self._t0 = None
+        self._annotation.__exit__(None, None, None)
+        self._annotation = None
+        self.elapsed_s = (t1 - t0) / 1e9
         prof = _active_profiler()
         if prof is not None and prof._recording and not prof.timer_only:
             prof._events.append(
-                (self.name, threading.get_ident(), self._t0, _now_ns()))
-        self._t0 = None
+                (self.name, threading.get_ident(), t0, t1, self.args))
 
     def __enter__(self):
         self.begin()
@@ -229,15 +257,17 @@ class Profiler:
         return self
 
     def stop(self):
-        if self._xla_tracing:
-            self._stop_xla_trace()
-        self._recording = False
-        self.current_state = ProfilerState.CLOSED
-        if _active_profiler() is self:
-            _active["profiler"] = None
-            from ..core import tensor as tensor_mod
-            tensor_mod._profile_hook = None
-        self._flush_window()
+        try:
+            if self._xla_tracing:
+                self._stop_xla_trace()
+        finally:
+            self._recording = False
+            self.current_state = ProfilerState.CLOSED
+            if _active_profiler() is self:
+                _active["profiler"] = None
+                from ..core import tensor as tensor_mod
+                tensor_mod._profile_hook = None
+            self._flush_window()
 
     def step(self, num_samples=None):
         t = time.perf_counter()
@@ -288,22 +318,21 @@ class Profiler:
                 self._stop_xla_trace()
 
     def _start_xla_trace(self):
+        """A device trace that cannot start (another profiler session is
+        running, the directory cannot be made) raises: a host-only trace
+        that looks like a whole one is how spans that never reached the
+        device trace came to be thought sufficient."""
         import tempfile
         import jax
-        self._device_trace_dir = tempfile.mkdtemp(prefix="paddle_xla_trace_")
-        try:
-            jax.profiler.start_trace(self._device_trace_dir)
-            self._xla_tracing = True
-        except Exception:
-            self._device_trace_dir = None
+        trace_dir = tempfile.mkdtemp(prefix="paddle_xla_trace_")
+        jax.profiler.start_trace(trace_dir)
+        self._device_trace_dir = trace_dir
+        self._xla_tracing = True
 
     def _stop_xla_trace(self):
         import jax
-        try:
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
         self._xla_tracing = False
+        jax.profiler.stop_trace()
 
     def __enter__(self):
         return self.start()
@@ -333,7 +362,8 @@ class Profiler:
     def aggregate(self):
         """name -> dict(calls, total_ns, avg_ns, max_ns, min_ns)."""
         agg: dict = {}
-        for name, _tid, t0, t1 in (self._events or self._all_events):
+        for name, _tid, t0, t1, *_ in (self._events
+                                       or self._all_events):
             d = t1 - t0
             a = agg.setdefault(name, {"calls": 0, "total": 0,
                                       "max": 0, "min": None})

@@ -182,11 +182,13 @@ model state.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import itertools
 import os
 import threading
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import numpy as np
 import jax
@@ -236,6 +238,35 @@ __all__ = ["ServingEngine", "resolve_unified_flag",
 _TERMINAL_EVENT = {"stop": "finish", "length": "finish",
                    "deadline": "deadline", "poisoned": "poison",
                    "replica_failure": "replica_death"}
+
+# Host spans (`profiler.RecordEvent`): names are constants, ids ride as
+# arguments. Each span is also a `jax.profiler.TraceAnnotation`, so a JAX
+# profiler session against the running engine holds them on the clock of
+# the device's `XLA Ops`. One scheduler round nests as
+#   round(step) > admit > {spill(page), restore(page), cow_copy}
+#               > plan > {spill, restore}
+#               > unified_step > launch, fetch
+#               > commit > embed_epilogue
+#               > report
+# and the seconds of plan/launch/fetch/commit/admit/report/spill also
+# feed `metrics.HOST_PHASE_COUNTERS`, one `on_host_phases` call a round.
+SPAN_ROUND = "serving::round"
+SPAN_ADMIT = "serving::admit"
+SPAN_PLAN = "serving::plan"
+SPAN_UNIFIED_STEP = "serving::unified_step"
+SPAN_LAUNCH = "serving::launch"
+SPAN_FETCH = "serving::fetch"
+SPAN_COMMIT = "serving::commit"
+SPAN_REPORT = "serving::report"
+# one page to the host tier (`_extract_page`), and the prefix cache's walk
+# of its tree for candidates (`_spill_walk`, wired by `set_host_tier`)
+SPAN_SPILL = "serving::spill"
+SPAN_RESTORE = "serving::restore"
+SPAN_COW_COPY = "serving::cow_copy"
+SPAN_EMBED = "serving::embed_epilogue"
+# the legacy alternating path's two program families
+SPAN_PREFILL = "serving::prefill"
+SPAN_DECODE_STEP = "serving::decode_step"
 
 UNIFIED_STEP_MODES = ("on", "off")
 PREEMPT_MODES = ("on", "off")
@@ -309,6 +340,16 @@ def resolve_preempt_flag(override=None) -> bool:
             f"PADDLE_TPU_PREEMPT must be one of {PREEMPT_MODES}, "
             f"got {v!r}")
     return v == "on"
+
+
+class _StepPlan(NamedTuple):
+    """What `_plan_unified` hands to the launch and to `_commit_unified`."""
+    decode_slots: list
+    grants: dict
+    draft_grants: dict
+    proposals: dict
+    args_tail: tuple
+    t0: float           # where `decode_step_s` starts: before the uploads
 
 
 class _SwapHandle:
@@ -781,7 +822,8 @@ class ServingEngine:
         if self.prefix_cache is not None and self.host_pages > 0:
             self.prefix_cache.set_host_tier(self._host_store_page,
                                             self._host_load_page,
-                                            self._host_drop_page)
+                                            self._host_drop_page,
+                                            self._spill_walk)
         self._slot_pages: Dict[int, List[int]] = {}
         self._prefill_cursor: Dict[str, int] = {}
         self._pt_host = np.full((self.num_slots, self.max_pages),
@@ -826,7 +868,6 @@ class ServingEngine:
         # (0 between launches): the watchdog scales its grace with
         # this, so a legitimately huge packed step is not condemned
         self.step_tokens_inflight = 0
-        self._spans: Dict[str, RecordEvent] = {}
         # fault-injection hook (serving/faults.py): called with the
         # round's participant request ids right BEFORE each compiled
         # launch; a raise aborts the round with no state mutated. The
@@ -890,9 +931,21 @@ class ServingEngine:
                              "reads_saved": 0, "collectives": 0,
                              "constrained_rows": 0,
                              "grammar_rejected": 0, "wall_s": 0.0}
+        # host-phase seconds since the last `metrics.on_host_phases`
+        # (one flush a round; a spill outside a round waits for the next)
+        self._host_phases = collections.defaultdict(int)
         # shutdown latch: flipped by drain()/abort_all(); add_request
         # raises EngineClosed once set
         self._closed = False
+
+    @contextlib.contextmanager
+    def _phase(self, span: str, counter: str, **args):
+        """One host phase: a span, and its seconds added to `counter`
+        of the round's account (an exception leaves the account as it
+        was: the round is void)."""
+        with RecordEvent(span, **args) as ev:
+            yield
+        self._host_phases[counter] += ev.elapsed_s
 
     def _obs_event(self, req: "Request", kind: str, **detail):
         """Record one request-timeline event (no-op with obs off)."""
@@ -1306,7 +1359,7 @@ class ServingEngine:
             tok[slot, 0] = int(req.prefill_ids[-1])
             pos[slot] = int(req.prefill_ids.size) - 1
             pt[slot] = self._pt_host[slot]
-        with RecordEvent("serving::embed_epilogue"):
+        with RecordEvent(SPAN_EMBED):
             h = np.asarray(self._embed_fn(
                 self._ct, self._dev(pos), self._dev(pt),
                 self._dev(tok)))
@@ -1337,7 +1390,7 @@ class ServingEngine:
     def _copy_page(self, src: int, dst: int):
         if self._copy_page_fn is None:
             self._copy_page_fn = self._build_copy_page()
-        with RecordEvent(f"serving::cow_copy[{src}->{dst}]"):
+        with RecordEvent(SPAN_COW_COPY, src=src, dst=dst):
             self._ct = self._copy_page_fn(self._ct, jnp.int32(src),
                                           jnp.int32(dst))
 
@@ -1396,7 +1449,8 @@ class ServingEngine:
         pool (HostPagePool payloads are opaque either way)."""
         if self._swap_out_fn is None:
             self._swap_out_fn = self._build_swap_out()
-        with RecordEvent(f"serving::swap_out[{src}]"):
+        self._host_phases["kv_spill_pages_total"] += 1
+        with self._phase(SPAN_SPILL, "kv_spill_s_total", page=src):
             out = self._swap_out_fn(self._ct, jnp.int32(src))
             if self.kv_dtype == "int8":
                 return (np.asarray(out[0]), np.asarray(out[1]))
@@ -1407,7 +1461,7 @@ class ServingEngine:
         `dst`."""
         if self._swap_in_fn is None:
             self._swap_in_fn = self._build_swap_in()
-        with RecordEvent(f"serving::swap_in[{dst}]"):
+        with RecordEvent(SPAN_RESTORE, page=dst):
             if self.kv_dtype == "int8":
                 codes, scales = data
                 self._ct = self._swap_in_fn(
@@ -1419,6 +1473,11 @@ class ServingEngine:
                                             jnp.int32(dst))
 
     # -- host tier callbacks (prefix-cache spill) --------------------------
+    def _spill_walk(self, need: int):
+        """Prefix spill: the cache's walk of its tree for `need` pages,
+        in the account of the copies that follow (`_extract_page`)."""
+        return self._phase(SPAN_SPILL, "kv_spill_s_total", need=need)
+
     def _host_store_page(self, page: int):
         """Prefix spill: copy a parked page's KV to the host tier;
         returns the host slot (the cache then swap_out's the device
@@ -1717,19 +1776,17 @@ class ServingEngine:
     # -- step boundary: retire / admit / prefill / decode ------------------
     def _finalize_request(self, req: Request, *, keep_id: bool = False):
         """The ONE host-side cleanup every path that takes a request
-        off a slot/queue must run: drop its prefill cursor and
-        drafter, close its profiler span (replica-death and
-        quarantine paths used to leak spans that were opened at
-        admission and never end()ed), and retire its id from
-        `_requests` unless it stays live (`keep_id=True` — the
-        preemption path: a preempted request resumes under the same
-        id and must keep its duplicate-id guard)."""
+        off a slot/queue must run: drop its prefill cursor, drafter
+        and grammar, and retire its id from `_requests` unless it
+        stays live (`keep_id=True` — the preemption path: a preempted
+        request resumes under the same id and must keep its
+        duplicate-id guard). A request's residency is no profiler
+        span (it crosses rounds, so it cannot nest in a trace): its
+        timeline is `obs.RequestTracer`'s, joined to the trace by the
+        step index that `serving::round` carries."""
         self._prefill_cursor.pop(req.request_id, None)
         self._drafters.pop(req.request_id, None)
         self._grammars.pop(req.request_id, None)
-        span = self._spans.pop(req.request_id, None)
-        if span is not None:
-            span.end()
         if not keep_id:
             self._requests.pop(req.request_id, None)
 
@@ -2081,9 +2138,6 @@ class ServingEngine:
         for slot, req in self.scheduler.assign(reserve=self._reserve):
             req.state = RequestState.PREFILL
             req.admitted_t = now
-            span = RecordEvent(f"serving::request[{req.request_id}]")
-            span.begin()
-            self._spans[req.request_id] = span
             self._slot_pages[slot] = req.pages
             self._pt_host[slot, :] = TRASH_PAGE
             self._pt_host[slot, :len(req.pages)] = req.pages
@@ -2218,8 +2272,8 @@ class ServingEngine:
         pt_full, _ = self._page_tables()
         self.step_tokens_inflight = int(bucket)
         self._beat()
-        with RecordEvent(f"serving::prefill[{req.request_id}"
-                         f"@{cursor}+{bucket}]"):
+        with RecordEvent(SPAN_PREFILL, slot=slot, cursor=cursor,
+                         bucket=bucket):
             self._ct, self._pos, self._last_logits = fn(
                 self._ct, self._pos, self._last_logits, pt_full,
                 self._dev(tokens), jnp.int32(slot),
@@ -2283,7 +2337,7 @@ class ServingEngine:
             self.step_tokens_inflight = int(self._active.sum())
             self._beat()
             t0 = time.perf_counter()
-            with RecordEvent("serving::decode_step"):
+            with RecordEvent(SPAN_DECODE_STEP):
                 self._ct, self._pos, self._last_logits, toks = \
                     self._decode_fn(
                         self._ct, self._pos, self._last_logits,
@@ -2546,9 +2600,47 @@ class ServingEngine:
         pass of a step their slot participated in. Returns the number
         of prefill tokens packed alongside the decodes (0 when nothing
         ran)."""
-        running = self.scheduler.running
-        if not running:
+        if not self.scheduler.running:
             return 0
+        with self._phase(SPAN_PLAN, "step_plan_s_total"):
+            plan = self._plan_unified(suppress)
+        if plan is None:
+            return 0
+        # launch-count probe: count registered-op dispatches while the
+        # launch runs. Only a (re)trace walks the Python op layer —
+        # compiled replays leave `counts` empty — so the histogram is
+        # the per-step LAUNCH census of the one program, captured once
+        # per compile at zero steady-state cost. Trace-time counting
+        # is deliberate: post-compile HLO computation counts would
+        # reflect the backend's fusion heuristics, not this codebase's
+        # op granularity.
+        counts: Dict[str, int] = {}
+        prev_probe = set_dispatch_probe(
+            lambda name: counts.__setitem__(name,
+                                            counts.get(name, 0) + 1))
+        try:
+            with RecordEvent(SPAN_UNIFIED_STEP):
+                with self._phase(SPAN_LAUNCH, "step_launch_s_total"):
+                    self._ct, self._pos, self._last_logits, toks, \
+                        accept = self._unified_fn(self._ct,
+                                                  *plan.args_tail)
+                # sync: the host waits for the device, then sees the
+                # tokens
+                with self._phase(SPAN_FETCH, "step_fetch_s_total"):
+                    toks = np.asarray(toks)
+                    accept = np.asarray(accept)
+        finally:
+            set_dispatch_probe(prev_probe)
+        with self._phase(SPAN_COMMIT, "step_commit_s_total"):
+            return self._commit_unified(plan, counts, toks, accept,
+                                        finished)
+
+    def _plan_unified(self, suppress) -> Optional["_StepPlan"]:
+        """The host's work before the launch (`serving::plan`): pack
+        this round's tokens, build the token/q_len arrays, the page
+        tables, the prefix-sharing groups and the modeled read count,
+        and upload the operands. None when nothing is to run."""
+        running = self.scheduler.running
         W = self.chunk_len
         remaining = {
             slot: int(req.prefill_ids.size)
@@ -2569,7 +2661,7 @@ class ServingEngine:
             draft_grants = {s: n for s, n in draft_grants.items()
                             if s not in suppress}
         if not decode_slots and not grants:
-            return 0
+            return None
         if self._draft is not None:
             # draft-cache warming rides the leftover budget (runs as
             # its own small launch BEFORE the target program — the
@@ -2748,26 +2840,17 @@ class ServingEngine:
         # operand pytree (the live self._ct stands in for the pools)
         # the one trace lowers against — [S]-sized arrays, not pools
         self._unified_args_tail = args_tail
-        # launch-count probe: count registered-op dispatches while the
-        # launch runs. Only a (re)trace walks the Python op layer —
-        # compiled replays leave `counts` empty — so the histogram is
-        # the per-step LAUNCH census of the one program, captured once
-        # per compile at zero steady-state cost. Trace-time counting
-        # is deliberate: post-compile HLO computation counts would
-        # reflect the backend's fusion heuristics, not this codebase's
-        # op granularity.
-        counts: Dict[str, int] = {}
-        prev_probe = set_dispatch_probe(
-            lambda name: counts.__setitem__(name,
-                                            counts.get(name, 0) + 1))
-        try:
-            with RecordEvent("serving::unified_step"):
-                self._ct, self._pos, self._last_logits, toks, accept = \
-                    self._unified_fn(self._ct, *args_tail)
-                toks = np.asarray(toks)  # sync: host sees the tokens
-                accept = np.asarray(accept)
-        finally:
-            set_dispatch_probe(prev_probe)
+        return _StepPlan(decode_slots, grants, draft_grants, proposals,
+                         args_tail, t0)
+
+    def _commit_unified(self, plan: "_StepPlan", counts: Dict[str, int],
+                        toks, accept,
+                        finished: List[RequestOutput]) -> int:
+        """The host's work after the fetch (`serving::commit`): the
+        step's wall time and counters, prefill cursors, token emission,
+        finish and free. Returns the prefill tokens the step packed."""
+        decode_slots, grants, draft_grants, proposals, _, t0 = plan
+        running = self.scheduler.running
         if counts:
             self._dispatch_counts = {
                 "total": int(sum(counts.values())),
@@ -2972,10 +3055,19 @@ class ServingEngine:
                              "reads_saved": 0, "collectives": 0,
                              "constrained_rows": 0,
                              "grammar_rejected": 0, "wall_s": 0.0}
+        with RecordEvent(SPAN_ROUND, step=self._step_idx):
+            self._round(finished)
+        self.metrics.on_host_phases(self._host_phases)
+        self._host_phases.clear()
+        return finished
+
+    def _round(self, finished: List[RequestOutput]):
+        """`step()`'s body, inside `serving::round`."""
         now = self._clock()
-        self._evict(now, finished)
-        self._admit(now)
-        self._preempt_for_overload(now)
+        with self._phase(SPAN_ADMIT, "round_admit_s_total"):
+            self._evict(now, finished)
+            self._admit(now)
+            self._preempt_for_overload(now)
         chunks = 0
         try:
             chunks = self._run_round(finished)
@@ -3000,6 +3092,13 @@ class ServingEngine:
                                          detail=repr(exc),
                                          step=self._step_idx,
                                          slo=self._slo_snap())
+        with self._phase(SPAN_REPORT, "round_report_s_total"):
+            self._report_round(chunks)
+
+    def _report_round(self, chunks: int):
+        """What the observability costs each round (`serving::report`):
+        the metrics' gauges and histograms, the cost census's first
+        capture, the flight recorder's record."""
         self.metrics.on_step(self.scheduler.queue_depth,
                              self.scheduler.occupancy, self.num_slots,
                              pages_used=self.pool.used_pages,
@@ -3080,7 +3179,6 @@ class ServingEngine:
                     "adapters_resident":
                         self.adapters.pool.used_pages
                         + self.adapters.pool.cached_pages})})
-        return finished
 
     # -- shutdown ----------------------------------------------------------
     @property
@@ -3124,20 +3222,11 @@ class ServingEngine:
         self._closed = True
         finished: List[RequestOutput] = []
         now = self._clock()
-        try:
-            for req in self.scheduler.pop_queued():
-                self._finish_and_free(req, reason, now, finished)
-            for slot in sorted(list(self.scheduler.running)):
-                self._finish_and_free(self.scheduler.running[slot],
-                                      reason, now, finished)
-        finally:
-            # replica-death hardening: a teardown that raises midway
-            # (a torn pool after a mid-step fault) must still close
-            # every open profiler span — the driver's _do_die swallows
-            # the raise, so this finally is the only place left
-            for span in self._spans.values():
-                span.end()
-            self._spans.clear()
+        for req in self.scheduler.pop_queued():
+            self._finish_and_free(req, reason, now, finished)
+        for slot in sorted(list(self.scheduler.running)):
+            self._finish_and_free(self.scheduler.running[slot],
+                                  reason, now, finished)
         self.pool.assert_quiesced()
         if self.adapters is not None:
             self.adapters.assert_quiesced()

@@ -41,10 +41,17 @@ from typing import Optional
 
 import numpy as np
 
+from ...profiler import RecordEvent
 from ..errors import EngineClosed, ServingError
 from ..request import Request, SamplingParams
 
 __all__ = ["EngineDriver", "ReplicaDead", "ReplicaHung"]
+
+# a handler thread inside `submit`: the inbox wait until the pump thread,
+# between two engine steps, calls `add_request` (and the reply's way
+# back). The wait itself is counted by the pump thread, from the stamp
+# on the `_Submission`: `metrics.submit_wait_s_total`.
+SPAN_SUBMIT = "http::submit"
 
 
 class ReplicaDead(ServingError):
@@ -58,12 +65,13 @@ class ReplicaHung(ReplicaDead):
 
 class _Submission:
     __slots__ = ("prompt_ids", "sampling", "request_id", "done",
-                 "request", "error")
+                 "request", "error", "t_put")
 
     def __init__(self, prompt_ids, sampling, request_id):
         self.prompt_ids = prompt_ids
         self.sampling = sampling
         self.request_id = request_id
+        self.t_put = time.perf_counter()    # into the inbox
         self.done = threading.Event()
         self.request: Optional[Request] = None
         self.error: Optional[BaseException] = None
@@ -235,21 +243,24 @@ class EngineDriver:
                 from self.death_exc
         if self._draining or not self._started:
             raise EngineClosed(f"{self.name} is not accepting requests")
-        sub = _Submission(prompt_ids, sampling, request_id)
-        self._inbox.put(("submit", sub))
-        self._wake.set()
-        deadline = time.monotonic() + self.submit_timeout_s
-        while not sub.done.wait(timeout=0.05):
-            if self._dead:
-                # one last grace period for _fail_pending to resolve it
-                if not sub.done.wait(timeout=0.1):
-                    raise ReplicaDead(f"{self.name} died mid-submit") \
-                        from self.death_exc
-                break
-            if time.monotonic() > deadline:
-                raise TimeoutError(
-                    f"{self.name}: submission not serviced within "
-                    f"{self.submit_timeout_s}s")
+        with RecordEvent(SPAN_SUBMIT):
+            sub = _Submission(prompt_ids, sampling, request_id)
+            self._inbox.put(("submit", sub))
+            self._wake.set()
+            deadline = time.monotonic() + self.submit_timeout_s
+            while not sub.done.wait(timeout=0.05):
+                if self._dead:
+                    # one last grace period for _fail_pending to
+                    # resolve it
+                    if not sub.done.wait(timeout=0.1):
+                        raise ReplicaDead(
+                            f"{self.name} died mid-submit") \
+                            from self.death_exc
+                    break
+                if time.monotonic() > deadline:
+                    raise TimeoutError(
+                        f"{self.name}: submission not serviced within "
+                        f"{self.submit_timeout_s}s")
         if sub.error is not None:
             raise sub.error
         return sub.request
@@ -395,6 +406,8 @@ class EngineDriver:
             except queue.Empty:
                 return
             if kind == "submit":
+                self.engine.metrics.on_submit_serviced(
+                    time.perf_counter() - payload.t_put)
                 try:
                     if self._faults is not None:
                         self._faults.on_add_request(self.name,

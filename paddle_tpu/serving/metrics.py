@@ -6,10 +6,12 @@ Three consumers, one source of truth:
 - `prometheus_render(...)` — the same snapshot as Prometheus text
   exposition for the HTTP server's `/metrics` endpoint, including
   fixed-bucket `_bucket` series for TTFT and inter-token latency.
-- `profiler.RecordEvent` spans emitted by the engine around prefill,
-  each decode step, and each request's whole residency — so a Chrome
-  trace from a serving run (profiler.Profiler + export) shows the
-  serving timeline next to the op/XLA spans.
+- `profiler.RecordEvent` spans emitted by the engine around each
+  scheduler round and its phases (engine.py lists them) — in a Chrome
+  trace from a serving run (profiler.Profiler + export) and, while a JAX
+  profiler session runs, in the device's own trace. The seconds of the
+  same spans are the `HOST_PHASE_COUNTERS` here; a request's residency
+  is `obs.RequestTracer`'s timeline, not a span.
 
 All recording hooks and `snapshot()` hold one lock, so a scrape thread
 (`/metrics`) never tears a read against the engine's driver thread —
@@ -25,6 +27,7 @@ from collections import deque
 from typing import Optional, Sequence
 
 __all__ = ["Histogram", "ServingMetrics", "prometheus_render",
+           "HOST_PHASE_COUNTERS",
            "TTFT_BUCKETS", "LATENCY_BUCKETS", "PACKED_TOKEN_BUCKETS",
            "SPEC_TOKEN_BUCKETS", "GROUP_SIZE_BUCKETS", "UTIL_BUCKETS"]
 
@@ -59,6 +62,28 @@ PRIORITY_CLASSES_MAX = 8
 # folds into "other" (a fleet may register thousands of adapters —
 # same cardinality-cap pattern as the per-priority labels)
 ADAPTER_IDS_MAX = 8
+
+# where the host's time goes, cumulative: each key is fed from the same
+# two clock reads that bound a `profiler.RecordEvent` span (the span in
+# parentheses; engine.py and http/driver.py hold the span names), so a
+# scrape, the benchmark's window difference and a profiler capture
+# agree. Seconds unless the name says pages or submits. `step_*` cover
+# one unified step's launch, `round_*` what a scheduler round does
+# around it; `kv_spill_*` lies inside `round_admit_s_total` (a spill
+# happens while pages are acquired), `submit_wait_s_total` on the
+# front-end's handler threads, outside the round.
+HOST_PHASE_COUNTERS = (
+    "step_plan_s_total",        # serving::plan
+    "step_launch_s_total",      # serving::launch
+    "step_fetch_s_total",       # serving::fetch
+    "step_commit_s_total",      # serving::commit
+    "round_admit_s_total",      # serving::admit
+    "round_report_s_total",     # serving::report
+    "kv_spill_s_total",         # serving::spill
+    "kv_spill_pages_total",     # serving::spill, one a page
+    "submit_wait_s_total",      # http::submit until add_request
+    "submits_serviced_total",   # submissions the pump thread took
+)
 
 
 class Histogram:
@@ -227,6 +252,8 @@ class ServingMetrics:
         # (False); set by the engine at construction — the second A/B
         # tag next to attn_impl so scrapes can tell the paths apart
         self.unified: Optional[bool] = None
+        # HOST_PHASE_COUNTERS, all cumulative
+        self.host_phases = dict.fromkeys(HOST_PHASE_COUNTERS, 0)
         # unified-step counters: steps run, and the packed token split
         self.unified_steps = 0
         self.packed_prefill_tokens = 0
@@ -575,6 +602,23 @@ class ServingMetrics:
                 self._util_recent.append(util)
             self.decode_step_s.record(wall_s)
 
+    def on_host_phases(self, phases: dict):
+        """One scheduler round's host phases, `{counter: increment}`
+        over HOST_PHASE_COUNTERS: one call and one lock acquisition a
+        round, not one a phase."""
+        with self._lock:
+            for name, inc in phases.items():
+                self.host_phases[name] += inc
+
+    def on_submit_serviced(self, wait_s: float):
+        """The pump thread took one submission from the driver's inbox
+        `wait_s` after the handler thread put it there: the queue wait
+        that precedes `queue_wait_s` (whose clock starts at
+        `add_request`)."""
+        with self._lock:
+            self.host_phases["submit_wait_s_total"] += wait_s
+            self.host_phases["submits_serviced_total"] += 1
+
     def on_grouped_step(self, flat_reads: int, actual_reads: int,
                         group_sizes: Sequence[int]):
         """One unified step's modeled page-block DMA traffic: the flat
@@ -695,6 +739,7 @@ class ServingMetrics:
             "dp": self.dp,
             "unified": self.unified,
             "unified_steps": self.unified_steps,
+            **self.host_phases,
             "packed_prefill_tokens": self.packed_prefill_tokens,
             "packed_decode_tokens": self.packed_decode_tokens,
             "packed_draft_tokens": self.packed_draft_tokens,
@@ -907,7 +952,9 @@ def prometheus_render(snapshots: dict, namespace: str = "paddle_serving",
                        ("cost_census_bytes", "gauge"),
                        ("cost_census_capacity_tokens", "gauge"),
                        ("slo_state", "gauge"),
-                       ("slo_burn_rate", "gauge")]:
+                       ("slo_burn_rate", "gauge"),
+                       *((name, "counter")
+                         for name in HOST_PHASE_COUNTERS)]:
         lines.append(f"# TYPE {namespace}_{name} {kind}")
     for replica, snap in sorted(snapshots.items()):
         lab = {"replica": str(replica)}
@@ -971,6 +1018,11 @@ def prometheus_render(snapshots: dict, namespace: str = "paddle_serving",
         lines.append(f"{namespace}_unified_steps_total"
                      + _fmt_labels(lab)
                      + f" {snap.get('unified_steps', 0)}")
+        # is the host or the chip the limit: seconds per phase of the
+        # host's loop, beside unified_steps_total
+        for name in HOST_PHASE_COUNTERS:
+            lines.append(f"{namespace}_{name}" + _fmt_labels(lab)
+                         + f" {snap.get(name, 0)}")
         lines.append(f"{namespace}_prefill_stall_steps_total"
                      + _fmt_labels(lab)
                      + f" {snap.get('prefill_stall_steps', 0)}")

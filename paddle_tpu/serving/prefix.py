@@ -74,6 +74,7 @@ inside a page.
 """
 from __future__ import annotations
 
+import contextlib
 import heapq
 import itertools
 import time
@@ -303,10 +304,14 @@ class RadixPrefixCache:
         # _host_load(host_slot) -> device page or None (allocates a
         # fresh page, restores into it, returns it PARKED cache-
         # resident), _host_drop(host_slot) (discard a spilled page's
-        # host copy — evicted from the tree while swapped)
+        # host copy — evicted from the tree while swapped),
+        # _spill_walk(need) -> context manager around one spill's walk
+        # of the tree (the engine's `serving::spill` span and account,
+        # the same as each page's copy)
         self._host_store = None
         self._host_load = None
         self._host_drop = None
+        self._spill_walk = contextlib.nullcontext
         self._n_spilled = 0
 
     # -- introspection -----------------------------------------------------
@@ -321,7 +326,8 @@ class RadixPrefixCache:
         """Tree nodes whose page currently lives in the host tier."""
         return self._n_spilled
 
-    def set_host_tier(self, store, load, drop):
+    def set_host_tier(self, store, load, drop,
+                      spill_walk=contextlib.nullcontext):
         """Wire the host-RAM page tier (engine callbacks — see the
         attribute docs in __init__). With these set, page pressure
         SPILLS parked pages to host before evicting, and a match on a
@@ -329,6 +335,7 @@ class RadixPrefixCache:
         self._host_store = store
         self._host_load = load
         self._host_drop = drop
+        self._spill_walk = spill_walk
 
     @property
     def pinned_pages(self) -> int:
@@ -651,14 +658,16 @@ class RadixPrefixCache:
         if need <= 0 or self._host_store is None:
             return 0
         heap = []
-        stack = list(self._roots.values())   # every tenant namespace
-        while stack:
-            node = stack.pop()
-            stack.extend(node.children.values())
-            if (node.tokens is not None and node.page is not None
-                    and self.pool.refcount(node.page) == 0
-                    and not self._pinned(node)):
-                heapq.heappush(heap, (node.last_used, id(node), node))
+        with self._spill_walk(need):    # the walk of the whole tree
+            stack = list(self._roots.values())   # every tenant namespace
+            while stack:
+                node = stack.pop()
+                stack.extend(node.children.values())
+                if (node.tokens is not None and node.page is not None
+                        and self.pool.refcount(node.page) == 0
+                        and not self._pinned(node)):
+                    heapq.heappush(heap,
+                                   (node.last_used, id(node), node))
         spilled = 0
         while spilled < need and heap:
             _, _, node = heapq.heappop(heap)

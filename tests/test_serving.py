@@ -313,7 +313,9 @@ class TestMetricsAndTrace:
     def test_chrome_trace_contains_per_request_spans(self, tmp_path):
         # pinned to the legacy alternating path (its per-chunk prefill
         # and decode_step spans); the unified step's spans are covered
-        # in tests/test_serving_unified.py
+        # in tests/test_serving_unified.py. Names are fixed, the chunk
+        # rides in the arguments, the per-request part is
+        # RequestTracer's timeline.
         model = tiny_gpt()
         eng = ServingEngine(model, num_slots=2, max_len=48,
                             unified=False)
@@ -328,19 +330,28 @@ class TestMetricsAndTrace:
         p.export(path)
         with open(path) as f:
             trace = json.load(f)
-        names = [e["name"] for e in trace["traceEvents"]]
-        for r in (r0, r1):
-            assert f"serving::request[{r.request_id}]" in names
-            # chunked prefill: one span per chunk, tagged @start+len
-            assert any(n.startswith(f"serving::prefill[{r.request_id}@")
-                       for n in names)
+        events = trace["traceEvents"]
+        names = [e["name"] for e in events]
+        # chunked prefill: one span per chunk, cursor + bucket as args
+        chunks = [e["args"] for e in events
+                  if e["name"] == "serving::prefill"]
+        assert {c["slot"] for c in chunks} == {0, 1}
+        assert all(c["cursor"] == 0 and c["bucket"] >= 2 for c in chunks)
         assert names.count("serving::decode_step") >= 3
-        # request spans cover their prefill + decode steps
-        req_ev = next(e for e in trace["traceEvents"]
-                      if e["name"] == f"serving::request[{r0.request_id}]")
-        step_ev = next(e for e in trace["traceEvents"]
+        assert not any("[" in n for n in names
+                       if n.startswith("serving::"))
+        for r in (r0, r1):
+            kinds = [e["kind"] for e in
+                     eng.obs.tracer.timeline(r.request_id)]
+            assert kinds[0] == "submit" and kinds[-1] == "finish"
+            assert "prefill_chunk" in kinds and "first_token" in kinds
+        # a round covers its prefill chunks and its decode step
+        round_ev = next(e for e in events
+                        if e["name"] == "serving::round")
+        step_ev = next(e for e in events
                        if e["name"] == "serving::decode_step")
-        assert req_ev["dur"] >= step_ev["dur"]
+        assert round_ev["dur"] >= step_ev["dur"]
+        assert round_ev["args"]["step"] == 1
 
     def test_metrics_histogram_percentiles(self):
         m = ServingMetrics()

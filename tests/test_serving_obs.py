@@ -318,7 +318,6 @@ class TestEngineObs:
                 assert tl[-1]["tokens"] == 8
             else:
                 assert eng.obs is None
-            assert eng._spans == {}
         assert outs[True] == outs[False]
 
     def test_flight_records_match_metrics(self):
@@ -360,7 +359,6 @@ class TestEngineObs:
         eng.run()
         assert bad.finish_reason == "poisoned"
         assert good.finish_reason in ("stop", "length")
-        assert eng._spans == {}
         snap = eng.obs.flight.snapshot()
         kinds = [i["kind"] for i in snap["incidents"]]
         assert "step_fault" in kinds and "poison_quarantine" in kinds
@@ -368,20 +366,29 @@ class TestEngineObs:
         assert tl[-1]["kind"] == "poison"
 
     def test_abort_all_closes_spans_even_when_teardown_raises(self):
-        """The PR's span-leak fix: a teardown that raises midway (the
-        replica-death path) still ends every open span."""
+        """A teardown that raises midway (the replica-death path)
+        leaves no span open: every span is a `with` block inside one
+        round, and a request's residency is the tracer's timeline, not
+        a span held across rounds."""
         model = tiny_gpt()
         eng = ServingEngine(model, num_slots=2, max_len=64,
                             chunk_len=8)
-        eng.add_request(np.array([3, 14, 15, 9], np.int64),
-                        SamplingParams(max_new_tokens=16))
-        eng.step()
-        assert eng._spans            # span open for the resident
-        eng.pool.free = lambda pages: (_ for _ in ()).throw(
-            RuntimeError("torn pool"))
-        with pytest.raises(RuntimeError):
-            eng.abort_all("replica_failure")
-        assert eng._spans == {}
+        r = eng.add_request(np.array([3, 14, 15, 9], np.int64),
+                            SamplingParams(max_new_tokens=16))
+        with profiler.Profiler(
+                targets=[profiler.ProfilerTarget.CPU]) as p:
+            eng.step()
+            eng.pool.free = lambda pages: (_ for _ in ()).throw(
+                RuntimeError("torn pool"))
+            with pytest.raises(RuntimeError):
+                eng.abort_all("replica_failure")
+        assert eng.closed
+        spans = p.aggregate()
+        assert spans["serving::round"]["calls"] == 1
+        assert not any(n.startswith("serving::request") for n in spans)
+        assert not hasattr(eng, "_spans")
+        kinds = [e["kind"] for e in eng.obs.tracer.timeline(r.request_id)]
+        assert kinds[:2] == ["submit", "admit"]
 
     def test_cancelled_queued_request_fully_retired(self):
         """cancel() of a queued request now runs the shared terminal

@@ -350,6 +350,33 @@ class TestRadixTreeUnit:
         pool.assert_quiesced()
 
 
+    def test_spill_walk_hook_wraps_each_walk_of_the_tree(self):
+        """The fourth host-tier callback: `spill` enters it once a call
+        with the pages it was asked for, around the walk that picks the
+        candidates and before the first page is stored (the engine puts
+        its `serving::spill` span and `kv_spill_s_total` there)."""
+        import contextlib
+        pool, cache = self.make()
+        host = HostPagePool(4)
+        seen = []
+
+        @contextlib.contextmanager
+        def walk(need):
+            seen.append(("enter", need))
+            yield
+            seen.append(("exit", need))
+
+        cache.set_host_tier(
+            store=lambda page: seen.append(("store", page))
+            or host.store(("kv", page)),
+            load=lambda slot: None, drop=host.free, spill_walk=walk)
+        self.insert_seq(pool, cache, np.arange(100, 112))   # 3 pages
+        assert cache.spill(2) == 2
+        assert [k for k, _ in seen] == ["enter", "exit", "store", "store"]
+        assert seen[0] == ("enter", 2)
+        assert cache.spill(0) == 0 and len(seen) == 4   # nothing to walk
+
+
 class TestEngineEquivalence:
     """Engine-level acceptance: token identity on/off, COW, multi-turn,
     eviction under pressure, no retraces."""

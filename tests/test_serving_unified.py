@@ -278,8 +278,10 @@ class TestUnifiedMetrics:
 
 
 def test_chrome_trace_has_unified_step_and_request_spans(tmp_path):
-    """Profiler spans on the unified path: one serving::unified_step
-    span per engine step, per-request residency spans intact."""
+    """Profiler spans on the unified path: fixed names, ids in the
+    arguments. One serving::round per engine step holding admit, plan,
+    unified_step (launch + fetch), commit and report; the per-request
+    part is RequestTracer's timeline, joined by the round's step."""
     from paddle_tpu import profiler
     model = tiny_gpt()
     eng = ServingEngine(model, num_slots=2, max_len=48, unified=True)
@@ -291,12 +293,38 @@ def test_chrome_trace_has_unified_step_and_request_spans(tmp_path):
     p.export(path)
     with open(path) as f:
         trace = json.load(f)
-    names = [e["name"] for e in trace["traceEvents"]]
-    assert f"serving::request[{r0.request_id}]" in names
+    events = trace["traceEvents"]
+    names = [e["name"] for e in events]
     assert names.count("serving::unified_step") >= 3
+    rounds = [e for e in events if e["name"] == "serving::round"]
+    assert [e["args"]["step"] for e in rounds] == \
+        list(range(1, eng._step_idx + 1))
+    for phase in ("admit", "plan", "launch", "fetch", "commit",
+                  "report"):
+        assert names.count(f"serving::{phase}") >= 3, phase
+
+    def inside(child, parent):
+        return parent["ts"] <= child["ts"] and \
+            child["ts"] + child["dur"] <= parent["ts"] + parent["dur"]
+    first = {n: next(e for e in events if e["name"] == f"serving::{n}")
+             for n in ("round", "admit", "plan", "unified_step",
+                       "launch", "fetch", "commit", "report")}
+    for n in ("admit", "plan", "unified_step", "commit", "report"):
+        assert inside(first[n], first["round"]), n
+    assert inside(first["launch"], first["unified_step"])
+    assert inside(first["fetch"], first["unified_step"])
+    # no name is built per call: nothing carries an id in brackets
+    assert not any("[" in n for n in names if n.startswith("serving::"))
     # the legacy program families never ran
     assert "serving::decode_step" not in names
-    assert not any(n.startswith("serving::prefill[") for n in names)
+    assert "serving::prefill" not in names
+    # the request's own timeline, on the rounds' step index
+    tl = eng.obs.tracer.timeline(r0.request_id)
+    kinds = [e["kind"] for e in tl]
+    assert kinds[0] == "submit" and kinds[-1] == "finish"
+    assert "admit" in kinds and "first_token" in kinds
+    steps = {e["args"]["step"] for e in rounds}
+    assert {e["step"] for e in tl if e["kind"] != "submit"} <= steps
 
 
 @pytest.mark.slow

@@ -265,3 +265,131 @@ def test_kernels_under_serving_mesh(topo, on_tpu_branch, monkeypatch,
                    shaped(((2048,), BF16), whole),
                    shaped(((2048,), BF16), whole)).compile().as_text()
         assert "tpu_custom_call" in text
+
+
+# -- trace names: every pallas_call site carries `ptk:<name>` -------------
+# The device trace names a Mosaic call by its HLO text. `name=` reaches
+# the instruction's name, `metadata=` its `kernel_metadata`; the
+# benchmark's per-kernel shares search the event text for `ptk:<name>`.
+
+def _shaped(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+        if hasattr(x, "shape") else x, tree)
+
+
+def _tiny_gpt(hidden, heads, positions):
+    import paddle_tpu as paddle
+    from paddle_tpu.nlp import GPTConfig, GPTForCausalLM
+    paddle.seed(0)
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=512, hidden_size=hidden, num_hidden_layers=2,
+        num_attention_heads=heads, max_position_embeddings=positions,
+        hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0))
+    model.to(dtype="bfloat16")
+    return model
+
+
+def test_kernel_names_are_distinct_and_on_every_site():
+    import re
+    tables = {mod: mod.KERNELS for mod in (pa, fa, pln)}
+    names = [n for t in tables.values() for n in t]
+    assert len(names) == len(set(names)) == 11
+    assert not [(a, b) for a in names for b in names
+                if a != b and a in b]
+    for mod, table in tables.items():
+        with open(mod.__file__) as f:
+            src = f.read()
+        sites = re.findall(r'\*\*KERNELS\["(\w+)"\]', src)
+        assert sorted(sites) == sorted(table)       # each name, one site
+        assert src.count("pl.pallas_call(") == len(sites)
+        for name, kw in table.items():
+            assert kw["name"] == name
+            assert kw["metadata"]["kernel"] == "ptk:" + name
+            # the kernel function's own name stays in the lowered text
+            # (chip_smoke.py and the benchmark's train check count it)
+            assert callable(getattr(mod, kw["metadata"]["fn"]))
+
+
+def test_unified_step_names_its_kernels(one_chip, monkeypatch):
+    """The serving step lowered for the described v5e: the walk's two
+    phases and the fused LayerNorm carry their `ptk:` names and their
+    kernel functions' names."""
+    import numpy as np
+    from paddle_tpu.nn.functional import norm as fnorm
+    from paddle_tpu.serving import ServingEngine, SamplingParams
+    model = _tiny_gpt(256, 2, 256)
+    model.eval()
+    # one step on the CPU (jnp references) fixes the operands' shapes
+    monkeypatch.setattr(pa, "_use_kernel", lambda: False)
+    eng = ServingEngine(model, num_slots=8, max_len=256, page_size=16,
+                        chunk_len=128, attn_impl="kernel")
+    eng.add_request(np.arange(1, 40, dtype=np.int64),
+                    SamplingParams(max_new_tokens=2))
+    eng.run()
+    # a fresh program, traced as the chip traces it
+    monkeypatch.setattr(pa, "_use_kernel", lambda: True)
+    monkeypatch.setattr(fnorm, "_use_pallas_ln", lambda: True)
+    prog = eng._build_unified()
+    text = prog._jit.lower(*_shaped(
+        (prog._state_vals, eng._ct, *eng._unified_args_tail),
+        one_chip)).as_text()
+    for name in ("ragged_walk", "grouped_phase1", "layer_norm_fwd"):
+        assert f"ptk:{name}" in text, name
+    for fn in ("_ragged_kernel", "_grouped_phase1_kernel",
+               "_ln_fwd_kernel"):
+        assert fn in text, fn
+
+
+def test_names_reach_the_compiled_instruction(one_chip, on_tpu_branch):
+    """What the device trace shows is the COMPILED instruction: `name=`
+    is its name (`%ragged_walk.N`, so `benchmark/trace.py` keys two
+    kernels with one result shape apart), `metadata=` its
+    `kernel_metadata`."""
+    text = _compiles_to_kernel(
+        pa.ragged_paged_attention_grouped, one_chip, _q(1), _pool(BF16),
+        _pool(BF16), TABLE, ROW, ROW, ROW, ROW, ROW)
+    for name in ("ragged_walk", "grouped_phase1"):
+        assert f'"kernel":"ptk:{name}"' in text, name
+        assert f"%{name}." in text, name
+
+
+def test_scatter_write_is_named(one_chip):
+    # the megakernel's KV write: not in the default engine's step
+    text = jax.jit(pa._paged_scatter_kernel).lower(*_shaped(
+        [jnp.zeros(s, d) for s, d in
+         (_pool(BF16), ((B, 1, H, D), BF16), ROW, TABLE)],
+        one_chip)).as_text()
+    assert "ptk:scatter_write" in text and "_scatter_write_kernel" in text
+
+
+def test_train_step_names_its_kernels(one_chip, monkeypatch):
+    """The train step lowered for the described v5e holds flash
+    attention forward, dq, dkv and fused LayerNorm forward and backward
+    under their `ptk:` names, and the kernel functions' own names."""
+    import numpy as np
+    import paddle_tpu as paddle
+    import paddle_tpu.optimizer as opt
+    from paddle_tpu import jit
+    from paddle_tpu.nn.functional import attention as fattn
+    from paddle_tpu.nn.functional import norm as fnorm
+    monkeypatch.setattr(fattn, "_use_pallas", lambda q_len, d: True)
+    monkeypatch.setattr(fnorm, "_use_pallas_ln", lambda: True)
+    model = _tiny_gpt(128, 2, 128)
+    optimizer = opt.AdamW(learning_rate=1e-4,
+                          parameters=model.parameters(),
+                          weight_decay=0.01)
+    step = jit.compile_train_step(
+        lambda ids, labels: model(ids, labels=labels), model, optimizer)
+    ids = np.zeros((2, 128), np.int64)
+    args = ([p._value for p in step.params],
+            [b._value for b in step.buffers], step.states, step.gstate,
+            np.float32(1e-4), paddle.core.random.next_key_host(),
+            jnp.asarray(ids), jnp.asarray(ids))
+    text = step._step.lower(*_shaped(args, one_chip)).as_text()
+    for name in ("flash_fwd", "flash_dq", "flash_dkv", "layer_norm_fwd",
+                 "layer_norm_bwd"):
+        assert f"ptk:{name}" in text, name
+    for fn in ("_fa_kernel", "_fa_dq_kernel", "_fa_dkv_kernel",
+               "_ln_fwd_kernel", "_ln_bwd_kernel"):
+        assert fn in text, fn
