@@ -62,7 +62,13 @@ next to `page_table`/`pos`/`q_len` and drive a TWO-PHASE kernel:
 
 A group of 1 (group_cnt 0) degenerates to the ungrouped walk: phase 1
 never touches the row and phase 2 starts at page 0 with the virgin
-(-inf, 0, 0) partials. Page order per row is IDENTICAL to the
+(-inf, 0, 0) partials. Phase 1's (group x page) sweep is as long as
+the data asks: its grid bound is DYNAMIC, `any(group_cnt > 0)` decided
+inside the one compiled step from operand data — the whole sweep on a
+step where some rows share, ONE grid step a q_block (which writes the
+virgin partials and nothing else) on a step where none do, because a
+predicated-off grid step still costs a grid step and the full sweep
+has as many as the walk proper. Page order per row is IDENTICAL to the
 ungrouped kernel (shared pages 0..cnt-1 then private cnt..last, the
 same online-softmax recurrence), so outputs match the ungrouped walk;
 off-TPU the op runs the SAME `ragged_attention_reference` as the
@@ -501,7 +507,9 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
     shared prefix — and every member's pos already covers the span
     (shared pages hold committed KV). group_leader[g] names a member
     row whose table phase 1 walks; singleton rows ride with group_cnt
-    0 and take phase 2 only, which is exactly the ungrouped walk."""
+    0 and take phase 2 only, which is exactly the ungrouped walk. On a
+    step where every group_cnt is 0 phase 1 shrinks to one grid step a
+    q_block (`_grouped_phase1`)."""
     b, lq, h, d = q.shape
     _, ps, hkv, _ = k_pool.shape
     mp = page_table.shape[1]
@@ -608,8 +616,18 @@ def _grouped_phase1(prefetch, ops, *, b, mp, ps, hkv, d, qblk, nqb, rep,
     """Run phase 1 of the grouped walk over `ops` (q5, pools and, on
     the int8 lane, scale pools — the operands phase 2 takes too) and
     return the per-row partials (m, l, acc), each
-    [n_qblk, B, H_kv, qblk * rep, 128 | D] f32."""
+    [n_qblk, B, H_kv, qblk * rep, 128 | D] f32.
+
+    The (group x page) axis of the grid is a dynamic bound: B * mp
+    steps when some group has a shared span, ONE when none has. That
+    one step is predicated off like every step of a sweep with nothing
+    to do, after its `_init` has written the virgin partials, so the
+    results are the full sweep's bit for bit, without its B * mp - 1
+    idle grid steps a q_block (0.12 us each on a v5e: a third of the
+    serving step's device time where nothing is shared, PERF.md §6)."""
     rows = qblk * rep
+    *_, gcn = prefetch
+    sweep = jnp.where(jnp.any(gcn > 0), b * mp, 1)
 
     def shared_page(t, u, tab, posr, qlr, gid, gld, gcn):
         # shared page sp of group grp via the LEADER's page table;
@@ -639,7 +657,7 @@ def _grouped_phase1(prefetch, ops, *, b, mp, ps, hkv, d, qblk, nqb, rep,
             rep=rep, scale=scale, has_scale=has_scale, fp8=fp8),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
-            grid=(nqb, b * mp),
+            grid=(nqb, sweep),
             in_specs=in_specs,
             out_specs=[pl.BlockSpec((1, b, hkv, rows, w),
                                     lambda t, u, *_: (t, 0, 0, 0, 0))

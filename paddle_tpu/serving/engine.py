@@ -124,12 +124,16 @@ as three extra [S] operands next to pos/q_len — operand DATA, so the
 ONE unified trace never retraces. On TPU the grouped op's two-phase
 walk streams each shared page once per GROUP (phase 1: all member
 rows' online-softmax partials fold in VMEM; phase 2: private tails
-merge per row — same page order, bit-identical outputs); on CPU it
+merge per row — same page order, bit-identical outputs; phase 1's
+sweep is a dynamic grid bound inside the trace, whole only on a step
+where some group_cnt is non-zero and one grid step a query block
+otherwise, when phase 2 starts from the virgin partials); on CPU it
 IS the ungrouped reference, so grouped on/off stays bit-token-
 identical by construction. `count_page_block_reads` models the DMA
 traffic host-side each step, feeding the page_block_reads /
 shared_page_reads_saved counters and the group-size histogram the
-`--prefix-share` A/B asserts on.
+`--prefix-share` A/B asserts on; `grouped_walk_steps_total` counts
+the steps on which phase 1 swept.
 
 MULTI-TENANT ADAPTERS (serving/adapters.py, default off, gated
 `adapters=...` / PADDLE_TPU_ADAPTERS): thousands of LoRA fine-tunes
@@ -2716,8 +2720,10 @@ class ServingEngine:
                           lora_bytes=lora_rows
                           * self._adapter_row_bytes)
         group_args = ()
+        phase1 = False
         if self.grouped:
             gid, gld, gcn = shared_prefix_groups(self._pt_host, q_len)
+            phase1 = bool(gcn.any())
             group_args = (self._dev(gid), self._dev(gld),
                           self._dev(gcn))
             flat_reads, step_reads, group_sizes, walk_bytes = \
@@ -2731,7 +2737,7 @@ class ServingEngine:
                                        page_size=self.page_size,
                                        fused=fused_spec, **shard)
         self.metrics.on_grouped_step(flat_reads, step_reads,
-                                     group_sizes)
+                                     group_sizes, phase1=phase1)
         # per-layer walk bytes -> whole-step modeled bytes: every
         # layer's attention issues the same walk over its own pools
         self._last_walk_bytes = {

@@ -263,10 +263,13 @@ class ServingMetrics:
         # engine runs it, the modeled page-block reads the step's walk
         # issues (CPU-reference count, one (layer, kv-head) sweep per
         # step), and how many reads grouping saved vs the flat walk
-        # (flat - grouped; 0 with grouping off)
+        # (flat - grouped; 0 with grouping off), and the steps whose
+        # group_cnt had a non-zero entry: those on which the compiled
+        # step runs the whole sweep of the walk's phase 1
         self.grouped: Optional[bool] = None
         self.page_block_reads = 0
         self.shared_page_reads_saved = 0
+        self.grouped_walk_steps = 0
         # decode megakernel (ops/pallas/paged_attention.py): whether
         # the engine fuses the per-layer scatter+attend(+LoRA) into
         # one dispatch — the A/B tag — and the launch-count probe's
@@ -620,13 +623,17 @@ class ServingMetrics:
             self.host_phases["submits_serviced_total"] += 1
 
     def on_grouped_step(self, flat_reads: int, actual_reads: int,
-                        group_sizes: Sequence[int]):
+                        group_sizes: Sequence[int],
+                        phase1: bool = False):
         """One unified step's modeled page-block DMA traffic: the flat
         (per-row) walk would issue `flat_reads`, the step actually
         issued `actual_reads` (== flat with grouping off), and
         `group_sizes` lists the member count of every group that
-        shared at least one page read."""
+        shared at least one page read. `phase1`: the step's group_cnt
+        operand had a non-zero entry, the datum the device sizes
+        phase 1's grid from, so phase 1 of the grouped walk swept."""
         with self._lock:
+            self.grouped_walk_steps += bool(phase1)
             self.page_block_reads += int(actual_reads)
             self.shared_page_reads_saved += \
                 int(flat_reads) - int(actual_reads)
@@ -758,6 +765,7 @@ class ServingMetrics:
             "page_block_reads_total": self.page_block_reads,
             "shared_page_reads_saved_total":
                 self.shared_page_reads_saved,
+            "grouped_walk_steps_total": self.grouped_walk_steps,
             "megakernel": self.megakernel,
             "unified_dispatch_ops": self.unified_dispatch_ops,
             "group_size_per_step": self.group_size_hist.snapshot(),
@@ -918,6 +926,7 @@ def prometheus_render(snapshots: dict, namespace: str = "paddle_serving",
                        ("host_bytes_total", "gauge"),
                        ("swap_in_seconds", "histogram"),
                        ("unified_steps_total", "counter"),
+                       ("grouped_walk_steps_total", "counter"),
                        ("prefill_stall_steps_total", "counter"),
                        ("spec_drafted_total", "counter"),
                        ("spec_accepted_total", "counter"),
@@ -1018,6 +1027,9 @@ def prometheus_render(snapshots: dict, namespace: str = "paddle_serving",
         lines.append(f"{namespace}_unified_steps_total"
                      + _fmt_labels(lab)
                      + f" {snap.get('unified_steps', 0)}")
+        lines.append(f"{namespace}_grouped_walk_steps_total"
+                     + _fmt_labels(lab)
+                     + f" {snap.get('grouped_walk_steps_total', 0)}")
         # is the host or the chip the limit: seconds per phase of the
         # host's loop, beside unified_steps_total
         for name in HOST_PHASE_COUNTERS:

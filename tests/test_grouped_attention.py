@@ -7,6 +7,11 @@ Contracts:
   recurrence — the two-phase walk changes HBM traffic, not math);
   a group of 1 (group_cnt 0) degenerates to exactly the ungrouped
   walk; the q8 lane moves code+scale pages through the same walk;
+- phase 1's (group x page) sweep runs only on a step where some
+  group_cnt is non-zero (a dynamic grid bound, decided from operand
+  data): the one-step sweep of a step with nothing shared leaves the
+  virgin partials the full sweep would have, and one trace serves
+  steps with and without a group, on both lanes;
 - `shared_prefix_groups` partitions rows by physical-page-prefix
   equality: trash entries never match, a COW'd page splits its row
   out exactly at the divergence point, deeper subgroup sharing beats
@@ -21,10 +26,13 @@ Contracts:
   int8 lane — while `shared_page_reads_saved_total` actually grows
   and the ONE unified trace never retraces;
 - the new metrics render to Prometheus (saved-reads counter,
-  group-size histogram, `grouped` tag in engine_info).
+  group-size histogram, `grouped` tag in engine_info), and
+  `grouped_walk_steps_total` counts exactly the steps whose group_cnt
+  had a non-zero entry.
 """
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 import paddle_tpu as paddle
@@ -84,6 +92,25 @@ def build_shared(rng, ps, mp, hkv, d, n_shared, members, extra):
     return kp, vp, pt, pos, q_len, gid, gld, gcnt
 
 
+def q8_pools(rng, shape):
+    """Random int8 code pools of `shape` [P, ps, H_kv, D] with their
+    rowwise scale pools: (kp, vp, ks, vs)."""
+    codes = [rng.randint(-127, 128, size=shape).astype(np.int8)
+             for _ in range(2)]
+    scales = [(np.abs(rng.randn(*shape[:3])) / 127).astype(np.float32)
+              for _ in range(2)]
+    return (*codes, *scales)
+
+
+# lane -> (ungrouped kernel, grouped op)
+LANE_OPS = {
+    "fp": (pa.ragged_paged_attention,
+           pa.ragged_paged_attention_grouped),
+    "q8": (pa.ragged_paged_attention_q8,
+           pa.ragged_paged_attention_grouped_q8),
+}
+
+
 class TestGroupedKernel:
     """Interpret-mode grouped kernel vs the ragged reference and the
     ungrouped kernel."""
@@ -115,7 +142,8 @@ class TestGroupedKernel:
             # same page order, same recurrence -> same bits
             np.testing.assert_array_equal(grp[r, :ql], ung[r, :ql])
 
-    def test_group_of_one_bit_identical_to_ungrouped(self):
+    @pytest.mark.parametrize("lane", ["fp", "q8"])
+    def test_group_of_one_bit_identical_to_ungrouped(self, lane):
         """All-singleton operands (group_cnt 0 everywhere) ARE the
         ungrouped walk: phase 1 touches nothing, phase 2 starts from
         the virgin partials at page 0."""
@@ -125,15 +153,95 @@ class TestGroupedKernel:
             rng, ps, mp, hkv, d, n_shared=0, members=0, extra=4)
         lq = int(q_len.max())
         q = rng.randn(4, lq, hkv, d).astype(np.float32)
-        args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-                jnp.asarray(pt), jnp.asarray(pos), jnp.asarray(q_len))
-        ung = np.asarray(pa.ragged_paged_attention(*args))
-        grp = np.asarray(pa.ragged_paged_attention_grouped(
+        pools = (kp, vp) if lane == "fp" else q8_pools(rng, kp.shape)
+        ung_op, grp_op = LANE_OPS[lane]
+        args = tuple(jnp.asarray(a)
+                     for a in (q, *pools, pt, pos, q_len))
+        ung = np.asarray(ung_op(*args))
+        grp = np.asarray(grp_op(
             *args, jnp.arange(4, dtype=jnp.int32),
             jnp.zeros(4, jnp.int32), jnp.zeros(4, jnp.int32)))
         for r in range(4):
             ql = int(q_len[r])
             np.testing.assert_array_equal(grp[r, :ql], ung[r, :ql])
+
+    @pytest.mark.parametrize("lane", ["fp", "q8"])
+    def test_no_group_sweep_leaves_virgin_partials(self, lane):
+        """With no group to serve, phase 1's one-step sweep leaves
+        every row the virgin partials (-inf, 0, 0) the ungrouped walk
+        starts from: cutting the sweep changes no bit."""
+        rng = np.random.RandomState(7)
+        b, ps, mp, hkv, d, rows, nqb = 4, 8, 5, 2, 16, 8, 2
+        kp, vp, pt, pos, q_len, *_ = build_shared(
+            rng, ps, mp, hkv, d, n_shared=0, members=0, extra=b)
+        pools = (kp, vp) if lane == "fp" else q8_pools(rng, kp.shape)
+        q5 = rng.randn(b, nqb, hkv, rows, d).astype(np.float32)
+        prefetch = tuple(jnp.asarray(a, jnp.int32) for a in (
+            pt, pos, q_len, np.arange(b), np.zeros(b), np.zeros(b)))
+        m, l, acc = pa._grouped_phase1(
+            prefetch, [jnp.asarray(a) for a in (q5, *pools)],
+            b=b, mp=mp, ps=ps, hkv=hkv, d=d, qblk=rows, nqb=nqb, rep=1,
+            scale=0.25, has_scale=lane == "q8", fp8=False)
+        assert m.shape == l.shape == (nqb, b, hkv, rows, 128)
+        assert acc.shape == (nqb, b, hkv, rows, d)
+        assert m.dtype == l.dtype == acc.dtype == jnp.float32
+        np.testing.assert_array_equal(
+            np.asarray(m), np.full(m.shape, pa._NEG_INF, np.float32))
+        assert not np.asarray(l).any() and not np.asarray(acc).any()
+
+    @pytest.mark.parametrize("leader_group", ["first", "last"])
+    @pytest.mark.parametrize("lane", ["fp", "q8"])
+    def test_one_trace_serves_steps_with_and_without_a_group(
+            self, lane, leader_group):
+        """Sharing comes and goes as operand DATA: one jitted call
+        runs phase 1's whole sweep on a step with a group (also when
+        the sharing group is the LAST of the sweep) and one grid step a
+        q_block on a step without, with one trace, and every step
+        equals the ungrouped kernel bit for bit."""
+        rng = np.random.RandomState(8)
+        ps, mp, hkv, d = 8, 6, 2, 16
+        members, extra = 3, 2
+        kp, vp, pt, pos, q_len, gid, gld, gcnt = build_shared(
+            rng, ps, mp, hkv, d, n_shared=2, members=members,
+            extra=extra)
+        b = members + extra
+        if leader_group == "last":
+            gid = np.array([b - 1] * members + list(range(extra)),
+                           np.int32)
+            gcnt = gcnt[::-1].copy()          # leader row 0 either way
+        q = rng.randn(b, int(q_len.max()), hkv * 2, d) \
+            .astype(np.float32)
+        pools = (kp, vp) if lane == "fp" else q8_pools(rng, kp.shape)
+        ung_op, grp_op = LANE_OPS[lane]
+        args = tuple(jnp.asarray(a)
+                     for a in (q, *pools, pt, pos, q_len))
+        traces = []
+
+        @jax.jit
+        def step(*a):
+            traces.append(1)
+            return grp_op(*a)
+
+        ung = np.asarray(ung_op(*args))
+        shared = (jnp.asarray(gid), jnp.asarray(gld),
+                  jnp.asarray(gcnt))
+        alone = (jnp.arange(b, dtype=jnp.int32),
+                 jnp.zeros(b, jnp.int32), jnp.zeros(b, jnp.int32))
+        for group in (shared, alone, shared):
+            out = np.asarray(step(*args, *group))
+            for r in range(b):
+                ql = int(q_len[r])
+                np.testing.assert_array_equal(out[r, :ql],
+                                              ung[r, :ql])
+        assert len(traces) == 1 and step._cache_size() == 1
+        # both phases in the one program, no branch around either:
+        # phase 1's sweep length is a dynamic grid bound
+        top = jax.make_jaxpr(grp_op)(*args, *shared).jaxpr.eqns
+        assert "cond" not in [e.primitive.name for e in top]
+        grids = {e.params["name"]:
+                 e.params["grid_mapping"].num_dynamic_grid_bounds
+                 for e in top if e.primitive.name == "pallas_call"}
+        assert grids == {"grouped_phase1": 1, "ragged_walk": 0}
 
     def test_grouped_q8_lane_matches_q8_reference(self):
         """Code AND scale pages chase the same grouped walk; results
@@ -142,20 +250,11 @@ class TestGroupedKernel:
         ps, mp, hkv, d = 8, 5, 2, 16
         _, _, pt, pos, q_len, gid, gld, gcnt = build_shared(
             rng, ps, mp, hkv, d, n_shared=2, members=3, extra=1)
-        n_pages = int(pt.max()) + 1
-        kp = rng.randint(-127, 128,
-                         size=(n_pages, ps, hkv, d)).astype(np.int8)
-        vp = rng.randint(-127, 128,
-                         size=(n_pages, ps, hkv, d)).astype(np.int8)
-        ks = (np.abs(rng.randn(n_pages, ps, hkv)) / 127) \
-            .astype(np.float32)
-        vs = (np.abs(rng.randn(n_pages, ps, hkv)) / 127) \
-            .astype(np.float32)
+        pools = q8_pools(rng, (int(pt.max()) + 1, ps, hkv, d))
         lq = int(q_len.max())
         q = rng.randn(len(q_len), lq, hkv * 2, d).astype(np.float32)
-        args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-                jnp.asarray(ks), jnp.asarray(vs), jnp.asarray(pt),
-                jnp.asarray(pos), jnp.asarray(q_len))
+        args = tuple(jnp.asarray(a)
+                     for a in (q, *pools, pt, pos, q_len))
         ref = np.asarray(pa.ragged_attention_reference_q8(*args))
         ung = np.asarray(pa.ragged_paged_attention_q8(*args))
         grp = np.asarray(pa.ragged_paged_attention_grouped_q8(
@@ -359,6 +458,57 @@ class TestGroupedEngine:
                             attn_impl="gather", grouped=True)
         assert eng.grouped is False
 
+    @pytest.mark.parametrize("share", [True, False])
+    def test_grouped_walk_steps_counts_steps_with_a_group(
+            self, monkeypatch, share):
+        """`grouped_walk_steps_total` counts exactly the unified steps
+        whose group_cnt operand had a non-zero entry (the datum the
+        compiled step sizes phase 1 from): some of the steps of
+        prompts that share a cached prefix, none for independent
+        prompts."""
+        from paddle_tpu.serving import engine as engine_mod
+        seen = []
+
+        def spy(pt, q_len):
+            out = shared_prefix_groups(pt, q_len)
+            seen.append(bool(out[2].any()))
+            return out
+
+        monkeypatch.setattr(engine_mod, "shared_prefix_groups", spy)
+        model = tiny_gpt()
+        rng = np.random.RandomState(9)
+        sys_p = rng.randint(0, 89, size=16).astype(np.int64)
+        eng = ServingEngine(model, num_slots=3, max_len=64,
+                            page_size=8, chunk_len=16, grouped=True)
+        if share:
+            eng.generate([sys_p], SamplingParams(max_new_tokens=2))
+            prompts = self._prompts(rng, sys_p, (3, 4, 5))
+        else:
+            prompts = [rng.randint(0, 89, size=n).astype(np.int64)
+                       for n in (19, 20, 21)]
+        eng.generate(prompts, SamplingParams(max_new_tokens=6))
+        snap = eng.metrics.snapshot()
+        assert len(seen) == snap["unified_steps"] > 0
+        assert snap["grouped_walk_steps_total"] == sum(seen)
+        assert (0 < sum(seen) < len(seen)) if share else not any(seen)
+        assert eng._unified_fn._cache_size() == 1
+        text = prometheus_render({"r0": snap})
+        assert ("paddle_serving_grouped_walk_steps_total"
+                f'{{replica="r0"}} {sum(seen)}') in text
+
+    def test_grouped_off_never_counts_a_phase1_step(self):
+        model = tiny_gpt()
+        rng = np.random.RandomState(10)
+        sys_p = rng.randint(0, 89, size=16).astype(np.int64)
+        eng = ServingEngine(model, num_slots=3, max_len=64,
+                            page_size=8, chunk_len=16, grouped=False)
+        eng.generate([sys_p], SamplingParams(max_new_tokens=2))
+        eng.generate(self._prompts(rng, sys_p, (3, 4, 5)),
+                     SamplingParams(max_new_tokens=4))
+        snap = eng.metrics.snapshot()
+        assert snap["unified_steps"] > 0
+        assert snap["grouped_walk_steps_total"] == 0
+
     def test_prometheus_renders_grouped_series(self):
         model = tiny_gpt()
         rng = np.random.RandomState(6)
@@ -372,3 +522,5 @@ class TestGroupedEngine:
         assert "paddle_serving_shared_page_reads_saved_total" in text
         assert "paddle_serving_page_block_reads_total" in text
         assert "paddle_serving_group_size_per_step_bucket" in text
+        assert ("# TYPE paddle_serving_grouped_walk_steps_total counter"
+                in text)

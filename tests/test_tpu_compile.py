@@ -311,10 +311,9 @@ def test_kernel_names_are_distinct_and_on_every_site():
             assert callable(getattr(mod, kw["metadata"]["fn"]))
 
 
-def test_unified_step_names_its_kernels(one_chip, monkeypatch):
-    """The serving step lowered for the described v5e: the walk's two
-    phases and the fused LayerNorm carry their `ptk:` names and their
-    kernel functions' names."""
+def _lower_unified_step(one_chip, monkeypatch, **engine_kw):
+    """The serving step of a tiny GPT (pages and head size as served),
+    lowered for the described v5e as the chip traces it."""
     import numpy as np
     from paddle_tpu.nn.functional import norm as fnorm
     from paddle_tpu.serving import ServingEngine, SamplingParams
@@ -323,7 +322,7 @@ def test_unified_step_names_its_kernels(one_chip, monkeypatch):
     # one step on the CPU (jnp references) fixes the operands' shapes
     monkeypatch.setattr(pa, "_use_kernel", lambda: False)
     eng = ServingEngine(model, num_slots=8, max_len=256, page_size=16,
-                        chunk_len=128, attn_impl="kernel")
+                        chunk_len=128, attn_impl="kernel", **engine_kw)
     eng.add_request(np.arange(1, 40, dtype=np.int64),
                     SamplingParams(max_new_tokens=2))
     eng.run()
@@ -331,14 +330,57 @@ def test_unified_step_names_its_kernels(one_chip, monkeypatch):
     monkeypatch.setattr(pa, "_use_kernel", lambda: True)
     monkeypatch.setattr(fnorm, "_use_pallas_ln", lambda: True)
     prog = eng._build_unified()
-    text = prog._jit.lower(*_shaped(
+    return prog._jit.lower(*_shaped(
         (prog._state_vals, eng._ct, *eng._unified_args_tail),
-        one_chip)).as_text()
+        one_chip)), eng._ct[0][0].shape
+
+
+def test_unified_step_names_its_kernels(one_chip, monkeypatch):
+    """The serving step lowered for the described v5e: the walk's two
+    phases and the fused LayerNorm carry their `ptk:` names and their
+    kernel functions' names."""
+    text = _lower_unified_step(one_chip, monkeypatch)[0].as_text()
     for name in ("ragged_walk", "grouped_phase1", "layer_norm_fwd"):
         assert f"ptk:{name}" in text, name
     for fn in ("_ragged_kernel", "_grouped_phase1_kernel",
                "_ln_fwd_kernel"):
         assert fn in text, fn
+
+
+def test_unified_step_sizes_phase1_sweep_from_its_operands(one_chip,
+                                                           monkeypatch):
+    """Phase 1 of the grouped walk takes the length of its (group x
+    page) sweep as an operand (a dynamic grid bound: the whole sweep
+    only on a step where some rows share a prefix), beside the walk
+    proper and under no conditional; and that costs no copy of a pool:
+    the compiled step copies no more pool-shaped arrays than the step
+    of an engine with the grouped walk off, which has no phase 1."""
+    import re
+    lowered, pool = _lower_unified_step(one_chip, monkeypatch)
+    text = lowered.as_text()
+    assert "stablehlo.case" not in text
+    calls = {name: [ln for ln in text.splitlines()
+                    if f"ptk:{name}" in ln]
+             for name in ("grouped_phase1", "ragged_walk")}
+    assert [len(v) for v in calls.values()] == [2, 2]     # one a layer
+    # operand types close the line: the bound leads phase 1's, a
+    # scalar; the walk's begin with the page table
+    for ln in calls["grouped_phase1"]:
+        assert re.search(r": \(tensor<i32>, tensor<8x16xi32>, ", ln)
+    for ln in calls["ragged_walk"]:
+        assert re.search(r": \(tensor<8x16xi32>, ", ln)
+
+    shape = ",".join(map(str, pool))
+    pool_copy = re.compile(
+        rf"= \w+\[{shape}\]\S* (copy|copy-start)\(")
+    compiled = lowered.compile().as_text()
+    assert "ptk:grouped_phase1" in compiled
+    assert " conditional(" not in compiled
+    ungrouped = _lower_unified_step(one_chip, monkeypatch,
+                                    grouped=False)[0].compile().as_text()
+    assert "ptk:grouped_phase1" not in ungrouped
+    assert len(pool_copy.findall(compiled)) \
+        <= len(pool_copy.findall(ungrouped))
 
 
 def test_names_reach_the_compiled_instruction(one_chip, on_tpu_branch):
