@@ -10,5 +10,7 @@ from .gpt import (GPTConfig, GPTModel, GPTForCausalLM,  # noqa: F401
                   GPTForCausalLMPipe)
 from .bert import BertConfig, BertModel  # noqa: F401
 from .llama import LlamaConfig, LlamaModel, LlamaForCausalLM  # noqa: F401
+from .laguna import (LagunaConfig, LagunaModel,  # noqa: F401
+                     LagunaForCausalLM)
 from .generation import (DecodeCache, init_decode_caches,  # noqa: F401
                          update_and_attend, CompiledGenerator)

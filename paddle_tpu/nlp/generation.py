@@ -391,16 +391,25 @@ def _kv8_attend_fwd(q, k8, v8, kscale, vscale, mask):
 register_op("kv8_attend", _kv8_attend_fwd, nondiff=True)
 
 
-def _window_mask_fwd(pos, l, lmax):
+def _window_mask_fwd(pos, l, lmax, window=None):
     """Bool mask: key j visible to query i iff j <= pos + i (causal
-    within the valid window of a static cache). Scalar pos ->
+    within the valid window of a static cache) and, in a layer with a
+    sliding `window`, j > pos + i - window. Scalar pos ->
     [1, 1, l, lmax]; per-row pos vector [B] -> [B, 1, l, lmax]."""
     p = pos.astype(jnp.int32)
     i = jnp.arange(l, dtype=jnp.int32)[:, None]
     j = jnp.arange(lmax, dtype=jnp.int32)[None, :]
     if p.ndim == 1:
-        return (j[None] <= (i[None] + p[:, None, None]))[:, None]
-    return (j <= (i + p))[None, None]
+        q_pos = i[None] + p[:, None, None]
+        j = j[None]
+        axis = 1
+    else:
+        q_pos = i + p
+        axis = (0, 1)
+    live = j <= q_pos
+    if window is not None:
+        live = live & (j > q_pos - window)
+    return jnp.expand_dims(live, axis)
 
 
 register_op("window_causal_mask", _window_mask_fwd, nondiff=True)
@@ -472,7 +481,7 @@ def _tp_gather_out(out, cache):
 
 def update_and_attend(q, k_new, v_new, cache: DecodeCache,
                       dropout_p=0.0, training=False, attn_mask=None,
-                      lora_x=None):
+                      lora_x=None, window=None):
     """Write k_new/v_new at cache.pos, attend q over the valid prefix.
 
     q: [B, l, H, D]; k_new/v_new: [B, l, H_kv, D] (GQA repeat handled
@@ -495,12 +504,28 @@ def update_and_attend(q, k_new, v_new, cache: DecodeCache,
     computes the per-row q/k/v LoRA deltas from it inside the kernel
     (q/k_new/v_new then carry the BASE projections only; the caller
     handles the o-delta via `lora_delta_paged`). Ignored otherwise.
+
+    window (optional, static): the calling LAYER's sliding window, the
+    query's own position included — query at position t then attends
+    keys t - window < j <= t only. Served by the unified ragged walk
+    (which starts at the window's first page) and by the masked read
+    over a float cache, dense or paged; the int8 lanes, the megakernel
+    and the single-token decode kernel have no window and refuse one.
     """
     from ..nn import functional as F
     from ..ops import manipulation
     quant = cache.k_scale is not None
     paged = cache.page_table is not None
     l = int(q.shape[1])
+    if window is not None and (quant or cache.megakernel or (
+            paged and cache.q_len is None and l == 1
+            and resolve_paged_attn_impl(cache.attn_impl) == "kernel")):
+        raise NotImplementedError(
+            "a sliding-window layer is served by the unified ragged "
+            "step or the gather fallback over a float cache; the int8 "
+            "lanes, the megakernel and the single-token decode kernel "
+            "take no window")
+    win_attr = {} if window is None else {"window": int(window)}
     if (paged and cache.megakernel and cache.q_len is not None
             and attn_mask is None
             and resolve_paged_attn_impl(cache.attn_impl) == "kernel"):
@@ -662,14 +687,18 @@ def update_and_attend(q, k_new, v_new, cache: DecodeCache,
             args.extend(cache.group)
         if user_m is not None:
             args.append(user_m)
-        out = _tp_gather_out(apply_op(op, *args), cache)
+        # (the grouped and int8 walks take no window, which was refused
+        # above or by the engine)
+        attrs = win_attr if op == "ragged_paged_attention" else None
+        out = _tp_gather_out(
+            apply_op(op, *args, attrs=attrs or None), cache)
         return out, DecodeCache(k_buf, v_buf, cache.pos + cache.q_len,
                                 k_sc, v_sc,
                                 page_table=cache.page_table,
                                 attn_impl=cache.attn_impl,
                                 q_len=cache.q_len, group=cache.group)
     mask = apply_op("window_causal_mask", cache.pos,
-                    attrs=dict(l=int(l), lmax=int(lmax)))
+                    attrs=dict(l=int(l), lmax=int(lmax), **win_attr))
     if user_m is not None:
         mask = apply_op("decode_merge_mask", mask, user_m)
     if quant and paged:

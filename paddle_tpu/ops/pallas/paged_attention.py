@@ -183,7 +183,8 @@ __all__ = ["paged_decode_attention", "paged_attention_reference",
            "ragged_attention_reference_q8", "dequantize_paged_q8",
            "ragged_paged_attention_grouped",
            "ragged_paged_attention_grouped_q8",
-           "count_page_block_reads", "FP8_DTYPE",
+           "count_page_block_reads", "count_window_page_reads",
+           "FP8_DTYPE",
            "resolve_megakernel_flag", "MEGAKERNEL_ENV",
            "quantize_kv_rowwise", "paged_scatter", "paged_scatter_q8",
            "lora_delta", "lora_delta_paged", "megakernel_decode",
@@ -314,27 +315,50 @@ def _attend_page(q, k, v, ks, vs, live, mask, m_ref, l_ref, acc_ref, *,
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
 
-def _live_window(t, p, pos_b, qlen_b, *, ps, qblk, rep):
+def _live_window(t, p, pos_b, qlen_b, *, ps, qblk, rep, window=None):
     """bool [qblk * rep, ps]: query t*qblk + i (live iff < q_len)
     attends key position p*ps + j iff it is <= pos + query index.
-    Masks the partial tail page AND trash-page positions."""
+    Masks the partial tail page AND trash-page positions. With a
+    sliding `window` (its size, the query's own position included) the
+    key must also lie above pos + query index - window: the partial
+    page at the window's lower edge."""
     shape = (qblk, rep, ps)
     qi = t * qblk + jax.lax.broadcasted_iota(
         jnp.int32, shape, 0).reshape(qblk * rep, ps)
     k_pos = p * ps + jax.lax.broadcasted_iota(
         jnp.int32, shape, 2).reshape(qblk * rep, ps)
-    return (qi < qlen_b) & (k_pos <= pos_b + qi)
+    live = (qi < qlen_b) & (k_pos <= pos_b + qi)
+    if window is not None:
+        live = live & (k_pos > pos_b + qi - window)
+    return live
+
+
+def _window_pages(window, qblk, ps):
+    """Pages one query block of a sliding-window layer can touch: its
+    live queries see window - 1 + qblk consecutive positions at most,
+    which lie on this many pages whatever their alignment. It is the
+    length of the page axis of a window layer's grid."""
+    return (window + qblk + ps - 3) // ps + 1
+
+
+def _window_first_page(pos_b, t, *, ps, qblk, window):
+    """The page that holds the lowest key the first query of block t
+    sees: the page a window layer's walk of that block starts from."""
+    return jnp.maximum(pos_b + t * qblk - (window - 1), 0) // ps
 
 
 def _ragged_kernel(*refs, ps, qblk, rep, scale, has_mask, has_scale,
-                   fp8, grouped):
+                   fp8, grouped, window=None):
     """The per-row page walk — grid (batch_row, q_block, page). With
     `grouped` it is phase 2 of the grouped walk: each row initializes
     from its phase-1 partials and skips pages below its group's shared
     span (their contribution is already folded in), so private tail
     pages stream once per row and shared pages are never re-read. The
     merge IS the online-softmax recurrence continuing where phase 1
-    stopped, so the page order per row matches the ungrouped walk."""
+    stopped, so the page order per row matches the ungrouped walk.
+    With `window` the page axis is RELATIVE: grid step p is the p-th
+    page from the one that holds the block's lowest visible key, so
+    pages wholly below the window have no grid step at all."""
     refs = list(refs)
     n_pre = 6 if grouped else 3
     pre, refs = refs[:n_pre], refs[n_pre:]
@@ -361,6 +385,11 @@ def _ragged_kernel(*refs, ps, qblk, rep, scale, has_mask, has_scale,
     qlen_b = qlen_ref[b]
     # last valid query of THIS block (block-dead when t*qblk >= q_len)
     last_qi = jnp.minimum((t + 1) * qblk, qlen_b) - 1
+    # the page this grid step stands for (`p` itself without a window)
+    page = p
+    if window is not None:
+        page = p + _window_first_page(pos_b, t, ps=ps, qblk=qblk,
+                                      window=window)
 
     @pl.when(p == 0)
     def _init():
@@ -377,7 +406,7 @@ def _ragged_kernel(*refs, ps, qblk, rep, scale, has_mask, has_scale,
     # block attends (j <= pos + last_qi); dead blocks skip every page —
     # fully-dead pages are exactly zero under the online softmax, so
     # skipping them is not an approximation
-    go = (t * qblk < qlen_b) & (p * ps <= pos_b + last_qi)
+    go = (t * qblk < qlen_b) & (page * ps <= pos_b + last_qi)
     if grouped:
         gid_ref, gcnt_ref = pre[3], pre[5]
         go = go & (p >= gcnt_ref[gid_ref[b]])
@@ -388,8 +417,8 @@ def _ragged_kernel(*refs, ps, qblk, rep, scale, has_mask, has_scale,
             q_ref[0, 0], k_ref[0], v_ref[0],
             ks_ref[0] if has_scale else None,
             vs_ref[0] if has_scale else None,
-            _live_window(t, p, pos_b, qlen_b, ps=ps, qblk=qblk,
-                         rep=rep),
+            _live_window(t, page, pos_b, qlen_b, ps=ps, qblk=qblk,
+                         rep=rep, window=window),
             mask_ref[0, 0, 0] if has_mask else None,
             m_ref, l_ref, acc_ref, scale=scale, fp8=fp8)
 
@@ -450,9 +479,27 @@ def _grouped_phase1_kernel(tab_ref, pos_ref, qlen_ref, gid_ref,
                     acc_out.at[0, bi], scale=scale, fp8=fp8)
 
 
+# A predicated-off grid step still costs a grid step (PR 28), so a walk
+# whose grid the step's SHAPE makes longer than this is bounded, inside
+# the compiled step, by what the step's rows ask. The threshold is the
+# grid of the accepted serving cells (8 rows x 16 q-blocks x 128 pages),
+# whose compiled walk ISSUE 29 holds as it was; whether a grid that
+# short gains from a dynamic bound too is a perf_opt PR's to measure.
+_FIXED_GRID_STEPS = 8 * 16 * 128
+
+
+def _zero_dead_queries(out, q_len):
+    """out [B, lq, H, D] with the queries at or past q_len[b] zeroed:
+    a bounded grid never writes the q-blocks past its bound."""
+    alive = jnp.arange(out.shape[1], dtype=jnp.int32)[None, :] \
+        < q_len[:, None]
+    return jnp.where(alive[:, :, None, None], out,
+                     jnp.zeros((), out.dtype))
+
+
 def _ragged_attention_kernel(q, k_pool, v_pool, page_table, pos, q_len,
                              mask, k_scale=None, v_scale=None,
-                             group=None):
+                             group=None, window=None):
     """`_ragged_attention_local` on every device of the kernel mesh
     (ops/pallas/__init__.py): under the tensor-parallel serving
     replica q, the pools, the scale pools and a user mask arrive
@@ -472,14 +519,15 @@ def _ragged_attention_kernel(q, k_pool, v_pool, page_table, pos, q_len,
         specs["k_scale"] = specs["v_scale"] = P(None, None, "heads")
     if group is not None:
         ops["group"], specs["group"] = tuple(group), (rows, rows, rows)
+    extra = {} if window is None else {"window": window}
     return _per_device(
-        lambda o: _ragged_attention_local(**{"mask": None, **o}),
+        lambda o: _ragged_attention_local(**{"mask": None, **o}, **extra),
         (specs,), heads)(ops)
 
 
 def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
                             mask, k_scale=None, v_scale=None,
-                            group=None):
+                            group=None, window=None):
     """q [B, lq, H, D]; pools [P, ps, H_kv, D]; page_table
     [B, max_pages] int32; pos/q_len [B] int32; mask None | additive f32
     [B, H, lq, lmax]. lq is padded up to a multiple of the query block
@@ -509,7 +557,36 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
     row whose table phase 1 walks; singleton rows ride with group_cnt
     0 and take phase 2 only, which is exactly the ungrouped walk. On a
     step where every group_cnt is 0 phase 1 shrinks to one grid step a
-    q_block (`_grouped_phase1`)."""
+    q_block (`_grouped_phase1`).
+
+    window (a sliding-window layer; None for full attention, which
+    leaves this function's program as it was): query i of row b sees
+    keys pos + i - window < j <= pos + i. The grid's page axis shrinks
+    to `_window_pages` steps, counted from the page that holds the
+    block's lowest visible key (`_window_first_page`), so a page wholly
+    below the window is neither fetched nor computed, and the partial
+    page at the edge is masked in `_live_window`. The page table may be
+    a ring over fewer physical pages than it has columns (the serving
+    engine's table for such layers is): only the pages of the window
+    are ever read. Neither groups nor a user mask combine with it.
+
+    A long grid is bounded. Where the fixed grid (rows x q-blocks x
+    pages, from the step's shape alone) is longer than
+    `_FIXED_GRID_STEPS` and neither groups nor a mask ride along, its
+    q-block and page axes become DYNAMIC bounds: the q-blocks of the
+    row with most live queries and the pages of the longest live
+    context (of the window, in a window layer), decided inside the one
+    compiled step from `pos` and `q_len`. At 16 rows x 16 q-blocks x
+    512 pages the fixed grid is 131072 steps a layer whatever the rows
+    hold (32 ms a layer in `laguna-s-2.1.code_mixed`; PERF.md section
+    6, PR 29); a step of decode rows over 3000-token contexts needs
+    16 x 1 x 188. The q-blocks past the bound are never written, so
+    the dead queries' outputs are zeroed after the call. A grid at or
+    under the threshold compiles as it always did."""
+    if window is not None and (group is not None or mask is not None):
+        raise NotImplementedError(
+            "the page walk of a sliding-window layer takes neither "
+            "prefix-sharing groups nor a user attention mask")
     b, lq, h, d = q.shape
     _, ps, hkv, _ = k_pool.shape
     mp = page_table.shape[1]
@@ -544,6 +621,11 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
         if grouped:
             gid, _, gcn = grp
             lo = jnp.minimum(gcn[gid[bi]], lp)
+        if window is not None:
+            # the walk starts at the window's first page
+            lo = jnp.minimum(_window_first_page(
+                posr[bi], t, ps=ps, qblk=qblk, window=window), lp)
+            p = p + lo
         return tab[bi, jnp.clip(p, lo, lp)]
 
     def kv_idx(bi, t, p, *pre):
@@ -587,12 +669,24 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
         kernel = functools.partial(
             _ragged_kernel, ps=ps, qblk=qblk, rep=rep, scale=scale,
             has_mask=mask is not None, has_scale=has_scale, fp8=fp8,
-            grouped=grouped)
+            grouped=grouped,
+            **({} if window is None else {"window": window}))
+        n_pages = mp if window is None else \
+            min(mp, _window_pages(window, qblk, ps))
+        n_qblk = nqb
+        bounded = (not grouped and mask is None
+                   and b * nqb * n_pages > _FIXED_GRID_STEPS)
+        if bounded:
+            live = q_len > 0
+            n_qblk = jnp.clip(jnp.max((q_len + qblk - 1) // qblk), 1, nqb)
+            if window is None:
+                n_pages = jnp.clip(jnp.max(jnp.where(
+                    live, (pos + q_len - 1) // ps + 1, 1)), 1, mp)
         out = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(prefetch),
-                grid=(b, nqb, mp),
+                grid=(b, n_qblk, n_pages),
                 in_specs=in_specs,
                 out_specs=q_spec,
                 scratch_shapes=[
@@ -607,8 +701,9 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
             interpret=_INTERPRET,
             **KERNELS["ragged_walk"],
         )(*prefetch, *ops)
-    return out.reshape(b, nqb, hkv, qblk, rep, d) \
+    out = out.reshape(b, nqb, hkv, qblk, rep, d) \
         .transpose(0, 1, 3, 2, 4, 5).reshape(b, lq_pad, h, d)[:, :lq]
+    return _zero_dead_queries(out, q_len) if bounded else out
 
 
 def _grouped_phase1(prefetch, ops, *, b, mp, ps, hkv, d, qblk, nqb, rep,
@@ -765,17 +860,21 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, pos,
                                      posv, mask)
 
 
-def _ragged_mask_attend(q, kf, vf, pos, q_len, mask):
+def _ragged_mask_attend(q, kf, vf, pos, q_len, mask, window=None):
     """Shared tail of the ragged references: grouped softmax over the
     dense logical K/V views under the ragged causal window — query i of
-    row b attends keys j <= pos[b] + i, queries at i >= q_len[b] are
-    fully masked (their outputs are unspecified)."""
+    row b attends keys j <= pos[b] + i (and, in a sliding-window layer,
+    j > pos[b] + i - window), queries at i >= q_len[b] are fully masked
+    (their outputs are unspecified)."""
     b, lq, h, _ = q.shape
     lmax = kf.shape[1]
     i = jnp.arange(lq, dtype=jnp.int32)[None, :, None]
     j = jnp.arange(lmax, dtype=jnp.int32)[None, None, :]
     live = (i < q_len.astype(jnp.int32)[:, None, None]) & \
         (j <= pos.astype(jnp.int32)[:, None, None] + i)
+    if window is not None:
+        live = live & (j > pos.astype(jnp.int32)[:, None, None] + i
+                       - window)
     add = jnp.where(live, jnp.float32(0.0), jnp.float32(_NEG_INF))
     add = add[:, None]                            # [B, 1, lq, lmax]
     if mask is not None:
@@ -784,7 +883,7 @@ def _ragged_mask_attend(q, kf, vf, pos, q_len, mask):
 
 
 def ragged_attention_reference(q, k_pool, v_pool, page_table, pos,
-                               q_len, mask=None):
+                               q_len, mask=None, window=None):
     """Pure-JAX ragged reference: gather the rows' pages into the dense
     logical view and run the grouped softmax under the ragged causal
     window. At lq == 1 this is EXACTLY `paged_attention_reference`'s
@@ -802,7 +901,7 @@ def ragged_attention_reference(q, k_pool, v_pool, page_table, pos,
         # fp8 lane: pure-convert dequant of the gathered view
         kf = kf.astype(jnp.float32)
         vf = vf.astype(jnp.float32)
-    return _ragged_mask_attend(q, kf, vf, pos, q_len, mask)
+    return _ragged_mask_attend(q, kf, vf, pos, q_len, mask, window)
 
 
 def dequantize_paged_q8(pool, scale_pool, page_table):
@@ -835,7 +934,7 @@ def ragged_attention_reference_q8(q, k_pool, v_pool, k_scale, v_scale,
 
 
 def ragged_paged_attention(q, k_pool, v_pool, page_table, pos, q_len,
-                           mask=None):
+                           mask=None, window=None):
     """Ragged paged attention over per-row query lengths (the
     registered op's forward): one invocation serves a mixed batch of
     mid-prefill rows (q_len > 1) and decoding rows (q_len == 1) against
@@ -845,7 +944,11 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, pos, q_len,
     dead (q_len == 0): no position advances and the row's output is
     unspecified-but-finite. mask: optional user attention mask (bool or
     additive float, broadcastable [B|1, H|1, lq|1, lmax]), composed
-    with the ragged causal window in-kernel."""
+    with the ragged causal window in-kernel. window (static; None for
+    full attention): the layer's sliding window, the query's own
+    position included — query i then attends only keys
+    j > pos[b] + i - window, and the walk starts at the window's first
+    page (`_ragged_attention_local`)."""
     b, lq, h, d = q.shape
     lmax = page_table.shape[1] * k_pool.shape[1]
     posv = pos.astype(jnp.int32)
@@ -861,9 +964,9 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, pos, q_len,
     if _use_kernel():
         return _ragged_attention_kernel(
             q, k_pool, v_pool, page_table.astype(jnp.int32), posv, qlv,
-            mask)
+            mask, window=window)
     return ragged_attention_reference(q, k_pool, v_pool, page_table,
-                                      posv, qlv, mask)
+                                      posv, qlv, mask, window)
 
 
 def ragged_paged_attention_q8(q, k_pool, v_pool, k_scale, v_scale,
@@ -1400,6 +1503,22 @@ def spec_verify_accept(logits_v, toks, q_len, is_decode):
         jnp.where(match & valid, 1, 0), axis=1).sum(axis=1) \
         .astype(jnp.int32)
     return jnp.where(is_decode, accept, 0)
+
+
+def count_window_page_reads(pos, q_len, *, page_size, window):
+    """Host-side (numpy) model of one sliding-window layer's walk, per
+    kv-head walk as `count_page_block_reads` counts: (pages walked,
+    pages a walk without the window would have read) summed over the
+    live rows. A row's walk covers the pages from the one that holds
+    its first query's lowest visible key to the one its last query
+    writes; without a window it starts at page 0."""
+    pos = np.asarray(pos, np.int64)
+    q_len = np.asarray(q_len, np.int64)
+    live = q_len > 0
+    last = (pos + np.maximum(q_len, 1) - 1) // page_size
+    first = np.maximum(pos - (window - 1), 0) // page_size
+    return (int(np.where(live, last - first + 1, 0).sum()),
+            int(np.where(live, last + 1, 0).sum()))
 
 
 def count_page_block_reads(page_table, pos, q_len, group_id=None,

@@ -192,6 +192,7 @@ import itertools
 import os
 import threading
 import time
+import warnings
 from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import numpy as np
@@ -209,6 +210,7 @@ from ..nlp.generation import (_StepProgram, _pack_caches,
                               _unpack_caches, decode_model_step,
                               resolve_paged_attn_impl, FP8_DTYPE)
 from ..ops.pallas.paged_attention import (count_page_block_reads,
+                                          count_window_page_reads,
                                           resolve_megakernel_flag)
 from .adapters import (AdapterStore, BASE_ADAPTER,
                        resolve_adapters_flag)
@@ -446,15 +448,41 @@ class ServingEngine:
                  slo=None, cost_census=None, grammar=None,
                  megakernel=None, session_ttl_s: float = 30.0,
                  draft_pages: Optional[int] = None):
+        # THE CACHE-SPEC CONTRACT. `model._decode_cache_spec()` (or
+        # the `cache_spec` argument) is
+        #   (n_layers, n_kv_heads, head_dim)
+        # for a model whose layers all attend their whole context: one
+        # KV geometry, one paged pool a layer, and ONE page table a
+        # slot that every layer shares; or
+        #   (n_layers, n_kv_heads, head_dim, windows)
+        # for a model with layer KINDS: `windows[i]` is layer i's
+        # sliding window in tokens (the query's own position included)
+        # or None for full attention. KV heads and head size are still
+        # one pair for all layers (query heads may differ: they never
+        # reach the cache). A window layer's attention module passes
+        # its window to `update_and_attend`; the engine gives such a
+        # layer a pool of its own, a per-slot RING of pages behind a
+        # static page table (below), and leaves the full layers on the
+        # shared paged pool.
         if cache_spec is None:
             if not hasattr(model, "_decode_cache_spec"):
                 raise ValueError(
                     "cache_spec not given and the model has no "
                     "_decode_cache_spec(); pass (n_layers, n_kv_heads, "
-                    "head_dim) explicitly")
+                    "head_dim), or (n_layers, n_kv_heads, head_dim, "
+                    "windows) with each layer's sliding window or None, "
+                    "explicitly")
             cache_spec = model._decode_cache_spec()
         self.model = model
-        self.n_layers, self.n_kv, self.head_dim = cache_spec
+        self.n_layers, self.n_kv, self.head_dim = cache_spec[:3]
+        windows = tuple(cache_spec[3]) if len(cache_spec) > 3 \
+            else (None,) * self.n_layers
+        if len(windows) != self.n_layers:
+            raise ValueError(f"cache_spec lists {len(windows)} windows "
+                             f"for {self.n_layers} layers")
+        # layer index -> window, for the layers that have one
+        self.kv_windows = {i: int(w) for i, w in enumerate(windows)
+                           if w is not None}
         self.num_slots = int(num_slots)
         self.max_len = int(max_len)
         self.page_size = int(page_size)
@@ -612,6 +640,42 @@ class ServingEngine:
                            and self.unified
                            and self.attn_impl == "kernel"
                            and self.tp is None)
+        if self.kv_windows:
+            # WINDOW LAYERS: what the engine does not do for them yet.
+            # Their KV lives in a per-slot ring that holds the last
+            # window + chunk positions only, so a page of the shared
+            # pool no longer stands for the same tokens in every
+            # layer: reusing a prefix (which would need the window
+            # layers' KV of the prefix's LAST window positions, kept
+            # and copied with it), swapping a resident out to the host
+            # tier and preempting one are switched off, loudly;
+            # silently wrong reuse is the one outcome that must not
+            # happen. ROADMAP.md (Queue 2) says what each would take.
+            wanted = [name for name, given in (
+                ("prefix_cache", prefix_cache), ("preempt", preempt),
+                ("host_pages", host_pages)) if given]
+            unbuilt = [name for name, on in (
+                ("mesh", self.tp is not None),
+                ("unified=False", not self.unified),
+                ("megakernel", self.megakernel),
+                ("adapters", bool(adapters)),
+                ("kv_dtype", resolve_kv_dtype(kv_dtype) != "fp"),
+                ("spec", self.spec is not None)) if on]
+            if wanted or unbuilt:
+                raise ValueError(
+                    f"the model has sliding-window layers "
+                    f"{sorted(self.kv_windows)}: {wanted + unbuilt} "
+                    f"cannot be had with them yet (the prefix cache, "
+                    f"the host tier and preemption would reuse or move "
+                    f"pages whose window-layer KV is gone; the others "
+                    f"have no window in their attention path)")
+            warnings.warn(
+                f"ServingEngine: sliding-window layers "
+                f"{sorted(self.kv_windows)}: the prefix cache, the "
+                f"host page tier, preemption and the grouped walk are "
+                f"switched off for this model", stacklevel=2)
+            prefix_cache, preempt, host_pages = False, False, 0
+            self.grouped = False
         self.metrics = metrics or ServingMetrics()
         self.metrics.attn_impl = self.attn_impl
         self.metrics.unified = self.unified
@@ -709,6 +773,27 @@ class ServingEngine:
         # code + scale pages together so int8 streams stay
         # deterministic across all of them.
         self.kv_dtype = resolve_kv_dtype(kv_dtype)
+        # window layers' KV: slot s owns pages 1 + s * ring_pages ..
+        # of each such layer's pool (page 0 stays the trash page), used
+        # as a RING: logical page lp of the slot lives in ring page
+        # lp % ring_pages. One step writes at most chunk_len positions
+        # ahead of the lowest key any of its queries still sees
+        # (window - 1 back), so window - 1 + chunk_len positions are
+        # alive at once: whatever max_len is, the ring holds that many
+        # plus page rounding. The table is static (never dirty, never
+        # trash-masked: a free slot's dead writes land in its own
+        # ring, which the next tenant overwrites from position 0 on
+        # before it reads).
+        self.ring_pages = 0
+        self._pt_ring = None
+        if self.kv_windows:
+            self.ring_pages = min(self.max_pages, -(-(
+                max(self.kv_windows.values()) + self.chunk_len)
+                // self.page_size) + 1)
+            self._pt_ring = jnp.asarray(
+                1 + np.arange(self.num_slots)[:, None] * self.ring_pages
+                + np.arange(self.max_pages)[None, :] % self.ring_pages,
+                jnp.int32)
         # device state: per-layer shared K/V pools, per-slot positions,
         # per-slot held next-token logits (filled by the final prefill
         # chunk, advanced by decode)
@@ -730,13 +815,16 @@ class ServingEngine:
             # spill) works on them unchanged
             pool_dt = (FP8_DTYPE if self.kv_dtype == "fp8"
                        else self._fp)
+            ring_total = self.num_slots * self.ring_pages + 1
             self._ct = tuple(
-                (jnp.zeros((self.num_pages, self.page_size, self.n_kv,
+                (jnp.zeros((pages, self.page_size, self.n_kv,
                             self.head_dim), pool_dt),
-                 jnp.zeros((self.num_pages, self.page_size, self.n_kv,
+                 jnp.zeros((pages, self.page_size, self.n_kv,
                             self.head_dim), pool_dt),
                  None, None)
-                for _ in range(self.n_layers))
+                for pages in (ring_total if i in self.kv_windows
+                              else self.num_pages
+                              for i in range(self.n_layers)))
         if self.tp is not None:
             # shard every pool over its kv-head axis (scale pools
             # alongside their code pools: a page and its scales are
@@ -754,8 +842,9 @@ class ServingEngine:
         kv_itemsize = (1 if self.kv_dtype in ("int8", "fp8")
                        else jnp.dtype(self._fp).itemsize)
         scale_bytes = 4 if self.kv_dtype == "int8" else 0
-        self.page_bytes = (self.n_layers * 2 * self.page_size
-                           * self.n_kv
+        # (a page of the shared pool: the full-attention layers only)
+        self.page_bytes = ((self.n_layers - len(self.kv_windows)) * 2
+                           * self.page_size * self.n_kv
                            * (self.head_dim * kv_itemsize
                               + scale_bytes))
         self.metrics.kv_dtype = self.kv_dtype
@@ -845,6 +934,11 @@ class ServingEngine:
         self._prefill_fns: Dict[int, object] = {}   # chunk bucket -> fn
         self._decode_fn = None
         self._unified_fn = None      # the ONE compiled ragged step
+        # counters a model keeps on the device (`STEP_STAT_COUNTERS`
+        # names them, `_step_stats()` yields them after a forward
+        # pass): the unified step appends them to its `accept` output
+        self._step_stat_names = tuple(
+            getattr(model, "STEP_STAT_COUNTERS", ()))
         # embeddings-lane epilogue (satellite): a pure-READ batched
         # one-token forward through the model BACKBONE (hidden states,
         # no LM head) that recomputes each retiring embed row's
@@ -1065,6 +1159,22 @@ class ServingEngine:
     def _restore_state(self, originals):
         _restore_state(self._state_tensors, originals)
 
+    def _unpack(self, ct, pos, page_table, rows=None, **kw):
+        """`_unpack_caches`, then each window layer's cache on its ring
+        table (`rows`: the one slot a batch-1 program serves)."""
+        caches = _unpack_caches(ct, pos, page_table,
+                                attn_impl=self.attn_impl,
+                                out_shard=self._out_shard, **kw)
+        if self.kv_windows:
+            ring = self._pt_ring
+            if rows is not None:
+                ring = jax.lax.dynamic_slice(
+                    ring, (rows, jnp.zeros((), jnp.int32)),
+                    (1, ring.shape[1]))
+            for i in self.kv_windows:
+                caches[i].page_table = Tensor(ring)
+        return caches
+
     def _build_prefill(self, bucket: int):
         """Compiled once per chunk BUCKET (not per prompt length): a
         batch-1 forward of `bucket` tokens for one slot, scattering the
@@ -1083,9 +1193,7 @@ class ServingEngine:
                 s = slot.astype(jnp.int32).reshape(())
                 pt_row = jax.lax.dynamic_slice(
                     page_table, (s, z), (1, page_table.shape[1]))
-                caches = _unpack_caches(ct, start, pt_row,
-                                        attn_impl=self.attn_impl,
-                                        out_shard=self._out_shard)
+                caches = self._unpack(ct, start, pt_row, rows=s)
                 logits_t, caches = model(Tensor(tokens), caches=caches)
                 v = logits_t._value.shape[-1]
                 row = jax.lax.dynamic_slice(
@@ -1116,9 +1224,7 @@ class ServingEngine:
                 nxt = _sample_rows(last_logits, key, temps, top_k,
                                    top_p, greedy)
                 nxt = jnp.where(active, nxt, 0).astype(jnp.int32)
-                caches = _unpack_caches(ct, pos, page_table,
-                                        attn_impl=self.attn_impl,
-                                        out_shard=self._out_shard)
+                caches = self._unpack(ct, pos, page_table)
                 last, caches = decode_model_step(model, nxt[:, None],
                                                  caches)
                 # only occupied slots advance; free/prefilling rows stay
@@ -1217,13 +1323,11 @@ class ServingEngine:
                         lora_layers = [
                             tuple(t[apage] for t in layer) + (ascale,)
                             for layer in apools]
-                caches = _unpack_caches(ct, pos, page_table,
-                                        attn_impl=self.attn_impl,
-                                        q_len=q_len, group=group,
-                                        out_shard=self._out_shard,
-                                        lora=lora_layers,
-                                        lora_paged=lora_paged_layers,
-                                        megakernel=self.megakernel)
+                caches = self._unpack(ct, pos, page_table,
+                                      q_len=q_len, group=group,
+                                      lora=lora_layers,
+                                      lora_paged=lora_paged_layers,
+                                      megakernel=self.megakernel)
                 logits_t, caches = model(Tensor(toks), caches=caches)
                 lg = logits_t._value.astype(jnp.float32)   # [S, W, V]
                 # greedy draft verification: column i's argmax is the
@@ -1268,6 +1372,13 @@ class ServingEngine:
                 new_last = jnp.where(live, row_last, last_logits)
                 new_pos = pos + jnp.where(is_decode, 1 + accept,
                                           q_len)
+                if self._step_stat_names:
+                    # what the model counted on the device rides out
+                    # behind `accept`, in the fetch the host makes
+                    # anyway
+                    accept = jnp.concatenate(
+                        [accept, model._step_stats()._value
+                         .astype(jnp.int32)])
                 return (_pack_caches(caches), new_pos, new_last, nxt,
                         accept)
             finally:
@@ -1327,9 +1438,7 @@ class ServingEngine:
         def estep(state_vals, ct, pos, page_table, tokens):
             originals = self._swap_state(state_vals)
             try:
-                caches = _unpack_caches(ct, pos, page_table,
-                                        attn_impl=self.attn_impl,
-                                        out_shard=self._out_shard)
+                caches = self._unpack(ct, pos, page_table)
                 h, _ = backbone(Tensor(tokens), caches=caches)
                 return h._value[:, -1, :].astype(jnp.float32)
             finally:
@@ -2633,6 +2742,9 @@ class ServingEngine:
                 with self._phase(SPAN_FETCH, "step_fetch_s_total"):
                     toks = np.asarray(toks)
                     accept = np.asarray(accept)
+                for name, n in zip(self._step_stat_names,
+                                   accept[self.num_slots:]):
+                    self._host_phases[name] += int(n)
         finally:
             set_dispatch_probe(prev_probe)
         with self._phase(SPAN_COMMIT, "step_commit_s_total"):
@@ -2738,6 +2850,13 @@ class ServingEngine:
                                        fused=fused_spec, **shard)
         self.metrics.on_grouped_step(flat_reads, step_reads,
                                      group_sizes, phase1=phase1)
+        for window in self.kv_windows.values():
+            walked, unwindowed = count_window_page_reads(
+                pos_host, q_len, page_size=self.page_size,
+                window=window)
+            self._host_phases["kv_window_pages_walked_total"] += walked
+            self._host_phases["kv_window_pages_skipped_total"] += \
+                unwindowed - walked
         # per-layer walk bytes -> whole-step modeled bytes: every
         # layer's attention issues the same walk over its own pools
         self._last_walk_bytes = {

@@ -27,7 +27,7 @@ from collections import deque
 from typing import Optional, Sequence
 
 __all__ = ["Histogram", "ServingMetrics", "prometheus_render",
-           "HOST_PHASE_COUNTERS",
+           "HOST_PHASE_COUNTERS", "STEP_WORK_COUNTERS",
            "TTFT_BUCKETS", "LATENCY_BUCKETS", "PACKED_TOKEN_BUCKETS",
            "SPEC_TOKEN_BUCKETS", "GROUP_SIZE_BUCKETS", "UTIL_BUCKETS"]
 
@@ -83,6 +83,26 @@ HOST_PHASE_COUNTERS = (
     "kv_spill_pages_total",     # serving::spill, one a page
     "submit_wait_s_total",      # http::submit until add_request
     "submits_serviced_total",   # submissions the pump thread took
+)
+
+
+# What a step did, counted where it is known and flushed with the host
+# phases (one `on_host_phases` call a round). `moe_*`: made on the
+# device by a model with routed experts (its `STEP_STAT_COUNTERS`),
+# carried out in the step's own fetch: (token, expert) assignments
+# routed over all the router's outputs, those computed by the experts
+# held here, over layers and steps the local experts that received at
+# least one token, and the expert layers run (steps x such layers).
+# `kv_window_*`: made on the host beside the page-read model, a
+# sliding-window layer and step: pages its walk covered, and pages a
+# walk without the window would have read besides.
+STEP_WORK_COUNTERS = (
+    "moe_assignments_total",
+    "moe_assignments_here_total",
+    "moe_experts_hit_total",
+    "moe_layer_steps_total",
+    "kv_window_pages_walked_total",
+    "kv_window_pages_skipped_total",
 )
 
 
@@ -253,7 +273,8 @@ class ServingMetrics:
         # tag next to attn_impl so scrapes can tell the paths apart
         self.unified: Optional[bool] = None
         # HOST_PHASE_COUNTERS, all cumulative
-        self.host_phases = dict.fromkeys(HOST_PHASE_COUNTERS, 0)
+        self.host_phases = dict.fromkeys(
+            HOST_PHASE_COUNTERS + STEP_WORK_COUNTERS, 0)
         # unified-step counters: steps run, and the packed token split
         self.unified_steps = 0
         self.packed_prefill_tokens = 0
@@ -962,8 +983,8 @@ def prometheus_render(snapshots: dict, namespace: str = "paddle_serving",
                        ("cost_census_capacity_tokens", "gauge"),
                        ("slo_state", "gauge"),
                        ("slo_burn_rate", "gauge"),
-                       *((name, "counter")
-                         for name in HOST_PHASE_COUNTERS)]:
+                       *((name, "counter") for name in
+                         HOST_PHASE_COUNTERS + STEP_WORK_COUNTERS)]:
         lines.append(f"# TYPE {namespace}_{name} {kind}")
     for replica, snap in sorted(snapshots.items()):
         lab = {"replica": str(replica)}
@@ -1032,7 +1053,7 @@ def prometheus_render(snapshots: dict, namespace: str = "paddle_serving",
                      + f" {snap.get('grouped_walk_steps_total', 0)}")
         # is the host or the chip the limit: seconds per phase of the
         # host's loop, beside unified_steps_total
-        for name in HOST_PHASE_COUNTERS:
+        for name in HOST_PHASE_COUNTERS + STEP_WORK_COUNTERS:
             lines.append(f"{namespace}_{name}" + _fmt_labels(lab)
                          + f" {snap.get(name, 0)}")
         lines.append(f"{namespace}_prefill_stall_steps_total"

@@ -948,6 +948,10 @@ ELSEWHERE = {
     # rotary embedding — tests/test_nlp_models.py (Llama family)
     "rope": EW("test_nlp_models.py", "Llama|rope"),
     "rope_dyn": EW("test_nlp_models.py", "Llama|rope"),
+    # Laguna's ops (nlp/laguna.py): against the plain float32 reference
+    "rope_half": EW("test_laguna.py", "eager_forward|rotary"),
+    "head_gate": EW("test_laguna.py", "eager_forward"),
+    "moe_routed_experts": EW("test_laguna.py", "eager_forward|share"),
     # quantization — tests/test_inference_quant.py
     "fake_quantize_dequantize": EW("test_inference_quant.py",
                                    "quant"),

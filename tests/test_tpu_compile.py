@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import layer_norm as pln
+from paddle_tpu.ops.pallas import moe
 from paddle_tpu.ops.pallas import paged_attention as pa
 
 B, H, D, PS, PAGES, MP, CHUNK, VOCAB = 8, 16, 128, 16, 2048, 128, 128, 50304
@@ -58,7 +59,7 @@ def real_kernels(monkeypatch):
     """Not interpret mode, whatever an earlier test file asked for
     (test_pallas_layer_norm.py sets PADDLE_TPU_PALLAS_INTERPRET at
     import, and the kernel modules read it when THEY are imported)."""
-    for mod in (fa, pln, pa):
+    for mod in (fa, pln, pa, moe):
         monkeypatch.setattr(mod, "_INTERPRET", False)
 
 
@@ -96,6 +97,43 @@ def test_paged_walk_bf16(one_chip, lq):
         lambda q, k, v, t, p, n: pa._ragged_attention_kernel(
             q, k, v, t, p, n, None),
         one_chip, _q(lq), _pool(BF16), _pool(BF16), TABLE, ROW, ROW)
+
+
+@pytest.mark.parametrize("lq", [CHUNK, 1])
+@pytest.mark.parametrize("heads,window", [(72, 512), (48, None)],
+                         ids=["window72", "full48"])
+def test_paged_walk_laguna(one_chip, lq, heads, window):
+    """Laguna-S-2.1's two layer kinds at its serving shape: 16 slots,
+    8 KV heads under 72 (window 512, over the per-slot ring's table) or
+    48 query heads, max_len 8192. The window layer's grid is 34 pages
+    long at most; the full layer's would be 512, which is longer than
+    `_FIXED_GRID_STEPS` at a chunk's 16 q-blocks, so that one compiles
+    with the dynamic bounds."""
+    slots, mp, ring = 16, 512, 41
+    text = _compiles_to_kernel(
+        lambda q, k, v, t, p, n: pa._ragged_attention_kernel(
+            q, k, v, t, p, n, None, window=window),
+        one_chip, ((slots, lq, heads, D), BF16),
+        *[((slots * (ring if window else mp) + 1, PS, 8, D), BF16)] * 2,
+        ((slots, mp), I32), ((slots,), I32), ((slots,), I32))
+    assert "ptk:ragged_walk" in text
+
+
+@pytest.mark.parametrize("rows", [16 * CHUNK, 16], ids=["step", "decode"])
+def test_moe_routed_experts(one_chip, rows, monkeypatch):
+    """The routed experts of one Laguna-S-2.1 layer, this chip's 128 of
+    256, over the unified step's 2048 token rows (and over 16): the
+    expert kernel under its `ptk:` name."""
+    h, f, held = 3072, 1024, 128
+    monkeypatch.setattr(moe, "_use_kernel", lambda: True)   # as the chip would
+    text = _compiles_to_kernel(
+        lambda x, v, wr, wg, wu, wd: moe.routed_experts(
+            x, v, wr, wg, wu, wd, top_k=10, scale=2.5, norm_topk=True,
+            first=0),
+        one_chip, ((rows, h), BF16), ((rows,), jnp.bool_),
+        ((h, 256), BF16), ((held, h, f), BF16), ((held, h, f), BF16),
+        ((held, f, h), BF16))
+    assert "ptk:moe_experts" in text
 
 
 @pytest.mark.parametrize("lq", [CHUNK, 1])
