@@ -68,9 +68,11 @@ inside the one compiled step from operand data — the whole sweep on a
 step where some rows share, ONE grid step a q_block (which writes the
 virgin partials and nothing else) on a step where none do, because a
 predicated-off grid step still costs a grid step and the full sweep
-has as many as the walk proper. Page order per row is IDENTICAL to the
-ungrouped kernel (shared pages 0..cnt-1 then private cnt..last, the
-same online-softmax recurrence), so outputs match the ungrouped walk;
+has as many as the walk proper. Its q-block axis is the walk proper's
+own dynamic bound (below), the same in both phases. Page order per row
+is IDENTICAL to the ungrouped kernel (shared pages 0..cnt-1 then
+private cnt..last, the same online-softmax recurrence), so outputs
+match the ungrouped walk;
 off-TPU the op runs the SAME `ragged_attention_reference` as the
 ungrouped op — grouping is a pure HBM-traffic hint, bit-identical by
 construction. `count_page_block_reads` is the host-side model of both
@@ -102,9 +104,15 @@ blocks past q_len[b] and pages past the row's live prefix
 ceil((pos[b] + q_len[b]) / page_size) are skipped: their grid steps
 clamp the K/V block index to the last live page (no re-fetch) and
 predicate compute off, so both HBM traffic and MXU work scale with the
-tokens actually packed, not with the padded step shape. Outputs at
-query positions >= q_len[b] are unspecified-but-finite (the engine
-discards them).
+tokens actually packed, not with the padded step shape. The GRID is
+as long as the rows ask too: its q-block and page axes are dynamic
+bounds (`walk_grid_bounds`: the q-blocks of the row with most live
+queries, the pages of the longest live context), decided inside the
+one compiled step from `pos` and `q_len`, for every walk: plain,
+grouped (both phases), masked, int8, fp8 and windowed. A skipped grid
+step still costs a grid step, and the padded shape has tens of times
+more of them than a step of decode rows needs. Outputs at query
+positions >= q_len[b] are zero (the engine discards them).
 
 MEGAKERNEL (`megakernel_decode` / `megakernel_decode_q8`, gated
 PADDLE_TPU_MEGAKERNEL, default off): the decode layer's remaining op
@@ -184,6 +192,7 @@ __all__ = ["paged_decode_attention", "paged_attention_reference",
            "ragged_paged_attention_grouped",
            "ragged_paged_attention_grouped_q8",
            "count_page_block_reads", "count_window_page_reads",
+           "count_walk_grid_steps", "walk_grid_bounds",
            "FP8_DTYPE",
            "resolve_megakernel_flag", "MEGAKERNEL_ENV",
            "quantize_kv_rowwise", "paged_scatter", "paged_scatter_q8",
@@ -479,18 +488,33 @@ def _grouped_phase1_kernel(tab_ref, pos_ref, qlen_ref, gid_ref,
                     acc_out.at[0, bi], scale=scale, fp8=fp8)
 
 
-# A predicated-off grid step still costs a grid step (PR 28), so a walk
-# whose grid the step's SHAPE makes longer than this is bounded, inside
-# the compiled step, by what the step's rows ask. The threshold is the
-# grid of the accepted serving cells (8 rows x 16 q-blocks x 128 pages),
-# whose compiled walk ISSUE 29 holds as it was; whether a grid that
-# short gains from a dynamic bound too is a perf_opt PR's to measure.
-_FIXED_GRID_STEPS = 8 * 16 * 128
+def _query_blocks(lq):
+    """(query block size, query blocks) a walk over lq query positions
+    a row tiles them into."""
+    qblk = min(lq, 8)
+    return qblk, -(-lq // qblk)
+
+
+def walk_grid_bounds(pos, q_len, *, lq, page_size, max_pages, xp=jnp):
+    """The two dynamic bounds of a full-attention walk's grid, from
+    what the step's rows ask: (the q-blocks of the row with most live
+    queries, the pages of the longest live context), each at least 1.
+    A q-block at or past the first holds dead queries only, and a page
+    at or past the second lies beyond every row's causal horizon, so
+    the grid steps the bounds remove were predicated off. One
+    expression for the traced wrapper (`xp=jnp`, int32 [B] operands of
+    the compiled step) and for the host's count of the same step
+    (`xp=np`, `count_walk_grid_steps`): they cannot drift."""
+    qblk, nqb = _query_blocks(lq)
+    n_qblk = xp.clip(xp.max((q_len + qblk - 1) // qblk), 1, nqb)
+    n_pages = xp.clip(xp.max(xp.where(
+        q_len > 0, (pos + q_len - 1) // page_size + 1, 1)), 1, max_pages)
+    return n_qblk, n_pages
 
 
 def _zero_dead_queries(out, q_len):
     """out [B, lq, H, D] with the queries at or past q_len[b] zeroed:
-    a bounded grid never writes the q-blocks past its bound."""
+    the grid never writes the q-blocks past its bound."""
     alive = jnp.arange(out.shape[1], dtype=jnp.int32)[None, :] \
         < q_len[:, None]
     return jnp.where(alive[:, :, None, None], out,
@@ -570,19 +594,21 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
     engine's table for such layers is): only the pages of the window
     are ever read. Neither groups nor a user mask combine with it.
 
-    A long grid is bounded. Where the fixed grid (rows x q-blocks x
-    pages, from the step's shape alone) is longer than
-    `_FIXED_GRID_STEPS` and neither groups nor a mask ride along, its
-    q-block and page axes become DYNAMIC bounds: the q-blocks of the
+    The grid is as long as the step's rows ask, never as long as the
+    step's SHAPE allows: a predicated-off grid step still costs a grid
+    step (0.12-0.16 us on a v5e; PERF.md section 6, PR 28 and 30), and
+    8 rows x 16 q-blocks x 128 pages are 16384 of them a layer where a
+    step of decode rows needs a few hundred. So the q-block and page
+    axes are DYNAMIC bounds (`walk_grid_bounds`): the q-blocks of the
     row with most live queries and the pages of the longest live
-    context (of the window, in a window layer), decided inside the one
-    compiled step from `pos` and `q_len`. At 16 rows x 16 q-blocks x
-    512 pages the fixed grid is 131072 steps a layer whatever the rows
-    hold (32 ms a layer in `laguna-s-2.1.code_mixed`; PERF.md section
-    6, PR 29); a step of decode rows over 3000-token contexts needs
-    16 x 1 x 188. The q-blocks past the bound are never written, so
-    the dead queries' outputs are zeroed after the call. A grid at or
-    under the threshold compiles as it always did."""
+    context (of the window, statically, in a window layer), decided
+    inside the one compiled step from `pos` and `q_len`. Groups, a
+    user mask and the int8 / fp8 lanes take the same bounds: a page
+    past the longest context is past every row's causal horizon
+    whatever else selects pages, and phase 1 of the grouped walk runs
+    over the same q-blocks, so phase 2 never reads a partial phase 1
+    did not write. The q-blocks past the bound are never written, so
+    the dead queries' outputs are zeroed after the call."""
     if window is not None and (group is not None or mask is not None):
         raise NotImplementedError(
             "the page walk of a sliding-window layer takes neither "
@@ -592,8 +618,7 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
     mp = page_table.shape[1]
     rep = h // hkv
     scale = 1.0 / math.sqrt(d)
-    qblk = min(lq, 8)
-    nqb = -(-lq // qblk)
+    qblk, nqb = _query_blocks(lq)
     lq_pad = nqb * qblk
     rows = qblk * rep
     if lq_pad != lq:
@@ -657,9 +682,13 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
             (1, 1, 1, hkv, rows, ps),
             lambda bi, t, p, *_: (bi, t, p, 0, 0, 0)))
     with _trace32():
+        n_qblk, n_pages = walk_grid_bounds(
+            pos, q_len, lq=lq, page_size=ps, max_pages=mp)
+        if window is not None:
+            n_pages = min(mp, _window_pages(window, qblk, ps))
         if grouped:
             ops.extend(_grouped_phase1(
-                prefetch, ops, b=b, mp=mp, ps=ps, hkv=hkv, d=d,
+                prefetch, ops, n_qblk, b=b, mp=mp, ps=ps, hkv=hkv, d=d,
                 qblk=qblk, nqb=nqb, rep=rep, scale=scale,
                 has_scale=has_scale, fp8=fp8))
             in_specs.extend(
@@ -671,17 +700,6 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
             has_mask=mask is not None, has_scale=has_scale, fp8=fp8,
             grouped=grouped,
             **({} if window is None else {"window": window}))
-        n_pages = mp if window is None else \
-            min(mp, _window_pages(window, qblk, ps))
-        n_qblk = nqb
-        bounded = (not grouped and mask is None
-                   and b * nqb * n_pages > _FIXED_GRID_STEPS)
-        if bounded:
-            live = q_len > 0
-            n_qblk = jnp.clip(jnp.max((q_len + qblk - 1) // qblk), 1, nqb)
-            if window is None:
-                n_pages = jnp.clip(jnp.max(jnp.where(
-                    live, (pos + q_len - 1) // ps + 1, 1)), 1, mp)
         out = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -703,17 +721,21 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
         )(*prefetch, *ops)
     out = out.reshape(b, nqb, hkv, qblk, rep, d) \
         .transpose(0, 1, 3, 2, 4, 5).reshape(b, lq_pad, h, d)[:, :lq]
-    return _zero_dead_queries(out, q_len) if bounded else out
+    return _zero_dead_queries(out, q_len)
 
 
-def _grouped_phase1(prefetch, ops, *, b, mp, ps, hkv, d, qblk, nqb, rep,
-                    scale, has_scale, fp8):
+def _grouped_phase1(prefetch, ops, n_qblk, *, b, mp, ps, hkv, d, qblk,
+                    nqb, rep, scale, has_scale, fp8):
     """Run phase 1 of the grouped walk over `ops` (q5, pools and, on
     the int8 lane, scale pools — the operands phase 2 takes too) and
     return the per-row partials (m, l, acc), each
-    [n_qblk, B, H_kv, qblk * rep, 128 | D] f32.
+    [nqb, B, H_kv, qblk * rep, 128 | D] f32.
 
-    The (group x page) axis of the grid is a dynamic bound: B * mp
+    Both axes of the grid are dynamic bounds. The q-block axis is
+    `n_qblk`, the walk proper's own bound (`walk_grid_bounds`, computed
+    once by the caller and used by both phases): the partials of the
+    q-blocks at or past it are never written, and phase 2, over the
+    same q-blocks, never reads them. The (group x page) axis is B * mp
     steps when some group has a shared span, ONE when none has. That
     one step is predicated off like every step of a sweep with nothing
     to do, after its `_init` has written the virgin partials, so the
@@ -752,7 +774,7 @@ def _grouped_phase1(prefetch, ops, *, b, mp, ps, hkv, d, qblk, nqb, rep,
             rep=rep, scale=scale, has_scale=has_scale, fp8=fp8),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
-            grid=(nqb, sweep),
+            grid=(n_qblk, sweep),
             in_specs=in_specs,
             out_specs=[pl.BlockSpec((1, b, hkv, rows, w),
                                     lambda t, u, *_: (t, 0, 0, 0, 0))
@@ -942,8 +964,10 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, pos, q_len,
     positions pos[b] .. pos[b] + q_len[b] - 1 (their K/V was just
     scattered there); query i attends keys j <= pos[b] + i. Rows may be
     dead (q_len == 0): no position advances and the row's output is
-    unspecified-but-finite. mask: optional user attention mask (bool or
-    additive float, broadcastable [B|1, H|1, lq|1, lmax]), composed
+    zero, as is every query's at or past q_len[b] (on the kernel path;
+    the reference leaves them unspecified-but-finite). mask: optional
+    user attention mask (bool or additive float, broadcastable
+    [B|1, H|1, lq|1, lmax]), composed
     with the ragged causal window in-kernel. window (static; None for
     full attention): the layer's sliding window, the query's own
     position included — query i then attends only keys
@@ -1519,6 +1543,22 @@ def count_window_page_reads(pos, q_len, *, page_size, window):
     first = np.maximum(pos - (window - 1), 0) // page_size
     return (int(np.where(live, last - first + 1, 0).sum()),
             int(np.where(live, last + 1, 0).sum()))
+
+
+def count_walk_grid_steps(pos, q_len, *, lq, page_size, max_pages):
+    """Host-side (numpy) count of one full-attention layer's walk over
+    one step: (grid steps its dynamically bounded grid has, grid steps
+    the grid the step's shape alone would give has). The bounds are
+    `walk_grid_bounds`, the expression the compiled step evaluates on
+    the same `pos` and `q_len`."""
+    pos = np.asarray(pos, np.int64)
+    q_len = np.asarray(q_len, np.int64)
+    n_qblk, n_pages = walk_grid_bounds(
+        pos, q_len, lq=lq, page_size=page_size, max_pages=max_pages,
+        xp=np)
+    rows = int(q_len.shape[0])
+    return (rows * int(n_qblk) * int(n_pages),
+            rows * _query_blocks(lq)[1] * int(max_pages))
 
 
 def count_page_block_reads(page_table, pos, q_len, group_id=None,

@@ -210,6 +210,7 @@ from ..nlp.generation import (_StepProgram, _pack_caches,
                               _unpack_caches, decode_model_step,
                               resolve_paged_attn_impl, FP8_DTYPE)
 from ..ops.pallas.paged_attention import (count_page_block_reads,
+                                          count_walk_grid_steps,
                                           count_window_page_reads,
                                           resolve_megakernel_flag)
 from .adapters import (AdapterStore, BASE_ADAPTER,
@@ -2850,6 +2851,13 @@ class ServingEngine:
                                        fused=fused_spec, **shard)
         self.metrics.on_grouped_step(flat_reads, step_reads,
                                      group_sizes, phase1=phase1)
+        # the grid the walk's dynamic bounds give this step, of the
+        # grid the step's shape alone would (one full-attention layer)
+        steps, full = count_walk_grid_steps(
+            pos_host, q_len, lq=W, page_size=self.page_size,
+            max_pages=self.max_pages)
+        self._host_phases["walk_grid_steps_total"] += steps
+        self._host_phases["walk_grid_steps_full_total"] += full
         for window in self.kv_windows.values():
             walked, unwindowed = count_window_page_reads(
                 pos_host, q_len, page_size=self.page_size,

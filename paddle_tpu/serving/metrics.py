@@ -95,7 +95,12 @@ HOST_PHASE_COUNTERS = (
 # least one token, and the expert layers run (steps x such layers).
 # `kv_window_*`: made on the host beside the page-read model, a
 # sliding-window layer and step: pages its walk covered, and pages a
-# walk without the window would have read besides.
+# walk without the window would have read besides. `walk_grid_steps_*`:
+# made on the host beside them, a step, for ONE full-attention layer's
+# walk: the grid steps its dynamically bounded grid has
+# (`paged_attention.count_walk_grid_steps`, the compiled step's own
+# expression on the same `pos` and `q_len`), and the grid steps the
+# step's shape alone would give it.
 STEP_WORK_COUNTERS = (
     "moe_assignments_total",
     "moe_assignments_here_total",
@@ -103,6 +108,8 @@ STEP_WORK_COUNTERS = (
     "moe_layer_steps_total",
     "kv_window_pages_walked_total",
     "kv_window_pages_skipped_total",
+    "walk_grid_steps_total",
+    "walk_grid_steps_full_total",
 )
 
 

@@ -179,7 +179,7 @@ class TestGroupedKernel:
         prefetch = tuple(jnp.asarray(a, jnp.int32) for a in (
             pt, pos, q_len, np.arange(b), np.zeros(b), np.zeros(b)))
         m, l, acc = pa._grouped_phase1(
-            prefetch, [jnp.asarray(a) for a in (q5, *pools)],
+            prefetch, [jnp.asarray(a) for a in (q5, *pools)], nqb,
             b=b, mp=mp, ps=ps, hkv=hkv, d=d, qblk=rows, nqb=nqb, rep=1,
             scale=0.25, has_scale=lane == "q8", fp8=False)
         assert m.shape == l.shape == (nqb, b, hkv, rows, 128)
@@ -235,13 +235,14 @@ class TestGroupedKernel:
                                               ung[r, :ql])
         assert len(traces) == 1 and step._cache_size() == 1
         # both phases in the one program, no branch around either:
-        # phase 1's sweep length is a dynamic grid bound
+        # phase 1's q-blocks and sweep, the walk's q-blocks and pages
+        # are dynamic grid bounds
         top = jax.make_jaxpr(grp_op)(*args, *shared).jaxpr.eqns
         assert "cond" not in [e.primitive.name for e in top]
         grids = {e.params["name"]:
                  e.params["grid_mapping"].num_dynamic_grid_bounds
                  for e in top if e.primitive.name == "pallas_call"}
-        assert grids == {"grouped_phase1": 1, "ragged_walk": 0}
+        assert grids == {"grouped_phase1": 2, "ragged_walk": 2}
 
     def test_grouped_q8_lane_matches_q8_reference(self):
         """Code AND scale pages chase the same grouped walk; results

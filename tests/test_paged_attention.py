@@ -441,6 +441,183 @@ class TestDenseGQAGrouped:
         np.testing.assert_array_equal(out_new.numpy(), out_old.numpy())
 
 
+# (pos, q_len) of three rows over 20 pages of 4 positions, 16 query
+# positions (2 q-blocks): what the grid's dynamic bounds come to
+DECODE_ONLY = ([37, 20, 0], [1, 1, 1])         # 1 q-block, 10 pages
+WITH_A_CHUNK = ([14, 60, 5], [3, 16, 0])       # 2 q-blocks, 19 pages
+ALL_DEAD = ([13, 20, 0], [0, 0, 0])            # 1 q-block, 1 page
+
+
+class TestDynamicGridBounds:
+    """Every walk's grid is as long as the step's rows ask
+    (`pa.walk_grid_bounds`), not as long as the step's shape allows:
+    plain, under a user mask, on the int8 lane and grouped (both
+    phases). Live queries equal the reference, which knows no grid;
+    dead queries, whose q-blocks the grid may never write, read zero."""
+
+    B, W, H, HKV, D, PS, MP = 3, 16, 6, 2, 16, 4, 20
+
+    @pytest.fixture(autouse=True)
+    def _interpret(self, monkeypatch):
+        monkeypatch.setattr(pa, "_INTERPRET", True)
+
+    def _operands(self, pos, q_len, seed=3):
+        rng = np.random.default_rng(seed)
+        b, mp = self.B, self.MP
+        shape = (b * mp + 1, self.PS, self.HKV, self.D)
+        pools = [jnp.asarray(rng.normal(size=shape), jnp.float32)
+                 for _ in range(2)]
+        tab = 1 + np.arange(b * mp).reshape(b, mp)
+        q = jnp.asarray(rng.normal(size=(b, self.W, self.H, self.D)),
+                        jnp.float32)
+        return (rng, q, pools, tab, jnp.asarray(pos, jnp.int32),
+                jnp.asarray(q_len, jnp.int32))
+
+    def _check(self, got, want, q_len, exact=False):
+        for row in range(self.B):
+            n = int(q_len[row])
+            if exact:
+                np.testing.assert_array_equal(got[row, :n], want[row, :n])
+            else:
+                np.testing.assert_allclose(got[row, :n], want[row, :n],
+                                           atol=2e-6)
+            assert not np.asarray(got[row, n:]).any()
+
+    @pytest.mark.parametrize("pos,q_len", [
+        ([37, 0, 20], [1, 1, 1]), ([5, 63, 0], [3, 16, 0]),
+        ([0, 0, 0], [0, 0, 0]), DECODE_ONLY, WITH_A_CHUNK])
+    def test_plain_walk_matches_the_reference(self, pos, q_len):
+        _, q, pools, tab, pos, q_len = self._operands(pos, q_len)
+        tab = jnp.asarray(tab, jnp.int32)
+        want = pa.ragged_attention_reference(q, *pools, tab, pos, q_len)
+        got = pa.ragged_paged_attention(q, *pools, tab, pos, q_len)
+        self._check(got, want, q_len)
+
+    @pytest.mark.parametrize("pos,q_len",
+                             [DECODE_ONLY, WITH_A_CHUNK, ALL_DEAD])
+    def test_masked_walk_matches_the_reference(self, pos, q_len):
+        rng, q, pools, tab, pos, q_len = self._operands(pos, q_len)
+        tab = jnp.asarray(tab, jnp.int32)
+        mask = jnp.asarray(rng.normal(
+            size=(self.B, self.H, self.W, self.MP * self.PS)), jnp.float32)
+        want = pa.ragged_attention_reference(q, *pools, tab, pos, q_len,
+                                             mask)
+        got = pa.ragged_paged_attention(q, *pools, tab, pos, q_len, mask)
+        self._check(got, want, q_len)
+        plain = pa.ragged_paged_attention(q, *pools, tab, pos, q_len)
+        if int(q_len.max()):
+            assert np.abs(np.asarray(got) - np.asarray(plain)).max() > 1e-3
+
+    @pytest.mark.parametrize("pos,q_len",
+                             [DECODE_ONLY, WITH_A_CHUNK, ALL_DEAD])
+    def test_int8_walk_matches_the_reference(self, pos, q_len):
+        rng, q, pools, tab, pos, q_len = self._operands(pos, q_len)
+        tab = jnp.asarray(tab, jnp.int32)
+        shape = pools[0].shape
+        codes = [jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+                 for _ in range(2)]
+        scales = [jnp.asarray(np.abs(rng.normal(size=shape[:3])) / 127,
+                              jnp.float32) for _ in range(2)]
+        want = pa.ragged_attention_reference_q8(q, *codes, *scales, tab,
+                                                pos, q_len)
+        got = pa.ragged_paged_attention_q8(q, *codes, *scales, tab, pos,
+                                           q_len)
+        self._check(got, want, q_len)
+
+    def _grouped(self, tab):
+        """Rows 0 and 1 share their first 3 pages; row 2 is alone."""
+        tab = tab.copy()
+        tab[1, :3] = tab[0, :3]
+        group = [jnp.asarray(v, jnp.int32) for v in
+                 ([0, 0, 1], [0, 2, 0], [3, 0, 0])]   # id, leader, count
+        return jnp.asarray(tab, jnp.int32), group
+
+    @pytest.mark.parametrize("pos,q_len",
+                             [DECODE_ONLY, WITH_A_CHUNK, ALL_DEAD])
+    def test_grouped_walk_matches_the_ungrouped(self, pos, q_len):
+        _, q, pools, tab, pos, q_len = self._operands(pos, q_len)
+        tab, group = self._grouped(tab)
+        want = pa.ragged_attention_reference(q, *pools, tab, pos, q_len)
+        plain = pa.ragged_paged_attention(q, *pools, tab, pos, q_len)
+        got = pa.ragged_paged_attention_grouped(q, *pools, tab, pos, q_len,
+                                                *group)
+        self._check(got, want, q_len)
+        self._check(got, plain, q_len, exact=True)
+
+    @pytest.mark.parametrize("pos,q_len,bounds", [
+        (*DECODE_ONLY, (1, 10)), (*WITH_A_CHUNK, (2, 19)),
+        (*ALL_DEAD, (1, 1)), ([0, 79, 3], [16, 16, 16], (2, 20))])
+    def test_host_count_is_the_grid_the_wrapper_asks_for(
+            self, pos, q_len, bounds, monkeypatch):
+        """`count_walk_grid_steps`, which the engine counts a step's
+        grid with, against the grids the traced wrapper hands to
+        `pallas_call`, for both phases of the grouped walk and for the
+        ungrouped one."""
+        _, q, pools, tab, pos, q_len = self._operands(pos, q_len)
+        tab, group = self._grouped(tab)
+        seen = []
+        real = pa.pl.pallas_call
+
+        def spy(kernel, **kw):
+            seen.append((kw["name"],
+                         tuple(int(g) for g in kw["grid_spec"].grid)))
+            return real(kernel, **kw)
+
+        monkeypatch.setattr(pa.pl, "pallas_call", spy)
+        pa.ragged_paged_attention_grouped(q, *pools, tab, pos, q_len,
+                                          *group)
+        pa.ragged_paged_attention(q, *pools, tab, pos, q_len)
+        n_qblk, n_pages = bounds
+        walk = (self.B, n_qblk, n_pages)
+        assert seen == [("grouped_phase1", (n_qblk, self.B * self.MP)),
+                        ("ragged_walk", walk), ("ragged_walk", walk)]
+        assert pa.count_walk_grid_steps(
+            np.asarray(pos), np.asarray(q_len), lq=self.W,
+            page_size=self.PS, max_pages=self.MP) \
+            == (self.B * n_qblk * n_pages, self.B * 2 * self.MP)
+
+    def test_engine_counts_the_grid_of_every_step(self, monkeypatch):
+        """`walk_grid_steps_total` and `walk_grid_steps_full_total`
+        hold, a unified step, what `count_walk_grid_steps` says of the
+        step's `pos` and `q_len`; `snapshot()` and `/metrics` carry
+        them."""
+        from paddle_tpu.serving import engine as engine_mod
+        from paddle_tpu.serving.metrics import prometheus_render
+        # the counters are the host's: the engine may run the reference
+        monkeypatch.setattr(pa, "_INTERPRET", False)
+        seen = []
+
+        def spy(pos, q_len, **kw):
+            seen.append(pa.count_walk_grid_steps(pos, q_len, **kw))
+            assert kw == dict(lq=8, page_size=8, max_pages=8)
+            return seen[-1]
+
+        monkeypatch.setattr(engine_mod, "count_walk_grid_steps", spy)
+        paddle.seed(21)
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=89, hidden_size=32, num_hidden_layers=2,
+            num_attention_heads=4, num_key_value_heads=2,
+            intermediate_size=48, max_position_embeddings=128))
+        model.eval()
+        eng = ServingEngine(model, num_slots=2, max_len=64, page_size=8,
+                            chunk_len=8)
+        eng.generate([np.arange(1, 20, dtype=np.int64),
+                      np.array([26, 5, 35], np.int64)],
+                     SamplingParams(max_new_tokens=6))
+        snap = eng.metrics.snapshot()
+        assert len(seen) == snap["unified_steps"] > 0
+        steps, full = (sum(col) for col in zip(*seen))
+        assert snap["walk_grid_steps_total"] == steps
+        assert snap["walk_grid_steps_full_total"] == full \
+            == len(seen) * 2 * 1 * 8
+        assert 0 < steps < full
+        text = prometheus_render({"r0": snap})
+        for name, n in (("walk_grid_steps_total", steps),
+                        ("walk_grid_steps_full_total", full)):
+            assert f"# TYPE paddle_serving_{name} counter" in text
+            assert f'paddle_serving_{name}{{replica="r0"}} {n}' in text
+
+
 class TestServingEngineAB:
     """E2E acceptance: identical greedy tokens under both
     PADDLE_TPU_PAGED_ATTN settings, through GQA, chunked prefill,

@@ -105,10 +105,10 @@ def test_paged_walk_bf16(one_chip, lq):
 def test_paged_walk_laguna(one_chip, lq, heads, window):
     """Laguna-S-2.1's two layer kinds at its serving shape: 16 slots,
     8 KV heads under 72 (window 512, over the per-slot ring's table) or
-    48 query heads, max_len 8192. The window layer's grid is 34 pages
-    long at most; the full layer's would be 512, which is longer than
-    `_FIXED_GRID_STEPS` at a chunk's 16 q-blocks, so that one compiles
-    with the dynamic bounds."""
+    48 query heads, max_len 8192. Every walk's grid has the dynamic
+    bounds (`pa.walk_grid_bounds`): the full layer's q-blocks and pages
+    (512 at most), the window layer's q-blocks over a page axis that is
+    statically its window's 34."""
     slots, mp, ring = 16, 512, 41
     text = _compiles_to_kernel(
         lambda q, k, v, t, p, n: pa._ragged_attention_kernel(
@@ -165,10 +165,22 @@ def test_paged_walk_user_mask(one_chip, lq):
 
 @pytest.mark.parametrize("lq", [CHUNK, 1])
 def test_grouped_walk_bf16(one_chip, on_tpu_branch, lq):
-    # through the public op: both phases are in the program
-    text = _compiles_to_kernel(
-        pa.ragged_paged_attention_grouped, one_chip, _q(lq), _pool(BF16),
-        _pool(BF16), TABLE, ROW, ROW, ROW, ROW, ROW)
+    """The grouped walk at the GPT-3 serving shape (8 slots, 16 heads x
+    128, 128 pages; a chunk and one token), through the public op: both
+    phases are in the program, and each takes its grid's dynamic bounds
+    as leading scalar operands: phase 1 the q-blocks and the sweep,
+    phase 2 the q-blocks and the pages."""
+    import re
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
+            (_q(lq), _pool(BF16), _pool(BF16), TABLE, ROW, ROW, ROW, ROW,
+             ROW)]
+    lowered = jax.jit(pa.ragged_paged_attention_grouped).lower(*args)
+    for name in ("grouped_phase1", "ragged_walk"):
+        (call,) = [ln for ln in lowered.as_text().splitlines()
+                   if f"ptk:{name}" in ln]
+        assert re.search(
+            rf": \(tensor<i32>, tensor<i32>, tensor<{B}x{MP}xi32>, ", call)
+    text = lowered.compile().as_text()
     assert text.count("tpu_custom_call") >= 2
 
 
@@ -387,10 +399,12 @@ def test_unified_step_names_its_kernels(one_chip, monkeypatch):
 
 def test_unified_step_sizes_phase1_sweep_from_its_operands(one_chip,
                                                            monkeypatch):
-    """Phase 1 of the grouped walk takes the length of its (group x
-    page) sweep as an operand (a dynamic grid bound: the whole sweep
-    only on a step where some rows share a prefix), beside the walk
-    proper and under no conditional; and that costs no copy of a pool:
+    """Both phases of the grouped walk take their grids' lengths as
+    operands (dynamic bounds: phase 1 the q-blocks of the row with most
+    live queries and the whole (group x page) sweep only on a step
+    where some rows share a prefix; the walk proper the same q-blocks
+    and the pages of the longest live context), under no conditional;
+    and that costs no copy of a pool:
     the compiled step copies no more pool-shaped arrays than the step
     of an engine with the grouped walk off, which has no phase 1."""
     import re
@@ -401,12 +415,11 @@ def test_unified_step_sizes_phase1_sweep_from_its_operands(one_chip,
                     if f"ptk:{name}" in ln]
              for name in ("grouped_phase1", "ragged_walk")}
     assert [len(v) for v in calls.values()] == [2, 2]     # one a layer
-    # operand types close the line: the bound leads phase 1's, a
-    # scalar; the walk's begin with the page table
-    for ln in calls["grouped_phase1"]:
-        assert re.search(r": \(tensor<i32>, tensor<8x16xi32>, ", ln)
-    for ln in calls["ragged_walk"]:
-        assert re.search(r": \(tensor<8x16xi32>, ", ln)
+    # operand types close the line: two scalar bounds lead, then the
+    # page table
+    for ln in calls["grouped_phase1"] + calls["ragged_walk"]:
+        assert re.search(
+            r": \(tensor<i32>, tensor<i32>, tensor<8x16xi32>, ", ln)
 
     shape = ",".join(map(str, pool))
     pool_copy = re.compile(
