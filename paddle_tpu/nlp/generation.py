@@ -118,8 +118,8 @@ class DecodeCache:
         # queries past q_len are dead padding. None = every row uses
         # the full width l (the classic prefill/decode shapes).
         self.q_len = q_len
-        # prefix-sharing groups (the serving engine's grouped walk,
-        # PADDLE_TPU_GROUPED_ATTN): a (group_id, group_leader,
+        # prefix-sharing groups (the serving engine's grouped walk): a
+        # (group_id, group_leader,
         # group_cnt) triple of [B] int32 Tensors declaring which rows
         # share a physical-page prefix — pure HBM-traffic hint, None =
         # the per-row walk
@@ -270,8 +270,8 @@ register_op("paged_decode_attention", paged_decode_attention,
 # Ragged generalization: per-row query lengths, so ONE kernel/step
 # serves a mixed batch — decode rows (q_len == 1) next to mid-prefill
 # rows (q_len == chunk) — over the same paged pool. The serving
-# engine's unified step (PADDLE_TPU_UNIFIED_STEP) attends through this
-# op; off-TPU the fwd runs the pure-JAX ragged reference.
+# engine's unified step attends through this op; off-TPU the fwd runs
+# the pure-JAX ragged reference.
 register_op("ragged_paged_attention", ragged_paged_attention,
             nondiff=True)
 

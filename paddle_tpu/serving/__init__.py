@@ -3,11 +3,11 @@
 Wraps the compiled decode path (nlp/generation.py) in a slot-based
 scheduler over a PAGED KV pool: requests arriving at different times,
 with different prompt lengths and sampling params, share ONE compiled
-unified ragged prefill+decode step (PADDLE_TPU_UNIFIED_STEP, default
-on) — decode rows next to mid-prefill rows at q_len up to chunk_len
-in the same fixed-shape invocation, prefill tokens packed into spare
-decode capacity — each holding only the KV pages its prompt + output
-budget needs. A decode row is no longer pinned to one token per step:
+unified ragged prefill+decode step — decode rows next to mid-prefill
+rows at q_len up to chunk_len in the same fixed-shape invocation,
+prefill tokens packed into spare decode capacity — each holding only
+the KV pages its prompt + output budget needs. A decode row is no
+longer pinned to one token per step:
 with SPECULATIVE DECODING on (PADDLE_TPU_SPEC_DECODE=ngram[:k] or
 model[:k] / ServingEngine(spec=...), serving/spec.py + serving/
 draft.py, default off) a per-request drafter — model-free n-gram
@@ -40,13 +40,12 @@ benched via serving_bench --quant-ab). fp8 is the pure-convert
 f8_e4m3 lane: no scale pages at all, one byte per element, pages
 move like fp pages (drift pinned in tests/test_serving_fp8.py).
 
-Attention is PREFIX-SHARING-AWARE (PADDLE_TPU_GROUPED_ATTN /
-ServingEngine(grouped=...), default on): rows whose page tables
+Attention is PREFIX-SHARING-AWARE (wherever the Pallas walk serves an
+engine that has a prefix cache; no option): rows whose page tables
 share a physical-page prefix — the radix cache attached the same
 pages — are grouped host-side each step and the kernel streams each
 shared page from HBM once per GROUP instead of once per row, outputs
-bit-identical either way (serving_bench --prefix-share runs the
-grouped-vs-flat A/B).
+bit-identical to the per-row walk.
 
 One replica can span a MULTI-CHIP MESH (serving/tp.py, default off,
 PADDLE_TPU_MESH=dpXmpY / ServingEngine(mesh=...)): the per-layer KV
@@ -138,9 +137,8 @@ from .controlplane import (ControlPlaneConfig, Decision,  # noqa: F401
                            DeadlineInfeasible, FleetController,
                            FleetSignals, parse_controlplane_spec,
                            resolve_controlplane, slo_placement_rank)
-from .engine import (ServingEngine, resolve_grouped_flag,  # noqa: F401
-                     resolve_kv_dtype, resolve_preempt_flag,
-                     resolve_unified_flag)
+from .engine import (ServingEngine, resolve_kv_dtype,  # noqa: F401
+                     resolve_preempt_flag)
 from .tp import (ServingTP, collective_counts,  # noqa: F401
                  parse_mesh_spec, resolve_serving_mesh)
 from .errors import (DeadlineExceeded, EngineClosed,  # noqa: F401
@@ -160,8 +158,7 @@ from .obs import (EngineObs, FlightRecorder,  # noqa: F401
                   RequestTracer, resolve_debug_flag,
                   resolve_flight_steps, resolve_obs_flag,
                   timeline_to_chrome)
-from .paging import (HostPagePool, PagePool, chunk_bucket,  # noqa: F401
-                     pages_needed)
+from .paging import HostPagePool, PagePool, pages_needed  # noqa: F401
 from .prefix import (PrefixGrant, RadixPrefixCache,  # noqa: F401
                      resolve_prefix_cache_flag, shared_prefix_groups)
 from .request import (Request, RequestOutput, RequestState,  # noqa: F401
@@ -177,13 +174,12 @@ from .draft import (DraftConfig, DraftEngine,  # noqa: F401
 
 __all__ = ["AdapterStore", "LoRAWeights", "make_random_lora",
            "resolve_adapters_flag", "BASE_ADAPTER",
-           "ServingEngine", "resolve_unified_flag",
+           "ServingEngine",
            "resolve_preempt_flag", "resolve_kv_dtype",
-           "resolve_grouped_flag", "shared_prefix_groups", "Scheduler",
+           "shared_prefix_groups", "Scheduler",
            "ServingMetrics", "Histogram",
            "prometheus_render", "PagePool", "HostPagePool",
-           "pages_needed",
-           "chunk_bucket", "RadixPrefixCache", "PrefixGrant",
+           "pages_needed", "RadixPrefixCache", "PrefixGrant",
            "resolve_prefix_cache_flag", "Request", "RequestOutput",
            "RequestState", "SamplingParams", "ServingError",
            "QueueFull", "EngineClosed", "RateLimited",

@@ -9,8 +9,7 @@ request no longer pins `max_len` dense rows; HBM capacity bounds
 concurrency by TOKENS IN FLIGHT, not by slots × max_len (Ragged Paged
 Attention, PAPERS.md).
 
-By default (PADDLE_TPU_UNIFIED_STEP=on / ServingEngine(unified=...))
-exactly ONE program shape touches the pool — the UNIFIED RAGGED
+Exactly ONE step program touches the pool — the UNIFIED RAGGED
 PREFILL+DECODE STEP, a fixed-shape [num_slots, chunk_len] forward in
 which every row carries its own live query count (`q_len`) through the
 ragged paged-attention op: decoding rows sample their next token from
@@ -47,13 +46,9 @@ produced — bit-token-identical on vs off, same oracle pattern as the
 other gates — and the prefix cache only ever indexes committed
 tokens.
 
-The legacy ALTERNATING path (PADDLE_TPU_UNIFIED_STEP=off) keeps the
-two old program families for A/B: one fixed-shape decode step for all
-slots, plus one chunked-prefill program per power-of-two chunk bucket
-(a batch-1 forward of `chunk_len` prompt tokens, ONE chunk per engine
-step interleaved with resident decodes, O(log chunk_len) traces
-total). Greedy outputs are token-identical across the gate, asserted
-against the solo CompiledGenerator oracle either way.
+A round is admit -> plan -> launch -> fetch -> commit -> report, with
+no fork; greedy outputs are asserted against the solo
+CompiledGenerator oracle.
 
 Free slots and retired requests point their page-table rows at the
 reserved trash page 0, so the fixed-shape scatter/gather stays safe for
@@ -111,14 +106,16 @@ COW/swap/spill exactly like fp pages. Lossier per read than rowwise
 int8 but operand-free; deterministic, drift pinned
 (tests/test_serving_fp8.py).
 
-PREFIX-SHARING-AWARE GROUPED ATTENTION (default on, gated
-`grouped=...` / PADDLE_TPU_GROUPED_ATTN): under high prefix share N
-residents' page tables point at the SAME physical system-prompt
-pages, yet the per-row kernel walk streams them from HBM N times per
-step. Each step the engine groups rows whose page tables share a
-physical-page prefix (serving/prefix.py's `shared_prefix_groups` —
-host-side, from the very page tables the cache built; a COW'd page
-splits its row out at the divergence, eviction and retirement shrink
+PREFIX-SHARING-AWARE GROUPED ATTENTION (no option: the step carries
+the group operands iff the Pallas walk serves it and the engine has a
+prefix cache, the one thing that puts a page into two rows' tables):
+under high prefix share N residents' page tables point at the SAME
+physical system-prompt pages, yet the per-row kernel walk streams them
+from HBM N times per step. Each step the engine groups rows whose page
+tables share a physical-page prefix (serving/prefix.py's
+`shared_prefix_groups` — host-side, from the very page tables the
+cache built; a COW'd page splits its row out at the divergence,
+eviction and retirement shrink
 groups between steps) and passes (group_id, group_leader, group_cnt)
 as three extra [S] operands next to pos/q_len — operand DATA, so the
 ONE unified trace never retraces. On TPU the grouped op's two-phase
@@ -128,12 +125,10 @@ merge per row — same page order, bit-identical outputs; phase 1's
 sweep is a dynamic grid bound inside the trace, whole only on a step
 where some group_cnt is non-zero and one grid step a query block
 otherwise, when phase 2 starts from the virgin partials); on CPU it
-IS the ungrouped reference, so grouped on/off stays bit-token-
-identical by construction. `count_page_block_reads` models the DMA
+IS the ungrouped reference. `count_page_block_reads` models the DMA
 traffic host-side each step, feeding the page_block_reads /
-shared_page_reads_saved counters and the group-size histogram the
-`--prefix-share` A/B asserts on; `grouped_walk_steps_total` counts
-the steps on which phase 1 swept.
+shared_page_reads_saved counters and the group-size histogram;
+`grouped_walk_steps_total` counts the steps on which phase 1 swept.
 
 MULTI-TENANT ADAPTERS (serving/adapters.py, default off, gated
 `adapters=...` / PADDLE_TPU_ADAPTERS): thousands of LoRA fine-tunes
@@ -207,8 +202,8 @@ from ..core.tensor import Tensor, set_dispatch_probe
 from ..profiler import RecordEvent
 from ..nlp.generation import (_StepProgram, _pack_caches,
                               _restore_state, _swap_state, _top_p_filter,
-                              _unpack_caches, decode_model_step,
-                              resolve_paged_attn_impl, FP8_DTYPE)
+                              _unpack_caches, resolve_paged_attn_impl,
+                              FP8_DTYPE)
 from ..ops.pallas.paged_attention import (count_page_block_reads,
                                           count_walk_grid_steps,
                                           count_window_page_reads,
@@ -221,8 +216,7 @@ from .fabric import decode_frame, encode_frame, frame_header
 from .grammar import (NEG_BIAS, TokenGrammar, resolve_grammar_flag)
 from .metrics import ServingMetrics
 from .obs import EngineObs, resolve_obs_flag
-from .paging import (HostPagePool, PagePool, TRASH_PAGE, chunk_bucket,
-                     pages_needed)
+from .paging import HostPagePool, PagePool, TRASH_PAGE, pages_needed
 from .prefix import (RadixPrefixCache, resolve_prefix_cache_flag,
                      shared_prefix_groups)
 from .request import Request, RequestOutput, RequestState, SamplingParams
@@ -232,9 +226,9 @@ from .slo import (SLOTracker, capture_cost_census, model_cost_census,
 from .spec import Drafter, ModelDrafter, resolve_spec_config
 from .tp import ServingTP, collective_counts, resolve_serving_mesh
 
-__all__ = ["ServingEngine", "resolve_unified_flag",
+__all__ = ["ServingEngine",
            "resolve_preempt_flag", "resolve_kv_dtype",
-           "resolve_grouped_flag", "resolve_obs_flag",
+           "resolve_obs_flag",
            "resolve_adapters_flag", "resolve_serving_mesh",
            "resolve_slo_config", "resolve_cost_census",
            "ServingTP"]
@@ -260,7 +254,7 @@ _TERMINAL_EVENT = {"stop": "finish", "length": "finish",
 SPAN_ROUND = "serving::round"
 SPAN_ADMIT = "serving::admit"
 SPAN_PLAN = "serving::plan"
-SPAN_UNIFIED_STEP = "serving::unified_step"
+SPAN_STEP = "serving::unified_step"
 SPAN_LAUNCH = "serving::launch"
 SPAN_FETCH = "serving::fetch"
 SPAN_COMMIT = "serving::commit"
@@ -271,37 +265,9 @@ SPAN_SPILL = "serving::spill"
 SPAN_RESTORE = "serving::restore"
 SPAN_COW_COPY = "serving::cow_copy"
 SPAN_EMBED = "serving::embed_epilogue"
-# the legacy alternating path's two program families
-SPAN_PREFILL = "serving::prefill"
-SPAN_DECODE_STEP = "serving::decode_step"
 
-UNIFIED_STEP_MODES = ("on", "off")
 PREEMPT_MODES = ("on", "off")
 KV_DTYPE_MODES = ("fp", "int8", "fp8")
-GROUPED_ATTN_MODES = ("on", "off")
-
-
-def resolve_grouped_flag(override=None) -> bool:
-    """Whether the unified step runs the PREFIX-SHARING-AWARE grouped
-    page walk (default on): rows whose page tables share a
-    physical-page prefix (the radix cache attached the same pages)
-    are grouped host-side each step, and the ragged kernel streams
-    each shared page from HBM once per GROUP instead of once per row
-    — under high prefix share the dominant decode HBM stream drops
-    ~Nx. Outputs are bit-identical either way (on CPU the grouped op
-    IS the ungrouped reference); groups are operand DATA, so the one
-    unified trace never retraces. An explicit override wins;
-    otherwise PADDLE_TPU_GROUPED_ATTN=on|off (read at engine
-    construction — the compiled step keeps the op it was traced
-    with)."""
-    if override is not None:
-        return bool(override)
-    v = os.environ.get("PADDLE_TPU_GROUPED_ATTN", "on")
-    if v not in GROUPED_ATTN_MODES:
-        raise ValueError(
-            f"PADDLE_TPU_GROUPED_ATTN must be one of "
-            f"{GROUPED_ATTN_MODES}, got {v!r}")
-    return v == "on"
 
 
 def resolve_kv_dtype(override=None) -> str:
@@ -337,8 +303,8 @@ def resolve_preempt_flag(override=None) -> bool:
     resident is preempted — its emitted tokens banked, its KV pages
     swapped to the host-RAM tier, its slot freed — and it resumes
     later via swap-in, token-identically. An explicit override wins;
-    otherwise PADDLE_TPU_PREEMPT=on|off (read at engine construction;
-    same gate pattern as PADDLE_TPU_UNIFIED_STEP)."""
+    otherwise PADDLE_TPU_PREEMPT=on|off (read at engine
+    construction)."""
     if override is not None:
         return bool(override)
     v = os.environ.get("PADDLE_TPU_PREEMPT", "on")
@@ -377,26 +343,6 @@ class _SwapHandle:
         self.drops = []         # host slots made redundant by a match
 
 
-def resolve_unified_flag(override=None) -> bool:
-    """Whether the engine runs the UNIFIED ragged prefill+decode step
-    (default on): ONE compiled program per engine — decode rows
-    (q_len 1) and mid-prefill rows (q_len up to chunk_len) share every
-    step through the ragged paged-attention op — instead of the old
-    two program families (per-bucket prefill chunks alternating with
-    the fixed-shape decode step). An explicit override wins; otherwise
-    PADDLE_TPU_UNIFIED_STEP=on|off (read at engine construction; the
-    old alternating path is kept for A/B, same oracle pattern as
-    PADDLE_TPU_PAGED_ATTN / PADDLE_TPU_PREFIX_CACHE)."""
-    if override is not None:
-        return bool(override)
-    v = os.environ.get("PADDLE_TPU_UNIFIED_STEP", "on")
-    if v not in UNIFIED_STEP_MODES:
-        raise ValueError(
-            f"PADDLE_TPU_UNIFIED_STEP must be one of "
-            f"{UNIFIED_STEP_MODES}, got {v!r}")
-    return v == "on"
-
-
 def _sample_rows(logits, key, temps, top_k, top_p, greedy, argmax=None):
     """Per-slot sampling over f32 logits [S, V]: each row applies ITS
     OWN temperature/top-k/top-p (vectors [S]); greedy rows take argmax
@@ -429,7 +375,7 @@ class ServingEngine:
     max-tokens / timeout / cancellation without perturbing neighbors.
     """
 
-    MIN_CHUNK = 8     # smallest prefill bucket (power of two)
+    MIN_CHUNK = 8     # smallest step width
 
     def __init__(self, model, cache_spec=None, *, num_slots: int = 8,
                  max_len: int = 256, page_size: int = 16,
@@ -438,10 +384,10 @@ class ServingEngine:
                  metrics: Optional[ServingMetrics] = None,
                  max_queue: Optional[int] = None, clock=time.monotonic,
                  attn_impl: Optional[str] = None,
-                 prefix_cache=None, unified=None,
+                 prefix_cache=None,
                  token_budget: Optional[int] = None, spec=None,
                  preempt=None, host_pages: Optional[int] = None,
-                 kv_dtype: Optional[str] = None, grouped=None,
+                 kv_dtype: Optional[str] = None,
                  obs=None, flight_steps: Optional[int] = None,
                  mesh=None, adapters=None,
                  adapter_pages: Optional[int] = None,
@@ -506,7 +452,7 @@ class ServingEngine:
         # paged decode attention implementation: "kernel" (Pallas
         # ragged paged attention, the default) or "gather" (the
         # paged_kv_gather + dense SDPA cross-check path). Resolved ONCE
-        # here — the compiled decode step keeps the impl it was traced
+        # here — the compiled step keeps the impl it was traced
         # with; flipping PADDLE_TPU_PAGED_ATTN later needs a new engine.
         self.attn_impl = resolve_paged_attn_impl(attn_impl)
         # multi-chip tensor-parallel replica (serving/tp.py, default
@@ -532,15 +478,12 @@ class ServingEngine:
                                     self.n_kv)),
                 hidden=int(getattr(cfgm, "hidden_size",
                                    self.n_kv * self.head_dim)))
-        # unified ragged prefill+decode step (default on): ONE compiled
-        # program of width chunk_len serves every prefill/decode mix
-        # per step — decode rows at q_len 1 (1 + k with speculative
-        # drafts riding along), mid-prefill rows at q_len up to
-        # chunk_len — and the scheduler PACKS prefill tokens into
-        # spare decode-step capacity (token_budget) instead of
-        # alternating program families. Gated by
-        # ServingEngine(unified=...) / PADDLE_TPU_UNIFIED_STEP.
-        self.unified = resolve_unified_flag(unified)
+        # the unified ragged prefill+decode step: ONE compiled program
+        # of width chunk_len serves every prefill/decode mix per step —
+        # decode rows at q_len 1 (1 + k with speculative drafts riding
+        # along), mid-prefill rows at q_len up to chunk_len — and the
+        # scheduler PACKS prefill tokens into spare decode-step
+        # capacity (token_budget).
         # per-step packed-token ceiling: decode rows always get their
         # token; prefill packing is throttled to the spare budget.
         # Default = the full compiled step shape (num_slots * chunk_len
@@ -557,16 +500,8 @@ class ServingEngine:
             raise ValueError("token_budget must be >= 1")
         # speculative decoding (serving/spec.py, default off): a
         # SpecConfig when drafting is on, None otherwise. The verify
-        # pass IS a unified-step row at q_len 1+k, so speculation
-        # requires the unified path — explicitly enabling both spec
-        # and the legacy alternating step is a config error.
+        # pass IS a unified-step row at q_len 1+k.
         self.spec = resolve_spec_config(spec)
-        if self.spec is not None and not self.unified:
-            raise ValueError(
-                "speculative decoding requires the unified ragged "
-                "step: the verify pass rides the per-row q_len>1 "
-                "path (set unified=True / PADDLE_TPU_UNIFIED_STEP=on "
-                "or turn PADDLE_TPU_SPEC_DECODE off)")
         # per-request drafters, created at admission for greedy
         # requests and dropped at retirement (request_id -> Drafter)
         self._drafters: Dict[str, Drafter] = {}
@@ -603,12 +538,6 @@ class ServingEngine:
         # bit-token-identity oracle); with it on, unconstrained rows
         # ride all-zero bias rows, so mixed batches stay one program.
         self.grammar_on = resolve_grammar_flag(grammar)
-        if self.grammar_on and not self.unified:
-            raise ValueError(
-                "grammar-constrained decoding requires the unified "
-                "ragged step: the mask operand rides the ONE compiled "
-                "program (set unified=True / PADDLE_TPU_UNIFIED_STEP"
-                "=on or turn PADDLE_TPU_GRAMMAR off)")
         # per-request automatons, request_id -> TokenGrammar (created
         # at admission, advanced on every committed token, dropped at
         # retirement; preemption/migration re-creates and replays —
@@ -617,28 +546,20 @@ class ServingEngine:
         # session pinning TTL: how long a finished `session=` request
         # keeps its radix prefix pages pinned above LRU
         self.session_ttl_s = float(session_ttl_s)
-        # prefix-sharing-aware grouped page walk (default on, gated
-        # PADDLE_TPU_GROUPED_ATTN / ServingEngine(grouped=...)): the
-        # unified kernel step streams each physically shared page once
-        # per GROUP. Only the unified + kernel path has a grouped
-        # walk; on the legacy/gather paths the flag is inert.
-        self.grouped = (resolve_grouped_flag(grouped) and self.unified
-                        and self.attn_impl == "kernel")
         # decode MEGAKERNEL (ops/pallas/paged_attention.py, default
         # off, gated PADDLE_TPU_MEGAKERNEL / megakernel=): the unified
         # step's per-layer scatter(+quantize)+attend op pair — and,
         # with adapters, the per-projection LoRA gathers — collapse
         # into ONE megakernel_decode[_q8] dispatch per layer, with
         # greedy argmax + spec acceptance as fused epilogue ops over
-        # the logits tile. Only the unified + kernel path has a fused
-        # form (silent downgrade, mirroring the grouped gate); a tp
+        # the logits tile. Only the kernel path has a fused form
+        # (silent downgrade on the gather path); a tp
         # mesh keeps the unfused path — in-place pool aliasing across
         # shards is not in this PR's oracle matrix. Outputs are
         # bit-identical either way (the shared-forward construction);
         # the referees are the launch-count probe and the fused-byte
         # census, not the floats.
         self.megakernel = (resolve_megakernel_flag(megakernel)
-                           and self.unified
                            and self.attn_impl == "kernel"
                            and self.tp is None)
         if self.kv_windows:
@@ -657,7 +578,6 @@ class ServingEngine:
                 ("host_pages", host_pages)) if given]
             unbuilt = [name for name, on in (
                 ("mesh", self.tp is not None),
-                ("unified=False", not self.unified),
                 ("megakernel", self.megakernel),
                 ("adapters", bool(adapters)),
                 ("kv_dtype", resolve_kv_dtype(kv_dtype) != "fp"),
@@ -676,10 +596,14 @@ class ServingEngine:
                 f"host page tier, preemption and the grouped walk are "
                 f"switched off for this model", stacklevel=2)
             prefix_cache, preempt, host_pages = False, False, 0
-            self.grouped = False
+        prefix_on = resolve_prefix_cache_flag(prefix_cache)
+        # prefix-sharing-aware grouped page walk: the step streams each
+        # physically shared page once per GROUP. Its operands ride the
+        # step iff the Pallas walk serves it and pages can be shared at
+        # all: only a prefix cache puts one page into two rows' tables.
+        self.grouped = self.attn_impl == "kernel" and prefix_on
         self.metrics = metrics or ServingMetrics()
         self.metrics.attn_impl = self.attn_impl
-        self.metrics.unified = self.unified
         self.metrics.grouped = self.grouped
         self.metrics.megakernel = self.megakernel
         self.metrics.spec = (None if self.spec is None
@@ -726,12 +650,6 @@ class ServingEngine:
         # traffic compile to the same single program.
         adapters_on = (isinstance(adapters, AdapterStore)
                        or resolve_adapters_flag(adapters))
-        if adapters_on and not self.unified:
-            raise ValueError(
-                "multi-tenant adapters require the unified ragged "
-                "step: the per-row gathered LoRA delta rides the ONE "
-                "compiled program (set unified=True / "
-                "PADDLE_TPU_UNIFIED_STEP=on or drop adapters)")
         if isinstance(adapters, AdapterStore):
             self.adapters: Optional[AdapterStore] = adapters
         elif adapters_on:
@@ -796,8 +714,8 @@ class ServingEngine:
                 + np.arange(self.max_pages)[None, :] % self.ring_pages,
                 jnp.int32)
         # device state: per-layer shared K/V pools, per-slot positions,
-        # per-slot held next-token logits (filled by the final prefill
-        # chunk, advanced by decode)
+        # per-slot held next-token logits (filled by a row's last
+        # prefill chunk, advanced by decode)
         if self.kv_dtype == "int8":
             self._ct = tuple(
                 (jnp.zeros((self.num_pages, self.page_size, self.n_kv,
@@ -874,9 +792,7 @@ class ServingEngine:
         if self.tp is not None:
             self._pos = self.tp.replicate(self._pos)
         self._last_logits = None      # [S, V] f32, lazy (V from prefill)
-        # host page state: allocator, per-slot page lists, page tables
-        # (full for prefill; decode variant trash-masks non-DECODE rows
-        # so their ignored writes can't touch live pages)
+        # host page state: allocator, per-slot page lists, page table
         self.pool = PagePool(self.num_pages)
         # automatic prefix cache (serving/prefix.py): radix tree of
         # finished requests' pages over the pool. Admission
@@ -888,7 +804,7 @@ class ServingEngine:
         self.prefix_cache = (
             RadixPrefixCache(self.pool, self.page_size,
                              clock=self._clock)
-            if resolve_prefix_cache_flag(prefix_cache) else None)
+            if prefix_on else None)
         # HOST-RAM page tier (graceful overload degradation + stage 1
         # of the fleet-scale prefix cache): whole-page KV payloads of
         # preempted residents — and, under pressure, of parked prefix
@@ -923,17 +839,13 @@ class ServingEngine:
         self._pt_host = np.full((self.num_slots, self.max_pages),
                                 TRASH_PAGE, np.int32)
         self._pt_dirty = True
-        self._pt_full = None
-        self._pt_decode = None
+        self._pt_dev = None
         # per-slot sampling vectors, rebuilt when membership changes
         self._vec_dirty = True
         self._temps = np.ones((self.num_slots,), np.float32)
         self._topk = np.zeros((self.num_slots,), np.int32)
         self._topp = np.ones((self.num_slots,), np.float32)
         self._greedy = np.ones((self.num_slots,), bool)
-        self._active = np.zeros((self.num_slots,), bool)
-        self._prefill_fns: Dict[int, object] = {}   # chunk bucket -> fn
-        self._decode_fn = None
         self._unified_fn = None      # the ONE compiled ragged step
         # counters a model keeps on the device (`STEP_STAT_COUNTERS`
         # names them, `_step_stats()` yields them after a forward
@@ -1145,8 +1057,7 @@ class ServingEngine:
             head_dim=self.head_dim, page_size=self.page_size,
             mp=self.mp)
         self._census = capture_cost_census(
-            self.census_mode,
-            self._unified_fn if self.unified else None,
+            self.census_mode, self._unified_fn,
             ((self._ct, *self._unified_args_tail)
              if self._unified_args_tail is not None else None),
             capacity_tokens=self.step_capacity_tokens,
@@ -1160,83 +1071,15 @@ class ServingEngine:
     def _restore_state(self, originals):
         _restore_state(self._state_tensors, originals)
 
-    def _unpack(self, ct, pos, page_table, rows=None, **kw):
+    def _unpack(self, ct, pos, page_table, **kw):
         """`_unpack_caches`, then each window layer's cache on its ring
-        table (`rows`: the one slot a batch-1 program serves)."""
+        table."""
         caches = _unpack_caches(ct, pos, page_table,
                                 attn_impl=self.attn_impl,
                                 out_shard=self._out_shard, **kw)
-        if self.kv_windows:
-            ring = self._pt_ring
-            if rows is not None:
-                ring = jax.lax.dynamic_slice(
-                    ring, (rows, jnp.zeros((), jnp.int32)),
-                    (1, ring.shape[1]))
-            for i in self.kv_windows:
-                caches[i].page_table = Tensor(ring)
+        for i in self.kv_windows:
+            caches[i].page_table = Tensor(self._pt_ring)
         return caches
-
-    def _build_prefill(self, bucket: int):
-        """Compiled once per chunk BUCKET (not per prompt length): a
-        batch-1 forward of `bucket` tokens for one slot, scattering the
-        chunk's K/V into the slot's pages at positions start..start+l-1
-        and recording the logits of the chunk's last REAL token into the
-        held-logits row. Host-side padding of the tail chunk rides on
-        the trash-page write redirect, so the padded tokens are inert."""
-        model = self.model
-        state_vals = self._state_vals
-
-        def prefill(state_vals, ct, pos, last_logits, page_table,
-                    tokens, slot, start, new_pos, last_idx):
-            originals = self._swap_state(state_vals)
-            try:
-                z = jnp.zeros((), jnp.int32)
-                s = slot.astype(jnp.int32).reshape(())
-                pt_row = jax.lax.dynamic_slice(
-                    page_table, (s, z), (1, page_table.shape[1]))
-                caches = self._unpack(ct, start, pt_row, rows=s)
-                logits_t, caches = model(Tensor(tokens), caches=caches)
-                v = logits_t._value.shape[-1]
-                row = jax.lax.dynamic_slice(
-                    logits_t._value, (z, last_idx.astype(jnp.int32), z),
-                    (1, 1, v))[:, 0, :].astype(jnp.float32)
-                new_ct = _pack_caches(caches)
-                pos = jax.lax.dynamic_update_slice(
-                    pos, new_pos.astype(jnp.int32).reshape(1), (s,))
-                last_logits = jax.lax.dynamic_update_slice(
-                    last_logits, row, (s, z))
-                return new_ct, pos, last_logits
-            finally:
-                self._restore_state(originals)
-
-        return _StepProgram(prefill, state_vals, self._mesh)
-
-    def _build_decode(self):
-        """ONE fixed-shape step for all slots: sample from held logits
-        with per-slot params, batched forward with per-row positions
-        through the paged pool."""
-        model = self.model
-        state_vals = self._state_vals
-
-        def step(state_vals, ct, pos, last_logits, page_table, key,
-                 temps, top_k, top_p, greedy, active):
-            originals = self._swap_state(state_vals)
-            try:
-                nxt = _sample_rows(last_logits, key, temps, top_k,
-                                   top_p, greedy)
-                nxt = jnp.where(active, nxt, 0).astype(jnp.int32)
-                caches = self._unpack(ct, pos, page_table)
-                last, caches = decode_model_step(model, nxt[:, None],
-                                                 caches)
-                # only occupied slots advance; free/prefilling rows stay
-                # frozen (their writes went to the trash page — the
-                # decode page table trash-masks non-DECODE rows)
-                new_pos = jnp.where(active, pos + 1, pos)
-                return _pack_caches(caches), new_pos, last, nxt
-            finally:
-                self._restore_state(originals)
-
-        return _StepProgram(step, state_vals, self._mesh)
 
     def _build_unified(self):
         """THE one compiled ragged prefill+decode+verify step: a
@@ -1245,7 +1088,7 @@ class ServingEngine:
         decoding rows, up to chunk_len for mid-prefill rows, 0 for
         idle/free rows) through the ragged paged-attention op. Decode
         rows first sample their next token from the held logits
-        (per-slot params, exactly the old decode step's math), feed it
+        (per-slot params, `_sample_rows`), feed it
         at column 0 with any speculative drafts behind it; prefill
         rows feed their prompt chunk. GREEDY ACCEPTANCE of drafts is
         fused into the same trace: draft column i+1 is accepted iff it
@@ -1256,16 +1099,14 @@ class ServingEngine:
         the new pos exactly like padding columns, overwritten before
         it is ever attended), and its held logits come from column
         `accept` so the next step's sample is the model's own
-        correction token. Prefill rows keep the PR-6 semantics: pos
-        advances by q_len, held logits from the last real column.
+        correction token. Prefill rows: pos advances by q_len, held
+        logits from the last real column.
         With speculation off decode rows simply ride at q_len 1,
         where accept is 0 by construction — SAME program, same trace,
         zero cost; enabling speculation changes only the host-side
         q_len/tokens values (the retrace probe asserts this). ONE
         trace serves every prefill/decode/verify mix, membership
-        change and packing decision (the engine's whole point: the
-        per-bucket prefill programs AND the separate decode program
-        collapse into this)."""
+        change and packing decision."""
         model = self.model
         state_vals = self._state_vals
 
@@ -1803,11 +1644,6 @@ class ServingEngine:
                 "request carries a grammar constraint but this "
                 "engine's grammar gate is off (enable it via "
                 "ServingEngine(grammar=True) / PADDLE_TPU_GRAMMAR=on)")
-        if getattr(sampling, "embed", False) and not self.unified:
-            raise ValueError(
-                "the embeddings lane rides the unified ragged step's "
-                "prefill packing (set unified=True / "
-                "PADDLE_TPU_UNIFIED_STEP=on)")
         need = pages_needed(prompt.size, self._budget_new(sampling),
                             self.page_size)
         if need > self.num_pages - 1:
@@ -1874,20 +1710,15 @@ class ServingEngine:
             return self.tp.replicate(np.asarray(x))
         return jnp.asarray(x)
 
-    # -- page-table device views -------------------------------------------
-    def _page_tables(self):
-        """(full, decode) device page tables. The decode variant points
-        every non-DECODE row at the trash page so the fixed-shape
-        decode scatter can't touch a mid-prefill slot's live pages."""
-        if self._pt_dirty or self._pt_full is None:
-            self._pt_full = self._dev(self._pt_host)
-            self._pt_decode = self._dev(
-                np.where(self._active[:, None], self._pt_host,
-                         TRASH_PAGE).astype(np.int32))
+    def _page_table(self):
+        """The device copy of the host page table, uploaded anew when a
+        row's pages changed."""
+        if self._pt_dirty or self._pt_dev is None:
+            self._pt_dev = self._dev(self._pt_host)
             self._pt_dirty = False
-        return self._pt_full, self._pt_decode
+        return self._pt_dev
 
-    # -- step boundary: retire / admit / prefill / decode ------------------
+    # -- step boundary: retire / admit / plan / commit ---------------------
     def _finalize_request(self, req: Request, *, keep_id: bool = False):
         """The ONE host-side cleanup every path that takes a request
         off a slot/queue must run: drop its prefill cursor, drafter
@@ -1911,7 +1742,6 @@ class ServingEngine:
         if req.slot is not None:
             slot = req.slot
             self.scheduler.retire(slot)
-            self._active[slot] = False
             self._vec_dirty = True
             pages = self._slot_pages.pop(slot, None)
             if pages:
@@ -1955,7 +1785,7 @@ class ServingEngine:
             self.pool.free(pages)
             return
         if reason in ("stop", "length"):
-            # every emitted token's KV was written by the decode step
+            # every emitted token's KV was written by the step
             # that sampled it, so prompt + output positions are valid
             aid = int(getattr(req.sampling, "adapter_id", 0) or 0)
             seq = np.concatenate([
@@ -2166,7 +1996,6 @@ class ServingEngine:
         provably identical to never having been preempted."""
         pages = self._slot_pages.pop(slot)
         self.scheduler.retire(slot)
-        self._active[slot] = False
         self._vec_dirty = True
         self._pt_host[slot, :] = TRASH_PAGE
         self._pt_dirty = True
@@ -2276,8 +2105,7 @@ class ServingEngine:
                 self._obs_event(req, "swap_in", pages=n_restore)
             # the slot's write position starts at the first uncached
             # token (0 on a prefix miss): the unified step reads it as
-            # the row's pos; the old path's prefill program passes the
-            # cursor explicitly and overwrites pos itself
+            # the row's pos
             self._pos = self._pos.at[slot].set(req.cached_tokens)
             # prefix-cache hit: the matched span's KV is already in the
             # attached pages — prefill starts at the first uncached
@@ -2347,63 +2175,6 @@ class ServingEngine:
         if self.tp is not None:
             self._last_logits = self.tp.replicate(self._last_logits)
 
-    def _advance_prefills(self, suppress=frozenset()) -> int:
-        """One chunk for EACH mid-prefill slot, then back to decode —
-        the interleave that keeps long prompts from stalling resident
-        decodes for more than one chunk. Slots in `suppress` idle
-        (quarantine probes). Returns chunks run."""
-        chunks = 0
-        for slot, req in sorted(self.scheduler.running.items()):
-            if req.state is not RequestState.PREFILL \
-                    or slot in suppress:
-                continue
-            if self.step_fault_hook is not None:
-                self.step_fault_hook([req.request_id])
-            self._prefill_chunk(slot, req)
-            chunks += 1
-            if self._prefill_cursor[req.request_id] >= \
-                    req.prefill_ids.size:
-                self._prefill_cursor.pop(req.request_id, None)
-                req.state = RequestState.DECODE
-                self._active[slot] = True
-                self._vec_dirty = True
-                self._pt_dirty = True    # row goes live for decode
-                self._obs_event(req, "decode")
-        return chunks
-
-    def _prefill_chunk(self, slot: int, req: Request):
-        plen = int(req.prefill_ids.size)
-        cursor = self._prefill_cursor[req.request_id]
-        bucket = chunk_bucket(plen - cursor, self.chunk_len,
-                              self.MIN_CHUNK)
-        fn = self._prefill_fns.get(bucket)
-        if fn is None:
-            fn = self._prefill_fns[bucket] = self._build_prefill(bucket)
-        self._ensure_last_logits(req)
-        real = min(plen - cursor, bucket)
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :real] = req.prefill_ids[cursor:cursor + real]
-        pt_full, _ = self._page_tables()
-        self.step_tokens_inflight = int(bucket)
-        self._beat()
-        with RecordEvent(SPAN_PREFILL, slot=slot, cursor=cursor,
-                         bucket=bucket):
-            self._ct, self._pos, self._last_logits = fn(
-                self._ct, self._pos, self._last_logits, pt_full,
-                self._dev(tokens), jnp.int32(slot),
-                self._dev(np.asarray([cursor], np.int32)),
-                jnp.int32(cursor + real), jnp.int32(real - 1))
-        self.step_tokens_inflight = 0
-        self._beat()
-        self._prefill_cursor[req.request_id] = cursor + real
-        self.metrics.on_prefill_chunk(real)
-        self._round_stats["prefill_tokens"] += real
-        if self.tp is not None:
-            self._round_stats["collectives"] += \
-                self.tp.step_collectives(self.n_layers)
-        self._obs_event(req, "prefill_chunk", tokens=real,
-                        cursor=cursor + real)
-
     def _refresh_vectors(self):
         for s in range(self.num_slots):
             req = self.scheduler.running.get(s)
@@ -2417,100 +2188,6 @@ class ServingEngine:
             self._topp[s] = sp.top_p if sp.top_p is not None else 1.0
             self._greedy[s] = sp.greedy
         self._vec_dirty = False
-
-    def _decode(self, now_fn, finished: List[RequestOutput],
-                suppress=frozenset()):
-        if self._decode_fn is None:
-            self._decode_fn = self._build_decode()
-        if self._vec_dirty:
-            self._refresh_vectors()
-        # quarantine probes suppress slots: deactivate them for this
-        # ONE invocation (their writes trash-mask, pos freezes) and
-        # afterwards restore both the active flags and their held
-        # logits rows (the decode program recomputes the whole [S, V]
-        # block; a suppressed row's output is garbage it must not keep)
-        saved_logits = self._last_logits
-        saved_active = self._active.copy() if suppress else None
-        if suppress:
-            for s in suppress:
-                self._active[s] = False
-            self._pt_dirty = True
-        ran = False
-        try:
-            if not self._active.any():
-                return
-            if self.step_fault_hook is not None:
-                ids = [r.request_id for s, r in
-                       sorted(self.scheduler.running.items())
-                       if r.state is RequestState.DECODE
-                       and s not in suppress]
-                if ids:
-                    self.step_fault_hook(ids)
-            _, pt_decode = self._page_tables()
-            key = random_mod.next_key_host()
-            self.step_tokens_inflight = int(self._active.sum())
-            self._beat()
-            t0 = time.perf_counter()
-            with RecordEvent(SPAN_DECODE_STEP):
-                self._ct, self._pos, self._last_logits, toks = \
-                    self._decode_fn(
-                        self._ct, self._pos, self._last_logits,
-                        pt_decode, key,
-                        self._dev(self._temps),
-                        self._dev(self._topk),
-                        self._dev(self._topp),
-                        self._dev(self._greedy),
-                        self._dev(self._active))
-                toks = np.asarray(toks)   # sync: host sees the tokens
-            self.step_tokens_inflight = 0
-            self._beat()
-            ran = True
-            # wall time of the synchronized step (the attn_impl A/B
-            # metric); real perf_counter regardless of an injected
-            # test clock
-            wall = time.perf_counter() - t0
-            self.metrics.on_decode_step(wall)
-            self._round_stats["decode_tokens"] += int(self._active.sum())
-            self._round_stats["wall_s"] += wall
-            if self.tp is not None:
-                self._round_stats["collectives"] += \
-                    self.tp.step_collectives(self.n_layers)
-            now = now_fn()
-            for slot, req in list(self.scheduler.running.items()):
-                if req.state is not RequestState.DECODE \
-                        or slot in suppress:
-                    continue          # mid-prefill: no token this step
-                tok = int(toks[slot])
-                prev_t = req._last_token_t
-                req._emit(tok, now)
-                self.metrics.on_token(req, now)
-                if prev_t is not None:
-                    self.metrics.on_inter_token(
-                        now - prev_t, priority=req.sampling.priority,
-                        adapter_id=int(getattr(
-                            req.sampling, "adapter_id", 0) or 0),
-                        now=now)
-                elif self.obs is not None:
-                    self._obs_event(req, "first_token")
-                sp = req.sampling
-                if sp.eos_token_id is not None \
-                        and tok == sp.eos_token_id:
-                    self._finish_and_free(req, "stop", now, finished)
-                elif len(req.output_tokens) >= sp.max_new_tokens:
-                    self._finish_and_free(req, "length", now, finished)
-        finally:
-            if suppress:
-                # restore ONLY the suppressed entries — innocents that
-                # finished during the probe must stay retired
-                for s in suppress:
-                    self._active[s] = saved_active[s]
-                self._pt_dirty = True
-                if ran:
-                    ll = np.array(self._last_logits)   # writable copy
-                    old = np.asarray(saved_logits)
-                    for s in suppress:
-                        ll[s] = old[s]
-                    self._last_logits = jnp.asarray(ll)
 
     @staticmethod
     def _grammar_bias(g, left, eos, V) -> np.ndarray:
@@ -2699,7 +2376,7 @@ class ServingEngine:
                 int(v.size) for v in entries.values())
 
     def _unified_step(self, finished: List[RequestOutput],
-                      suppress=frozenset()) -> int:
+                      suppress=frozenset()):
         """One UNIFIED ragged step: pack this round's tokens — every
         decoding slot's next token, its granted speculative drafts,
         plus as many prefill prompt tokens as the spare token budget
@@ -2711,15 +2388,13 @@ class ServingEngine:
         (quarantine probes): positions, cursors and held logits
         untouched by construction, and no drafted-but-unverified token
         can leak — drafts are only ever emitted through the verify
-        pass of a step their slot participated in. Returns the number
-        of prefill tokens packed alongside the decodes (0 when nothing
-        ran)."""
+        pass of a step their slot participated in."""
         if not self.scheduler.running:
-            return 0
+            return
         with self._phase(SPAN_PLAN, "step_plan_s_total"):
             plan = self._plan_unified(suppress)
         if plan is None:
-            return 0
+            return
         # launch-count probe: count registered-op dispatches while the
         # launch runs. Only a (re)trace walks the Python op layer —
         # compiled replays leave `counts` empty — so the histogram is
@@ -2733,7 +2408,7 @@ class ServingEngine:
             lambda name: counts.__setitem__(name,
                                             counts.get(name, 0) + 1))
         try:
-            with RecordEvent(SPAN_UNIFIED_STEP):
+            with RecordEvent(SPAN_STEP):
                 with self._phase(SPAN_LAUNCH, "step_launch_s_total"):
                     self._ct, self._pos, self._last_logits, toks, \
                         accept = self._unified_fn(self._ct,
@@ -2749,8 +2424,7 @@ class ServingEngine:
         finally:
             set_dispatch_probe(prev_probe)
         with self._phase(SPAN_COMMIT, "step_commit_s_total"):
-            return self._commit_unified(plan, counts, toks, accept,
-                                        finished)
+            self._commit_unified(plan, counts, toks, accept, finished)
 
     def _plan_unified(self, suppress) -> Optional["_StepPlan"]:
         """The host's work before the launch (`serving::plan`): pack
@@ -2809,11 +2483,11 @@ class ServingEngine:
             self._unified_fn = self._build_unified()
         if self._vec_dirty:
             self._refresh_vectors()
-        pt_full, _ = self._page_tables()
+        page_table = self._page_table()
         # prefix-sharing groups for this step's walk (host-side, from
         # the page tables — pure operand data) + the modeled page-block
-        # read count both walks would issue (the CPU-reference number
-        # the --prefix-share A/B and the saved-reads counter report)
+        # read count both walks would issue (the number the
+        # saved-reads counter reports)
         pos_host = np.asarray(self._pos)
         # on a mesh the DMA model counts what ONE CHIP issues per
         # layer (n_kv/mp local head walks over 1/mp page slices) —
@@ -2833,22 +2507,17 @@ class ServingEngine:
                           lora_bytes=lora_rows
                           * self._adapter_row_bytes)
         group_args = ()
+        gid = gcn = None
         phase1 = False
         if self.grouped:
             gid, gld, gcn = shared_prefix_groups(self._pt_host, q_len)
             phase1 = bool(gcn.any())
             group_args = (self._dev(gid), self._dev(gld),
                           self._dev(gcn))
-            flat_reads, step_reads, group_sizes, walk_bytes = \
-                count_page_block_reads(self._pt_host, pos_host, q_len,
-                                       gid, gcn,
-                                       page_size=self.page_size,
-                                       fused=fused_spec, **shard)
-        else:
-            flat_reads, step_reads, group_sizes, walk_bytes = \
-                count_page_block_reads(self._pt_host, pos_host, q_len,
-                                       page_size=self.page_size,
-                                       fused=fused_spec, **shard)
+        flat_reads, step_reads, group_sizes, walk_bytes = \
+            count_page_block_reads(self._pt_host, pos_host, q_len,
+                                   gid, gcn, page_size=self.page_size,
+                                   fused=fused_spec, **shard)
         self.metrics.on_grouped_step(flat_reads, step_reads,
                                      group_sizes, phase1=phase1)
         # the grid the walk's dynamic bounds give this step, of the
@@ -2963,7 +2632,7 @@ class ServingEngine:
             grammar_args = (self._dev(gsamp),)
             if gver is not None:
                 grammar_args += (self._dev(gver),)
-        args_tail = (self._pos, self._last_logits, pt_full,
+        args_tail = (self._pos, self._last_logits, page_table,
                      self._dev(tokens), self._dev(q_len),
                      self._dev(is_decode), key,
                      self._dev(self._temps), self._dev(self._topk),
@@ -2978,10 +2647,10 @@ class ServingEngine:
 
     def _commit_unified(self, plan: "_StepPlan", counts: Dict[str, int],
                         toks, accept,
-                        finished: List[RequestOutput]) -> int:
+                        finished: List[RequestOutput]):
         """The host's work after the fetch (`serving::commit`): the
         step's wall time and counters, prefill cursors, token emission,
-        finish and free. Returns the prefill tokens the step packed."""
+        finish and free."""
         decode_slots, grants, draft_grants, proposals, _, t0 = plan
         running = self.scheduler.running
         if counts:
@@ -3028,9 +2697,7 @@ class ServingEngine:
                     embed_rows.append((slot, req))
                     continue
                 req.state = RequestState.DECODE
-                self._active[slot] = True
                 self._vec_dirty = True
-                self._pt_dirty = True
                 self._obs_event(req, "decode")
         if embed_rows:
             # embedding BEFORE retirement: the epilogue reads the
@@ -3039,8 +2706,8 @@ class ServingEngine:
             self._embed_rows(embed_rows)
             for slot, req in embed_rows:
                 self._finish_and_free(req, "stop", now, finished)
-        # decode emission: the old decode step's retirement, token by
-        # token over the verified burst — EOS or the token budget can
+        # decode emission and retirement, token by token over the
+        # verified burst — EOS or the token budget can
         # end the request mid-burst, and the sequential semantics
         # (emit the terminal token, drop everything after it) are
         # exactly what one-at-a-time decode would have done
@@ -3102,23 +2769,6 @@ class ServingEngine:
             self.metrics.on_spec(spec_drafted, spec_accepted,
                                  spec_burst_sizes)
             self._round_stats["accepted_tokens"] += spec_accepted
-        return n_prefill
-
-    def _run_round(self, finished: List[RequestOutput],
-                   suppress=frozenset()) -> int:
-        """Run one round's compiled work — the unified ragged step, or
-        the legacy prefill-chunks-then-decode pair — excluding any
-        slots in `suppress` (they idle this round: positions, held
-        logits and prefill cursors untouched). Suppression exists for
-        `_quarantine_poison`'s bisection probes. Returns prefill
-        chunks run ahead of the decode (legacy path only)."""
-        if self.unified:
-            self._unified_step(finished, suppress=suppress)
-            return 0
-        chunks = self._advance_prefills(suppress)
-        if self._active.any():
-            self._decode(self._clock, finished, suppress=suppress)
-        return chunks
 
     def _quarantine_poison(self, finished: List[RequestOutput]) -> bool:
         """A round raised: find the ONE resident request that
@@ -3140,7 +2790,7 @@ class ServingEngine:
         while len(candidates) > 1:
             half = frozenset(candidates[:len(candidates) // 2])
             try:
-                self._run_round(finished, suppress=half)
+                self._unified_step(finished, suppress=half)
             except Exception:
                 survivors = [s for s in candidates if s not in half]
             else:
@@ -3154,7 +2804,7 @@ class ServingEngine:
         if req is None:
             return False
         try:     # verdict check: the round must succeed without it
-            self._run_round(finished, suppress=frozenset([slot]))
+            self._unified_step(finished, suppress=frozenset([slot]))
         except Exception:
             return False
         req.error = PoisonedRequest(
@@ -3168,13 +2818,10 @@ class ServingEngine:
         placement deadline -> fail-fast "deadline"), admit queued
         requests whose pages fit, PREEMPT the least-important resident
         when a strictly higher-priority head is still blocked
-        (graceful overload degradation), then run the round's tokens.
-        With the unified step (default) that is ONE compiled ragged
-        program — decode tokens and packed prefill chunks together, so
-        a long prompt never stalls a resident decoder. On the legacy
-        alternating path (PADDLE_TPU_UNIFIED_STEP=off) it is one
-        prefill chunk per mid-prefill slot, then one compiled decode
-        step for every decoding slot. A round that RAISES goes through
+        (graceful overload degradation), then run the round's tokens
+        through ONE compiled ragged program — decode tokens and packed
+        prefill chunks together, so a long prompt never stalls a
+        resident decoder. A round that RAISES goes through
         poison quarantine (`_quarantine_poison`): if exactly one
         resident deterministically kills the step, it alone fails and
         the replica keeps serving; otherwise the exception propagates
@@ -3201,9 +2848,8 @@ class ServingEngine:
             self._evict(now, finished)
             self._admit(now)
             self._preempt_for_overload(now)
-        chunks = 0
         try:
-            chunks = self._run_round(finished)
+            self._unified_step(finished)
         except Exception as exc:
             # the black box freezes BEFORE recovery runs: whatever
             # quarantine decides, the postmortem keeps the steps that
@@ -3226,9 +2872,9 @@ class ServingEngine:
                                          step=self._step_idx,
                                          slo=self._slo_snap())
         with self._phase(SPAN_REPORT, "round_report_s_total"):
-            self._report_round(chunks)
+            self._report_round()
 
-    def _report_round(self, chunks: int):
+    def _report_round(self):
         """What the observability costs each round (`serving::report`):
         the metrics' gauges and histograms, the cost census's first
         capture, the flight recorder's record."""
@@ -3236,7 +2882,6 @@ class ServingEngine:
                              self.scheduler.occupancy, self.num_slots,
                              pages_used=self.pool.used_pages,
                              pages_total=self.num_pages - 1,
-                             stall_chunks=chunks,
                              pages_cached=self.pool.cached_pages,
                              pages_swapped=self.pool.swapped_pages,
                              host_pages_used=self.host_pool.used_pages,
@@ -3408,8 +3053,7 @@ class ServingEngine:
             "adapters": (None if self.adapters is None else {
                 "pool": self.adapters.stats(),
                 "registered": self.adapters.debug()}),
-            "config": {"unified": self.unified,
-                       "grouped": self.grouped,
+            "config": {"grouped": self.grouped,
                        "attn_impl": self.attn_impl,
                        "kv_dtype": self.kv_dtype,
                        "mesh": (None if self.tp is None

@@ -235,10 +235,7 @@ class ServingMetrics:
         self.queue_depth = 0
         self.slot_occupancy = 0.0
         self.num_slots = 0
-        # paged KV pool gauges: used/total allocatable pages, and the
-        # prefill-stall gauge — how many prefill chunk programs ran
-        # ahead of the latest decode step (each one delays every
-        # resident decode by one chunk forward)
+        # paged KV pool gauges: used/total allocatable pages
         self.pool_pages_used = 0
         self.pool_pages_total = 0
         self.pool_pages_cached = 0
@@ -247,7 +244,6 @@ class ServingMetrics:
         self.pool_pages_swapped = 0
         self.host_pages_used = 0
         self.host_pages_total = 0
-        self.prefill_stall = 0
         # prefix-cache mirror (source of truth: RadixPrefixCache; the
         # engine pushes a stats() snapshot every step so scrapes never
         # touch the cache's tree): lookups/hits/cached-token counters,
@@ -274,11 +270,6 @@ class ServingMetrics:
         self.mp = 1
         self.dp = 1
         self.pool_shard_bytes_per_page = 0
-        # whether the engine runs the unified ragged prefill+decode
-        # step (True) or the legacy alternating program families
-        # (False); set by the engine at construction — the second A/B
-        # tag next to attn_impl so scrapes can tell the paths apart
-        self.unified: Optional[bool] = None
         # HOST_PHASE_COUNTERS, all cumulative
         self.host_phases = dict.fromkeys(
             HOST_PHASE_COUNTERS + STEP_WORK_COUNTERS, 0)
@@ -287,8 +278,8 @@ class ServingMetrics:
         self.packed_prefill_tokens = 0
         self.packed_decode_tokens = 0
         self.packed_draft_tokens = 0
-        # prefix-sharing grouped walk (the fifth A/B tag): whether the
-        # engine runs it, the modeled page-block reads the step's walk
+        # prefix-sharing grouped walk: whether the step program was
+        # compiled with it, the modeled page-block reads the step's walk
         # issues (CPU-reference count, one (layer, kv-head) sweep per
         # step), and how many reads grouping saved vs the flat walk
         # (flat - grouped; 0 with grouping off), and the steps whose
@@ -308,8 +299,8 @@ class ServingMetrics:
         self.megakernel: Optional[bool] = None
         self.unified_dispatch_ops: Optional[int] = None
         # speculative decoding (serving/spec.py): the drafter mode tag
-        # ("ngram"; None = off) — third A/B label next to
-        # attn_impl/unified — plus the drafted-vs-accepted economics:
+        # ("ngram"; None = off) — a label next to attn_impl — plus
+        # the drafted-vs-accepted economics:
         # spec_drafted_tokens counts every draft packed into a verify
         # row, spec_accepted_tokens the subset the model confirmed
         # AND the engine committed (acceptance rate = accepted/drafted)
@@ -335,16 +326,11 @@ class ServingMetrics:
         self.grammar_masked_steps = 0
         self.grammar_masked_rows = 0
         self.grammar_rejected_drafts = 0
-        # off-path counter: engine steps where prefill chunk programs
-        # ran ahead of the decode step, stalling every resident decoder
-        # (the TTFT spike the unified step exists to kill; stays 0 with
-        # unified on)
-        self.prefill_stall_steps = 0
         # histograms (TTFT/inter-token carry fixed Prometheus buckets)
         self.ttft_s = Histogram(buckets=TTFT_BUCKETS)
         self.inter_token_s = Histogram(buckets=LATENCY_BUCKETS)
-        # synchronized wall time of one compiled decode step — the
-        # number the attn_impl A/B compares
+        # synchronized wall time of one compiled step (operand
+        # uploads -> host fetch)
         self.decode_step_s = Histogram(buckets=LATENCY_BUCKETS)
         # wall time of one preempted request's RESUME swap-in (all its
         # restored pages, host->device) — the latency a preemption
@@ -405,7 +391,6 @@ class ServingMetrics:
         self.queue_depth_hist = Histogram()
         self.occupancy_hist = Histogram()
         self.pool_utilization_hist = Histogram()
-        self.prefill_stall_hist = Histogram()
         # per-admission prefix-cache hit size (tokens served from
         # shared pages; 0 on a cold miss)
         self.prefix_cached_tokens_hist = Histogram()
@@ -580,10 +565,6 @@ class ServingMetrics:
             if self.adapters_enabled:
                 self._adapter_class(aid)["e2e_s"].record(e2e)
 
-    def on_decode_step(self, wall_s: float):
-        with self._lock:
-            self.decode_step_s.record(wall_s)
-
     def on_preempt(self, pages_out: int):
         """One resident was preempted: `pages_out` of its KV pages
         swapped out to the host tier (0 = pure recompute fallback)."""
@@ -617,8 +598,7 @@ class ServingMetrics:
         """One unified ragged step ran, packing `prefill_tokens` prompt
         tokens and `draft_tokens` speculative drafts next to
         `decode_tokens` sampled tokens. The wall time lands in the
-        same decode_step_s histogram the alternating path records, so
-        the on/off A/B compares like for like."""
+        decode_step_s histogram."""
         with self._lock:
             self.unified_steps += 1
             self.packed_prefill_tokens += int(prefill_tokens)
@@ -687,7 +667,7 @@ class ServingMetrics:
 
     def on_step(self, queue_depth: int, occupancy: float, num_slots: int,
                 pages_used: int = 0, pages_total: int = 0,
-                stall_chunks: int = 0, pages_cached: int = 0,
+                pages_cached: int = 0,
                 pages_swapped: int = 0, host_pages_used: int = 0,
                 host_pages_total: int = 0,
                 draft_pages_used: int = 0,
@@ -714,12 +694,8 @@ class ServingMetrics:
                 self.draft_pool_pages_total = draft_pages_total
             if prefix_stats is not None:
                 self.prefix = dict(prefix_stats)
-            self.prefill_stall = stall_chunks
-            if stall_chunks:
-                self.prefill_stall_steps += 1
             if pages_total:
                 self.pool_utilization_hist.record(pages_used / pages_total)
-            self.prefill_stall_hist.record(stall_chunks)
 
     # -- reading ----------------------------------------------------------
     @property
@@ -772,7 +748,6 @@ class ServingMetrics:
             "mesh": self.mesh,
             "mp": self.mp,
             "dp": self.dp,
-            "unified": self.unified,
             "unified_steps": self.unified_steps,
             **self.host_phases,
             "packed_prefill_tokens": self.packed_prefill_tokens,
@@ -797,7 +772,6 @@ class ServingMetrics:
             "megakernel": self.megakernel,
             "unified_dispatch_ops": self.unified_dispatch_ops,
             "group_size_per_step": self.group_size_hist.snapshot(),
-            "prefill_stall_steps": self.prefill_stall_steps,
             "decode_step_s": self.decode_step_s.snapshot(),
             "tokens_per_sec": self.tokens_per_sec,
             "queue_depth": self.queue_depth,
@@ -837,8 +811,6 @@ class ServingMetrics:
                 "bytes_recv": self.fabric_bytes_recv,
                 "restored_pages": self.fabric_restored_pages,
             },
-            "prefill_stall": self.prefill_stall,
-            "prefill_stall_hist": self.prefill_stall_hist.snapshot(),
             "ttft_s": self.ttft_s.snapshot(),
             "inter_token_s": self.inter_token_s.snapshot(),
             "queue_wait_s": self.queue_wait_s.snapshot(),
@@ -955,7 +927,6 @@ def prometheus_render(snapshots: dict, namespace: str = "paddle_serving",
                        ("swap_in_seconds", "histogram"),
                        ("unified_steps_total", "counter"),
                        ("grouped_walk_steps_total", "counter"),
-                       ("prefill_stall_steps_total", "counter"),
                        ("spec_drafted_total", "counter"),
                        ("spec_accepted_total", "counter"),
                        ("spec_tokens_per_step", "histogram"),
@@ -995,14 +966,12 @@ def prometheus_render(snapshots: dict, namespace: str = "paddle_serving",
         lines.append(f"# TYPE {namespace}_{name} {kind}")
     for replica, snap in sorted(snapshots.items()):
         lab = {"replica": str(replica)}
-        # info-style gauge: the A/B tags (which attention impl, unified
-        # vs alternating step, spec mode, paged-pool dtype) ride as
-        # labels so scrapes from an A/B fleet are distinguishable
-        # without relabeling
+        # info-style gauge: the A/B tags (which attention impl, spec
+        # mode, paged-pool dtype) ride as labels so scrapes from an
+        # A/B fleet are distinguishable without relabeling
         lines.append(
             f"{namespace}_engine_info" + _fmt_labels({
                 **lab, "attn_impl": snap.get("attn_impl") or "unknown",
-                "unified": ("on" if snap.get("unified") else "off"),
                 "spec": snap.get("spec") or "off",
                 "spec_draft_model": ("on"
                                      if snap.get("spec_draft_model")
@@ -1063,9 +1032,6 @@ def prometheus_render(snapshots: dict, namespace: str = "paddle_serving",
         for name in HOST_PHASE_COUNTERS + STEP_WORK_COUNTERS:
             lines.append(f"{namespace}_{name}" + _fmt_labels(lab)
                          + f" {snap.get(name, 0)}")
-        lines.append(f"{namespace}_prefill_stall_steps_total"
-                     + _fmt_labels(lab)
-                     + f" {snap.get('prefill_stall_steps', 0)}")
         lines.append(f"{namespace}_spec_drafted_total"
                      + _fmt_labels(lab)
                      + f" {snap.get('spec_drafted_tokens', 0)}")

@@ -2,10 +2,8 @@
 
 The device state is a shared per-layer pool [num_pages, page_size, H, D]
 plus a per-slot page table [S, max_pages] (see nlp/generation.py's paged
-DecodeCache). This module owns the HOST half: which pages are free,
-which belong to which request, and how prompts are cut into
-power-of-two chunk buckets so the compiled prefill-trace count stays
-O(log max_len) instead of one trace per distinct prompt length.
+DecodeCache). This module owns the HOST half: which pages are free
+and which belong to which request.
 
 Page 0 is reserved as the TRASH page: it is never handed out, free
 slots' page-table rows point every entry at it, and the device scatter
@@ -46,8 +44,7 @@ from __future__ import annotations
 
 from typing import Dict, Iterable, List, Optional
 
-__all__ = ["PagePool", "HostPagePool", "TRASH_PAGE", "pages_needed",
-           "chunk_bucket"]
+__all__ = ["PagePool", "HostPagePool", "TRASH_PAGE", "pages_needed"]
 
 TRASH_PAGE = 0      # reserved: never allocated, absorbs masked writes
 
@@ -367,18 +364,3 @@ def pages_needed(prompt_len: int, max_new_tokens: int,
     return -(-(int(prompt_len) + int(max_new_tokens)) // int(page_size))
 
 
-def chunk_bucket(remaining: int, chunk_len: int, min_chunk: int = 8
-                 ) -> int:
-    """Length of the next prefill chunk: full `chunk_len` chunks while
-    the remainder is large, then ONE power-of-two bucket >= the tail
-    (clamped to [min_chunk, chunk_len]). Distinct bucket values over
-    all prompts are {chunk_len} ∪ {min_chunk * 2**i <= chunk_len}, so
-    the engine compiles O(log chunk_len) prefill programs total."""
-    if remaining <= 0:
-        raise ValueError("remaining must be > 0")
-    if remaining >= chunk_len:
-        return chunk_len
-    b = min_chunk
-    while b < remaining:
-        b *= 2
-    return min(b, chunk_len)

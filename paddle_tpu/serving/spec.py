@@ -208,7 +208,7 @@ class SpecConfig:
     the request's remaining token budget); `drafter` is a zero-arg
     factory producing one `Drafter` PER REQUEST — or one of the tier
     names "ngram"/"model", which also sets `mode`; `mode` is the tag
-    metrics/Prometheus report next to `attn_impl`/`unified`.
+    metrics/Prometheus report next to `attn_impl`.
     `draft_model` (model tier only) is the resident draft model the
     engine's DraftEngine serves — None makes the engine shrink one
     from the target via `serving.draft.make_draft_model`."""
@@ -246,8 +246,8 @@ def resolve_spec_config(override=None) -> Optional[SpecConfig]:
     None (off). An explicit override wins; otherwise
     PADDLE_TPU_SPEC_DECODE=off|ngram[:k]|model[:k] (read at engine
     construction, default off — same env-gate pattern as
-    PADDLE_TPU_PAGED_ATTN / PADDLE_TPU_PREFIX_CACHE /
-    PADDLE_TPU_UNIFIED_STEP). Accepted overrides: None (use the env),
+    PADDLE_TPU_PAGED_ATTN / PADDLE_TPU_PREFIX_CACHE). Accepted
+    overrides: None (use the env),
     a SpecConfig, a mode string ("off", "ngram", "ngram:8", "model",
     "model:6"), or a bool (True = default ngram config). Every
     malformed spelling — unknown mode, 'off' with a knob, an empty or

@@ -14,21 +14,11 @@ suppress with --out -):
     {"bench": "serving", "schema_version": 19, "attn_impl": "kernel",
      "requests": ..., "ttft_p50_s": ..., "tokens_per_sec": ...,
      "decode_step_ms_p50": ..., "ab": {"kernel": {...},
-     "gather": {...}}, "prefix_stats": {...}, "unified": {...},
+     "gather": {...}}, "prefix_stats": {...},
      "spec": {...}, "chaos": {...}, ...}
 
 Top-level numbers are the default ("kernel") run; "ab" holds the
 per-impl summaries (tokens/s, TTFT, per-step decode wall time).
-
-`--unified-ab` adds the unified-step A/B: the SAME Poisson trace under
-a LONG-PROMPT-HEAVY mix runs once with the unified ragged
-prefill+decode step ON (one compiled program, prefill packed into
-spare decode capacity) and once OFF (the legacy alternating
-prefill-bucket/decode families), recording client-observed TTFT
-p50/p99, tokens/s, prefill-stall steps and packed tokens per step
-under the report's "unified" key — and asserts TTFT p99 does not
-regress with the unified step on (the stall-kill this step exists
-for).
 
 `--spec-ab` adds the speculative-decoding A/B: the SAME Poisson
 arrivals over a TEMPLATED/CODE-HEAVY prompt mix (repeating template
@@ -210,19 +200,6 @@ OFF, and the report's "prefix" section records TTFT and
 prefill-steps-per-request for both (plus hit rate / cached tokens),
 so the cache's win is a number in the trajectory, not a claim.
 
-`--prefix-share` also runs the GROUPED-vs-FLAT attention A/B (the
-report's "grouped" section): the SAME shared-prefix trace, prefix
-cache on both times, once with the prefix-sharing-aware grouped page
-walk (PADDLE_TPU_GROUPED_ATTN, default on — shared pages stream from
-HBM once per group) and once with the flat per-row walk. Both arms
-collect every emitted token; the script ASSERTS the arms are
-token-identical, that the grouped arm's modeled page-block reads per
-step (counted by the CPU reference, `page_block_reads_total`) are
-strictly below the flat arm's, and that tokens/s does not regress.
-The saved-reads total and the per-step group-size histogram land in
-the section — the ~Nx HBM claim as a number (CPU models the traffic;
-the real-chip A/B is the ROADMAP's open measurement).
-
 Usage:
     python scripts/serving_bench.py            # platform-sized run
     python scripts/serving_bench.py --smoke    # seconds-fast CI run
@@ -285,11 +262,9 @@ _SECTION_HEADLINES = {
     # section -> headline extractor (tokens/s-shaped number); missing
     # sections are simply absent from the entry
     "serving": lambda r: r.get("tokens_per_sec"),
-    "unified": lambda r: r["unified"]["on"]["tokens_per_sec"],
     "spec": lambda r: r["spec"]["on"]["tokens_per_sec"],
     "fused": lambda r: r["fused"]["on"]["tokens_per_sec"],
     "obs": lambda r: r["obs"]["on"]["tokens_per_sec"],
-    "grouped": lambda r: r["grouped"]["on"]["tokens_per_sec"],
     "quant": lambda r: r["quant"]["int8"]["tokens_per_sec"],
     "lora": lambda r: r["lora"]["batched"]["tokens_per_sec"],
     "tp": lambda r: r["tp"]["mp2"]["tokens_per_sec"],
@@ -413,10 +388,6 @@ def main():
                     "A/B over the same trace to the report")
     ap.add_argument("--prefix-prompts", type=int, default=4,
                     help="K: number of distinct shared system prompts")
-    ap.add_argument("--unified-ab", action="store_true",
-                    help="run the same Poisson trace under a "
-                    "long-prompt-heavy mix with the unified ragged "
-                    "step on vs off and record the TTFT/stall A/B")
     ap.add_argument("--spec-ab", action="store_true",
                     help="run the same Poisson arrivals over a "
                     "templated/code-heavy prompt mix with "
@@ -556,7 +527,6 @@ def main():
         max_len = args.max_len or 64
         chunk = args.chunk or 16
         prompt_lens = [3, 5, 8]
-        long_prompt_lens = [3, 30, 40, 45]
         prefix_len = 24
     elif on_tpu:
         n_req = args.requests or 128
@@ -565,7 +535,6 @@ def main():
         max_len = args.max_len or 1024
         chunk = args.chunk or 128
         prompt_lens = [32, 64, 128, 256]
-        long_prompt_lens = [32, 384, 512, 768]
         prefix_len = 256
     else:
         n_req = args.requests or 24
@@ -574,7 +543,6 @@ def main():
         max_len = args.max_len or 128
         chunk = args.chunk or 32
         prompt_lens = [4, 8, 12, 16]
-        long_prompt_lens = [6, 60, 80, 100]
         prefix_len = 40
 
     rng = np.random.RandomState(args.seed)
@@ -605,40 +573,6 @@ def main():
             max_len=max_len, page_size=args.page_size, pages=args.pages,
             chunk=chunk, attn_impl=attn_impl)
 
-    # the unified-step A/B: the SAME arrivals under a LONG-PROMPT-HEAVY
-    # mix (the traffic shape whose prefill chunks stall every resident
-    # decoder on the alternating path) once with the unified ragged
-    # step on, once off
-    unified_runs = {}
-    if args.unified_ab:
-        # TTFT-focused load spike: more requests than slots arriving in
-        # a burst (10x the base rate), long prompts, tiny output
-        # budgets — the prefill-stall scenario whose TTFT spikes the
-        # unified step exists to kill. Both runs replay the SAME
-        # arrivals/prompts/budgets; only the step architecture differs.
-        uni_n = max(n_req, 2 * args.slots)
-        uni_arrivals = np.cumsum(
-            rng.exponential(1.0 / (rate * 10.0), size=uni_n))
-        long_prompts = [
-            rng.randint(0, cfg.vocab_size,
-                        size=rng.choice(long_prompt_lens))
-            .astype(np.int64) for _ in range(uni_n)]
-        ttft_budgets = rng.randint(1, 3, size=uni_n)
-        for flag in (True, False):
-            # best-of-2 per arm by TTFT p99: a single OS/GC hiccup in
-            # a sub-100ms replay poisons a p99 of max-of-N samples;
-            # the MIN across repeats is the stable statistic (same
-            # convention as op_bench / decode_roofline timing)
-            attempts = [run_trace(
-                model, uni_arrivals, long_prompts, ttft_budgets,
-                slots=args.slots, max_len=max_len,
-                page_size=args.page_size, pages=args.pages,
-                chunk=chunk, attn_impl="kernel", unified=flag)
-                for _ in range(2)]
-            unified_runs["on" if flag else "off"] = min(
-                attempts,
-                key=lambda r: r["snap"]["ttft_s"]["p99"] or 0.0)
-
     # the speculative-decoding A/B: the SAME Poisson arrivals over a
     # TEMPLATED/CODE-HEAVY prompt mix (repeating template blocks — the
     # shape prompt-lookup drafting wins on) once with speculation off,
@@ -668,8 +602,8 @@ def main():
                 np.concatenate([head, np.tile(tpl, tpl_reps)]))
         spec_budgets = np.full(spec_n, spec_max_new)
         for mode in ("off", "on"):
-            # best-of-3 per arm by tokens/s (the unified A/B's
-            # hiccup-absorbing convention, one repeat deeper: the
+            # best-of-3 per arm by tokens/s (a hiccup-absorbing
+            # convention: the
             # spec arms' sub-second replays are the most
             # OS-jitter-sensitive sections in the file); tokens are
             # identical across attempts, so either attempt's list
@@ -842,7 +776,6 @@ def main():
     # radix cache on vs off (cache pre-warmed with the K system
     # prompts — steady-state behavior, not cold-start compile noise)
     prefix_runs = {}
-    grouped_runs = {}
     if share > 0.0:
         for flag in (True, False):
             prefix_runs["on" if flag else "off"] = run_trace(
@@ -850,26 +783,6 @@ def main():
                 max_len=max_len, page_size=args.page_size,
                 pages=args.pages, chunk=chunk, attn_impl="kernel",
                 prefix_cache=flag, warm_prompts=sys_prompts)
-        # the grouped-vs-flat attention A/B: same trace, cache ON both
-        # times (groups only exist where pages are shared), once with
-        # the grouped page walk and once flat. Tokens collected so the
-        # bit-identity claim is asserted, not assumed. Best-of-2 per
-        # arm by tokens/s (the hiccup-absorbing convention of the
-        # other A/Bs — a sub-second CPU replay's throughput is OS
-        # noise; the read counts are deterministic across attempts).
-        for flag in (True, False):
-            attempts = [run_trace(
-                model, arrivals, prompts, budgets, slots=args.slots,
-                max_len=max_len, page_size=args.page_size,
-                pages=args.pages, chunk=chunk, attn_impl="kernel",
-                prefix_cache=True, warm_prompts=sys_prompts,
-                grouped=flag, collect_tokens=True) for _ in range(2)]
-            for a in attempts[1:]:
-                assert a["tokens"] == attempts[0]["tokens"], \
-                    "grouped arm not deterministic across repeats"
-            grouped_runs["on" if flag else "off"] = max(
-                attempts,
-                key=lambda r: r["snap"]["tokens_per_sec"] or 0.0)
 
     snap = runs["kernel"]["snap"]
     pool = snap["pool"]
@@ -887,23 +800,6 @@ def main():
             "decode_steps": s["decode_steps"],
             "decode_step_ms_p50": _ms(s["decode_step_s"]["p50"]),
             "decode_step_ms_p99": _ms(s["decode_step_s"]["p99"]),
-            "completed": s["requests"]["completed"],
-        }
-
-    def _unified_summary(run):
-        s = run["snap"]
-        packed = s.get("packed_tokens_per_step") or {}
-        return {
-            "wall_s": round(run["wall_s"], 4),
-            "tokens_per_sec": s["tokens_per_sec"],
-            "ttft_p50_s": s["ttft_s"]["p50"],
-            "ttft_p99_s": s["ttft_s"]["p99"],
-            "inter_token_p99_s": s["inter_token_s"]["p99"],
-            "decode_steps": s["decode_steps"],
-            "unified_steps": s["unified_steps"],
-            "prefill_stall_steps": s["prefill_stall_steps"],
-            "packed_tokens_per_step_mean": packed.get("mean"),
-            "packed_tokens_per_step_max": packed.get("max"),
             "completed": s["requests"]["completed"],
         }
 
@@ -964,7 +860,6 @@ def main():
         "pool_utilization_mean": pool["utilization"]["mean"],
         "pool_utilization_max": pool["utilization"]["max"],
         "prefill_chunks": snap["prefill_chunks"],
-        "prefill_stall_p99": snap["prefill_stall_hist"]["p99"],
         "decode_steps": snap["decode_steps"],
         "completed": snap["requests"]["completed"],
         "ab": {impl: _ab(run) for impl, run in runs.items()},
@@ -972,13 +867,6 @@ def main():
         # kernel run — nonzero only when the trace actually shares
         "prefix_stats": snap.get("prefix"),
     }
-    if unified_runs:
-        report["unified"] = {
-            "long_prompt_lens": [int(x) for x in long_prompt_lens],
-            "requests": uni_n,
-            **{flag: _unified_summary(run)
-               for flag, run in unified_runs.items()},
-        }
     if spec_runs:
         on_s, off_s = (_spec_summary(spec_runs["on"]),
                        _spec_summary(spec_runs["off"]))
@@ -1181,43 +1069,6 @@ def main():
             **{flag: _prefix_summary(run)
                for flag, run in prefix_runs.items()},
         }
-
-        def _grouped_summary(run):
-            s = run["snap"]
-            steps = max(1, s["unified_steps"])
-            gs = s.get("group_size_per_step") or {}
-            return {
-                "wall_s": round(run["wall_s"], 4),
-                "tokens_per_sec": s["tokens_per_sec"],
-                "unified_steps": s["unified_steps"],
-                "page_block_reads_total":
-                    s.get("page_block_reads_total", 0),
-                "page_block_reads_per_step":
-                    s.get("page_block_reads_total", 0) / steps,
-                "shared_page_reads_saved_total":
-                    s.get("shared_page_reads_saved_total", 0),
-                "group_size_mean": gs.get("mean"),
-                "group_size_max": gs.get("max"),
-                "completed": s["requests"]["completed"],
-            }
-
-        on_g, off_g = (_grouped_summary(grouped_runs["on"]),
-                       _grouped_summary(grouped_runs["off"]))
-        report["grouped"] = {
-            "share": share,
-            "on": on_g,
-            "off": off_g,
-            "reads_per_step_ratio": (
-                None if not off_g["page_block_reads_per_step"]
-                else on_g["page_block_reads_per_step"]
-                / off_g["page_block_reads_per_step"]),
-            "tokens_per_sec_ratio": (
-                None if not off_g["tokens_per_sec"]
-                else (on_g["tokens_per_sec"] or 0.0)
-                / off_g["tokens_per_sec"]),
-            "token_identical": (grouped_runs["on"]["tokens"]
-                                == grouped_runs["off"]["tokens"]),
-        }
     if args.quant_ab:
         report["quant"] = quant_trace(
             model, cfg, slots=args.slots, seed=args.seed + 4,
@@ -1276,20 +1127,6 @@ def main():
     for flag, run in prefix_runs.items():
         assert run["snap"]["requests"]["completed"] == n_req, \
             (flag, run["snap"]["requests"], n_req)
-    for flag, run in unified_runs.items():
-        assert run["snap"]["requests"]["completed"] == uni_n, \
-            (flag, run["snap"]["requests"], uni_n)
-    if unified_runs:
-        on, off = report["unified"]["on"], report["unified"]["off"]
-        # the acceptance numbers: packing really happened, the off
-        # path really stalled, and client-observed TTFT p99 does not
-        # regress with the unified step on (small tolerance absorbs
-        # scheduler-noise on sub-ms CPU smoke steps)
-        assert on["prefill_stall_steps"] == 0, report["unified"]
-        assert off["prefill_stall_steps"] > 0, report["unified"]
-        assert on["packed_tokens_per_step_max"] > 1, report["unified"]
-        assert on["ttft_p99_s"] <= off["ttft_p99_s"] * 1.15, \
-            report["unified"]
     if spec_runs:
         sp = report["spec"]
         # the acceptance numbers: the two arms emitted EXACTLY the
@@ -1303,7 +1140,7 @@ def main():
         assert sp["accepted_tokens_per_step"] is not None \
             and sp["accepted_tokens_per_step"] > 1.0, sp
         # no tokens/s regression — with the same scheduler-noise pin
-        # the grouped/grammar A/Bs use: sub-second smoke arms get the
+        # the grammar A/B uses: sub-second smoke arms get the
         # wide pin (at ~0.3s/arm one OS hiccup moves the ratio ~30%),
         # longer arms pin at 15%
         sp_noise = 2.0 if sp["on"]["wall_s"] < 1.0 else 1.15
@@ -1362,8 +1199,8 @@ def main():
         assert gm["off"]["grammar_requests"] == 0, gm
         assert gm["spec"]["accepted_tokens_per_step"] is not None \
             and gm["spec"]["accepted_tokens_per_step"] > 1.0, gm
-        # sub-second smoke arms get the wide scheduler-hiccup pin the
-        # grouped A/B uses; longer arms pin at 15%
+        # sub-second smoke arms get the wide scheduler-hiccup pin;
+        # longer arms pin at 15%
         gm_noise = 2.0 if gm["on"]["wall_s"] < 1.0 else 1.15
         assert gm["tokens_per_sec_ratio"] is not None \
             and gm["tokens_per_sec_ratio"] >= 1.0 / gm_noise, gm
@@ -1405,32 +1242,6 @@ def main():
         assert on["prefill_chunks_per_request"] < \
             off["prefill_chunks_per_request"], report["prefix"]
         assert on["hit_rate"] and on["hit_rate"] > 0, report["prefix"]
-        gr = report["grouped"]
-        # the grouped-walk acceptance numbers: the two arms emitted
-        # EXACTLY the same tokens (grouping is an HBM-traffic hint,
-        # never a math change), the grouped arm's modeled page-block
-        # reads per step are strictly below the flat arm's (shared
-        # pages streamed once per group — the saved-reads counter
-        # agrees), groups really formed (mean size > 1), and both
-        # arms served the whole trace
-        assert gr["token_identical"], "grouped on/off token mismatch"
-        assert gr["on"]["completed"] == gr["off"]["completed"] \
-            == n_req, gr
-        assert gr["on"]["page_block_reads_per_step"] < \
-            gr["off"]["page_block_reads_per_step"], gr
-        assert gr["on"]["shared_page_reads_saved_total"] > 0, gr
-        assert gr["off"]["shared_page_reads_saved_total"] == 0, gr
-        assert gr["on"]["group_size_mean"] is not None \
-            and gr["on"]["group_size_mean"] > 1.0, gr
-        # no tokens/s regression — with the same scheduler-noise
-        # tolerance the unified A/B uses: on CPU the smoke run models
-        # the HBM traffic (the read counts above are the claim), it
-        # cannot observe the bandwidth win itself. Sub-second smoke
-        # arms get a wider pin: at ~0.2s/arm a single scheduler
-        # hiccup moves the ratio ~30%, drowning the 15% margin.
-        gr_noise = 1.5 if gr["on"]["wall_s"] < 1.0 else 1.15
-        assert gr["tokens_per_sec_ratio"] is not None \
-            and gr["tokens_per_sec_ratio"] >= 1.0 / gr_noise, gr
     if args.http:
         assert report["http"]["completed"] == n_req, report["http"]
     if args.chaos:
@@ -1577,17 +1388,16 @@ def main():
 
 def run_trace(model, arrivals, prompts, budgets, *, slots, max_len,
               page_size, pages, chunk, attn_impl, prefix_cache=None,
-              warm_prompts=(), unified=None, spec=None,
-              collect_tokens=False, kv_dtype=None, grouped=None,
+              warm_prompts=(), spec=None,
+              collect_tokens=False, kv_dtype=None,
               obs=None, mesh=None, collect_collectives=False,
               slo=None, cost_census=None, grammar=None,
               grammar_spec=None, eos=None, megakernel=None):
     """One Poisson-trace replay through a fresh engine pinned to
     `attn_impl` (and, for the prefix A/B, to `prefix_cache` on/off;
-    for the unified-step A/B, to `unified` on/off; for the spec A/B,
+    for the spec A/B,
     to `spec` — False forces speculation off, "ngram[:k]" turns the
-    drafter on; for the quant A/B, to `kv_dtype` fp/int8; for the
-    grouped-walk A/B, to `grouped` on/off); returns
+    drafter on; for the quant A/B, to `kv_dtype` fp/int8); returns
     {snap, wall_s, engine-shape fields, and — with collect_tokens —
     every request's emitted token list in submission order, the
     spec/quant A/Bs' token evidence}. `warm_prompts` run to completion
@@ -1600,8 +1410,8 @@ def run_trace(model, arrivals, prompts, budgets, *, slots, max_len,
     eng = ServingEngine(model, num_slots=slots, max_len=max_len,
                         page_size=page_size, num_pages=pages,
                         chunk_len=chunk, attn_impl=attn_impl,
-                        prefix_cache=prefix_cache, unified=unified,
-                        spec=spec, kv_dtype=kv_dtype, grouped=grouped,
+                        prefix_cache=prefix_cache,
+                        spec=spec, kv_dtype=kv_dtype,
                         obs=obs, mesh=mesh, slo=slo,
                         cost_census=cost_census, grammar=grammar,
                         megakernel=megakernel)
@@ -1615,8 +1425,7 @@ def run_trace(model, arrivals, prompts, budgets, *, slots, max_len,
         sp_kw["grammar"] = grammar_spec
 
     # warm the compiled programs so the trace measures steady state, not
-    # XLA compile time: one request per distinct prompt length (chunk
-    # bucketing folds these into O(log chunk) prefill traces)
+    # XLA compile time: one request per distinct prompt length
     for pl in sorted({p.size for p in prompts}):
         eng.add_request(np.arange(1, pl + 1, dtype=np.int64),
                         SamplingParams(max_new_tokens=2))
@@ -1635,7 +1444,6 @@ def run_trace(model, arrivals, prompts, budgets, *, slots, max_len,
     eng.metrics.step_capacity_tokens = eng.step_capacity_tokens
     eng.metrics.cost_census = eng._census
     eng.metrics.attn_impl = eng.attn_impl
-    eng.metrics.unified = eng.unified
     eng.metrics.grouped = eng.grouped
     eng.metrics.megakernel = eng.megakernel
     eng.metrics.spec = None if eng.spec is None else eng.spec.mode
@@ -2203,7 +2011,6 @@ def overload_trace(model, cfg, *, slots, seed, scale=1):
         eng.run()                  # compile-warm outside the clock
         eng.metrics.__init__()
         eng.metrics.attn_impl = eng.attn_impl
-        eng.metrics.unified = eng.unified
         wall0 = time.monotonic()
         reqs, submitted = [], 0
         while submitted < n or eng.has_work:

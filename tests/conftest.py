@@ -46,3 +46,17 @@ def _reset_parallel_state():
     yield
     from paddle_tpu.distributed import fleet
     fleet.shutdown()
+
+
+@pytest.fixture
+def only_the_unified_step():
+    """A check that a ServingEngine holds no step program but
+    `_unified_fn`: every other compiled-program attribute is one of its
+    helpers (embed epilogue, COW copy, host-tier swaps), and the step
+    never retraced."""
+    def check(eng):
+        assert {k for k in vars(eng) if k.endswith(("_fn", "_fns"))} == {
+            "_unified_fn", "_embed_fn", "_copy_page_fn", "_swap_out_fn",
+            "_swap_in_fn"}
+        assert eng._unified_fn._cache_size() == 1
+    return check

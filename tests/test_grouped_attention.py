@@ -20,8 +20,9 @@ Contracts:
 - `count_page_block_reads` (the CPU-reference DMA model) prices the
   flat walk at one read per live page per row and the grouped walk at
   one read per shared page per GROUP;
-- a ServingEngine with the grouped walk on emits bit-identical greedy
-  tokens to grouped-off — through prefix-cache COW landing mid-span,
+- a ServingEngine that compiles the grouped walk (it has a prefix
+  cache) emits bit-identical greedy tokens to one that compiles the
+  flat walk (it has none) — through prefix-cache COW landing mid-span,
   eviction pressure, member retirement shrinking a group, and the
   int8 lane — while `shared_page_reads_saved_total` actually grows
   and the ONE unified trace never retraces;
@@ -39,8 +40,7 @@ import paddle_tpu as paddle
 from paddle_tpu.nlp import GPTConfig, GPTForCausalLM
 from paddle_tpu.ops.pallas import paged_attention as pa
 from paddle_tpu.serving import (SamplingParams, ServingEngine,
-                                prometheus_render, resolve_grouped_flag,
-                                shared_prefix_groups)
+                                prometheus_render, shared_prefix_groups)
 
 _MODELS = {}
 
@@ -342,12 +342,15 @@ class TestSharedPrefixGroups:
 
 
 def run_ab(model, prompts, max_new, *, warm=(), **kw):
-    """The same batch through grouped-on and grouped-off engines;
-    returns (tokens_on, tokens_off, engine_on)."""
+    """The same batch through an engine that compiles the grouped walk
+    and one that compiles the flat walk (no prefix cache: nothing can
+    put a page into two rows' tables); returns (tokens_grouped,
+    tokens_flat, engine_grouped)."""
     outs = {}
     engines = {}
     for flag in (True, False):
-        eng = ServingEngine(model, grouped=flag, **kw)
+        eng = ServingEngine(model, prefix_cache=flag, **kw)
+        assert eng.grouped is flag
         if warm:
             eng.generate(list(warm), SamplingParams(max_new_tokens=2))
         res = eng.generate(prompts, SamplingParams(
@@ -384,7 +387,7 @@ class TestGroupedEngine:
         """Prompts whose shared prefix ends mid-page COW their partial
         page (the COW'd row's group span stops at the divergence), and
         a small pool forces eviction between steps — tokens stay
-        bit-identical across the gate through both."""
+        bit-identical to the flat walk's through both."""
         model = tiny_gpt()
         rng = np.random.RandomState(1)
         sys_p = rng.randint(0, 89, size=20).astype(np.int64)  # 2.5 pgs
@@ -407,7 +410,7 @@ class TestGroupedEngine:
         rng = np.random.RandomState(2)
         sys_p = rng.randint(0, 89, size=16).astype(np.int64)
         eng = ServingEngine(model, num_slots=3, max_len=64,
-                            page_size=8, chunk_len=16, grouped=True)
+                            page_size=8, chunk_len=16)
         eng.generate([sys_p], SamplingParams(max_new_tokens=2))
         prompts = self._prompts(rng, sys_p, (3, 4, 5))
         reqs = [eng.add_request(p, SamplingParams(
@@ -439,25 +442,18 @@ class TestGroupedEngine:
         assert eng.metrics.snapshot()[
             "shared_page_reads_saved_total"] > 0
 
-    def test_gate_resolution_and_inert_paths(self, monkeypatch):
-        assert resolve_grouped_flag() is True            # default on
-        monkeypatch.setenv("PADDLE_TPU_GROUPED_ATTN", "off")
-        assert resolve_grouped_flag() is False
-        assert resolve_grouped_flag(True) is True        # override
-        monkeypatch.setenv("PADDLE_TPU_GROUPED_ATTN", "maybe")
-        with pytest.raises(ValueError, match="PADDLE_TPU_GROUPED"):
-            resolve_grouped_flag()
-        monkeypatch.delenv("PADDLE_TPU_GROUPED_ATTN")
-        # the flag is inert off the unified/kernel path
+    def test_the_engine_derives_the_grouped_walk(self):
+        """No option: the group operands ride the step iff the Pallas
+        walk serves it and the engine has a prefix cache."""
+        kw = dict(num_slots=2, max_len=32, page_size=8, chunk_len=8)
         model = tiny_gpt()
-        eng = ServingEngine(model, num_slots=2, max_len=32,
-                            page_size=8, chunk_len=8, unified=False,
-                            grouped=True)
-        assert eng.grouped is False
-        eng = ServingEngine(model, num_slots=2, max_len=32,
-                            page_size=8, chunk_len=8,
-                            attn_impl="gather", grouped=True)
-        assert eng.grouped is False
+        assert ServingEngine(model, **kw).grouped is True
+        assert ServingEngine(model, prefix_cache=False,
+                             **kw).grouped is False
+        assert ServingEngine(model, attn_impl="gather",
+                             **kw).grouped is False
+        with pytest.raises(TypeError):
+            ServingEngine(model, grouped=False, **kw)
 
     @pytest.mark.parametrize("share", [True, False])
     def test_grouped_walk_steps_counts_steps_with_a_group(
@@ -480,7 +476,7 @@ class TestGroupedEngine:
         rng = np.random.RandomState(9)
         sys_p = rng.randint(0, 89, size=16).astype(np.int64)
         eng = ServingEngine(model, num_slots=3, max_len=64,
-                            page_size=8, chunk_len=16, grouped=True)
+                            page_size=8, chunk_len=16)
         if share:
             eng.generate([sys_p], SamplingParams(max_new_tokens=2))
             prompts = self._prompts(rng, sys_p, (3, 4, 5))
@@ -497,12 +493,13 @@ class TestGroupedEngine:
         assert ("paddle_serving_grouped_walk_steps_total"
                 f'{{replica="r0"}} {sum(seen)}') in text
 
-    def test_grouped_off_never_counts_a_phase1_step(self):
+    def test_no_prefix_cache_never_counts_a_phase1_step(self):
         model = tiny_gpt()
         rng = np.random.RandomState(10)
         sys_p = rng.randint(0, 89, size=16).astype(np.int64)
         eng = ServingEngine(model, num_slots=3, max_len=64,
-                            page_size=8, chunk_len=16, grouped=False)
+                            page_size=8, chunk_len=16,
+                            prefix_cache=False)
         eng.generate([sys_p], SamplingParams(max_new_tokens=2))
         eng.generate(self._prompts(rng, sys_p, (3, 4, 5)),
                      SamplingParams(max_new_tokens=4))

@@ -454,14 +454,10 @@ class TestEngineMegakernel:
                             megakernel=True)
         assert eng.megakernel is True
         assert eng.metrics.megakernel is True
-        # silent downgrade off the fused-capable path (mirrors the
-        # grouped gate): the gather impl and the legacy step families
-        # have no fused form
+        # silent downgrade off the fused-capable path: the gather
+        # impl has no fused form
         eng = ServingEngine(m, num_slots=2, max_len=64,
                             megakernel=True, attn_impl="gather")
-        assert eng.megakernel is False
-        eng = ServingEngine(m, num_slots=2, max_len=64,
-                            megakernel=True, unified=False)
         assert eng.megakernel is False
         monkeypatch.setenv(pa.MEGAKERNEL_ENV, "1")
         eng = ServingEngine(m, num_slots=2, max_len=64)

@@ -15,7 +15,6 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu import profiler
 from paddle_tpu.nlp import (GPTConfig, GPTForCausalLM, LlamaConfig,
                             LlamaForCausalLM)
 from paddle_tpu.serving import (EngineClosed, QueueFull, Request,
@@ -310,49 +309,6 @@ class TestMetricsAndTrace:
         assert snap["slot_occupancy"] == 0.0         # drained
         assert snap["decode_steps"] > 0
 
-    def test_chrome_trace_contains_per_request_spans(self, tmp_path):
-        # pinned to the legacy alternating path (its per-chunk prefill
-        # and decode_step spans); the unified step's spans are covered
-        # in tests/test_serving_unified.py. Names are fixed, the chunk
-        # rides in the arguments, the per-request part is
-        # RequestTracer's timeline.
-        model = tiny_gpt()
-        eng = ServingEngine(model, num_slots=2, max_len=48,
-                            unified=False)
-        with profiler.Profiler(
-                targets=[profiler.ProfilerTarget.CPU]) as p:
-            r0 = eng.add_request(np.array([1, 2, 3], np.int64),
-                                 SamplingParams(max_new_tokens=3))
-            r1 = eng.add_request(np.array([4, 5], np.int64),
-                                 SamplingParams(max_new_tokens=3))
-            eng.run()
-        path = str(tmp_path / "serving_trace.json")
-        p.export(path)
-        with open(path) as f:
-            trace = json.load(f)
-        events = trace["traceEvents"]
-        names = [e["name"] for e in events]
-        # chunked prefill: one span per chunk, cursor + bucket as args
-        chunks = [e["args"] for e in events
-                  if e["name"] == "serving::prefill"]
-        assert {c["slot"] for c in chunks} == {0, 1}
-        assert all(c["cursor"] == 0 and c["bucket"] >= 2 for c in chunks)
-        assert names.count("serving::decode_step") >= 3
-        assert not any("[" in n for n in names
-                       if n.startswith("serving::"))
-        for r in (r0, r1):
-            kinds = [e["kind"] for e in
-                     eng.obs.tracer.timeline(r.request_id)]
-            assert kinds[0] == "submit" and kinds[-1] == "finish"
-            assert "prefill_chunk" in kinds and "first_token" in kinds
-        # a round covers its prefill chunks and its decode step
-        round_ev = next(e for e in events
-                        if e["name"] == "serving::round")
-        step_ev = next(e for e in events
-                       if e["name"] == "serving::decode_step")
-        assert round_ev["dur"] >= step_ev["dur"]
-        assert round_ev["args"]["step"] == 1
-
     def test_metrics_histogram_percentiles(self):
         m = ServingMetrics()
         for v in [1.0, 2.0, 3.0, 4.0, 5.0]:
@@ -456,35 +412,6 @@ class TestPagedPoolAndChunkedPrefill:
         assert eng.metrics.pool_pages_total == num_pages - 1
         np.testing.assert_array_equal(
             np.asarray(reqs[0].output_tokens), want)
-
-    def test_single_compiled_program_per_shape_no_retrace(self):
-        """The decode step stays ONE compiled program and each chunk
-        bucket ONE prefill program across admissions, evictions,
-        cancellations and page reuse; total prefill traces stay within
-        the O(log chunk_len) bucket bound. (Pinned to the legacy
-        alternating path — the unified step collapses all of this into
-        ONE program, asserted in tests/test_serving_unified.py.)"""
-        import math
-        model = tiny_gpt()
-        eng = ServingEngine(model, num_slots=3, max_len=64,
-                            page_size=8, chunk_len=16, unified=False)
-        rng = np.random.RandomState(0)
-        reqs = []
-        for plen in [1, 2, 3, 5, 7, 9, 12, 15, 17, 20, 23, 30]:
-            reqs.append(eng.add_request(
-                rng.randint(0, 97, size=plen).astype(np.int64),
-                SamplingParams(max_new_tokens=4)))
-        eng.step()
-        eng.cancel(reqs[2].request_id)      # eviction mid-run
-        eng.run()
-        assert all(r.finished for r in reqs)
-        assert eng._decode_fn._cache_size() == 1
-        # buckets: {8, 16} = {min_chunk * 2**i <= chunk_len}
-        bound = int(math.log2(eng.chunk_len)) + 1
-        assert len(eng._prefill_fns) <= bound, eng._prefill_fns.keys()
-        assert set(eng._prefill_fns) == {8, 16}
-        assert all(fn._cache_size() == 1
-                   for fn in eng._prefill_fns.values())
 
 
 class TestSchedulerEdgeCases:
@@ -770,17 +697,6 @@ def test_serving_bench_prefix_share_smoke(tmp_path, monkeypatch):
         < off["prefill_chunks_per_request"]
     assert on["hit_rate"] > 0 and on["cached_tokens"] > 0
     assert off["cached_tokens"] == 0
-    # the grouped-vs-flat attention A/B rides the same trace: tokens
-    # bit-identical across the gate, the grouped arm's modeled
-    # page-block reads per step strictly below the flat arm's, and
-    # real groups formed (mean member count > 1)
-    gr = report["grouped"]
-    assert gr["token_identical"] is True
-    assert gr["on"]["page_block_reads_per_step"] \
-        < gr["off"]["page_block_reads_per_step"]
-    assert gr["on"]["shared_page_reads_saved_total"] > 0
-    assert gr["off"]["shared_page_reads_saved_total"] == 0
-    assert gr["on"]["group_size_mean"] > 1.0
 
 
 @pytest.mark.slow

@@ -155,11 +155,6 @@ class TestAdapterFlag:
         with pytest.raises(ValueError, match="PADDLE_TPU_ADAPTERS"):
             resolve_adapters_flag()
 
-    def test_adapters_require_unified_step(self):
-        with pytest.raises(ValueError, match="unified"):
-            ServingEngine(tiny_gpt(), num_slots=2, max_len=64,
-                          adapters=True, unified=False)
-
     def test_sampling_adapter_id_validated(self):
         with pytest.raises(ValueError, match="adapter_id"):
             SamplingParams(adapter_id=-1)

@@ -467,16 +467,13 @@ class TestWatchdogEndToEnd:
 
 # -- poison quarantine ------------------------------------------------------
 class TestPoisonQuarantine:
-    @pytest.mark.parametrize("unified", [True, False])
-    def test_bisect_isolates_poison_neighbors_token_identical(
-            self, unified):
+    def test_bisect_isolates_poison_neighbors_token_identical(self):
         """A poisoned resident deterministically kills the step; the
         engine bisects the batch, 422s it ALONE (typed
         PoisonedRequest) and every innocent co-resident completes
         bit-identical to solo decode on the SAME replica."""
         model = tiny_gpt()
-        eng = ServingEngine(model, num_slots=4, max_len=64,
-                            unified=unified)
+        eng = ServingEngine(model, num_slots=4, max_len=64)
         inj = FaultInjector()
         eng.step_fault_hook = \
             lambda ids: inj.on_engine_step("r0", ids)
@@ -492,7 +489,7 @@ class TestPoisonQuarantine:
         for i in (0, 2, 3):
             assert reqs[i].finish_reason == "length"
             assert reqs[i].output_tokens == oracle_greedy(
-                model, prompts[i], 10), (unified, i)
+                model, prompts[i], 10), i
         assert eng.metrics.requests_poisoned == 1
         assert eng.metrics.snapshot()["requests"]["poisoned"] == 1
         eng.drain()

@@ -137,9 +137,9 @@ class TestFp8Engine:
         assert runs[0] == runs[1]
 
     def test_feature_gates_token_identical_at_fp8(self):
-        """Prefix cache on/off and grouped walk on/off change page
-        ids and HBM walks, never tokens — the same oracle pattern as
-        int8's, now on the fp8 lane."""
+        """Prefix cache on/off (and with it the grouped or the flat
+        walk) changes page ids and HBM walks, never tokens — the same
+        oracle pattern as int8's, now on the fp8 lane."""
         model = tiny_gpt()
         rng = np.random.RandomState(2)
         sys_p = rng.randint(0, 97, size=16).astype(np.int64)
@@ -148,15 +148,14 @@ class TestFp8Engine:
             for n in (3, 5)]
         base = None
         for pc in (True, False):
-            for grouped in (True, False):
-                toks, eng = run_engine(
-                    model, prompts, 6, num_slots=2, max_len=64,
-                    page_size=8, chunk_len=16, kv_dtype="fp8",
-                    prefix_cache=pc, grouped=grouped)
-                assert eng.kv_dtype == "fp8"
-                if base is None:
-                    base = toks
-                assert toks == base
+            toks, eng = run_engine(
+                model, prompts, 6, num_slots=2, max_len=64,
+                page_size=8, chunk_len=16, kv_dtype="fp8",
+                prefix_cache=pc)
+            assert eng.kv_dtype == "fp8" and eng.grouped is pc
+            if base is None:
+                base = toks
+            assert toks == base
 
     def test_preemption_swap_roundtrip_moves_fp8_pages_whole(self):
         """A page extracted to the host tier and restored into a

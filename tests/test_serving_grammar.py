@@ -209,12 +209,6 @@ class TestGrammarGate:
                             page_size=8, chunk_len=8)
         assert not eng.grammar_on
 
-    def test_grammar_requires_unified_step(self):
-        with pytest.raises(ValueError):
-            ServingEngine(tiny_gpt(), num_slots=2, max_len=32,
-                          page_size=8, chunk_len=8, grammar=True,
-                          unified=False)
-
     def test_constrained_request_needs_the_gate(self):
         eng = ServingEngine(tiny_gpt(), num_slots=2, max_len=32,
                             page_size=8, chunk_len=8, grammar=False)
@@ -504,11 +498,12 @@ class TestGrammarPreemptionMigration:
 
 # -- retrace probe: masks and embed rows are DATA ---------------------------
 class TestRetraceProbe:
-    def test_mixed_rows_one_compiled_program(self):
-        """ISSUE acceptance: a batch mixing a constrained row, an
-        unconstrained row and an embeddings row (with spec decode
-        live) runs THE one unified program — cache_size 1, no legacy
-        families, the embed epilogue is its own (single) jit."""
+    def test_mixed_rows_one_compiled_program(self,
+                                             only_the_unified_step):
+        """A batch mixing a constrained row, an unconstrained row and
+        an embeddings row (with spec decode live) runs THE one unified
+        program — cache_size 1, no other step program, the embed
+        epilogue is its own (single) jit."""
         eng = ServingEngine(tiny_gpt(), num_slots=3, max_len=64,
                             page_size=8, chunk_len=16, grammar=True,
                             spec="ngram")
@@ -528,8 +523,7 @@ class TestRetraceProbe:
         assert con.finish_reason in ("stop", "length")
         assert plain.finish_reason == "length"
         assert emb.embedding is not None
-        assert eng._unified_fn._cache_size() == 1
-        assert eng._prefill_fns == {} and eng._decode_fn is None
+        only_the_unified_step(eng)
         snap = eng.metrics.snapshot()
         assert snap["grammar_requests"] == 1
         assert snap["grammar_masked_rows"] > 0
@@ -557,14 +551,6 @@ class TestEmbeddings:
                                    rtol=1e-5, atol=1e-5)
         eng.drain()
         eng.pool.assert_quiesced()
-
-    def test_embed_requires_unified(self):
-        eng = ServingEngine(tiny_gpt(), num_slots=2, max_len=32,
-                            page_size=8, chunk_len=8, unified=False)
-        with pytest.raises(ValueError):
-            eng.add_request(np.array([1, 2, 3], np.int64),
-                            SamplingParams(embed=True))
-        eng.drain()
 
     def test_http_embeddings_endpoint(self):
         import http.client

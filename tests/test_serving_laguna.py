@@ -155,14 +155,21 @@ def test_window_layers_switch_reuse_off_and_say_so():
                             chunk_len=16)
     assert eng.prefix_cache is None and not eng.preempt
     assert eng.host_pages == 0 and not eng.grouped
-    for kw in (dict(prefix_cache=True), dict(preempt=True),
-               dict(host_pages=4), dict(kv_dtype="int8"),
-               dict(unified=False), dict(megakernel=True)):
-        with pytest.raises(ValueError, match="sliding-window"):
-            ServingEngine(model, num_slots=2, max_len=64, page_size=4,
-                          chunk_len=16, **kw)
     with pytest.raises(ValueError, match="windows"):
         ServingEngine(model, cache_spec=(5, 2, 16, (None, 8)))
+
+
+@pytest.mark.parametrize("name,value", [
+    ("prefix_cache", True), ("preempt", True), ("host_pages", 4),
+    ("kv_dtype", "int8"), ("megakernel", True), ("mesh", "dp1mp2"),
+    ("adapters", True), ("spec", "ngram")])
+def test_window_layers_refuse(name, value):
+    """Each feature the engine cannot give a model with window layers
+    is refused by name when asked for."""
+    with pytest.raises(ValueError,
+                       match=rf"sliding-window.*'{name}'"):
+        ServingEngine(tiny_laguna(), num_slots=2, max_len=64,
+                      page_size=4, chunk_len=16, **{name: value})
 
 
 def test_same_prompt_twice_recomputes_and_agrees():

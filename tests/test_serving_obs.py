@@ -431,7 +431,7 @@ class TestEngineObs:
         assert {"slot", "request_id", "state", "pages",
                 "priority"} <= set(res)
         assert st["pool"]["pages_total"] == eng.num_pages - 1
-        assert st["config"]["unified"] is True
+        assert st["config"]["grouped"] is True
         assert st["obs"]["flight"]["steps_recorded"] == 1
         json.dumps(st)                   # endpoint-serializable
         eng.run()

@@ -248,7 +248,8 @@ class TestPreemptionOracle:
         return eng, lo, hi
 
     @pytest.mark.parametrize("prefix_cache", [True, False])
-    def test_preempt_resume_token_identical(self, prefix_cache):
+    def test_preempt_resume_token_identical(self, prefix_cache,
+                                            only_the_unified_step):
         """The core oracle, plus (on the same engine, no extra
         cycles): the retrace probe — swap-out/swap-in are ONE program
         each and the unified step keeps its single trace across
@@ -266,8 +267,7 @@ class TestPreemptionOracle:
         assert lo.output().preemptions >= 1     # usage surface
         assert eng._swap_out_fn._cache_size() == 1
         assert eng._swap_in_fn._cache_size() == 1
-        assert eng._unified_fn._cache_size() == 1
-        assert eng._prefill_fns == {} and eng._decode_fn is None
+        only_the_unified_step(eng)
         text = prometheus_render({"replica-0":
                                   eng.metrics.snapshot()})
         assert ('paddle_serving_preemptions_total'
@@ -278,21 +278,6 @@ class TestPreemptionOracle:
         assert 'outcome="deadline"' in text
         eng.drain()
         assert eng.pool.swapped_pages == eng.host_pool.used_pages
-
-    @pytest.mark.slow
-    def test_preempt_resume_legacy_alternating_path(self):
-        """Preemption is host-side bookkeeping: the legacy
-        alternating prefill/decode program families resume a
-        preempted request just as exactly as the unified step.
-        (Soak lane: the default path's oracle runs above.)"""
-        model = tiny_gpt()
-        eng, lo, hi = self._preempt_cycle(unified=False)
-        assert eng.metrics.preemptions >= 1
-        assert lo.output_tokens == oracle_greedy(model,
-                                                 np.arange(1, 9), 24)
-        assert hi.output_tokens == oracle_greedy(model,
-                                                 np.arange(30, 38), 24)
-        eng.drain()
 
     def test_preempt_resume_with_spec_decode(self):
         """The drafter is dropped at preemption and re-seeded from the

@@ -19,7 +19,7 @@ from paddle_tpu.nlp import GPTConfig, GPTForCausalLM
 from paddle_tpu.serving import (HostPagePool, PagePool,
                                 RadixPrefixCache,
                                 RequestState, SamplingParams,
-                                ServingEngine, chunk_bucket,
+                                ServingEngine,
                                 resolve_prefix_cache_flag)
 
 _MODELS = {}
@@ -123,46 +123,6 @@ class TestPagePoolInvariants:
         assert pool.alloc(4) is None     # only 3 allocatable
         assert pool.free_pages == 3
         assert pool.alloc(3) is not None
-
-
-class TestChunkBucket:
-    """Satellite: prefill-chunk bucketing boundaries — the compiled-
-    program-count bound depends on the min-chunk clamp being exact."""
-
-    def test_large_remainder_is_full_chunk(self):
-        assert chunk_bucket(100, 32) == 32
-        assert chunk_bucket(32, 32) == 32      # exact boundary
-
-    def test_tail_rounds_to_power_of_two_bucket(self):
-        assert chunk_bucket(9, 32) == 16
-        assert chunk_bucket(16, 32) == 16      # exact bucket fit
-        assert chunk_bucket(17, 32) == 32      # next bucket == chunk
-
-    def test_min_chunk_boundary(self):
-        """Everything at or below min_chunk clamps UP to min_chunk —
-        including remaining == 1 and remaining == min_chunk exactly —
-        and one past it doubles."""
-        assert chunk_bucket(1, 32) == 8
-        assert chunk_bucket(8, 32) == 8
-        assert chunk_bucket(9, 32, min_chunk=8) == 16
-        assert chunk_bucket(3, 32, min_chunk=4) == 4
-        assert chunk_bucket(5, 32, min_chunk=4) == 8
-
-    def test_min_chunk_never_exceeds_chunk_len(self):
-        """A min_chunk above chunk_len clamps DOWN: the bucket set
-        must stay inside [min_chunk, chunk_len]."""
-        assert chunk_bucket(3, 8, min_chunk=16) == 8
-        assert chunk_bucket(7, 8, min_chunk=8) == 8
-
-    def test_bucket_set_is_logarithmic(self):
-        """Distinct values over every prompt length: {chunk_len} ∪
-        {min_chunk * 2**i} — the O(log chunk_len) program bound."""
-        got = {chunk_bucket(r, 32) for r in range(1, 200)}
-        assert got == {8, 16, 32}
-
-    def test_zero_remaining_raises(self):
-        with pytest.raises(ValueError, match="remaining"):
-            chunk_bucket(0, 32)
 
 
 class TestHostPagePool:
@@ -474,16 +434,12 @@ class TestEngineEquivalence:
         assert accounting_closes(eng)
 
     def test_no_retrace_across_hit_miss_eviction(self):
-        """The compiled decode step, each prefill bucket, and the COW
-        copy stay ONE program each across hits, misses, COW admissions
-        and evictions. (Pinned to the legacy alternating path; the
-        unified step's single-program property is asserted in
-        tests/test_serving_unified.py.)"""
-        import math
+        """The compiled step, the COW copy and the swap programs stay
+        ONE program each across hits, misses, COW admissions and
+        evictions."""
         model = tiny_gpt()
         eng = ServingEngine(model, num_slots=3, max_len=32,
-                            page_size=8, num_pages=9, chunk_len=16,
-                            unified=False)
+                            page_size=8, num_pages=9, chunk_len=16)
         base = np.arange(1, 10, dtype=np.int64)
         rng = np.random.RandomState(0)
         for i in range(6):
@@ -500,11 +456,7 @@ class TestEngineEquivalence:
         # up as spills first and evictions only once the tier is full
         assert (eng.prefix_cache.evicted_pages_total
                 + eng.prefix_cache.spilled_pages_total) > 0
-        assert eng._decode_fn._cache_size() == 1
-        bound = int(math.log2(eng.chunk_len)) + 1
-        assert len(eng._prefill_fns) <= bound
-        assert all(fn._cache_size() == 1
-                   for fn in eng._prefill_fns.values())
+        assert eng._unified_fn._cache_size() == 1
         if eng._copy_page_fn is not None:
             assert eng._copy_page_fn._cache_size() == 1
         for fn in (eng._swap_out_fn, eng._swap_in_fn):

@@ -344,22 +344,6 @@ class TestInt8Engine:
         agree = sum(a == b for a, b in zip(flat_q8, flat_fp))
         assert agree / len(flat_fp) >= 0.8, (kern, fp)
 
-    def test_unified_vs_legacy_token_identity(self):
-        """int8 through the legacy alternating path (bucketed prefill
-        programs + the separate decode step, both now running the
-        quantized scatter/gather) == int8 through the unified step."""
-        model = tiny_gpt()
-        rng = np.random.RandomState(1)
-        prompts = [rng.randint(0, 97, size=int(rng.randint(3, 8)))
-                   .astype(np.int64) for _ in range(4)]
-        uni, _ = run_engine(model, prompts, 6, num_slots=3,
-                            max_len=64, page_size=8, chunk_len=16,
-                            unified=True)
-        leg, _ = run_engine(model, prompts, 6, num_slots=3,
-                            max_len=64, page_size=8, chunk_len=16,
-                            unified=False)
-        assert uni == leg
-
 
 # -- the serving feature matrix at int8 -------------------------------------
 class TestInt8FeatureMatrix:
@@ -520,7 +504,8 @@ class TestInt8FeatureMatrix:
 
 # -- retrace discipline ------------------------------------------------------
 class TestInt8RetraceDiscipline:
-    def test_one_unified_program_and_one_trace_swap_cow(self):
+    def test_one_unified_program_and_one_trace_swap_cow(
+            self, only_the_unified_step):
         """int8 on changes the POOL DTYPE, not the program count:
         exactly ONE compiled ragged step across every mix, ONE trace
         for each of COW-copy / swap-out / swap-in over traced page
@@ -551,8 +536,7 @@ class TestInt8RetraceDiscipline:
             vt[0] += 0.01
         assert all(r.finished for r in lows)
         assert eng.metrics.snapshot()["preemptions"] >= 1
-        assert eng._unified_fn._cache_size() == 1
-        assert eng._decode_fn is None and eng._prefill_fns == {}
+        only_the_unified_step(eng)
         assert eng._copy_page_fn._cache_size() == 1
         assert eng._swap_out_fn._cache_size() == 1
         assert eng._swap_in_fn._cache_size() == 1

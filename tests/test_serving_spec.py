@@ -6,8 +6,7 @@ The tentpole contracts:
   EOS landing mid-burst, page pressure with LRU eviction live, the
   prefix cache on/off, sampled (non-speculating) slot neighbors, and a
   throttled token budget — the same oracle pattern as
-  PADDLE_TPU_PAGED_ATTN / PADDLE_TPU_PREFIX_CACHE /
-  PADDLE_TPU_UNIFIED_STEP;
+  PADDLE_TPU_PAGED_ATTN / PADDLE_TPU_PREFIX_CACHE;
 - enabling speculation adds NO compiled program: drafting is
   host-side, the verify pass rides THE one unified ragged step
   (cache_size probe), and a spec-off engine compiles the exact same
@@ -233,12 +232,6 @@ class TestSpecGate:
                             page_size=8, chunk_len=8)
         assert eng.spec is None and eng.metrics.spec is None
 
-    def test_spec_requires_unified_step(self):
-        with pytest.raises(ValueError):
-            ServingEngine(tiny_gpt(), num_slots=2, max_len=32,
-                          page_size=8, chunk_len=8, spec="ngram",
-                          unified=False)
-
     def test_only_greedy_requests_get_a_drafter(self):
         eng = ServingEngine(tiny_gpt(), num_slots=2, max_len=64,
                             page_size=8, chunk_len=8, spec="ngram")
@@ -450,13 +443,14 @@ class TestSpecTokenIdentity:
 
 # -- retrace probe: speculation adds NO compiled program --------------------
 class TestSpecRetraceProbe:
-    def test_verify_rides_the_one_unified_program(self):
+    def test_verify_rides_the_one_unified_program(
+            self, only_the_unified_step):
         """ISSUE acceptance: enabling speculation compiles NOTHING new
         — drafting is host-side and the verify pass is just another
         q_len value through THE one `[num_slots, chunk_len]` ragged
         step. Across accepted bursts, rejected drafts, retirements and
         draft-free steps: exactly ONE program, never retraced, and no
-        legacy family ever built."""
+        other step program."""
         model = tiny_gpt()
         eng = ServingEngine(model, num_slots=3, max_len=64,
                             page_size=8, chunk_len=16, spec="ngram")
@@ -467,9 +461,7 @@ class TestSpecRetraceProbe:
         assert snap["spec_drafted_tokens"] > 0          # drafts ran
         assert snap["spec_accepted_tokens"] \
             < snap["spec_drafted_tokens"]               # some rejected
-        assert eng._decode_fn is None
-        assert eng._prefill_fns == {}
-        assert eng._unified_fn._cache_size() == 1
+        only_the_unified_step(eng)
         # ...and the spec-off engine compiles the SAME single program
         # shape: speculation is a host-side packing decision, not a
         # second executable
@@ -506,7 +498,8 @@ class TestModelSpecDecoding:
         b = d.gpt.embeddings.word_embeddings.weight.numpy()
         assert np.array_equal(a, b)
 
-    def test_identity_two_programs_metrics_and_quiesce(self):
+    def test_identity_two_programs_metrics_and_quiesce(
+            self, only_the_unified_step):
         """The consolidated non-slow acceptance: mixed-length greedy
         prompts through spec='model:4' are bit-token-identical to the
         solo oracle, drafting really happened and really paid, the
@@ -531,10 +524,8 @@ class TestModelSpecDecoding:
         assert sum(o.accepted_draft_tokens for o in outs) \
             == snap["spec_accepted_tokens"]
         assert snap["draft_pool"]["pages_total"] > 0
-        # exactly TWO compiled programs, no legacy families
-        assert eng._decode_fn is None
-        assert eng._prefill_fns == {}
-        assert eng._unified_fn._cache_size() == 1
+        # exactly TWO compiled programs
+        only_the_unified_step(eng)
         assert eng._draft._fn._cache_size() == 1
         # observability surfaces
         text = prometheus_render({"0": snap})

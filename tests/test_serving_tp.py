@@ -213,16 +213,13 @@ class TestOneTrace:
     """The mesh must not cost a single extra trace: ONE unified
     program, one-trace COW and swap programs."""
 
-    def test_retrace_probe(self, mp2_eng):
+    def test_retrace_probe(self, mp2_eng, only_the_unified_step):
         # the fixture already served several batches with different
         # membership/page mixes across tests; serve one more and
         # assert the ONE-trace discipline held throughout
         prompts = _prompts(89, (7, 15, 4), seed=5)
         _serve(mp2_eng, prompts)
-        assert mp2_eng._unified_fn is not None
-        assert mp2_eng._unified_fn._cache_size() == 1
-        assert mp2_eng._prefill_fns == {}     # legacy families never built
-        assert mp2_eng._decode_fn is None
+        only_the_unified_step(mp2_eng)
 
     def test_cow_and_swap_one_trace_on_sharded_pool(self):
         m = tiny_llama()
@@ -301,11 +298,13 @@ class TestGroupedShardingInterplay:
 
     def test_grouped_walk_on_sharded_pool_token_identical(
             self, mp1_eng, mp2_eng):
-        # both fixtures run the grouped walk (default on); a
+        # both fixtures run the grouped walk (they have a prefix
+        # cache); a
         # shared-prefix trace forms real groups over the SHARDED pool
         # and the tokens must still match the single-device engine
-        # bit-for-bit (PR 11 proved grouped==flat on one device, so
-        # this chains to flat). Zero extra engine compiles.
+        # bit-for-bit (test_grouped_attention.py holds grouped == flat
+        # on one device, so this chains to flat). Zero extra engine
+        # compiles.
         sysp = _prompts(89, (21,), seed=30)[0]
         prompts = [np.concatenate([sysp, t])
                    for t in _prompts(89, (3, 5, 2), seed=31)]
@@ -327,8 +326,11 @@ class TestGroupedShardingInterplay:
         prompts = [np.concatenate([sysp, t])
                    for t in _prompts(89, (3, 5, 2, 9), seed=14)]
         runs = {}
+        # the flat walk is what an engine without a prefix cache
+        # compiles: nothing can put a page into two rows' tables
         for grouped in (True, False):
-            eng = _engine(m, mesh="dp1mp2", grouped=grouped)
+            eng = _engine(m, mesh="dp1mp2", prefix_cache=grouped)
+            assert eng.grouped is grouped
             _serve(eng, [sysp], max_new=2)     # warm the radix tree
             runs[grouped] = (_serve(eng, prompts, max_new=6), eng)
         assert runs[True][0] == runs[False][0]

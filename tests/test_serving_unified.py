@@ -1,23 +1,16 @@
-"""Unified ragged prefill+decode step (PADDLE_TPU_UNIFIED_STEP).
+"""Unified ragged prefill+decode step: the engine's one step program.
 
-The tentpole contracts:
-- greedy outputs with the unified step ON (default) are token-identical
-  to the legacy alternating path AND to the solo CompiledGenerator
+The contracts:
+- greedy outputs are token-identical to the solo CompiledGenerator
   oracle, on mixed prefill/decode traces, under page pressure, and with
-  the prefix cache enabled — the same oracle pattern as
-  PADDLE_TPU_PAGED_ATTN / PADDLE_TPU_PREFIX_CACHE;
-- the per-bucket prefill trace explosion is GONE: with the unified step
-  on, exactly ONE compiled ragged program serves every prefill/decode
-  mix (cache_size probe, the technique of test_serving_prefix.py) —
-  no per-bucket prefill programs, no separate decode program;
+  the prefix cache on or off;
+- exactly ONE compiled ragged program serves every prefill/decode mix
+  (cache_size probe, the technique of test_serving_prefix.py), and the
+  engine has no other step program;
 - the scheduler PACKS prefill tokens into spare decode-step capacity
-  (token budget) instead of alternating program families, so the off
-  path's prefill-stall steps never happen with the step on.
+  (token budget).
 """
 import json
-import math
-import os
-import sys
 
 import numpy as np
 import pytest
@@ -25,8 +18,7 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.nlp import GPTConfig, GPTForCausalLM
 from paddle_tpu.serving import (SamplingParams, Scheduler,
-                                ServingEngine, prometheus_render,
-                                resolve_unified_flag)
+                                ServingEngine, prometheus_render)
 from paddle_tpu.serving.request import Request, RequestState
 
 _MODELS = {}
@@ -69,30 +61,7 @@ def mixed_prompts(rng, n=8, shared_prefix=None):
     return out
 
 
-class TestUnifiedFlag:
-    def test_env_resolution_and_override(self, monkeypatch):
-        monkeypatch.delenv("PADDLE_TPU_UNIFIED_STEP", raising=False)
-        assert resolve_unified_flag() is True            # default on
-        monkeypatch.setenv("PADDLE_TPU_UNIFIED_STEP", "off")
-        assert resolve_unified_flag() is False
-        assert resolve_unified_flag(True) is True        # override wins
-        monkeypatch.setenv("PADDLE_TPU_UNIFIED_STEP", "maybe")
-        with pytest.raises(ValueError):
-            resolve_unified_flag()
-
-    def test_engine_picks_up_env_gate(self, monkeypatch):
-        model = tiny_gpt()
-        monkeypatch.setenv("PADDLE_TPU_UNIFIED_STEP", "off")
-        eng = ServingEngine(model, num_slots=2, max_len=32,
-                            page_size=8, chunk_len=8)
-        assert eng.unified is False
-        assert eng.metrics.unified is False
-        monkeypatch.delenv("PADDLE_TPU_UNIFIED_STEP")
-        eng = ServingEngine(model, num_slots=2, max_len=32,
-                            page_size=8, chunk_len=8)
-        assert eng.unified is True
-        assert eng.metrics.unified is True
-
+class TestStepOptions:
     def test_token_budget_validation(self):
         with pytest.raises(ValueError):
             ServingEngine(tiny_gpt(), num_slots=2, max_len=32,
@@ -138,7 +107,7 @@ class TestSchedulerPacking:
 
 
 class TestUnifiedTokenIdentity:
-    """Greedy outputs: unified on == unified off == solo oracle."""
+    """Greedy outputs == solo oracle."""
 
     def _run(self, prompts, n_new, **kw):
         eng = ServingEngine(tiny_gpt(), max_len=64, page_size=8,
@@ -149,38 +118,32 @@ class TestUnifiedTokenIdentity:
         eng.drain()
         return toks, eng
 
-    def test_mixed_trace_on_off_oracle(self):
+    def test_mixed_trace_oracle(self):
         model = tiny_gpt()
         rng = np.random.RandomState(0)
         prompts = mixed_prompts(rng)
         want = [oracle_greedy(model, p, 8) for p in prompts]
-        on, eng_on = self._run(prompts, 8, num_slots=3, chunk_len=16,
-                               unified=True)
-        off, eng_off = self._run(prompts, 8, num_slots=3, chunk_len=16,
-                                 unified=False)
-        assert on == want and off == want
-        snap = eng_on.metrics.snapshot()
+        got, eng = self._run(prompts, 8, num_slots=3, chunk_len=16)
+        assert got == want
+        snap = eng.metrics.snapshot()
         assert snap["unified_steps"] > 0
         assert snap["packed_prefill_tokens"] > 0
         assert snap["packed_decode_tokens"] > 0
-        assert eng_off.metrics.snapshot()["unified_steps"] == 0
 
     def test_under_page_pressure_and_prefix_cache(self):
         """The acceptance matrix: page pressure (pool smaller than the
-        trace wants, LRU eviction live) x prefix cache on/off, unified
-        on vs off, all token-identical to the oracle."""
+        trace wants, LRU eviction live) x prefix cache on/off, all
+        token-identical to the oracle."""
         model = tiny_gpt()
         rng = np.random.RandomState(1)
         shared = np.arange(1, 20, dtype=np.int64)
         prompts = mixed_prompts(rng, shared_prefix=shared)
         want = [oracle_greedy(model, p, 6) for p in prompts]
-        for unified in (True, False):
-            for pc in (True, False):
-                got, eng = self._run(
-                    prompts, 6, num_slots=3, chunk_len=8,
-                    num_pages=16, unified=unified, prefix_cache=pc)
-                assert got == want, (unified, pc)
-                eng.pool.assert_quiesced()
+        for pc in (True, False):
+            got, eng = self._run(prompts, 6, num_slots=3, chunk_len=8,
+                                 num_pages=16, prefix_cache=pc)
+            assert got == want, pc
+            eng.pool.assert_quiesced()
 
     def test_tight_token_budget_stays_correct(self):
         """A budget barely above the decode load spreads prefill over
@@ -190,7 +153,7 @@ class TestUnifiedTokenIdentity:
         prompts = mixed_prompts(rng, n=5)
         want = [oracle_greedy(model, p, 6) for p in prompts]
         got, eng = self._run(prompts, 6, num_slots=3, chunk_len=16,
-                             unified=True, token_budget=4)
+                             token_budget=4)
         assert got == want
         # the budget really throttled packing: no step packed more
         # than 4 tokens
@@ -199,15 +162,14 @@ class TestUnifiedTokenIdentity:
 
 
 class TestUnifiedRetraceDetection:
-    def test_one_compiled_ragged_program_serves_all_mixes(self):
-        """The satellite assertion: the per-bucket prefill trace
-        explosion is gone. Across prompt lengths that used to span
-        every chunk bucket, admissions, retirements, cancellations and
-        page reuse, the unified engine compiles EXACTLY ONE program —
-        no prefill buckets, no separate decode step."""
+    def test_one_compiled_ragged_program_serves_all_mixes(
+            self, only_the_unified_step):
+        """Across prompt lengths from one token to two chunks,
+        admissions, retirements, cancellations and page reuse, the
+        engine compiles EXACTLY ONE step program, and has no other."""
         model = tiny_gpt()
         eng = ServingEngine(model, num_slots=3, max_len=64,
-                            page_size=8, chunk_len=16, unified=True)
+                            page_size=8, chunk_len=16)
         rng = np.random.RandomState(0)
         reqs = []
         for plen in [1, 2, 3, 5, 7, 9, 12, 15, 17, 20, 23, 30]:
@@ -218,73 +180,41 @@ class TestUnifiedRetraceDetection:
         eng.cancel(reqs[2].request_id)        # eviction mid-run
         eng.run()
         assert all(r.finished for r in reqs)
-        # the two legacy program families never got built...
-        assert eng._decode_fn is None
-        assert eng._prefill_fns == {}
-        # ...and the one ragged program never retraced
-        assert eng._unified_fn._cache_size() == 1
-
-    def test_off_path_still_bucketized(self):
-        """The A/B control: with the gate off the legacy families come
-        back, bucket-bounded as before."""
-        model = tiny_gpt()
-        eng = ServingEngine(model, num_slots=2, max_len=64,
-                            page_size=8, chunk_len=16, unified=False)
-        rng = np.random.RandomState(3)
-        for plen in [3, 9, 17, 25]:
-            eng.add_request(rng.randint(0, 97, size=plen)
-                            .astype(np.int64),
-                            SamplingParams(max_new_tokens=3))
-        eng.run()
-        assert eng._unified_fn is None
-        assert eng._decode_fn._cache_size() == 1
-        bound = int(math.log2(eng.chunk_len)) + 1
-        assert 0 < len(eng._prefill_fns) <= bound
+        only_the_unified_step(eng)
 
 
 class TestUnifiedMetrics:
-    def _load(self, unified):
+    def _load(self):
         model = tiny_gpt()
         rng = np.random.RandomState(4)
         eng = ServingEngine(model, num_slots=2, max_len=64,
-                            page_size=8, chunk_len=8, unified=unified)
-        # long prompts behind residents: the off path must alternate
-        # (stall steps), the on path must pack
+                            page_size=8, chunk_len=8)
+        # long prompts behind residents: the step must pack
         prompts = [rng.randint(0, 97, size=n).astype(np.int64)
                    for n in [30, 28, 25, 27]]
         eng.generate(prompts, SamplingParams(max_new_tokens=4))
         return eng.metrics.snapshot()
 
-    def test_stall_steps_counted_off_killed_on(self):
-        off = self._load(unified=False)
-        on = self._load(unified=True)
-        assert off["prefill_stall_steps"] > 0
-        assert on["prefill_stall_steps"] == 0
-        assert on["packed_tokens_per_step"]["count"] == \
-            on["unified_steps"]
+    def test_prometheus_carries_step_counters_and_histogram(self):
+        snap = self._load()
+        assert snap["packed_tokens_per_step"]["count"] == \
+            snap["unified_steps"]
         # packed histogram saw multi-token steps (prefill + decode)
-        assert on["packed_tokens_per_step"]["max"] > 1
-
-    def test_prometheus_carries_unified_tag_and_histogram(self):
-        snap = self._load(unified=True)
+        assert snap["packed_tokens_per_step"]["max"] > 1
         text = prometheus_render({"0": snap})
         assert 'attn_impl="kernel"' in text
-        assert 'unified="on"' in text
         assert "paddle_serving_unified_steps_total" in text
-        assert "paddle_serving_prefill_stall_steps_total" in text
         assert "paddle_serving_packed_tokens_per_step_bucket" in text
-        off = self._load(unified=False)
-        assert 'unified="off"' in prometheus_render({"0": off})
 
 
 def test_chrome_trace_has_unified_step_and_request_spans(tmp_path):
-    """Profiler spans on the unified path: fixed names, ids in the
+    """Profiler spans of a round: fixed names, ids in the
     arguments. One serving::round per engine step holding admit, plan,
     unified_step (launch + fetch), commit and report; the per-request
     part is RequestTracer's timeline, joined by the round's step."""
     from paddle_tpu import profiler
     model = tiny_gpt()
-    eng = ServingEngine(model, num_slots=2, max_len=48, unified=True)
+    eng = ServingEngine(model, num_slots=2, max_len=48)
     with profiler.Profiler(targets=[profiler.ProfilerTarget.CPU]) as p:
         r0 = eng.add_request(np.array([1, 2, 3], np.int64),
                              SamplingParams(max_new_tokens=3))
@@ -315,9 +245,6 @@ def test_chrome_trace_has_unified_step_and_request_spans(tmp_path):
     assert inside(first["fetch"], first["unified_step"])
     # no name is built per call: nothing carries an id in brackets
     assert not any("[" in n for n in names if n.startswith("serving::"))
-    # the legacy program families never ran
-    assert "serving::decode_step" not in names
-    assert "serving::prefill" not in names
     # the request's own timeline, on the rounds' step index
     tl = eng.obs.tracer.timeline(r0.request_id)
     kinds = [e["kind"] for e in tl]
@@ -325,39 +252,3 @@ def test_chrome_trace_has_unified_step_and_request_spans(tmp_path):
     assert "admit" in kinds and "first_token" in kinds
     steps = {e["args"]["step"] for e in rounds}
     assert {e["step"] for e in tl if e["kind"] != "submit"} <= steps
-
-
-@pytest.mark.slow
-def test_serving_bench_unified_ab_smoke(tmp_path, monkeypatch):
-    """`serving_bench.py --smoke --unified-ab` (ISSUE acceptance): the
-    same long-prompt-heavy Poisson trace with the unified step on vs
-    off lands in BENCH_serving.json's "unified" section (schema v5),
-    the off path shows the prefill stalls the on path kills, and TTFT
-    p99 does not regress with the unified step on."""
-    import importlib.util
-    script = os.path.join(os.path.dirname(__file__), os.pardir,
-                          "scripts", "serving_bench.py")
-    spec = importlib.util.spec_from_file_location(
-        "serving_bench_unified", script)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    out = str(tmp_path / "BENCH_serving.json")
-    monkeypatch.setattr(sys, "argv",
-                        ["serving_bench.py", "--smoke", "--requests",
-                         "4", "--unified-ab", "--out", out])
-    mod.main()
-    with open(out) as f:
-        report = json.load(f)
-    assert report["schema_version"] == 19
-    uni = report["unified"]
-    assert set(uni) >= {"on", "off", "long_prompt_lens", "requests"}
-    on, off = uni["on"], uni["off"]
-    # the A/B trace is a load SPIKE: at least 2x the slot count
-    assert uni["requests"] >= 2 * report["slots"]
-    assert on["completed"] == off["completed"] == uni["requests"]
-    assert on["unified_steps"] > 0 and off["unified_steps"] == 0
-    assert on["prefill_stall_steps"] == 0
-    assert off["prefill_stall_steps"] > 0
-    assert on["packed_tokens_per_step_max"] > 1
-    # the acceptance number: no TTFT p99 regression with the step on
-    assert on["ttft_p99_s"] <= off["ttft_p99_s"] * 1.15

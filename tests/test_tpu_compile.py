@@ -406,7 +406,7 @@ def test_unified_step_sizes_phase1_sweep_from_its_operands(one_chip,
     and the pages of the longest live context), under no conditional;
     and that costs no copy of a pool:
     the compiled step copies no more pool-shaped arrays than the step
-    of an engine with the grouped walk off, which has no phase 1."""
+    of an engine without a prefix cache, whose walk has no phase 1."""
     import re
     lowered, pool = _lower_unified_step(one_chip, monkeypatch)
     text = lowered.as_text()
@@ -427,8 +427,9 @@ def test_unified_step_sizes_phase1_sweep_from_its_operands(one_chip,
     compiled = lowered.compile().as_text()
     assert "ptk:grouped_phase1" in compiled
     assert " conditional(" not in compiled
-    ungrouped = _lower_unified_step(one_chip, monkeypatch,
-                                    grouped=False)[0].compile().as_text()
+    ungrouped = _lower_unified_step(
+        one_chip, monkeypatch,
+        prefix_cache=False)[0].compile().as_text()
     assert "ptk:grouped_phase1" not in ungrouped
     assert len(pool_copy.findall(compiled)) \
         <= len(pool_copy.findall(ungrouped))
