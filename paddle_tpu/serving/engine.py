@@ -75,8 +75,10 @@ the queue head is blocked — no slot, or its page budget doesn't fit —
 and a STRICTLY lower-priority resident exists, that resident is
 PREEMPTED instead of the head being refused: its emitted tokens are
 banked (the client's stream object stays live), its private KV pages
-swap out whole-page to a HOST-RAM tier (`HostPagePool`; one compiled
-copy program per direction over traced page ids — no retrace), its
+swap out whole-page to a HOST-RAM tier (`HostPagePool`; out in one
+batch, a compiled gather a width of `SPILL_WIDTHS` over a traced
+vector of page ids and an asynchronous copy that the next step hides;
+in by one compiled program a page — no retrace), its
 shared prefix pages return to the radix tree, and its slot frees. It
 re-admits later via swap-in: pos restored from the banked pages, held
 logits regenerated by re-prefilling one token, the drafter re-seeded
@@ -183,6 +185,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import itertools
 import os
 import threading
@@ -244,9 +247,9 @@ _TERMINAL_EVENT = {"stop": "finish", "length": "finish",
 # arguments. Each span is also a `jax.profiler.TraceAnnotation`, so a JAX
 # profiler session against the running engine holds them on the clock of
 # the device's `XLA Ops`. One scheduler round nests as
-#   round(step) > admit > {spill(page), restore(page), cow_copy}
+#   round(step) > admit > {spill(need | pages), restore(page), cow_copy}
 #               > plan > {spill, restore}
-#               > unified_step > launch, fetch
+#               > unified_step > launch, spill(pages), fetch, spill
 #               > commit > embed_epilogue
 #               > report
 # and the seconds of plan/launch/fetch/commit/admit/report/spill also
@@ -259,12 +262,25 @@ SPAN_LAUNCH = "serving::launch"
 SPAN_FETCH = "serving::fetch"
 SPAN_COMMIT = "serving::commit"
 SPAN_REPORT = "serving::report"
-# one page to the host tier (`_extract_page`), and the prefix cache's walk
-# of its tree for candidates (`_spill_walk`, wired by `set_host_tier`)
+# pages to the host tier: the prefix cache's walk of its tree for
+# candidates (`_spill_walk`, wired by `set_host_tier`), the dispatch of
+# one spill's gathers (`_host_store_pages`), setting their copies off
+# after the step's launch (`_start_pages`) and whatever the host BLOCKS
+# for when the copies are collected (`_fetch_pages`)
 SPAN_SPILL = "serving::spill"
 SPAN_RESTORE = "serving::restore"
 SPAN_COW_COPY = "serving::cow_copy"
 SPAN_EMBED = "serving::embed_epilogue"
+
+# A spill's pages leave the pools in pieces of these many pages, the
+# count split largest first (45 = 32 + 8 + 4 + 1): one compiled gather a
+# width, all of them compiled at the engine's first spill, and no
+# padding is ever gathered or crosses the link. At most
+# SPILL_IN_FLIGHT_PAGES gathered pages wait on the device for their copy
+# to be collected (128 pages of the 1.3B cell are 0.4 GB); a spill
+# beyond that collects first.
+SPILL_WIDTHS = (32, 16, 8, 4, 2, 1)
+SPILL_IN_FLIGHT_PAGES = 128
 
 PREEMPT_MODES = ("on", "off")
 KV_DTYPE_MODES = ("fp", "int8", "fp8")
@@ -830,7 +846,7 @@ class ServingEngine:
         # overload preemption gate (PADDLE_TPU_PREEMPT, default on)
         self.preempt = resolve_preempt_flag(preempt)
         if self.prefix_cache is not None and self.host_pages > 0:
-            self.prefix_cache.set_host_tier(self._host_store_page,
+            self.prefix_cache.set_host_tier(self._host_store_pages,
                                             self._host_load_page,
                                             self._host_drop_page,
                                             self._spill_walk)
@@ -865,9 +881,10 @@ class ServingEngine:
         # its collectives against compiled HLO
         self._unified_args_tail = None
         self._copy_page_fn = None    # COW single-page copy, jitted once
-        # host-tier swap programs, each jitted ONCE over traced page
-        # ids (the PR 5 COW no-retrace discipline): device->host reads
-        # one page's K/V across all layers, host->device writes it back
+        # host-tier swap programs over traced page ids (the PR 5 COW
+        # no-retrace discipline): device->host gathers the pages of one
+        # spill across all layers, compiled once a width of
+        # SPILL_WIDTHS; host->device writes one page back, jitted once
         self._swap_out_fn = None
         self._swap_in_fn = None
         # liveness hook (serving/http/driver.py): called at every step
@@ -1350,26 +1367,32 @@ class ServingEngine:
                                           jnp.int32(dst))
 
     def _build_swap_out(self):
-        """ONE compiled device->host page read: stacks one page's K and
-        V across every layer into a [n_layers, 2, page_size, H, D]
-        block — on the int8 pool, PLUS the matching
-        [n_layers, 2, page_size, H] scale block (codes without their
-        scales are meaningless; the pair is the page). The page id is
-        a traced scalar, so every swap-out of every page reuses this
-        single program (no retrace ever — the COW-copy discipline).
-        int8 pages being half the bytes means swap traffic halves
-        too."""
+        """THE compiled device->host page read: gathers the pages named
+        by the `int32` vector `ids`, K and V across every layer, and
+        hands each back as an array of its own,
+        [n_layers, 2, page_size, H, D] in the pool's dtype — on the
+        int8 pool a (codes, scales) pair, the scales
+        [n_layers, 2, page_size, H] (codes without their scales are
+        meaningless; the pair is the page). An array a page, because
+        that is what crosses the link fast (a page-major block of 32
+        pages is relaid on the host at 0.7 GB/s, its pages one by one
+        go at 3-7: my chip run, PR 32) and what a host slot can own.
+        The ids are a traced operand, so the program is compiled once
+        a WIDTH (`SPILL_WIDTHS`) and never again: `_gather_pages` warms
+        every width the first time it is called. int8 pages being half
+        the bytes means swap traffic halves too."""
+        def pages_of(pools, ids):
+            block = jnp.stack([jnp.stack((k[ids], v[ids]), axis=1)
+                               for k, v in pools], axis=1)
+            return [block[i] for i in range(ids.shape[0])]
         if self.kv_dtype == "int8":
-            def so(ct, src):
-                codes = jnp.stack([jnp.stack((k[src], v[src]))
-                                   for k, v, _, _ in ct])
-                scales = jnp.stack([jnp.stack((ks[src], vs[src]))
-                                    for _, _, ks, vs in ct])
-                return codes, scales
+            def so(ct, ids):
+                return list(zip(
+                    pages_of([(k, v) for k, v, _, _ in ct], ids),
+                    pages_of([(ks, vs) for _, _, ks, vs in ct], ids)))
         else:
-            def so(ct, src):
-                return jnp.stack([jnp.stack((k[src], v[src]))
-                                  for k, v, _, _ in ct])
+            def so(ct, ids):
+                return pages_of([(k, v) for k, v, _, _ in ct], ids)
         return jax.jit(so)
 
     def _build_swap_in(self):
@@ -1398,18 +1421,58 @@ class ServingEngine:
                 return tuple(out)
         return jax.jit(si)
 
-    def _extract_page(self, src: int):
-        """Read one device page's KV (all layers) to host RAM: an
-        ndarray block, or a (codes, scales) ndarray pair on the int8
-        pool (HostPagePool payloads are opaque either way)."""
+    def _gather_pages(self, pages):
+        """Dispatch the read of `pages` (device page ids) off the pools
+        AS THEY ARE NOW — the pools are never donated, so whatever a
+        later step, restore or COW writes to those pages lands in new
+        buffers. Returns the pieces in page order, each a list of
+        device payloads (`_build_swap_out`), for `_start_pages` and
+        `_fetch_pages`."""
         if self._swap_out_fn is None:
             self._swap_out_fn = self._build_swap_out()
-        self._host_phases["kv_spill_pages_total"] += 1
-        with self._phase(SPAN_SPILL, "kv_spill_s_total", page=src):
-            out = self._swap_out_fn(self._ct, jnp.int32(src))
-            if self.kv_dtype == "int8":
-                return (np.asarray(out[0]), np.asarray(out[1]))
-            return np.asarray(out)
+            for w in SPILL_WIDTHS:      # every width, now and never again
+                self._swap_out_fn(self._ct, np.zeros((w,), np.int32))
+        ids = np.asarray(pages, np.int32)
+        pieces, at = [], 0
+        for w in SPILL_WIDTHS:
+            while ids.size - at >= w:
+                pieces.append(self._swap_out_fn(self._ct, ids[at:at + w]))
+                at += w
+        self._host_phases["kv_spill_pages_total"] += int(ids.size)
+        self._host_phases["kv_spill_batches_total"] += len(pieces)
+        return pieces
+
+    def _start_pages(self, piece):
+        """Set a gathered piece's copies to host RAM off, all at once.
+        NOT before the step is launched: with tens of MB outstanding
+        the call itself can block for most of the transfer (my chip
+        run, PR 32), and after the launch nobody waits for the host."""
+        with self._phase(SPAN_SPILL, "kv_spill_s_total",
+                         pages=len(piece)):
+            for block in jax.tree_util.tree_leaves(piece):
+                block.copy_to_host_async()
+
+    def _fetch_pages(self, piece):
+        """Collect a gathered piece: wait until its copies have arrived
+        and hand back one payload a page — an ndarray
+        [n_layers, 2, page_size, H, D], or a (codes, scales) pair on
+        the int8 pool (HostPagePool payloads are opaque either way).
+        The wait is `kv_spill_wait_s_total`: what the step that ran
+        meanwhile did not hide."""
+        with self._phase(SPAN_SPILL, "kv_spill_s_total",
+                         pages=len(piece)):
+            t0 = time.perf_counter()
+            payloads = jax.tree_util.tree_map(np.asarray, piece)
+            self._host_phases["kv_spill_wait_s_total"] += \
+                time.perf_counter() - t0
+        return payloads
+
+    def _extract_page(self, src: int):
+        """One device page's KV (all layers) in host RAM, now: the
+        spill's own gather at a width of one, collected at once."""
+        with self._phase(SPAN_SPILL, "kv_spill_s_total", pages=1):
+            piece, = self._gather_pages([src])
+        return self._fetch_pages(piece)[0]
 
     def _restore_page(self, data, dst: int):
         """Write one host-RAM page payload back into device page
@@ -1430,14 +1493,34 @@ class ServingEngine:
     # -- host tier callbacks (prefix-cache spill) --------------------------
     def _spill_walk(self, need: int):
         """Prefix spill: the cache's walk of its tree for `need` pages,
-        in the account of the copies that follow (`_extract_page`)."""
+        in the account of the copy that follows (`_host_store_pages`)."""
         return self._phase(SPAN_SPILL, "kv_spill_s_total", need=need)
 
-    def _host_store_page(self, page: int):
-        """Prefix spill: copy a parked page's KV to the host tier;
-        returns the host slot (the cache then swap_out's the device
-        page) or None when the tier is full."""
-        return self.host_pool.store(self._extract_page(page))
+    def _host_store_pages(self, pages) -> List[int]:
+        """Move `pages` (a prefix spill's victims, a preempted
+        request's private pages) to the host tier: as many of them,
+        from the front, as the tier has room for, asked BEFORE anything
+        is gathered. Returns their host slots at once — the caller
+        swap_out's the device pages and admission goes on — while the
+        bytes cross the link behind the next step (`_unified_step`
+        sets the copies off at its launch and collects them after its
+        fetch; a round without a launch, `drain`, `abort_all` and the
+        fabric's exports and imports collect at their end)."""
+        pages = list(pages)[:self.host_pool.free_pages]
+        slots: List[int] = []
+        for at in range(0, len(pages), SPILL_IN_FLIGHT_PAGES):
+            some = pages[at:at + SPILL_IN_FLIGHT_PAGES]
+            if self.host_pool.pending_pages + len(some) \
+                    > SPILL_IN_FLIGHT_PAGES:
+                self.host_pool.collect_pending()
+            with self._phase(SPAN_SPILL, "kv_spill_s_total",
+                             pages=len(some)):
+                for piece in self._gather_pages(some):
+                    slots += self.host_pool.store_pending(
+                        len(piece),
+                        functools.partial(self._start_pages, piece),
+                        functools.partial(self._fetch_pages, piece))
+        return slots
 
     def _host_load_page(self, host_slot: int):
         """Prefix restore: swap a spilled page back into a freshly
@@ -1560,6 +1643,7 @@ class ServingEngine:
             tokens, payloads, int(header["valid"]),
             int(header["adapter_id"]),
             alloc_restore=self._fabric_alloc_restore)
+        self.host_pool.collect_pending()    # what the graft pushed out
         self._fabric_pages_recv += grafted
         self._fabric_bytes_recv += len(frame)
         self.metrics.on_fabric(recv_pages=grafted,
@@ -1576,6 +1660,7 @@ class ServingEngine:
         (the router snapshots a drained replica before teardown)."""
         if self.prefix_cache is None:
             return None
+        self.host_pool.collect_pending()
         snap = self.prefix_cache.snapshot(
             self._extract_page, self.host_pool.load)
         snap["geometry"] = self.fabric_geometry
@@ -1597,6 +1682,7 @@ class ServingEngine:
                 f"this engine ({self.fabric_geometry})")
         restored = self.prefix_cache.load(
             snap, alloc_restore=self._fabric_alloc_restore)
+        self.host_pool.collect_pending()    # what the load pushed out
         self.metrics.on_fabric(restored_pages=restored)
         self.obs.flight.note(
             "fabric:restore",
@@ -1987,7 +2073,7 @@ class ServingEngine:
         """Preempt one resident: bank its committed tokens (the stream
         object stays live — the client notices nothing but a gap),
         swap its private KV pages to the host tier (whole-page copies
-        through the one compiled swap program), release its shared
+        through the spill's batched gather), release its shared
         prefix pages back to the tree, free the slot, and requeue it
         by its ORIGINAL arrival key. Resume is `_reserve_resume` +
         `_apply_swap_in`: pos restored from the swapped pages, held
@@ -2029,12 +2115,8 @@ class ServingEngine:
             self.prefix_cache.release(shared)
         n_kv = -(-kv_len // self.page_size)
         n_keep = max(0, min(n_kv - base, len(private)))
-        host_slots = []
-        for p in private[:n_keep]:
-            host_slot = self.host_pool.store(self._extract_page(p))
-            if host_slot is None:
-                break        # host tier full: the tail recomputes
-            host_slots.append(host_slot)
+        # a full host tier takes fewer: the tail recomputes
+        host_slots = self._host_store_pages(private[:n_keep])
         kept = private[:len(host_slots)]
         if kept:
             self.pool.swap_out(kept)
@@ -2413,11 +2495,17 @@ class ServingEngine:
                     self._ct, self._pos, self._last_logits, toks, \
                         accept = self._unified_fn(self._ct,
                                                   *plan.args_tail)
+                # the round's spills were gathered before this launch:
+                # their copies cross the link while the step runs
+                self.host_pool.start_pending()
                 # sync: the host waits for the device, then sees the
                 # tokens
                 with self._phase(SPAN_FETCH, "step_fetch_s_total"):
                     toks = np.asarray(toks)
                     accept = np.asarray(accept)
+                # the copies have had the step to arrive in: take them
+                # into host RAM and drop their device side
+                self.host_pool.collect_pending()
                 for name, n in zip(self._step_stat_names,
                                    accept[self.num_slots:]):
                     self._host_phases[name] += int(n)
@@ -2871,6 +2959,7 @@ class ServingEngine:
                                          detail=repr(exc),
                                          step=self._step_idx,
                                          slo=self._slo_snap())
+        self.host_pool.collect_pending()    # a round without a launch
         with self._phase(SPAN_REPORT, "round_report_s_total"):
             self._report_round()
 
@@ -2984,6 +3073,7 @@ class ServingEngine:
         for req in resume:
             self.scheduler.requeue(req)
         finished.extend(self.run())
+        self.host_pool.collect_pending()
         self.pool.assert_quiesced()
         if self.adapters is not None:
             self.adapters.assert_quiesced()
@@ -3005,6 +3095,7 @@ class ServingEngine:
         for slot in sorted(list(self.scheduler.running)):
             self._finish_and_free(self.scheduler.running[slot],
                                   reason, now, finished)
+        self.host_pool.collect_pending()
         self.pool.assert_quiesced()
         if self.adapters is not None:
             self.adapters.assert_quiesced()

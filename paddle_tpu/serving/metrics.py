@@ -69,8 +69,11 @@ ADAPTER_IDS_MAX = 8
 # scrape, the benchmark's window difference and a profiler capture
 # agree. Seconds unless the name says pages or submits. `step_*` cover
 # one unified step's launch, `round_*` what a scheduler round does
-# around it; `kv_spill_*` lies inside `round_admit_s_total` (a spill
-# happens while pages are acquired), `submit_wait_s_total` on the
+# around it; of `kv_spill_s_total` the tree walk and the dispatch lie
+# inside `round_admit_s_total` (a spill happens while pages are
+# acquired), the copies are set off after the step's launch and
+# collected after its fetch, `kv_spill_wait_s_total` being the part of
+# the collection the host spent blocked; `submit_wait_s_total` on the
 # front-end's handler threads, outside the round.
 HOST_PHASE_COUNTERS = (
     "step_plan_s_total",        # serving::plan
@@ -81,6 +84,8 @@ HOST_PHASE_COUNTERS = (
     "round_report_s_total",     # serving::report
     "kv_spill_s_total",         # serving::spill
     "kv_spill_pages_total",     # serving::spill, one a page
+    "kv_spill_batches_total",   # serving::spill, one a gather
+    "kv_spill_wait_s_total",    # serving::spill, blocked on a copy
     "submit_wait_s_total",      # http::submit until add_request
     "submits_serviced_total",   # submissions the pump thread took
 )

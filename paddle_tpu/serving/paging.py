@@ -309,7 +309,14 @@ class HostPagePool:
     returns the payload for swap-in; `free` releases the slot. Slot
     invariants mirror PagePool's: loading or freeing a slot that is
     not live raises (a swap-in of a freed page is a use-after-free,
-    never silent garbage)."""
+    never silent garbage).
+
+    `store_pending` admits pages whose bytes are still ON THE DEVICE
+    (the engine's gathered copies of them): the slots are live at once,
+    `start_pending` sets the copies off towards host RAM, and the
+    payloads arrive when the copy is COLLECTED — by a `load` of one of
+    its slots or by `collect_pending`, whichever comes first. A slot
+    freed before that never gets its payload."""
 
     def __init__(self, num_pages: int):
         if num_pages < 0:
@@ -318,6 +325,9 @@ class HostPagePool:
         self._data: Dict[int, object] = {}
         self._next = 0
         self._free: List[int] = []
+        # copies not yet collected, oldest first: each `_data` entry of
+        # its slots IS the `_PendingCopy` until then
+        self._pending: List[_PendingCopy] = []
 
     @property
     def used_pages(self) -> int:
@@ -327,26 +337,75 @@ class HostPagePool:
     def free_pages(self) -> int:
         return self.num_pages - len(self._data)
 
+    @property
+    def pending_pages(self) -> int:
+        """Pages of the copies not yet collected (what they hold on the
+        device, freed slots included)."""
+        return sum(len(copy.slots) for copy in self._pending)
+
+    def _take_slot(self) -> int:
+        if self._free:
+            return self._free.pop()
+        self._next += 1
+        return self._next - 1
+
     def store(self, payload) -> Optional[int]:
         """Admit one page payload; returns its host slot id, or None
         (no side effects) when the tier is full."""
         if len(self._data) >= self.num_pages:
             return None
-        if self._free:
-            slot = self._free.pop()
-        else:
-            slot = self._next
-            self._next += 1
+        slot = self._take_slot()
         self._data[slot] = payload
         return slot
 
+    def store_pending(self, n: int, start, fetch) -> List[int]:
+        """Admit `n` pages of one copy that is yet to cross to host
+        RAM; returns their host slots, taken as `n` calls of `store`
+        would take them. `start()` sets the copy off without waiting
+        for it, and is called once: by `start_pending`, or at
+        collection if that comes first; `fetch()` is called once, at
+        collection: it blocks until the copy has arrived and returns
+        the `n` payloads in slot order. The caller asks
+        `free_pages` first: more than fit raises."""
+        if n > self.free_pages:
+            raise ValueError(
+                f"store_pending of {n} pages into {self.free_pages} "
+                "free host slots")
+        copy = _PendingCopy(start, fetch,
+                            [self._take_slot() for _ in range(n)])
+        for slot in copy.slots:
+            self._data[slot] = copy
+        self._pending.append(copy)
+        return list(copy.slots)
+
+    def start_pending(self):
+        """Set off every copy that has not been started yet."""
+        for copy in self._pending:
+            copy.start()
+
+    def _collect(self, copy: "_PendingCopy"):
+        copy.start()        # a load that comes before `start_pending`
+        payloads = copy.fetch()
+        self._pending.remove(copy)
+        for slot, payload in zip(copy.slots, payloads):
+            if self._data.get(slot) is copy:    # not freed meanwhile
+                self._data[slot] = payload
+
+    def collect_pending(self):
+        """Bring every pending copy into host RAM, oldest first."""
+        while self._pending:
+            self._collect(self._pending[0])
+
     def load(self, slot: int):
-        """Payload of a live slot (the swap-in read). Raises on a slot
+        """Payload of a live slot (the swap-in read); collects the
+        slot's copy first if that is still pending. Raises on a slot
         that was never stored or already freed."""
         if slot not in self._data:
             raise ValueError(
                 f"load of dead host page {slot} (swap-in of a freed "
                 "page)")
+        if isinstance(self._data[slot], _PendingCopy):
+            self._collect(self._data[slot])
         return self._data[slot]
 
     def free(self, slot: int):
@@ -355,6 +414,23 @@ class HostPagePool:
             raise ValueError(f"double free of host page {slot}")
         del self._data[slot]
         self._free.append(slot)
+
+
+class _PendingCopy:
+    """One device-to-host copy on its way and the host slots its pages
+    were given."""
+    __slots__ = ("_start", "fetch", "slots")
+
+    def __init__(self, start, fetch, slots: List[int]):
+        self._start = start
+        self.fetch = fetch
+        self.slots = slots
+
+    def start(self):
+        """Sets the copy off, the first time it is called."""
+        if self._start is not None:
+            self._start()
+            self._start = None
 
 
 def pages_needed(prompt_len: int, max_new_tokens: int,

@@ -299,15 +299,17 @@ class RadixPrefixCache:
         self.spilled_pages_total = 0
         self.restored_pages_total = 0
         # host tier callbacks (engine-wired; None = no host tier):
-        # _host_store(page) -> host slot or None (copies the device
-        # page's KV to host RAM; the cache then swap_out's the page),
+        # _host_store(pages) -> host slots of the first len(slots) of
+        # them, fewer than asked when the tier is full (copies the
+        # device pages' KV to host RAM, all pages of one spill in one
+        # call; the cache then swap_out's each page it has a slot for),
         # _host_load(host_slot) -> device page or None (allocates a
         # fresh page, restores into it, returns it PARKED cache-
         # resident), _host_drop(host_slot) (discard a spilled page's
         # host copy — evicted from the tree while swapped),
         # _spill_walk(need) -> context manager around one spill's walk
         # of the tree (the engine's `serving::spill` span and account,
-        # the same as each page's copy)
+        # the same as the pages' copy)
         self._host_store = None
         self._host_load = None
         self._host_drop = None
@@ -657,7 +659,7 @@ class RadixPrefixCache:
         device pages actually freed (0 without a wired host tier)."""
         if need <= 0 or self._host_store is None:
             return 0
-        heap = []
+        found = []
         with self._spill_walk(need):    # the walk of the whole tree
             stack = list(self._roots.values())   # every tenant namespace
             while stack:
@@ -666,24 +668,22 @@ class RadixPrefixCache:
                 if (node.tokens is not None and node.page is not None
                         and self.pool.refcount(node.page) == 0
                         and not self._pinned(node)):
-                    heapq.heappush(heap,
-                                   (node.last_used, id(node), node))
-        spilled = 0
-        while spilled < need and heap:
-            _, _, node = heapq.heappop(heap)
-            if node.page is None or self.pool.refcount(node.page) != 0:
-                continue
-            slot = self._host_store(node.page)
-            if slot is None:
-                break                       # host tier full: stop
+                    found.append((node.last_used, id(node), node))
+            victims = [node for _, _, node
+                       in heapq.nsmallest(need, found)]
+        if not victims:
+            return 0
+        # one call for the whole spill: the tier answers with the
+        # slots of as many pages, from the front, as it has room for
+        slots = self._host_store([node.page for node in victims])
+        for node, slot in zip(victims, slots):
             self.pool.swap_out([node.page], spill=True)
             del self._owner[node.page]
             node.host = slot
             node.page = None
             self._n_spilled += 1
             self.spilled_pages_total += 1
-            spilled += 1
-        return spilled
+        return len(slots)
 
     # -- eviction ----------------------------------------------------------
     def _evictable(self, obj) -> bool:

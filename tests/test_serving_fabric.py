@@ -318,7 +318,8 @@ class TestTreeFabricUnit:
         pool, cache, store, ar = self.make()
         host = HostPagePool(8)
         cache.set_host_tier(
-            store=lambda page: host.store(np.array(store[page])),
+            store=lambda pages: [host.store(np.array(store[p]))
+                                 for p in pages],
             load=lambda slot: ar(host.load(slot)),
             drop=host.free)
         toks = np.arange(30, 42)
@@ -345,7 +346,8 @@ class TestTreeFabricUnit:
         pool, cache, store, ar = self.make()
         host = HostPagePool(8)
         cache.set_host_tier(
-            store=lambda page: host.store(np.array(store[page])),
+            store=lambda pages: [host.store(np.array(store[p]))
+                                 for p in pages],
             load=lambda slot: ar(host.load(slot)),
             drop=host.free)
         toks = np.arange(60, 72)

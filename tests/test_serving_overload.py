@@ -32,6 +32,7 @@ from paddle_tpu.serving import (DeadlineExceeded, FaultInjector,
                                 Scheduler, ServingEngine,
                                 prometheus_render,
                                 resolve_preempt_flag)
+from paddle_tpu.serving.engine import SPILL_WIDTHS
 from paddle_tpu.serving.http import (EngineDriver, ReplicaDead,
                                      ReplicaWatchdog, Router, serve)
 from paddle_tpu.serving.http.protocol import (status_for_error,
@@ -265,7 +266,7 @@ class TestPreemptionOracle:
         assert hi.output_tokens == oracle_greedy(model,
                                                  np.arange(30, 38), 24)
         assert lo.output().preemptions >= 1     # usage surface
-        assert eng._swap_out_fn._cache_size() == 1
+        assert eng._swap_out_fn._cache_size() == len(SPILL_WIDTHS)
         assert eng._swap_in_fn._cache_size() == 1
         only_the_unified_step(eng)
         text = prometheus_render({"replica-0":
@@ -278,6 +279,42 @@ class TestPreemptionOracle:
         assert 'outcome="deadline"' in text
         eng.drain()
         assert eng.pool.swapped_pages == eng.host_pool.used_pages
+
+    @pytest.mark.parametrize("host_pages", [None, 2])
+    def test_preempt_swaps_a_long_resident_out_in_one_batch(self,
+                                                            host_pages):
+        """A victim with several private KV pages goes to the host tier
+        through the spill's batched call (fewer gathers than pages, its
+        slots handed out while the copy is in flight) and resumes to the
+        oracle's tokens; a host tier with room for two pages takes two
+        and the tail recomputes."""
+        model = tiny_gpt()
+        prompt = np.arange(1, 41) % 97
+        kw = {} if host_pages is None else {"host_pages": host_pages}
+        eng = ServingEngine(model, num_slots=2, max_len=64, page_size=8,
+                            num_pages=9, chunk_len=16,
+                            prefix_cache=False, **kw)
+        lo = eng.add_request(prompt, SamplingParams(max_new_tokens=12,
+                                                    priority=5))
+        for _ in range(6):
+            eng.step()
+        assert 1 <= len(lo.output_tokens) < 12
+        hi = eng.add_request(np.arange(30, 62) % 97,
+                             SamplingParams(max_new_tokens=12,
+                                            priority=0))
+        eng.step()
+        assert lo.preemptions == 1
+        pages = eng.metrics.swapped_out_pages
+        assert pages == (6 if host_pages is None else 2)
+        assert eng.host_pool.pending_pages == 0     # the step took it in
+        eng.run()
+        snap = eng.metrics.snapshot()
+        assert snap["kv_spill_pages_total"] == pages
+        assert 0 < snap["kv_spill_batches_total"] <= 2   # 6 = 4 + 2
+        assert lo.output_tokens == oracle_greedy(model, prompt, 12)
+        assert hi.output_tokens == oracle_greedy(
+            model, np.arange(30, 62) % 97, 12)
+        eng.drain()
 
     def test_preempt_resume_with_spec_decode(self):
         """The drafter is dropped at preemption and re-seeded from the

@@ -21,6 +21,7 @@ from paddle_tpu.serving import (HostPagePool, PagePool,
                                 RequestState, SamplingParams,
                                 ServingEngine,
                                 resolve_prefix_cache_flag)
+from paddle_tpu.serving.engine import SPILL_WIDTHS
 
 _MODELS = {}
 
@@ -292,7 +293,7 @@ class TestRadixTreeUnit:
         host = HostPagePool(4)
         alive = {"load": True}
         cache.set_host_tier(
-            store=lambda page: host.store(("kv", page)),
+            store=lambda pages: [host.store(("kv", p)) for p in pages],
             load=lambda slot: (pool.alloc(1) or [None])[0]
             if alive["load"] else None,
             drop=host.free)
@@ -313,8 +314,9 @@ class TestRadixTreeUnit:
     def test_spill_walk_hook_wraps_each_walk_of_the_tree(self):
         """The fourth host-tier callback: `spill` enters it once a call
         with the pages it was asked for, around the walk that picks the
-        candidates and before the first page is stored (the engine puts
-        its `serving::spill` span and `kv_spill_s_total` there)."""
+        candidates and before the pages are stored, all in one call (the
+        engine puts its `serving::spill` span and `kv_spill_s_total`
+        there)."""
         import contextlib
         pool, cache = self.make()
         host = HostPagePool(4)
@@ -327,14 +329,15 @@ class TestRadixTreeUnit:
             seen.append(("exit", need))
 
         cache.set_host_tier(
-            store=lambda page: seen.append(("store", page))
-            or host.store(("kv", page)),
+            store=lambda pages: seen.append(("store", list(pages)))
+            or [host.store(("kv", p)) for p in pages],
             load=lambda slot: None, drop=host.free, spill_walk=walk)
         self.insert_seq(pool, cache, np.arange(100, 112))   # 3 pages
         assert cache.spill(2) == 2
-        assert [k for k, _ in seen] == ["enter", "exit", "store", "store"]
-        assert seen[0] == ("enter", 2)
-        assert cache.spill(0) == 0 and len(seen) == 4   # nothing to walk
+        # ONE store call for the spill, with both pages, LRU first
+        assert [k for k, _ in seen] == ["enter", "exit", "store"]
+        assert seen[0] == ("enter", 2) and len(seen[2][1]) == 2
+        assert cache.spill(0) == 0 and len(seen) == 3   # nothing to walk
 
 
 class TestEngineEquivalence:
@@ -459,9 +462,10 @@ class TestEngineEquivalence:
         assert eng._unified_fn._cache_size() == 1
         if eng._copy_page_fn is not None:
             assert eng._copy_page_fn._cache_size() == 1
-        for fn in (eng._swap_out_fn, eng._swap_in_fn):
-            if fn is not None:      # spill/restore traffic happened
-                assert fn._cache_size() == 1
+        if eng._swap_out_fn is not None:    # spill traffic happened
+            assert eng._swap_out_fn._cache_size() == len(SPILL_WIDTHS)
+        if eng._swap_in_fn is not None:     # restore traffic happened
+            assert eng._swap_in_fn._cache_size() == 1
         assert accounting_closes(eng)
 
     def test_cancel_while_holding_shared_pages(self):

@@ -29,6 +29,7 @@ from paddle_tpu.serving import (SamplingParams, ServingEngine,
                                 parse_mesh_spec, prometheus_render,
                                 resolve_serving_mesh,
                                 shared_prefix_groups)
+from paddle_tpu.serving.engine import SPILL_WIDTHS
 
 _MODELS = {}   # engines never mutate the model: share per module
 
@@ -250,7 +251,7 @@ class TestOneTrace:
         eng.run()
         assert all(r.finished for r in [*lo, hi])
         assert sum(r.preemptions for r in [*lo, hi]) >= 1
-        assert eng._swap_out_fn._cache_size() == 1
+        assert eng._swap_out_fn._cache_size() == len(SPILL_WIDTHS)
         assert eng._swap_in_fn._cache_size() == 1
         assert eng._unified_fn._cache_size() == 1
 

@@ -487,3 +487,30 @@ def test_train_step_names_its_kernels(one_chip, monkeypatch):
     for fn in ("_fa_kernel", "_fa_dq_kernel", "_fa_dkv_kernel",
                "_ln_fwd_kernel", "_ln_bwd_kernel"):
         assert fn in text, fn
+
+
+@pytest.mark.parametrize("kv_dtype, dt", [("fp", BF16), ("int8", I8)])
+def test_spill_gather_at_its_widest(one_chip, kv_dtype, dt):
+    """The host tier's gather (`ServingEngine._build_swap_out`) at the
+    GPT-3 1.3B serving shape, 24 layers, its widest piece: one array a
+    page comes out, in the pool's dtype, and the program's temporaries
+    stay under the piece's own size (0.1 GB)."""
+    import types
+    from paddle_tpu.serving import engine as engine_mod
+    width = engine_mod.SPILL_WIDTHS[0]
+    so = engine_mod.ServingEngine._build_swap_out(
+        types.SimpleNamespace(kv_dtype=kv_dtype))
+
+    def shaped(spec):
+        return jax.ShapeDtypeStruct(*spec, sharding=one_chip)
+    scales = shaped(SCALES) if kv_dtype == "int8" else None
+    ct = tuple((shaped(_pool(dt)), shaped(_pool(dt)), scales, scales)
+               for _ in range(24))
+    compiled = so.lower(ct, shaped(((width,), I32))).compile()
+    out = jax.tree_util.tree_leaves(compiled.out_info)
+    pages = [o for o in out if o.shape == (24, 2, PS, H, D)]
+    assert len(pages) == width and all(o.dtype == dt for o in pages)
+    assert len(out) == width * (2 if kv_dtype == "int8" else 1)
+    page_bytes = 24 * 2 * PS * H * D * jnp.dtype(dt).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= 1.1 * width * page_bytes
