@@ -12,5 +12,7 @@ from .bert import BertConfig, BertModel  # noqa: F401
 from .llama import LlamaConfig, LlamaModel, LlamaForCausalLM  # noqa: F401
 from .laguna import (LagunaConfig, LagunaModel,  # noqa: F401
                      LagunaForCausalLM)
+from .deepseek_v2 import (DeepseekV2Config, DeepseekV2Model,  # noqa: F401
+                          DeepseekV2ForCausalLM)
 from .generation import (DecodeCache, init_decode_caches,  # noqa: F401
                          update_and_attend, CompiledGenerator)

@@ -34,6 +34,7 @@ from ..core.tensor import Tensor
 from ..core import dtype as dtypes
 from ..ops._helpers import apply_op, as_tensor
 from ..ops.pallas import kernel_mesh
+from ..ops.pallas.mla import latent_attend
 from ..ops.pallas.paged_attention import (dequantize_paged_q8,
                                           gqa_attend_reference,
                                           paged_decode_attention,
@@ -53,6 +54,7 @@ from ..ops.pallas.paged_attention import (dequantize_paged_q8,
                                           spec_verify_accept)
 
 __all__ = ["DecodeCache", "init_decode_caches", "update_and_attend",
+           "update_and_attend_latent",
            "CompiledGenerator", "decode_model_step", "sample_logits",
            "resolve_paged_attn_impl", "PAGED_ATTN_IMPLS",
            "quantize_kv_rowwise"]
@@ -842,12 +844,47 @@ class _StepProgram:
         return self._jit._cache_size()
 
 
+# Latent attention in its absorbed form over the paged pool of a
+# layer's latent rows (pallas/mla.py): the pages read in place by the
+# kernel; the dense jnp form over gathered views off-TPU.
+register_op("latent_paged_attention", latent_attend, nondiff=True)
+
+
+def update_and_attend_latent(q, row_new, cache: DecodeCache, *, d_v,
+                             scale):
+    """`update_and_attend` for a layer of the LATENT kind (the engine's
+    cache-spec contract): `cache.k` is the pool of the layer's rows
+    [num_pages, page_size, row], key and value of every head at once,
+    under the slot's one page table; `cache.v` is None. Writes row_new
+    [B, l, row] at cache.pos, then attends q [B, l, H, row] (the
+    absorbed queries) over the rows at or below each query. Returns
+    (out [B, l, H, d_v]: the weighted sums of the rows' first d_v
+    values, advanced cache). Served in the unified ragged step only
+    (paged, per-row q_len). The write is the XLA row scatter: the
+    Pallas scatter's blocks are one token's [1, heads, D] tile, and a
+    row without a head axis is no such tile."""
+    if cache.page_table is None or cache.q_len is None \
+            or cache.k_scale is not None or cache.megakernel:
+        raise NotImplementedError(
+            "a latent-attention layer is served by the unified ragged "
+            "step over a float paged pool (ServingEngine)")
+    rows = apply_op("kv_cache_update_paged", cache.k, row_new, cache.pos,
+                    cache.page_table)
+    out = apply_op("latent_paged_attention", q, rows, cache.page_table,
+                   cache.pos, cache.q_len,
+                   attrs=dict(d_v=int(d_v), scale=float(scale)))
+    return out, DecodeCache(rows, None, cache.pos + cache.q_len,
+                            page_table=cache.page_table,
+                            attn_impl=cache.attn_impl, q_len=cache.q_len)
+
+
 def _pack_caches(caches):
     """DecodeCache list -> loop-carry pytree: per layer
     (k, v, k_scale|None, v_scale|None). None entries keep the pytree
-    structure identical whether or not the int8 cache is active."""
+    structure identical whether or not the int8 cache is active (and
+    v is None for a layer of the latent kind: its rows are `k`)."""
     return tuple(
-        (c.k._value, c.v._value,
+        (c.k._value, None if c.v is None else c.v._value,
          None if c.k_scale is None else c.k_scale._value,
          None if c.v_scale is None else c.v_scale._value)
         for c in caches)
@@ -886,7 +923,8 @@ def _unpack_caches(ct, pos, page_table=None, attn_impl=None,
     lora_paged = ([None] * len(ct) if lora_paged is None
                   else [tuple(Tensor(a) for a in layer)
                         for layer in lora_paged])
-    return [DecodeCache(Tensor(k), Tensor(v), Tensor(pos),
+    return [DecodeCache(Tensor(k), None if v is None else Tensor(v),
+                        Tensor(pos),
                         None if ks is None else Tensor(ks),
                         None if vs is None else Tensor(vs),
                         page_table=pt, attn_impl=attn_impl, q_len=ql,

@@ -43,8 +43,9 @@ from ..nn import functional as F
 from ..core.tensor import Tensor
 from ..core.dispatch import register_op
 from ..ops._helpers import apply_op
-from ..ops.pallas.moe import routed_experts
 from ..nn.initializer import Normal
+from .moe_common import (MOE_STEP_STAT_COUNTERS, NormalByExpert, SwiGLU,
+                         cast, linear, moe_stats, valid_columns)
 
 __all__ = ["LagunaConfig", "LagunaModel", "LagunaForCausalLM"]
 
@@ -222,52 +223,8 @@ def _head_gate_fwd(a, g):
 register_op("head_gate", _head_gate_fwd, nondiff=True)
 
 
-def _routed_experts_fwd(x, valid, router_w, w_gate, w_up, w_down, *,
-                        top_k, scale, norm_topk, first):
-    b, l, h = x.shape
-    out, stats = routed_experts(
-        x.reshape(b * l, h), valid.reshape(b * l), router_w, w_gate,
-        w_up, w_down, top_k=top_k, scale=scale, norm_topk=norm_topk,
-        first=first)
-    return out.reshape(b, l, h), stats
-
-
-register_op("moe_routed_experts", _routed_experts_fwd, nondiff=True)
-
-
-class _NormalByExpert(Normal):
-    """Normal(0, std) over [experts, ...], drawn a block of experts at
-    a time: the base class samples in float32 and casts, which for one
-    layer's 128 experts in bfloat16 is 3 GB of temporaries beside
-    11 GB of weights. Each block is waited for: dispatch is
-    asynchronous, and a queue of float32 blocks not yet cast peaked
-    4.3 GB above the weights (my chip run, PR 29)."""
-    BLOCK = 16
-
-    def _generate(self, shape, np_dtype, key):
-        keys = jax.random.split(key, -(-shape[0] // self.BLOCK))
-        return jnp.concatenate([
-            jax.block_until_ready(Normal._generate(
-                self, (min(self.BLOCK, shape[0] - i * self.BLOCK),)
-                + tuple(shape[1:]), np_dtype, k))
-            for i, k in enumerate(keys)])
-
-
-def _cast(layer, cfg):
-    """`layer` in the configuration's dtype, as soon as it exists."""
-    if cfg.dtype is not None:
-        layer.to(dtype=cfg.dtype)
-    return layer
-
-
-def _linear(in_f, out_f, cfg):
-    return _cast(nn.Linear(in_f, out_f, weight_attr=nn.ParamAttr(
-        initializer=Normal(0.0, cfg.initializer_range)), bias_attr=False),
-        cfg)
-
-
 def _rms_norm(cfg):
-    return _cast(nn.RMSNorm(cfg.hidden_size, epsilon=cfg.rms_norm_eps), cfg)
+    return cast(nn.RMSNorm(cfg.hidden_size, epsilon=cfg.rms_norm_eps), cfg)
 
 
 class LagunaAttention(nn.Layer):
@@ -284,11 +241,11 @@ class LagunaAttention(nn.Layer):
         # a constant of the trace, not a weight
         self._inv_freq = np.asarray(inv, np.float32)
         h = cfg.hidden_size
-        self.q_proj = _linear(h, self.n_heads * d, cfg)
-        self.k_proj = _linear(h, self.n_kv * d, cfg)
-        self.v_proj = _linear(h, self.n_kv * d, cfg)
-        self.g_proj = _linear(h, self.n_heads, cfg)
-        self.o_proj = _linear(self.n_heads * d, h, cfg)
+        self.q_proj = linear(h, self.n_heads * d, cfg)
+        self.k_proj = linear(h, self.n_kv * d, cfg)
+        self.v_proj = linear(h, self.n_kv * d, cfg)
+        self.g_proj = linear(h, self.n_heads, cfg)
+        self.o_proj = linear(self.n_heads * d, h, cfg)
 
     def _rope(self, x, pos):
         return apply_op("rope_half", x, pos,
@@ -333,20 +290,6 @@ class LagunaAttention(nn.Layer):
         return out, new_cache
 
 
-class LagunaMLP(nn.Layer):
-    """SwiGLU of a given width: the dense layer's MLP and the shared
-    expert."""
-
-    def __init__(self, cfg: LagunaConfig, width: int):
-        super().__init__()
-        self.gate_proj = _linear(cfg.hidden_size, width, cfg)
-        self.up_proj = _linear(cfg.hidden_size, width, cfg)
-        self.down_proj = _linear(width, cfg.hidden_size, cfg)
-
-    def forward(self, x, valid=None):
-        return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
-
-
 class LagunaSparseMoE(nn.Layer):
     """Router over all `num_experts`, the experts held here, and the
     shared expert (module doc: expert parallelism). `last_stats` holds
@@ -360,15 +303,15 @@ class LagunaSparseMoE(nn.Layer):
         self.scale = cfg.moe_routed_scaling_factor
         self.norm_topk = cfg.norm_topk_prob
         self.first = cfg.ep_rank * n
-        init = _NormalByExpert(0.0, cfg.initializer_range)
-        self.router = _linear(h, cfg.num_experts, cfg)
+        init = NormalByExpert(0.0, cfg.initializer_range)
+        self.router = linear(h, cfg.num_experts, cfg)
         self.experts_gate = self.create_parameter(
             [n, h, f], dtype=cfg.dtype, default_initializer=init)
         self.experts_up = self.create_parameter(
             [n, h, f], dtype=cfg.dtype, default_initializer=init)
         self.experts_down = self.create_parameter(
             [n, f, h], dtype=cfg.dtype, default_initializer=init)
-        self.shared_expert = LagunaMLP(
+        self.shared_expert = SwiGLU(
             cfg, cfg.shared_expert_intermediate_size)
         self.last_stats = None
 
@@ -389,7 +332,7 @@ class LagunaDecoderLayer(nn.Layer):
         self.input_layernorm = _rms_norm(cfg)
         self.self_attn = LagunaAttention(cfg, layer)
         self.post_attention_layernorm = _rms_norm(cfg)
-        self.mlp = (LagunaMLP(cfg, cfg.intermediate_size)
+        self.mlp = (SwiGLU(cfg, cfg.intermediate_size)
                     if cfg.mlp_layer_types[layer] == "dense"
                     else LagunaSparseMoE(cfg))
 
@@ -405,7 +348,7 @@ class LagunaModel(nn.Layer):
     def __init__(self, cfg: LagunaConfig):
         super().__init__()
         self.config = cfg
-        self.embed_tokens = _cast(nn.Embedding(
+        self.embed_tokens = cast(nn.Embedding(
             cfg.vocab_size, cfg.hidden_size, weight_attr=nn.ParamAttr(
                 initializer=Normal(0.0, cfg.initializer_range))), cfg)
         self.layers = nn.LayerList([LagunaDecoderLayer(cfg, i)
@@ -414,14 +357,7 @@ class LagunaModel(nn.Layer):
 
     def forward(self, input_ids, caches=None):
         x = self.embed_tokens(input_ids)
-        valid = None
-        if caches is not None and caches[0].q_len is not None:
-            # the unified step's rows are padded to one width: a column
-            # at or past the row's q_len is no token, and is routed to
-            # no expert
-            width = int(x.shape[1])
-            valid = Tensor(jnp.arange(width, dtype=jnp.int32)[None, :]
-                           < caches[0].q_len._value[:, None])
+        valid = valid_columns(int(x.shape[1]), caches)
         new_caches = [] if caches is not None else None
         for i, layer in enumerate(self.layers):
             x, c = layer(x, cache=None if caches is None else caches[i],
@@ -434,25 +370,14 @@ class LagunaModel(nn.Layer):
         return x
 
     def moe_stats(self):
-        """int32 [4] Tensor over the expert layers of the latest call:
-        assignments routed (all experts), assignments computed here,
-        local experts that received a token, expert layers run."""
-        stats = [layer.mlp.last_stats for layer in self.layers
-                 if isinstance(layer.mlp, LagunaSparseMoE)]
-        if not stats:
-            return None
-        total = stats[0]._value
-        for s in stats[1:]:
-            total = total + s._value
-        return Tensor(jnp.concatenate(
-            [total, jnp.full((1,), len(stats), jnp.int32)]))
+        return moe_stats(self.layers)
 
 
 class LagunaForCausalLM(nn.Layer):
     def __init__(self, cfg: LagunaConfig):
         super().__init__()
         self.laguna = LagunaModel(cfg)
-        self.lm_head = _linear(cfg.hidden_size, cfg.vocab_size, cfg)
+        self.lm_head = linear(cfg.hidden_size, cfg.vocab_size, cfg)
         self.config = cfg
 
     def forward(self, input_ids, caches=None):
@@ -473,10 +398,7 @@ class LagunaForCausalLM(nn.Layer):
     def _step_stats(self):
         """Counts the latest forward pass made on the device, for the
         engine to carry out of its step (`STEP_STAT_COUNTERS` names
-        them): see `LagunaModel.moe_stats`."""
+        them): see `moe_stats`."""
         return self.laguna.moe_stats()
 
-    STEP_STAT_COUNTERS = ("moe_assignments_total",
-                          "moe_assignments_here_total",
-                          "moe_experts_hit_total",
-                          "moe_layer_steps_total")
+    STEP_STAT_COUNTERS = MOE_STEP_STAT_COUNTERS

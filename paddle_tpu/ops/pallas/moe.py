@@ -8,8 +8,10 @@ of the router's `n_experts` outputs), for the tokens marked `valid`:
 
     out[t] = sum over e in top_k(t), e held here, of w[t, e] * E_e(x[t])
 
-with w the router's softmax score renormalised over the token's whole
-top-k set (the experts held elsewhere included) and scaled. No token is
+with w the router's softmax score (of the token's top-k, or of its
+top-k within its best groups of experts: `_top_experts`), renormalised
+over the token's whole top-k set where the model asks for it (the
+experts held elsewhere included), and scaled. No token is
 dropped and no capacity is fixed: the (token, expert) assignments are
 sorted by expert and the experts run over their own rows only.
 
@@ -90,10 +92,38 @@ def route_scope():
         yield
 
 
+def _top_experts(score, top_k, n_group, topk_group):
+    """score f32 [T, E] -> (the scores of each token's `top_k` experts
+    [T, top_k], their indices). With `n_group` > 1 the choice is
+    GROUP-LIMITED (DeepSeek-V2's `group_limited_greedy`): the experts
+    lie in `n_group` runs of E / n_group neighbours (a run is a device
+    of the deployment), a run's score is its best expert's, only the
+    `topk_group` best runs keep their scores, the others count as 0,
+    and the top-k is taken over what is left. A kept run is found by
+    comparing its score with the `topk_group`-th best (ties between
+    runs go to the lower index, as a sort's would): comparisons and
+    sorts only, no scatter into a mask."""
+    if n_group > 1:
+        t, e = score.shape
+        per = score.reshape(t, n_group, e // n_group)
+        best = jnp.max(per, axis=-1)                        # [T, G]
+        # a run's rank among the runs: how many beat it (a lower index
+        # wins a tie)
+        g = jnp.arange(n_group, dtype=jnp.int32)
+        beats = (best[:, None, :] > best[:, :, None]) | (
+            (best[:, None, :] == best[:, :, None])
+            & (g[None, None, :] < g[None, :, None]))
+        keep = jnp.sum(beats, axis=-1, dtype=jnp.int32) < topk_group
+        score = jnp.where(keep[:, :, None], per, 0.0).reshape(t, e)
+    return jax.lax.top_k(score, top_k)
+
+
 def moe_route(x, router_w, valid, *, top_k, scale, norm_topk, first,
-              n_local, tile_rows=TILE_ROWS):
+              n_local, tile_rows=TILE_ROWS, n_group=1, topk_group=1):
     """x [T, h]; router_w [h, n_experts]; valid bool [T] -> a dict of
-    the routing's fixed-shape arrays (see the module doc). Assignment
+    the routing's fixed-shape arrays (see the module doc). `n_group`,
+    `topk_group`: the group limit on a token's choice (`_top_experts`;
+    1: none). Assignment
     a = k * T + t is token t's k-th expert (k-major, so that the sum
     over k at the end is over whole [T, h] slabs):
 
@@ -113,8 +143,8 @@ def moe_route(x, router_w, valid, *, top_k, scale, norm_topk, first,
         logits = jnp.dot(x.astype(jnp.float32),
                          router_w.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        score = jax.nn.softmax(logits, axis=-1)
-        top_v, top_i = jax.lax.top_k(score, top_k)
+        top_v, top_i = _top_experts(jax.nn.softmax(logits, axis=-1),
+                                    top_k, n_group, topk_group)
         if norm_topk:
             top_v = top_v / jnp.sum(top_v, axis=-1, keepdims=True)
         weight = (top_v * jnp.float32(scale)).T.reshape(tk)
@@ -275,16 +305,18 @@ def moe_experts_ragged_dot(x, route, w_gate, w_up, w_down):
 
 
 def routed_experts(x, valid, router_w, w_gate, w_up, w_down, *, top_k,
-                   scale, norm_topk, first):
+                   scale, norm_topk, first, n_group=1, topk_group=1):
     """The routed part of a mixture-of-experts block for the experts
     held here (the registered op's forward; module doc). x [T, h],
-    valid bool [T]; returns (out [T, h] in x's dtype, stats int32 [3]).
+    valid bool [T]; `n_group`, `topk_group`: `moe_route`'s; returns
+    (out [T, h] in x's dtype, stats int32 [3]).
     The expert product is the Pallas kernel on a TPU (and in interpret
     mode) and the ragged_dot form elsewhere."""
     t, h = x.shape
     n_local = w_gate.shape[0]
     route = moe_route(x, router_w, valid, top_k=top_k, scale=scale,
-                      norm_topk=norm_topk, first=first, n_local=n_local)
+                      norm_topk=norm_topk, first=first, n_local=n_local,
+                      n_group=n_group, topk_group=topk_group)
     if _use_kernel():
         with route_scope():
             xs = x[route["src"]]

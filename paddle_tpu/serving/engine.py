@@ -207,6 +207,7 @@ from ..nlp.generation import (_StepProgram, _pack_caches,
                               _restore_state, _swap_state, _top_p_filter,
                               _unpack_caches, resolve_paged_attn_impl,
                               FP8_DTYPE)
+from ..ops.pallas.mla import count_latent_keys
 from ..ops.pallas.paged_attention import (count_page_block_reads,
                                           count_walk_grid_steps,
                                           count_window_page_reads,
@@ -217,7 +218,7 @@ from .draft import DraftConfig, DraftEngine, make_draft_model
 from .errors import DeadlineExceeded, EngineClosed, PoisonedRequest
 from .fabric import decode_frame, encode_frame, frame_header
 from .grammar import (NEG_BIAS, TokenGrammar, resolve_grammar_flag)
-from .metrics import ServingMetrics
+from .metrics import LATENT_COUNTERS, ServingMetrics
 from .obs import EngineObs, resolve_obs_flag
 from .paging import HostPagePool, PagePool, TRASH_PAGE, pages_needed
 from .prefix import (RadixPrefixCache, resolve_prefix_cache_flag,
@@ -426,7 +427,20 @@ class ServingEngine:
         # its window to `update_and_attend`; the engine gives such a
         # layer a pool of its own, a per-slot RING of pages behind a
         # static page table (below), and leaves the full layers on the
-        # shared paged pool.
+        # shared paged pool; or
+        #   (n_layers, 1, row, windows, "latent")
+        # for a model whose layers are all of the LATENT kind
+        # (nlp/deepseek_v2.py): a token's cached state in a layer is
+        # ONE row of `row` values that is key and value for every query
+        # head (so "kv heads" is 1 and "head_dim" the row's width: the
+        # model rounds it up to the device's 128 lanes, zeros behind,
+        # because the walk reads a page by one DMA and the tiling pads
+        # a row to that in HBM anyway). There is no V: a layer has ONE
+        # pool [num_pages, page_size, row], page-shaped and under the
+        # slot's ONE page table like any other, with no head axis (a
+        # head axis of one would pad every row to a whole tile). The
+        # attention module writes and reads it through
+        # `update_and_attend_latent`. `windows` must all be None.
         if cache_spec is None:
             if not hasattr(model, "_decode_cache_spec"):
                 raise ValueError(
@@ -434,7 +448,8 @@ class ServingEngine:
                     "_decode_cache_spec(); pass (n_layers, n_kv_heads, "
                     "head_dim), or (n_layers, n_kv_heads, head_dim, "
                     "windows) with each layer's sliding window or None, "
-                    "explicitly")
+                    "or (n_layers, 1, row, windows, 'latent') for one "
+                    "latent row a token, explicitly")
             cache_spec = model._decode_cache_spec()
         self.model = model
         self.n_layers, self.n_kv, self.head_dim = cache_spec[:3]
@@ -446,6 +461,13 @@ class ServingEngine:
         # layer index -> window, for the layers that have one
         self.kv_windows = {i: int(w) for i, w in enumerate(windows)
                            if w is not None}
+        # the latent kind: one pool a layer, no V (the contract above)
+        self.kv_latent = len(cache_spec) > 4
+        if self.kv_latent and (self.kv_windows or self.n_kv != 1
+                               or cache_spec[4] != "latent"):
+            raise ValueError(
+                f"a latent cache_spec is (n_layers, 1, row, all-None "
+                f"windows, 'latent'), got {cache_spec}")
         self.num_slots = int(num_slots)
         self.max_len = int(max_len)
         self.page_size = int(page_size)
@@ -486,7 +508,8 @@ class ServingEngine:
         self.tp = resolve_serving_mesh(mesh)
         self.mp = self.tp.mp if self.tp is not None else 1
         self.dp = self.tp.dp if self.tp is not None else 1
-        if self.tp is not None:
+        if self.tp is not None and not self.kv_latent:
+            # (a latent cache has no head axis to shard: refused below)
             cfgm = getattr(model, "config", None)
             self.tp.validate_geometry(
                 n_kv=self.n_kv,
@@ -578,9 +601,16 @@ class ServingEngine:
         self.megakernel = (resolve_megakernel_flag(megakernel)
                            and self.attn_impl == "kernel"
                            and self.tp is None)
-        if self.kv_windows:
-            # WINDOW LAYERS: what the engine does not do for them yet.
-            # Their KV lives in a per-slot ring that holds the last
+        if self.kv_windows or self.kv_latent:
+            # WINDOW LAYERS AND LATENT ROWS: what the engine does not
+            # do for them yet. (Latent rows: their pool is page-shaped
+            # and would move as any page does, but every program that
+            # moves pages -- COW copy, spill gather, restore, the PKVF
+            # frame -- is built for a K and a V pool with a head axis;
+            # until each has been taught a lone pool without one and
+            # tested, reuse is switched off rather than trusted.
+            # ROADMAP R2.) A window layer's KV lives
+            # in a per-slot ring that holds the last
             # window + chunk positions only, so a page of the shared
             # pool no longer stands for the same tokens in every
             # layer: reusing a prefix (which would need the window
@@ -598,17 +628,20 @@ class ServingEngine:
                 ("adapters", bool(adapters)),
                 ("kv_dtype", resolve_kv_dtype(kv_dtype) != "fp"),
                 ("spec", self.spec is not None)) if on]
+            kind = (f"sliding-window layers {sorted(self.kv_windows)}"
+                    if self.kv_windows else
+                    f"latent rows of {self.head_dim} values and no V pool")
             if wanted or unbuilt:
                 raise ValueError(
-                    f"the model has sliding-window layers "
-                    f"{sorted(self.kv_windows)}: {wanted + unbuilt} "
+                    f"the model has {kind}: {wanted + unbuilt} "
                     f"cannot be had with them yet (the prefix cache, "
                     f"the host tier and preemption would reuse or move "
-                    f"pages whose window-layer KV is gone; the others "
-                    f"have no window in their attention path)")
+                    f"pages whose window-layer KV is gone, or whose lone "
+                    f"latent pool no page program knows; the others "
+                    f"have no window and no latent row in their "
+                    f"attention path)")
             warnings.warn(
-                f"ServingEngine: sliding-window layers "
-                f"{sorted(self.kv_windows)}: the prefix cache, the "
+                f"ServingEngine: {kind}: the prefix cache, the "
                 f"host page tier, preemption and the grouped walk are "
                 f"switched off for this model", stacklevel=2)
             prefix_cache, preempt, host_pages = False, False, 0
@@ -744,6 +777,12 @@ class ServingEngine:
                  jnp.zeros((self.num_pages, self.page_size,
                             self.n_kv), jnp.float32))
                 for _ in range(self.n_layers))
+        elif self.kv_latent:
+            # one pool of rows a layer and no V (the contract above)
+            self._ct = tuple(
+                (jnp.zeros((self.num_pages, self.page_size,
+                            self.head_dim), self._fp), None, None, None)
+                for _ in range(self.n_layers))
         else:
             # fp8: pure-convert e4m3 pages ride the fp container shape
             # (no scale pools) — every whole-page program (COW, swap,
@@ -760,6 +799,7 @@ class ServingEngine:
                 for pages in (ring_total if i in self.kv_windows
                               else self.num_pages
                               for i in range(self.n_layers)))
+
         if self.tp is not None:
             # shard every pool over its kv-head axis (scale pools
             # alongside their code pools: a page and its scales are
@@ -777,8 +817,10 @@ class ServingEngine:
         kv_itemsize = (1 if self.kv_dtype in ("int8", "fp8")
                        else jnp.dtype(self._fp).itemsize)
         scale_bytes = 4 if self.kv_dtype == "int8" else 0
-        # (a page of the shared pool: the full-attention layers only)
-        self.page_bytes = ((self.n_layers - len(self.kv_windows)) * 2
+        # (a page of the shared pool: the full-attention layers only;
+        # one pool a layer, not two, for latent rows)
+        self.page_bytes = ((self.n_layers - len(self.kv_windows))
+                           * (1 if self.kv_latent else 2)
                            * self.page_size * self.n_kv
                            * (self.head_dim * kv_itemsize
                               + scale_bytes))
@@ -2610,11 +2652,17 @@ class ServingEngine:
                                      group_sizes, phase1=phase1)
         # the grid the walk's dynamic bounds give this step, of the
         # grid the step's shape alone would (one full-attention layer)
-        steps, full = count_walk_grid_steps(
-            pos_host, q_len, lq=W, page_size=self.page_size,
-            max_pages=self.max_pages)
-        self._host_phases["walk_grid_steps_total"] += steps
-        self._host_phases["walk_grid_steps_full_total"] += full
+        if self.kv_latent:
+            # no such walk runs: the latent stream of the step, a layer
+            for name, count in zip(LATENT_COUNTERS,
+                                   count_latent_keys(pos_host, q_len)):
+                self._host_phases[name] += count * self.n_layers
+        else:
+            steps, full = count_walk_grid_steps(
+                pos_host, q_len, lq=W, page_size=self.page_size,
+                max_pages=self.max_pages)
+            self._host_phases["walk_grid_steps_total"] += steps
+            self._host_phases["walk_grid_steps_full_total"] += full
         for window in self.kv_windows.values():
             walked, unwindowed = count_window_page_reads(
                 pos_host, q_len, page_size=self.page_size,

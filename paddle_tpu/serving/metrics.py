@@ -27,7 +27,7 @@ from collections import deque
 from typing import Optional, Sequence
 
 __all__ = ["Histogram", "ServingMetrics", "prometheus_render",
-           "HOST_PHASE_COUNTERS", "STEP_WORK_COUNTERS",
+           "HOST_PHASE_COUNTERS", "STEP_WORK_COUNTERS", "LATENT_COUNTERS",
            "TTFT_BUCKETS", "LATENCY_BUCKETS", "PACKED_TOKEN_BUCKETS",
            "SPEC_TOKEN_BUCKETS", "GROUP_SIZE_BUCKETS", "UTIL_BUCKETS"]
 
@@ -105,7 +105,18 @@ HOST_PHASE_COUNTERS = (
 # walk: the grid steps its dynamically bounded grid has
 # (`paged_attention.count_walk_grid_steps`, the compiled step's own
 # expression on the same `pos` and `q_len`), and the grid steps the
-# step's shape alone would give it.
+# step's shape alone would give it. `mla_*`: made on the host beside
+# them, a step of a model of the latent kind
+# (`ops/pallas/mla.count_latent_keys`, in its order), summed over its
+# layers: the (query, key) PAIRS the live query rows attend over, what
+# the arithmetic is proportional to; what has to be READ at least once
+# however a slot's queries share their reads, a slot's context once a
+# step; and the live query rows.
+LATENT_COUNTERS = (
+    "mla_pairs_total",
+    "mla_keys_distinct_total",
+    "mla_rows_total",
+)
 STEP_WORK_COUNTERS = (
     "moe_assignments_total",
     "moe_assignments_here_total",
@@ -115,7 +126,7 @@ STEP_WORK_COUNTERS = (
     "kv_window_pages_skipped_total",
     "walk_grid_steps_total",
     "walk_grid_steps_full_total",
-)
+) + LATENT_COUNTERS
 
 
 class Histogram:

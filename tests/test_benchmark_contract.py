@@ -11,6 +11,7 @@ on the CPU. It reads the metric files and edits nothing there.
   every `series` a histogram attribute with the `count` and `_recent`
   that `benchmark/kinds/serve_http.py` `EngineWindow` takes;
 - `moe_roofline`: its `hit` and `here` counters likewise;
+  `latent_roofline`: its `pairs` and `distinct` counters;
 - `span_idle`: every span under `spans` and `excluding` is a `SPAN_*`
   constant that the engine or the HTTP driver opens.
 """
@@ -59,6 +60,8 @@ def _metric_files():
         counters, series = engine_names(spec["args"])
         if spec["reader"] == "moe_roofline":
             counters += [spec["args"]["hit"], spec["args"]["here"]]
+        if spec["reader"] == "latent_roofline":
+            counters += [spec["args"]["pairs"], spec["args"]["distinct"]]
         spans = (_listed(spec["args"].get("spans"))
                  + _listed(spec["args"].get("excluding"))
                  if spec["reader"] == "span_idle" else [])
@@ -101,11 +104,11 @@ def opened_spans():
 
 
 def test_the_yardstick_names_something():
-    """26 metrics read the engine's counters and histograms at the top
-    of their arguments, 2 more in an operand, 1 through the roofline
-    reader; 8 read spans."""
+    """27 metrics read the engine's counters and histograms at the top
+    of their arguments, 2 more in an operand, 2 through the roofline
+    readers; 8 read spans."""
     cases = [p.values for p in _metric_files()]
-    assert sum(1 for c, s, sp in cases if c or s) == 29
+    assert sum(1 for c, s, sp in cases if c or s) == 31
     assert sum(1 for c, s, sp in cases if sp) == 8
 
 

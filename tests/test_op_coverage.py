@@ -952,6 +952,13 @@ ELSEWHERE = {
     "rope_half": EW("test_laguna.py", "eager_forward|rotary"),
     "head_gate": EW("test_laguna.py", "eager_forward"),
     "moe_routed_experts": EW("test_laguna.py", "eager_forward|share"),
+    # DeepSeek-V2's ops (nlp/deepseek_v2.py, nlp/generation.py): against
+    # the plain float32 reference, eagerly and through the engine's cache
+    **{n: EW("test_deepseek_v2.py", "eager_forward|rotary")
+       for n in ["rope_pairs", "mla_expanded_attention"]},
+    **{n: EW("test_serving_deepseek_v2.py", "chunked_prefill|absorbed")
+       for n in ["mla_absorb_q", "mla_expand_v",
+                 "latent_paged_attention"]},
     # quantization — tests/test_inference_quant.py
     "fake_quantize_dequantize": EW("test_inference_quant.py",
                                    "quant"),

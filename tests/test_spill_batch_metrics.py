@@ -49,8 +49,12 @@ def test_metric_reads_the_new_counters_and_is_silent_without(name, reader,
 
 
 def test_manifest_lists_them_last():
+    """Last when PR 32 added them (the driver takes new entries only at
+    the end of a list); what later PRs add comes after, in name order."""
     with open(os.path.join(METRICS, os.pardir, os.pardir,
                            "BENCHMARK.json")) as f:
         names = [m["name"] for m in json.load(f)["per_layer"]]
-    assert names[-2:] == ["where.z.kv_spill.pages_per_batch.backlog",
-                          "where.z.kv_spill.wait_ms_per_step.backlog"]
+    at = names.index("where.z.kv_spill.pages_per_batch.backlog")
+    assert names[at + 1] == "where.z.kv_spill.wait_ms_per_step.backlog"
+    assert names[:at + 2] == sorted(names[:at + 2])
+    assert all(n > names[at + 1] for n in names[at + 2:])

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import layer_norm as pln
+from paddle_tpu.ops.pallas import mla
 from paddle_tpu.ops.pallas import moe
 from paddle_tpu.ops.pallas import paged_attention as pa
 
@@ -59,7 +60,7 @@ def real_kernels(monkeypatch):
     """Not interpret mode, whatever an earlier test file asked for
     (test_pallas_layer_norm.py sets PADDLE_TPU_PALLAS_INTERPRET at
     import, and the kernel modules read it when THEY are imported)."""
-    for mod in (fa, pln, pa, moe):
+    for mod in (fa, pln, pa, moe, mla):
         monkeypatch.setattr(mod, "_INTERPRET", False)
 
 
@@ -133,6 +134,45 @@ def test_moe_routed_experts(one_chip, rows, monkeypatch):
         one_chip, ((rows, h), BF16), ((rows,), jnp.bool_),
         ((h, 256), BF16), ((held, h, f), BF16), ((held, h, f), BF16),
         ((held, f, h), BF16))
+    assert "ptk:moe_experts" in text
+
+
+# DeepSeek-V2's latent rows at its serving shape: 16 slots x chunk 128
+# (and the decoding rows' one query), 128 heads over rows of 512 + 64
+# (640 in the cache: `DeepseekV2Config.cache_row`), rows of max_len
+# 16384 in pages of 16 of a pool of 16,385 (read in place: the pool in
+# HBM, a DMA a page)
+DS = dict(slots=16, chunk=128, heads=128, row=640, latent=512, n=16384,
+          pages=16385, ps=16)
+
+
+@pytest.mark.parametrize("lq", [DS["chunk"], 1])
+def test_latent_mla_walk(one_chip, lq):
+    a = DS
+    text = _compiles_to_kernel(
+        lambda q, kv, pt, p, n: mla.mla_walk(
+            q, kv, pt, p, n, d_v=a["latent"], scale=0.1147),
+        one_chip, ((a["slots"], lq, a["heads"], a["row"]), BF16),
+        ((a["pages"], a["ps"], a["row"]), BF16),
+        ((a["slots"], a["n"] // a["ps"]), I32),
+        ((a["slots"],), I32), ((a["slots"],), I32))
+    assert "ptk:mla_walk" in text
+
+
+@pytest.mark.parametrize("rows", [16 * 128, 16], ids=["step", "decode"])
+def test_moe_routed_experts_group_limited(one_chip, rows, monkeypatch):
+    """One DeepSeek-V2 expert layer, this chip's 20 of 160 experts at
+    hidden 5120 / width 1536, softmax scores, top-6 within 3 of 8
+    groups, not renormalised, x 16."""
+    h, f, held = 5120, 1536, 20
+    monkeypatch.setattr(moe, "_use_kernel", lambda: True)   # as the chip would
+    text = _compiles_to_kernel(
+        lambda x, v, wr, wg, wu, wd: moe.routed_experts(
+            x, v, wr, wg, wu, wd, top_k=6, scale=16.0, norm_topk=False,
+            first=0, n_group=8, topk_group=3),
+        one_chip, ((rows, h), BF16), ((rows,), jnp.bool_),
+        ((h, 160), BF16), ((held, h, f), BF16),
+        ((held, h, f), BF16), ((held, f, h), BF16))
     assert "ptk:moe_experts" in text
 
 
@@ -342,9 +382,9 @@ def _tiny_gpt(hidden, heads, positions):
 
 def test_kernel_names_are_distinct_and_on_every_site():
     import re
-    tables = {mod: mod.KERNELS for mod in (pa, fa, pln)}
+    tables = {mod: mod.KERNELS for mod in (pa, fa, pln, moe, mla)}
     names = [n for t in tables.values() for n in t]
-    assert len(names) == len(set(names)) == 11
+    assert len(names) == len(set(names)) == 13
     assert not [(a, b) for a in names for b in names
                 if a != b and a in b]
     for mod, table in tables.items():
@@ -433,6 +473,62 @@ def test_unified_step_sizes_phase1_sweep_from_its_operands(one_chip,
     assert "ptk:grouped_phase1" not in ungrouped
     assert len(pool_copy.findall(compiled)) \
         <= len(pool_copy.findall(ungrouped))
+
+
+def test_unified_step_of_a_latent_model_compiles_with_its_kernels(
+        one_chip, monkeypatch):
+    """The serving step of a small DeepSeek-V2 in bfloat16 (real rope
+    part and head sizes, a latent of 192 so that the cached row is
+    padded 256, pages of 16, chunk 128, rows of 2048 keys), lowered and
+    COMPILED for the described v5e as the chip traces it: the walk
+    twice a layer (chunk rows, decoding rows), the expert kernel, no
+    conditional, no ragged walk, and no `[slots, max_len, row]` view of
+    the pool anywhere in it."""
+    import warnings
+    import numpy as np
+    import paddle_tpu as paddle
+    from paddle_tpu.nlp import DeepseekV2Config, DeepseekV2ForCausalLM
+    from paddle_tpu.serving import ServingEngine, SamplingParams
+    paddle.seed(0)
+    model = DeepseekV2ForCausalLM(DeepseekV2Config(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        moe_intermediate_size=128, num_hidden_layers=2,
+        num_attention_heads=8, num_key_value_heads=8, q_lora_rank=128,
+        kv_lora_rank=192, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, n_routed_experts=16, num_experts_per_tok=3,
+        n_group=4, topk_group=2, ep_size=4, dtype="bfloat16",
+        rope_scaling={"type": "yarn", "factor": 40, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 0.707,
+                      "mscale_all_dim": 0.707,
+                      "original_max_position_embeddings": 4096}))
+    model.eval()
+    for mod in (mla, moe):
+        monkeypatch.setattr(mod, "_use_kernel", lambda: False)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        eng = ServingEngine(model, num_slots=8, max_len=2048, page_size=16,
+                            chunk_len=128)
+    eng.add_request(np.arange(1, 40, dtype=np.int64),
+                    SamplingParams(max_new_tokens=2))
+    eng.run()
+    for mod in (mla, moe):
+        monkeypatch.setattr(mod, "_use_kernel", lambda: True)
+    prog = eng._build_unified()
+    lowered = prog._jit.lower(*_shaped(
+        (prog._state_vals, eng._ct, *eng._unified_args_tail), one_chip))
+    text = lowered.as_text()
+    assert "stablehlo.case" not in text
+    for name, calls in (("mla_walk", 4), ("moe_experts", 1)):
+        assert sum(f'ptk:{name}' in ln
+                   for ln in text.splitlines()) == calls, name
+    assert "ptk:ragged_walk" not in text and "ptk:mla_project" in text
+    compiled = lowered.compile().as_text()
+    assert " conditional(" not in compiled
+    assert "%mla_walk." in compiled
+    # the pool's pages are read in place: nothing gathers a slot's
+    # max_len view of it (rows of 192 + 64 values fill 256)
+    assert "bf16[8,2048,256]" not in compiled
+    assert "bf16[8,128,16,256]" not in compiled
 
 
 def test_names_reach_the_compiled_instruction(one_chip, on_tpu_branch):
