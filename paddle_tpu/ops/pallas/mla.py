@@ -16,8 +16,10 @@ query rows over their cached rows, causal, and reads the slot's PAGES
 IN PLACE: the pool stays in HBM, the page table rides in as a
 scalar-prefetch operand, and a grid step brings the pages of its key
 block into VMEM by one DMA a page while the step before it computes
-(`_walk_paged`). Nothing gathers a row's `max_len` view, so a row pays
-for the pages under its horizon only.
+(`paged_attention._walk_paged`, the walker the head-carrying page walk
+runs on too: this kernel asks it for whole key blocks of one pool).
+Nothing gathers a row's `max_len` view, so a row pays for the pages
+under its horizon only.
 
 A query block's rows are ordered (query, head), which is the order the
 step's `[B, l, H, D]` operand lies in: it is reshaped, never
@@ -25,12 +27,13 @@ transposed, on the way in and on the way out; all heads of a query
 block are the rows of ONE matmul against the shared cached rows.
 
 WORK ITEMS. The grid's first axis runs over the step's LIVE query
-blocks only (`_work_items`: a list of (row, query block) pairs that
-rides in as scalar-prefetch operands, live ones first, and their count
-as the axis' dynamic bound); its second over the key blocks of the
-longest live context (`paged_attention.walk_grid_bounds`' rule, at a
-key block's size). A step of one prefill chunk beside fifteen decoding
-rows therefore has no grid step for the query blocks that hold nothing.
+blocks only (`paged_attention._work_items`: a list of (row, query
+block) pairs that rides in as scalar-prefetch operands, live ones
+first, and `_live_query_blocks`, their count, as the axis' dynamic
+bound); its second over the key blocks of the longest live context
+(`_key_block_bound`, the rule of `paged_attention.walk_grid_bounds`).
+A step of one prefill chunk beside fifteen decoding rows therefore has
+no grid step for the query blocks that hold nothing.
 Rows that decode (one live query) take the same kernel a second time
 at a query block of ONE row: in a block of `Q_BLOCK` their matmuls
 would be padding but for one query's heads.
@@ -50,7 +53,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import kernel_id as _kernel_id, trace32 as _trace32
-from .paged_attention import _prec, walk_grid_bounds
+from .paged_attention import (_key_block_bound, _live_query_blocks, _prec,
+                              _walk_paged, _walk_scratch, _work_items)
 
 __all__ = ["latent_attend", "latent_attend_reference", "mla_walk",
            "gather_view", "count_latent_keys", "KERNELS", "LANES"]
@@ -78,18 +82,6 @@ def _use_kernel():
     return _INTERPRET or jax.devices()[0].platform == "tpu"
 
 
-def _work_items(q_len, qb, nqb):
-    """(row of each item, its query block, live items) over the
-    [rows x nqb] query blocks of the step, the live ones (those that
-    hold a query below the row's q_len) first, in row order."""
-    t = jnp.arange(nqb, dtype=jnp.int32)[None, :]
-    live = (t * qb < q_len[:, None]).reshape(-1)
-    order = jnp.argsort(jnp.logical_not(live), stable=True) \
-        .astype(jnp.int32)
-    return (order // nqb, order % nqb,
-            jnp.maximum(jnp.sum(live, dtype=jnp.int32), 1))
-
-
 def _blocks(l, pool, page_table):
     """(query block, query blocks, padded l, key block, keys a row) for
     l query positions over rows of `page_table`'s pages of `pool`."""
@@ -110,65 +102,6 @@ def gather_view(pool, page_table):
     form's operand; the kernel never builds it)."""
     g = jnp.take(pool, page_table.astype(jnp.int32), axis=0)
     return g.reshape(g.shape[0], -1, pool.shape[-1])
-
-
-def _walk_paged(refs, pool_ref, buf, sem, cnt, compute, *, qb, kb):
-    """One grid step (work item i, key block k) of a kernel that reads
-    the pool's pages in place: where the block lies at or below the
-    item's horizon, waits for its pages in `buf[slot]` ([2, pages a
-    block, page_size, D] VMEM, one DMA a page from `pool_ref` in HBM)
-    and runs `compute(keys [kb, D])`. The block of the NEXT step that
-    computes (this item's next block, or the next item's first) is set
-    off into the other slot before the wait, so its copy runs under
-    this step's arithmetic; `cnt` (SMEM) counts the steps that computed
-    and gives the slot. Steps past the horizon move nothing."""
-    ib_ref, it_ref, pos_ref, qlen_ref, pt_ref = refs
-    i, k = pl.program_id(0), pl.program_id(1)
-    n_items = pl.num_programs(0)
-    ppb = buf.shape[1]
-    max_pages = pt_ref.shape[0] // pos_ref.shape[0]
-
-    def item(j):
-        """(row, whether it holds a live query, its last key block)."""
-        b, t = ib_ref[j], it_ref[j]
-        qlen_b = qlen_ref[b]
-        last_qi = jnp.minimum((t + 1) * qb, qlen_b) - 1
-        return b, t * qb < qlen_b, (pos_ref[b] + last_qi) // kb
-
-    def copies(b, kblk, slot):
-        base = b * max_pages + kblk * ppb
-        return [pltpu.make_async_copy(
-            pool_ref.at[pt_ref[base + j]], buf.at[slot, j], sem.at[slot])
-            for j in range(ppb)]
-
-    @pl.when((i == 0) & (k == 0))
-    def _reset():
-        cnt[0] = 0
-
-    b, live, last = item(i)
-
-    @pl.when(live & (k <= last))
-    def _step():
-        slot = cnt[0] % 2
-
-        @pl.when((i == 0) & (k == 0))
-        def _first():
-            for c in copies(b, k, slot):
-                c.start()
-
-        nb, nlive, _ = item(jnp.minimum(i + 1, n_items - 1))
-        same = k < last
-
-        @pl.when(same | ((i + 1 < n_items) & nlive))
-        def _ahead():
-            for c in copies(jnp.where(same, b, nb),
-                            jnp.where(same, k + 1, 0), 1 - slot):
-                c.start()
-
-        for c in copies(b, k, slot):
-            c.wait()
-        compute(buf[slot].reshape(kb, buf.shape[3]))
-        cnt[0] = cnt[0] + 1
 
 
 def _pad_queries(x, l_pad):
@@ -197,6 +130,17 @@ def _mla_walk_kernel(ib_ref, it_ref, pos_ref, qlen_ref, pt_ref, q_ref,
     i, k = pl.program_id(0), pl.program_id(1)
     b, t = ib_ref[i], it_ref[i]
     pos_b, qlen_b = pos_ref[b], qlen_ref[b]
+    ppb = buf.shape[1]
+    max_pages = pt_ref.shape[0] // pos_ref.shape[0]
+
+    def item(j):
+        """`_walk_paged`'s account of work item j: its key blocks whole
+        (no first page), to the block that holds its last query's
+        position."""
+        bj, tj = ib_ref[j], it_ref[j]
+        last_qi = jnp.minimum((tj + 1) * qb, qlen_ref[bj]) - 1
+        return (bj * max_pages, tj * qb < qlen_ref[bj], None,
+                (pos_ref[bj] + last_qi) // (kb // ppb))
 
     @pl.when(k == 0)
     def _init():
@@ -204,7 +148,8 @@ def _mla_walk_kernel(ib_ref, it_ref, pos_ref, qlen_ref, pt_ref, q_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def compute(kv):                                    # [kb, d]
+    def compute(slot, blk):
+        kv = buf[slot].reshape(kb, buf.shape[3])        # [kb, d]
         q = q_ref[0]                                    # [qb * heads, d]
         prec = _prec(q.dtype)
         s = jax.lax.dot_general(
@@ -212,7 +157,7 @@ def _mla_walk_kernel(ib_ref, it_ref, pos_ref, qlen_ref, pt_ref, q_ref,
             preferred_element_type=jnp.float32,
             precision=prec) * jnp.float32(scale)        # [qb * heads, kb]
         qi = t * qb + jax.lax.broadcasted_iota(jnp.int32, (qb, kb), 0)
-        kpos = k * kb + jax.lax.broadcasted_iota(jnp.int32, (qb, kb), 1)
+        kpos = blk * kb + jax.lax.broadcasted_iota(jnp.int32, (qb, kb), 1)
         seen = (kpos <= pos_b + qi) & (qi < qlen_b)
         s = s + _per_query(jnp.where(seen, 0.0, jnp.float32(_NEG_INF)), qb,
                            heads)
@@ -231,8 +176,7 @@ def _mla_walk_kernel(ib_ref, it_ref, pos_ref, qlen_ref, pt_ref, q_ref,
             preferred_element_type=jnp.float32, precision=prec)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
-    _walk_paged((ib_ref, it_ref, pos_ref, qlen_ref, pt_ref), pool_ref, buf,
-                sem, cnt, compute, qb=qb, kb=kb)
+    _walk_paged(item, ppb, cnt, compute, pt_ref, (pool_ref,), (buf,), sem)
 
     @pl.when(k == pl.num_programs(1) - 1)
     def _store():
@@ -258,11 +202,9 @@ def mla_walk(q, pool, page_table, pos, q_len, *, d_v, scale):
     q3 = _pad_queries(q, l_pad).reshape(b, l_pad * h, d)
     rows = qb * h
     with _trace32():
-        ib, it, n_items = _work_items(q_len, qb, nqb)
-        # the key blocks of the longest live context: the walk's page
-        # bound, at a key block for a page
-        _, n_kblk = walk_grid_bounds(pos, q_len, lq=l, page_size=kb,
-                                     max_pages=n // kb)
+        ib, it = _work_items(q_len, qb, nqb)
+        n_items = _live_query_blocks(q_len, qb, nqb)
+        n_kblk = _key_block_bound(pos, q_len, kb, n // kb)
 
         def r_idx(i, k, ib, it, pos, ql, pt):
             return (ib[i], it[i], 0)
@@ -276,10 +218,7 @@ def mla_walk(q, pool, page_table, pos, q_len, *, d_v, scale):
                 in_specs=[pl.BlockSpec((1, rows, d), r_idx),
                           pl.BlockSpec(memory_space=pl.ANY)],
                 out_specs=pl.BlockSpec((1, rows, d_v), r_idx),
-                scratch_shapes=[
-                    pltpu.VMEM((2, kb // ps, ps, d), pool.dtype),
-                    pltpu.SemaphoreType.DMA((2,)),
-                    pltpu.SMEM((1,), jnp.int32),
+                scratch_shapes=_walk_scratch([pool], kb // ps) + [
                     pltpu.VMEM((rows, LANES), jnp.float32),
                     pltpu.VMEM((rows, LANES), jnp.float32),
                     pltpu.VMEM((rows, d_v), jnp.float32)]),

@@ -9,29 +9,38 @@ and XLA cannot fuse a data-dependent gather into the attention reads
 "Ragged Paged Attention" (PAPERS.md): walk the page table and stream
 ONLY the pages a row actually occupies.
 
-Structure — grid (batch_row, q_block, page):
+Structure — grid (work item, key block):
 
-- `page_table` [B, max_pages], `pos` [B] and `q_len` [B] ride in as
-  SCALAR-PREFETCH operands (pltpu.PrefetchScalarGridSpec), so the K/V
-  BlockSpec index maps can chase the page table: grid step (b, t, p)
-  DMAs pool page `page_table[b, p]` — the WHOLE page, all kv heads
-  (block (1, page_size, H_kv, D): Mosaic wants a block's two minor
-  dimensions (8, 128)-divisible or whole, so a block cannot take one
-  head out of [H_kv, D]; see `_ragged_attention_local`). Steps past
-  the row's last live page clamp their index to that page — the
-  pipeline skips the re-fetch of an unchanged block, so HBM traffic is
-  O(pages actually used) per row, and compute there is predicated off.
-- Flash-style online softmax across page blocks: running (m, l, acc)
+- A WORK ITEM is one live (batch_row, query block) pair; the items
+  ride in as SCALAR-PREFETCH operands (pltpu.PrefetchScalarGridSpec)
+  next to `pos` [B], `q_len` [B] and the flat `page_table`, the live
+  ones first, and their count is the first axis' dynamic bound
+  (`_work_items`). A query block is as wide as one kv head's matmul
+  wants rows (`_query_blocks`: a whole chunk of 128 where every head
+  has its own kv head, 32 or 16 queries where six or nine share one),
+  so a chunk's context is read once, not once for every few queries.
+- A KEY BLOCK is `K_BLOCK` keys: whole pages, each with ALL its kv
+  heads, as the pool [P, ps, H_kv, D] holds them. The pool stays in
+  HBM; a grid step copies its block's pages into VMEM through the page
+  table, one DMA a page, double-buffered under the arithmetic of the
+  step before, and only the pages the row really occupies
+  (`_walk_paged`, which `mla.py`'s walk shares): HBM traffic is
+  O(pages actually used) per row.
+- Flash-style online softmax across key blocks: running (m, l, acc)
   scratch in VMEM, exactly the flash_attention.py recurrence with
-  page_size-wide key blocks, the kv heads as the batch dimension of
-  both dots (`_attend_page`). The partial tail page is handled by
-  in-page masking (position > pos[b] -> -inf), which also covers
+  K_BLOCK-wide key blocks, the kv heads as the batch dimension of both
+  dots (`_attend_block`). The partial last block is handled by masking
+  key positions (position > pos[b] + i -> -inf), which also covers
   trash-page rows: a retired/free slot's page-table row points at the
   reserved page 0 and every position past `pos` contributes -inf.
 - GQA without materialization: queries are grouped
   [B, n_qblk, H_kv, qblk * rep, D] so kv head g serves its
   `rep = H // H_kv` query heads from ONE streamed copy of K/V — no
   `repeat_interleave` of the cache.
+- A query block with few live queries (a decoding row's one) runs the
+  same body over its first `_NARROW_ROWS` rows alone, chosen in the
+  kernel from q_len (`_by_width`): a decoding row does not pay a
+  chunk's matmuls.
 - The single-token decode op (`paged_decode_attention`) is this walk
   at q_len 1.
 
@@ -50,35 +59,34 @@ group), `group_leader` [B] (group -> a representative row) and
 `group_cnt` [B] (group -> shared page count; 0 for singletons) — ride
 next to `page_table`/`pos`/`q_len` and drive a TWO-PHASE kernel:
 
-- phase 1 walks each group's shared pages via the LEADER's page table
-  (grid (q_block, group x page)), streaming every shared page from
-  HBM ONCE PER GROUP while updating the online-softmax partials
-  (m, l, acc) of every MEMBER row in VMEM (non-member rows are
-  predicated off, so their partials stay bit-exact);
-- phase 2 is exactly the per-row walk above, except each row STARTS
-  from its phase-1 partials and its page sweep clamps to
-  [group_cnt[group_id[b]], last_live] — private tail pages stream
-  once per row, shared pages are never re-read.
+- phase 1 walks each sharing group's shared span via the LEADER's
+  page table (grid ((q_block, group), key block of the span)),
+  bringing every shared page in from HBM ONCE PER GROUP while updating
+  the online-softmax partials (m, l, acc) of every MEMBER row in VMEM;
+  a member's partials leave for HBM behind the span's last block, and
+  the rows of no sharing group are never touched;
+- phase 2 is exactly the per-row walk above, except that a row of a
+  sharing group STARTS from its phase-1 partials and from the key
+  block that holds the first key past the span (the keys below it are
+  masked by position) — private tail pages stream once per row,
+  shared pages are never re-read.
 
 A group of 1 (group_cnt 0) degenerates to the ungrouped walk: phase 1
 never touches the row and phase 2 starts at page 0 with the virgin
-(-inf, 0, 0) partials. Phase 1's (group x page) sweep is as long as
-the data asks: its grid bound is DYNAMIC, `any(group_cnt > 0)` decided
-inside the one compiled step from operand data — the whole sweep on a
-step where some rows share, ONE grid step a q_block (which writes the
-virgin partials and nothing else) on a step where none do, because a
-predicated-off grid step still costs a grid step and the full sweep
-has as many as the walk proper. Its q-block axis is the walk proper's
-own dynamic bound (below), the same in both phases. Page order per row
-is IDENTICAL to the ungrouped kernel (shared pages 0..cnt-1 then
-private cnt..last, the same online-softmax recurrence), so outputs
-match the ungrouped walk;
+(-inf, 0, 0) partials. Phase 1's grid is as long as the data asks: its
+work items are the groups that share, so a step where none does has
+ONE grid step, which does nothing (a grid step with nothing to do
+still costs a grid step, and an idle sweep was once a third of the
+step). Where the span ends on a key block's edge the blocks a row
+folds, and their order, are IDENTICAL to the ungrouped kernel's;
+where it ends inside one, that block is folded in two parts, which
+changes rounding only;
 off-TPU the op runs the SAME `ragged_attention_reference` as the
 ungrouped op — grouping is a pure HBM-traffic hint, bit-identical by
 construction. `count_page_block_reads` is the host-side model of both
 walks' DMA behavior (the number the serving bench and metrics
-report). The q8 lane (`ragged_paged_attention_grouped_q8`) streams
-the rowwise scale pages through the same grouped walk.
+report). The q8 lane (`ragged_paged_attention_grouped_q8`) takes the
+rows' scales through the same grouped walk.
 
 FP8 LANE: pools may hold float8_e4m3fn — a PURE-CONVERT quantized
 cache (no scale pages at all: the e4m3 value IS the number, saturating
@@ -90,9 +98,8 @@ there is nothing to keep paired, so COW/swap/spill move fp8 pages
 exactly like fp pages.
 
 RAGGED GENERALIZATION (`ragged_paged_attention`): the same walk, but
-every row carries its own query length — grid
-(batch_row, q_block, page), with `q_len` [B] riding next to
-`page_table`/`pos` as a third scalar-prefetch operand. Row b's query
+every row carries its own query length, `q_len` [B] riding next to
+`page_table`/`pos` as a scalar-prefetch operand. Row b's query
 token i sits at global position pos[b] + i and attends keys
 j <= pos[b] + i (the causal window of the chunk being written), so ONE
 invocation serves a mixed batch: decode rows at q_len == 1 next to
@@ -100,19 +107,15 @@ mid-prefill rows at q_len == chunk — the one-kernel/step target of
 Ragged Paged Attention (PAPERS.md), with the per-row tail causally
 masked in the fused online-softmax loop (the low-precision-friendly
 primitive style of Tensor Processing Primitives, PAPERS.md). Query
-blocks past q_len[b] and pages past the row's live prefix
-ceil((pos[b] + q_len[b]) / page_size) are skipped: their grid steps
-clamp the K/V block index to the last live page (no re-fetch) and
-predicate compute off, so both HBM traffic and MXU work scale with the
-tokens actually packed, not with the padded step shape. The GRID is
-as long as the rows ask too: its q-block and page axes are dynamic
-bounds (`walk_grid_bounds`: the q-blocks of the row with most live
-queries, the pages of the longest live context), decided inside the
-one compiled step from `pos` and `q_len`, for every walk: plain,
-grouped (both phases), masked, int8, fp8 and windowed. A skipped grid
-step still costs a grid step, and the padded shape has tens of times
-more of them than a step of decode rows needs. Outputs at query
-positions >= q_len[b] are zero (the engine discards them).
+blocks past q_len[b] are no work items and pages past the row's live
+prefix ceil((pos[b] + q_len[b]) / page_size) are never copied, so both
+HBM traffic and MXU work scale with the tokens actually packed, not
+with the padded step shape. The GRID is as long as the rows ask too:
+both axes are dynamic bounds (`walk_grid_bounds`: the live work
+items, the key blocks of the longest live context), decided inside
+the one compiled step from `pos` and `q_len`, for every walk: plain,
+grouped (both phases), masked, int8, fp8 and windowed. Outputs at
+query positions >= q_len[b] are zero (the engine discards them).
 
 MEGAKERNEL (`megakernel_decode` / `megakernel_decode_q8`, gated
 PADDLE_TPU_MEGAKERNEL, default off): the decode layer's remaining op
@@ -156,14 +159,16 @@ so the cost census can assert bytes-accessed per token drops.
 INT8 LANE (`ragged_paged_attention_q8`): the same walk over an int8
 POOL — code pages [P, page_size, H_kv, D] int8 plus rowwise scale
 pages [P, page_size, H_kv] f32 (one scale per (position, kv head),
-written by generation.py's quantized paged scatter). Code and scale
-blocks stream into VMEM together and the dequant (convert x rowwise
+written by generation.py's quantized paged scatter). A key block's
+codes and scales meet in VMEM and the dequant (convert x rowwise
 scale) is FUSED into the online-softmax loop — no HBM-side
-dequantized copy is ever materialized, which is the whole point:
+dequantized copy is ever materialized, which is the whole point
+(the scales, 1/32 of the codes' bytes, come as each row's gathered
+view: Mosaic cannot cut a [page_size, H_kv] f32 page out of HBM):
 decode is HBM-bandwidth-bound, and halving the KV byte stream halves
 the dominant HBM traffic (the fused low-precision-primitive idiom of
-Tensor Processing Primitives, PAPERS.md). Dead-page / dead-row
-clamping is unchanged. Off-TPU the op runs
+Tensor Processing Primitives, PAPERS.md). Dead pages and dead rows
+are skipped as on every lane. Off-TPU the op runs
 `ragged_attention_reference_q8`, which dequantizes through EXACTLY the
 same elementwise expression as generation.py's `paged_kv_gather_q8`
 (`dequantize_paged_q8` is shared), so the CPU kernel lane stays
@@ -274,21 +279,21 @@ def _mask_to_additive(mask, b, h, lmax, lq=1):
     return out.reshape(b, h, lmax) if lq == 1 else out
 
 
-def _attend_page(q, k, v, ks, vs, live, mask, m_ref, l_ref, acc_ref, *,
-                 scale, fp8):
-    """Fold ONE streamed page into the online-softmax partials of one
-    row's query block, for every kv head at once. q [H_kv, R, D] with
-    R = qblk * rep query rows per kv head; k/v [ps, H_kv, D] exactly as
-    the page sits in the pool (the page block carries ALL kv heads —
-    see `_ragged_attention_kernel`); ks/vs the int8 lane's rowwise
-    scales [ps, H_kv] f32 or None; live bool [R, ps]; mask additive f32
-    [H_kv, R, ps] or None. m/l [H_kv, R, 128] and acc [H_kv, R, D] are
-    refs updated in place. The head axis is a batch dimension of both
-    dots, so per head this is the flash_attention.py recurrence with
-    page_size-wide key blocks."""
+def _attend_block(q, k, v, ks, vs, live, mask, m_ref, l_ref, acc_ref, *,
+                  scale, fp8):
+    """Fold ONE key block into the online-softmax partials of one row's
+    query block, for every kv head at once. q [H_kv, R, D] with R query
+    rows per kv head; k/v [kb, H_kv, D], the block's pages exactly as
+    they sit in the pool, one behind another (a page carries ALL kv
+    heads — see `_ragged_attention_local`); ks/vs the int8 lane's
+    rowwise scales [kb, H_kv] f32 or None; live bool [R, kb]; mask
+    additive f32 [H_kv, R, kb] or None. m/l [H_kv, R, 128] and acc
+    [H_kv, R, D] are refs updated in place. The head axis is a batch
+    dimension of both dots, so per head this is the flash_attention.py
+    recurrence with kb-wide key blocks."""
     if ks is not None:
         # fused in-VMEM dequant: int8 codes x rowwise scale — the
-        # dequantized page never round-trips through HBM
+        # dequantized block never round-trips through HBM
         q = q.astype(jnp.float32)
         k = k.astype(jnp.float32) * ks[:, :, None]
         v = v.astype(jnp.float32) * vs[:, :, None]
@@ -299,12 +304,12 @@ def _attend_page(q, k, v, ks, vs, live, mask, m_ref, l_ref, acc_ref, *,
         k = k.astype(jnp.float32)
         v = v.astype(jnp.float32)
     prec = _prec(q.dtype)
-    k = jnp.swapaxes(k, 0, 1)                      # [H_kv, ps, D]
+    k = jnp.swapaxes(k, 0, 1)                      # [H_kv, kb, D]
     v = jnp.swapaxes(v, 0, 1)
     s = jax.lax.dot_general(
         q, k, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
-        precision=prec) * jnp.float32(scale)       # [H_kv, R, ps]
+        precision=prec) * jnp.float32(scale)       # [H_kv, R, kb]
     s = jnp.where(live[None], s, jnp.float32(_NEG_INF))
     if mask is not None:
         s = s + mask
@@ -324,30 +329,39 @@ def _attend_page(q, k, v, ks, vs, live, mask, m_ref, l_ref, acc_ref, *,
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
 
-def _live_window(t, p, pos_b, qlen_b, *, ps, qblk, rep, window=None):
-    """bool [qblk * rep, ps]: query t*qblk + i (live iff < q_len)
-    attends key position p*ps + j iff it is <= pos + query index.
-    Masks the partial tail page AND trash-page positions. With a
-    sliding `window` (its size, the query's own position included) the
-    key must also lie above pos + query index - window: the partial
-    page at the window's lower edge."""
-    shape = (qblk, rep, ps)
-    qi = t * qblk + jax.lax.broadcasted_iota(
-        jnp.int32, shape, 0).reshape(qblk * rep, ps)
-    k_pos = p * ps + jax.lax.broadcasted_iota(
-        jnp.int32, shape, 2).reshape(qblk * rep, ps)
+def _live_block(q0, k0, pos_b, qlen_b, *, n, rep, kb, window=None,
+                lo_key=None, hi_key=None):
+    """bool [n, kb] for the first n rows of a query block whose first
+    query is q0 (rows in (query, head-of-the-group) order): query q0 + i
+    (live iff < q_len) attends key position k0 + j iff it is <= pos +
+    query index. Masks the partial last block AND trash-page positions.
+    With a sliding `window` (its size, the query's own position
+    included) the key must also lie above pos + query index - window:
+    the partial block at the window's lower edge. `lo_key` / `hi_key`
+    keep the keys in [lo_key, hi_key): the two phases of the grouped
+    walk split a block at the end of the shared span."""
+    shape = (-(-n // rep), rep, kb)
+    rows = shape[0] * rep
+    qi = q0 + jax.lax.broadcasted_iota(
+        jnp.int32, shape, 0).reshape(rows, kb)[:n]
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (n, kb), 1)
     live = (qi < qlen_b) & (k_pos <= pos_b + qi)
     if window is not None:
         live = live & (k_pos > pos_b + qi - window)
+    if lo_key is not None:
+        live = live & (k_pos >= lo_key)
+    if hi_key is not None:
+        live = live & (k_pos < hi_key)
     return live
 
 
-def _window_pages(window, qblk, ps):
-    """Pages one query block of a sliding-window layer can touch: its
-    live queries see window - 1 + qblk consecutive positions at most,
-    which lie on this many pages whatever their alignment. It is the
-    length of the page axis of a window layer's grid."""
-    return (window + qblk + ps - 3) // ps + 1
+def _window_blocks(window, qblk, kb):
+    """Key blocks one query block of a sliding-window layer can touch:
+    its live queries see window - 1 + qblk consecutive positions at
+    most, which lie on this many blocks of kb keys whatever their
+    alignment. It is the length of the key-block axis of a window
+    layer's grid."""
+    return (window + qblk + kb - 3) // kb + 1
 
 
 def _window_first_page(pos_b, t, *, ps, qblk, window):
@@ -356,165 +370,430 @@ def _window_first_page(pos_b, t, *, ps, qblk, window):
     return jnp.maximum(pos_b + t * qblk - (window - 1), 0) // ps
 
 
-def _ragged_kernel(*refs, ps, qblk, rep, scale, has_mask, has_scale,
-                   fp8, grouped, window=None):
-    """The per-row page walk — grid (batch_row, q_block, page). With
-    `grouped` it is phase 2 of the grouped walk: each row initializes
-    from its phase-1 partials and skips pages below its group's shared
-    span (their contribution is already folded in), so private tail
-    pages stream once per row and shared pages are never re-read. The
-    merge IS the online-softmax recurrence continuing where phase 1
-    stopped, so the page order per row matches the ungrouped walk.
-    With `window` the page axis is RELATIVE: grid step p is the p-th
-    page from the one that holds the block's lowest visible key, so
-    pages wholly below the window have no grid step at all."""
+def _work_items(q_len, qb, nqb):
+    """(row of each item, its query block) over the [rows x nqb] query
+    blocks of the step, the live ones (those that hold a query below the
+    row's q_len: `_live_query_blocks` of them) first, in row order."""
+    t = jnp.arange(nqb, dtype=jnp.int32)[None, :]
+    live = (t * qb < q_len[:, None]).reshape(-1)
+    order = jnp.argsort(jnp.logical_not(live), stable=True) \
+        .astype(jnp.int32)
+    return order // nqb, order % nqb
+
+
+def _walk_paged(item, ppb, cnt, compute, pt_ref=None, pools=(), bufs=(),
+                sem=None):
+    """One grid step (work item i, key block k) of a kernel that reads
+    the pools' pages in place. `item(j)` says of work item j (where its
+    row's page table starts in `pt_ref`, whether it has anything to do,
+    its first page, its last page); its key blocks are the blocks of
+    `ppb` pages from the one that holds its first page to the one that
+    holds its last, and grid step k is the k-th of them.
+    Where that block exists, waits for its pages in `bufs[n][slot]`
+    ([2, pages a block, ...a page] VMEM, one DMA a page and pool from
+    `pools[n]` in HBM, and only the pages between the item's first and
+    last: the rest of the buffer keeps what it held; an item whose first
+    page is None asks for its blocks whole, from the row's first, and
+    gets every page of a block by a copy written out, not looped) and runs
+    `compute(slot, block)`. The block of the NEXT step that computes
+    (this item's next block, or the next item's first: items with
+    something to do come first) is set off into the other slot before
+    the wait, so its copy runs under this step's arithmetic; `cnt`
+    (SMEM) counts the steps that computed and gives the slot. Steps past
+    an item's last block move nothing. Without `pools` (a caller whose
+    keys arrive by BlockSpec) it only says which steps compute."""
+    i, k = pl.program_id(0), pl.program_id(1)
+    n_items = pl.num_programs(0)
+
+    def span(j):
+        base, live, lo, hi = item(j)
+        first = 0 if lo is None else jnp.minimum(lo, hi) // ppb
+        return base, live, lo, hi, first, hi // ppb
+
+    def move(base, lo, hi, blk, slot, wait):
+        if not pools:
+            return
+        p0 = blk * ppb
+
+        def page(j, carry):
+            for n, (pool, buf) in enumerate(zip(pools, bufs)):
+                c = pltpu.make_async_copy(
+                    pool.at[pt_ref[base + p0 + j]], buf.at[slot, j],
+                    sem.at[n, slot])
+                if wait:
+                    c.wait()
+                else:
+                    c.start()
+            return carry
+
+        if lo is None:
+            for j in range(ppb):
+                page(j, 0)
+        else:
+            jax.lax.fori_loop(jnp.maximum(lo - p0, 0),
+                              jnp.minimum(hi - p0 + 1, ppb), page, 0)
+
+    @pl.when((i == 0) & (k == 0))
+    def _reset():
+        cnt[0] = 0
+
+    base, live, lo, hi, first, last = span(i)
+    blk = first + k
+
+    @pl.when(live & (blk <= last))
+    def _step():
+        slot = cnt[0] % 2
+
+        @pl.when(cnt[0] == 0)
+        def _first():
+            move(base, lo, hi, blk, slot, False)
+
+        nbase, nlive, nlo, nhi, nfirst, _ = span(
+            jnp.minimum(i + 1, n_items - 1))
+        same = blk < last
+
+        @pl.when(same | ((i + 1 < n_items) & nlive))
+        def _ahead():
+            move(jnp.where(same, base, nbase),
+                 lo if lo is None else jnp.where(same, lo, nlo),
+                 jnp.where(same, hi, nhi),
+                 jnp.where(same, blk + 1, nfirst), 1 - slot, False)
+
+        move(base, lo, hi, blk, slot, True)
+        compute(slot, blk)
+        cnt[0] = cnt[0] + 1
+
+
+def _by_width(left, rep, rows, fn):
+    """`fn(n)` at the number of a query block's rows that hold its live
+    queries: `_NARROW_ROWS` where the `left` (traced) queries the row
+    has from the block's first on, `rep` rows each, fit them, else all
+    the block's `rows`."""
+    if _NARROW_ROWS >= rows:
+        fn(rows)
+        return
+    narrow = left * rep <= _NARROW_ROWS
+    pl.when(narrow)(lambda: fn(_NARROW_ROWS))
+    pl.when(jnp.logical_not(narrow))(lambda: fn(rows))
+
+
+def _clear_values(v_buf):
+    """At a call's first grid step: zero the V buffer, whose pages past
+    an item's last are never brought in. A masked key weighs 0 in the
+    softmax, but 0 x what VMEM happened to hold may be NaN; after this
+    it holds zeros or pages of the pool. (A masked key's score is
+    replaced, not scaled: K needs nothing.)"""
+    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
+    def _():
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+
+
+def _top_rows(ref, n):
+    """The first n rows of every kv head of `ref` [H_kv, rows, ...]; the
+    ref itself where those are all its rows (Mosaic cuts HBM by whole
+    tiles: a block of one row cannot be cut at its one row)."""
+    return ref if n == ref.shape[1] else ref.at[:, :n]
+
+
+def _virgin(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, jnp.float32(_NEG_INF))
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _block_keys(pools, bufs, slot):
+    """The key block as `_attend_block` takes it, k and v [kb, H_kv, D]:
+    the pages in `bufs[..][slot]` one behind another, or, without
+    buffers, the block of the rows' views that `pools` then are."""
+    if not bufs:
+        return [p[0] for p in pools]
+    return [buf[slot].reshape((-1,) + buf.shape[3:]) for buf in bufs]
+
+
+def _walk_item(j, pre, *, ps, qblk, grouped, window):
+    """Work item j of the walk, from its scalar-prefetch operands (the
+    kernel's refs, or an index map's): (row, query block, whether it
+    holds a live query, the first page its walk reads, the last)."""
+    ib, it, pos, qlen, pt = pre[:5]
+    b, t = ib[j], it[j]
+    max_pages = pt.shape[0] // pos.shape[0]
+    last_qi = jnp.minimum((t + 1) * qblk, qlen[b]) - 1
+    hi = jnp.clip((pos[b] + last_qi) // ps, 0, max_pages - 1)
+    lo = 0
+    if grouped:
+        gid, gcnt = pre[5:]
+        lo = gcnt[gid[b]]
+    if window is not None:
+        lo = _window_first_page(pos[b], t, ps=ps, qblk=qblk, window=window)
+    return b, t, t * qblk < qlen[b], jnp.minimum(lo, hi), hi
+
+
+def _ragged_kernel(*refs, ps, ppb, qblk, rep, scale, has_mask, has_scale,
+                   fp8, grouped, in_place, window=None):
+    """The per-row page walk — grid (work item, key block). A work item
+    is one LIVE (row, query block) pair (`_work_items`), a key block
+    `K_BLOCK` keys of the row's context, brought in from the pools in
+    HBM a page a DMA (`_walk_paged`). With `grouped` it is phase 2 of
+    the grouped walk: a row whose group shares starts from its phase-1
+    partials and from the key block that holds the first key past the
+    shared span (the keys below it, whose contribution is already
+    folded in, are masked by position), so private tail pages stream
+    once per row and shared pages are never re-read. The merge IS the
+    online-softmax recurrence continuing where phase 1 stopped. With
+    `window` the walk starts at the block that holds the item's lowest
+    visible key, so blocks wholly below the window have no grid step at
+    all. A query block whose live queries fit `_NARROW_ROWS` rows (a
+    decoding row's one query; a verify row's few, where the heads of a
+    group are few) runs its matmuls over those rows alone
+    (`_by_width`)."""
     refs = list(refs)
-    n_pre = 6 if grouped else 3
+    n_pre = 7 if grouped else 5
     pre, refs = refs[:n_pre], refs[n_pre:]
-    pos_ref, qlen_ref = pre[1], pre[2]
-    q_ref, k_ref, v_ref = refs[:3]
-    refs = refs[3:]
+    ib_ref, it_ref, pos_ref, qlen_ref, pt_ref = pre[:5]
+    q_ref, refs = refs[0], refs[1:]
+    pools, refs = refs[:2], refs[2:]
     ks_ref = vs_ref = mask_ref = None
     if has_scale:
-        # int8 lane: rowwise dequant scales ride next to the code
-        # pages — one [ps, H_kv] f32 block per streamed K/V page
+        # int8 lane: the key block's rowwise dequant scales [kb, H_kv]
         ks_ref, vs_ref = refs[:2]
         refs = refs[2:]
     if has_mask:
         mask_ref, refs = refs[0], refs[1:]
     if grouped:
-        m_in, l_in, acc_in = refs[:3]
-        refs = refs[3:]
-    o_ref, m_ref, l_ref, acc_ref = refs
-    b = pl.program_id(0)
-    t = pl.program_id(1)
-    p = pl.program_id(2)
-    n_p = pl.num_programs(2)
-    pos_b = pos_ref[b]
-    qlen_b = qlen_ref[b]
-    # last valid query of THIS block (block-dead when t*qblk >= q_len)
-    last_qi = jnp.minimum((t + 1) * qblk, qlen_b) - 1
-    # the page this grid step stands for (`p` itself without a window)
-    page = p
-    if window is not None:
-        page = p + _window_first_page(pos_b, t, ps=ps, qblk=qblk,
-                                      window=window)
+        gid_ref, gcnt_ref = pre[5:]
+        parts_in, refs = refs[:3], refs[3:]
+    o_ref, refs = refs[0], refs[1:]
+    bufs, sem = (), None
+    if in_place:
+        bufs, sem, refs = refs[:2], refs[2], refs[3:]
+    cnt, m_ref, l_ref, acc_ref = refs[:4]
+    parts = (m_ref, l_ref, acc_ref)
+    i, k = pl.program_id(0), pl.program_id(1)
+    max_pages = pt_ref.shape[0] // pos_ref.shape[0]
+    kb = ppb * ps
+    rows = q_ref.shape[3]
 
-    @pl.when(p == 0)
+    def item(j):
+        b, _, live, lo, hi = _walk_item(j, pre, ps=ps, qblk=qblk,
+                                        grouped=grouped, window=window)
+        return b * max_pages, live, lo, hi
+
+    b, t = ib_ref[i], it_ref[i]
+    pos_b, qlen_b = pos_ref[b], qlen_ref[b]
+    shared = gcnt_ref[gid_ref[b]] if grouped else None
+    by_width = functools.partial(_by_width, qlen_b - t * qblk, rep, rows)
+
+    @pl.when(k == 0)
     def _init():
-        if grouped:
-            m_ref[...] = m_in[0, 0]
-            l_ref[...] = l_in[0, 0]
-            acc_ref[...] = acc_in[0, 0]
-        else:
-            m_ref[...] = jnp.full_like(m_ref, jnp.float32(_NEG_INF))
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+        def virgin(n):
+            _virgin(*(p.at[:, :n] for p in parts))
 
-    # a page contributes iff it holds a position some live query of the
-    # block attends (j <= pos + last_qi); dead blocks skip every page —
-    # fully-dead pages are exactly zero under the online softmax, so
-    # skipping them is not an approximation
-    go = (t * qblk < qlen_b) & (page * ps <= pos_b + last_qi)
-    if grouped:
-        gid_ref, gcnt_ref = pre[3], pre[5]
-        go = go & (p >= gcnt_ref[gid_ref[b]])
+        if not grouped:
+            by_width(virgin)
+            return
+        psem = refs[4]
 
-    @pl.when(go)
-    def _compute():
-        _attend_page(
-            q_ref[0, 0], k_ref[0], v_ref[0],
-            ks_ref[0] if has_scale else None,
-            vs_ref[0] if has_scale else None,
-            _live_window(t, page, pos_b, qlen_b, ps=ps, qblk=qblk,
-                         rep=rep, window=window),
-            mask_ref[0, 0, 0] if has_mask else None,
-            m_ref, l_ref, acc_ref, scale=scale, fp8=fp8)
+        def fetch(n):
+            copies = [pltpu.make_async_copy(_top_rows(src.at[t, b], n),
+                                            _top_rows(dst, n), psem.at[0])
+                      for src, dst in zip(parts_in, parts)]
+            for c in copies:
+                c.start()
+            for c in copies:
+                c.wait()
 
-    @pl.when(p == n_p - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, :, :1], jnp.float32(1e-30))
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        pl.when(shared > 0)(lambda: by_width(fetch))
+        pl.when(shared == 0)(lambda: by_width(virgin))
 
+    def compute(slot, blk):
+        def attend(n):
+            _attend_block(
+                q_ref[0, 0, :, :n], *_block_keys(pools, bufs, slot),
+                ks_ref[0] if has_scale else None,
+                vs_ref[0] if has_scale else None,
+                _live_block(t * qblk, blk * kb, pos_b, qlen_b, n=n,
+                            rep=rep, kb=kb, window=window,
+                            lo_key=shared * ps if grouped else None),
+                mask_ref[0, 0, 0, :, :n] if has_mask else None,
+                *(p.at[:, :n] for p in parts), scale=scale, fp8=fp8)
 
-def _grouped_phase1_kernel(tab_ref, pos_ref, qlen_ref, gid_ref,
-                           gldr_ref, gcnt_ref, q_ref, k_ref, v_ref,
-                           *rest, b, mp, ps, qblk, rep, scale,
-                           has_scale, fp8):
-    """Phase 1 of the grouped walk — grid (q_block, group x
-    shared_page): each grid step streams ONE shared page of ONE group
-    (via the group leader's page table; the index map clamps dead
-    steps so their DMA is skipped) and folds it into the
-    online-softmax partials of every MEMBER row. Non-member rows (and
-    groups with no shared span) are predicated off, so their partials
-    leave this phase exactly as they entered: (-inf, 0, 0) — the
-    virgin state phase 2 would have initialized anyway. The partials
-    accumulate in the output blocks, which stay resident in VMEM
-    across the whole (group x page) sweep of one q_block."""
-    del tab_ref, gldr_ref
-    if has_scale:
-        ks_ref, vs_ref, m_out, l_out, acc_out = rest
+        by_width(attend)
+
+    if in_place:
+        _clear_values(bufs[1])
+        _walk_paged(item, ppb, cnt, compute, pt_ref, pools, bufs, sem)
     else:
-        ks_ref = vs_ref = None
-        m_out, l_out, acc_out = rest
-    t = pl.program_id(0)
-    u = pl.program_id(1)
-    grp = u // mp
-    sp = u % mp
+        _walk_paged(item, ppb, cnt, compute)
 
-    @pl.when(u == 0)
-    def _init():
-        m_out[...] = jnp.full_like(m_out, jnp.float32(_NEG_INF))
-        l_out[...] = jnp.zeros_like(l_out)
-        acc_out[...] = jnp.zeros_like(acc_out)
+    @pl.when(k == pl.num_programs(1) - 1)
+    def _finalize():
+        def store(n):
+            l = jnp.maximum(l_ref[:, :n, :1], jnp.float32(1e-30))
+            o_ref[0, 0, :, :n] = (acc_ref[:, :n] / l).astype(o_ref.dtype)
 
-    # a step is live iff its group really has this shared page
-    @pl.when(sp < gcnt_ref[grp])
-    def _page():
-        k, v = k_ref[0], v_ref[0]
-        ks = ks_ref[0] if has_scale else None
-        vs = vs_ref[0] if has_scale else None
-        for bi in range(b):
-            pos_b = pos_ref[bi]
-            qlen_b = qlen_ref[bi]
-
-            @pl.when((gid_ref[bi] == grp) & (t * qblk < qlen_b))
-            def _member(bi=bi, pos_b=pos_b, qlen_b=qlen_b):
-                _attend_page(
-                    q_ref[bi, 0], k, v, ks, vs,
-                    _live_window(t, sp, pos_b, qlen_b, ps=ps,
-                                 qblk=qblk, rep=rep),
-                    None, m_out.at[0, bi], l_out.at[0, bi],
-                    acc_out.at[0, bi], scale=scale, fp8=fp8)
+        by_width(store)
 
 
-def _query_blocks(lq):
-    """(query block size, query blocks) a walk over lq query positions
-    a row tiles them into."""
-    qblk = min(lq, 8)
+def _grouped_phase1_kernel(gi_ref, ti_ref, pos_ref, qlen_ref, pt_ref,
+                           gid_ref, gld_ref, gcnt_ref, q_ref, *refs, b,
+                           ps, ppb, qblk, rep, scale, has_scale, fp8,
+                           in_place):
+    """Phase 1 of the grouped walk — grid ((q_block, group), key block
+    of the shared span): each grid step brings in ONE key block of ONE
+    group's shared pages (via the group leader's page table, the pages
+    of the span only) and folds it into the online-softmax partials of
+    every MEMBER row, which wait in VMEM through the item's sweep and
+    leave for HBM (a DMA a member) behind its last block. A member's
+    queries arrive the same way at its first. Rows of no sharing group
+    are never touched, here or in HBM: phase 2 starts them virgin. The
+    work items are the groups that share, so a step where none does has
+    ONE grid step, which does nothing."""
+    pools, refs = refs[:2], refs[2:]
+    ks_ref = vs_ref = None
+    if has_scale:
+        ks_ref, vs_ref = refs[:2]
+        refs = refs[2:]
+    parts_out, refs = refs[:3], refs[3:]
+    bufs, sem = (), None
+    if in_place:
+        bufs, sem, refs = refs[:2], refs[2], refs[3:]
+    cnt, q_buf, m_buf, l_buf, acc_buf, psem = refs
+    parts = (m_buf, l_buf, acc_buf)
+    i, k = pl.program_id(0), pl.program_id(1)
+    max_pages = pt_ref.shape[0] // pos_ref.shape[0]
+    kb = ppb * ps
+    rows = q_ref.shape[3]
+
+    def item(j):
+        span = gcnt_ref[gi_ref[j]]
+        return (gld_ref[gi_ref[j]] * max_pages, span > 0, 0,
+                jnp.maximum(span - 1, 0))
+
+    grp, t = gi_ref[i], ti_ref[i]
+    span = gcnt_ref[grp]
+
+    def members(fn):
+        """`fn(row, rows of its block that hold its live queries)` for
+        every row of the group with a live query in block t."""
+        def row(bi, carry):
+            left = qlen_ref[bi] - t * qblk
+            pl.when((gid_ref[bi] == grp) & (left > 0))(
+                lambda: _by_width(left, rep, rows, lambda n: fn(bi, n)))
+            return carry
+
+        jax.lax.fori_loop(0, b, row, 0)
+
+    def moves(bi, n, out):
+        if out:
+            return [pltpu.make_async_copy(_top_rows(src.at[bi], n),
+                                          _top_rows(dst.at[t, bi], n),
+                                          psem.at[0])
+                    for src, dst in zip(parts, parts_out)]
+        return [pltpu.make_async_copy(_top_rows(q_ref.at[bi, t], n),
+                                      _top_rows(q_buf.at[bi], n),
+                                      psem.at[0])]
+
+    def start(bi, n, out):
+        for c in moves(bi, n, out):
+            c.start()
+
+    def wait(bi, n, out):
+        for c in moves(bi, n, out):
+            c.wait()
+
+    @pl.when((k == 0) & (span > 0))
+    def _arrive():
+        members(lambda bi, n: start(bi, n, False))
+        members(lambda bi, n: _virgin(*(p.at[bi, :, :n] for p in parts)))
+        members(lambda bi, n: wait(bi, n, False))
+
+    def compute(slot, blk):
+        def attend(bi, n):
+            _attend_block(
+                q_buf[bi, :, :n], *_block_keys(pools, bufs, slot),
+                ks_ref[0] if has_scale else None,
+                vs_ref[0] if has_scale else None,
+                _live_block(t * qblk, blk * kb, pos_ref[bi], qlen_ref[bi],
+                            n=n, rep=rep, kb=kb, hi_key=span * ps),
+                None, *(p.at[bi, :, :n] for p in parts), scale=scale,
+                fp8=fp8)
+
+        members(attend)
+
+    if in_place:
+        _clear_values(bufs[1])
+        _walk_paged(item, ppb, cnt, compute, pt_ref, pools, bufs, sem)
+    else:
+        _walk_paged(item, ppb, cnt, compute)
+
+    @pl.when((span > 0) & (k == (span - 1) // ppb))
+    def _leave():
+        members(lambda bi, n: start(bi, n, True))
+        members(lambda bi, n: wait(bi, n, True))
+
+
+# keys a grid step of the walk takes (whole pages of them), the rows of
+# one kv head's matmuls a query block may have, and the rows the narrow
+# form of a block computes over (one bf16 tile of sublanes)
+K_BLOCK = 256
+_Q_ROWS = 256
+_NARROW_ROWS = 16
+_VMEM_LIMIT = 96 * 1024 * 1024
+
+
+def _query_blocks(lq, rep=1):
+    """(query block size, query blocks) a walk tiles a row's lq query
+    positions into, where `rep` query heads share a kv head: the largest
+    power of two of queries whose rows in one kv head's matmuls (a query
+    and head each) stay within `_Q_ROWS`. A chunk of 128 is ONE block
+    where every head has its own kv head, 32 or 16 queries where six or
+    nine share one."""
+    cap = max(1, _Q_ROWS // rep)
+    qblk = min(lq, 1 << (cap.bit_length() - 1))
     return qblk, -(-lq // qblk)
 
 
-def walk_grid_bounds(pos, q_len, *, lq, page_size, max_pages, xp=jnp):
+def _key_blocks(page_size, max_pages):
+    """(pages a key block, key blocks a row of max_pages pages)."""
+    ppb = max(1, min(K_BLOCK // page_size, max_pages))
+    return ppb, -(-max_pages // ppb)
+
+
+def _live_query_blocks(q_len, qblk, nqb, xp=jnp):
+    """The step's live (row, query block) pairs, at least 1: the work
+    items of a walk's grid."""
+    return xp.maximum(xp.sum(
+        xp.minimum((q_len + qblk - 1) // qblk, nqb)), 1)
+
+
+def _key_block_bound(pos, q_len, kb, n_blocks, xp=jnp):
+    """The blocks of kb keys of the longest live context, from 1 to
+    n_blocks: a block at or past it lies beyond every row's causal
+    horizon."""
+    return xp.clip(xp.max(xp.where(
+        q_len > 0, (pos + q_len - 1) // kb + 1, 1)), 1, n_blocks)
+
+
+def walk_grid_bounds(pos, q_len, *, lq, rep, page_size, max_pages,
+                     xp=jnp):
     """The two dynamic bounds of a full-attention walk's grid, from
-    what the step's rows ask: (the q-blocks of the row with most live
-    queries, the pages of the longest live context), each at least 1.
-    A q-block at or past the first holds dead queries only, and a page
-    at or past the second lies beyond every row's causal horizon, so
-    the grid steps the bounds remove were predicated off. One
+    what the step's rows ask: (its live work items, the key blocks of
+    the longest live context), each at least 1. A (row, query block)
+    pair that is no work item holds dead queries only, and a key block
+    at or past the second bound lies beyond every row's causal horizon,
+    so the grid steps the bounds remove would have done nothing. One
     expression for the traced wrapper (`xp=jnp`, int32 [B] operands of
     the compiled step) and for the host's count of the same step
     (`xp=np`, `count_walk_grid_steps`): they cannot drift."""
-    qblk, nqb = _query_blocks(lq)
-    n_qblk = xp.clip(xp.max((q_len + qblk - 1) // qblk), 1, nqb)
-    n_pages = xp.clip(xp.max(xp.where(
-        q_len > 0, (pos + q_len - 1) // page_size + 1, 1)), 1, max_pages)
-    return n_qblk, n_pages
+    qblk, nqb = _query_blocks(lq, rep)
+    ppb, n_blocks = _key_blocks(page_size, max_pages)
+    return (_live_query_blocks(q_len, qblk, nqb, xp),
+            _key_block_bound(pos, q_len, ppb * page_size, n_blocks, xp))
 
 
 def _zero_dead_queries(out, q_len):
     """out [B, lq, H, D] with the queries at or past q_len[b] zeroed:
-    the grid never writes the q-blocks past its bound."""
+    the grid never writes the q-blocks of no work item."""
     alive = jnp.arange(out.shape[1], dtype=jnp.int32)[None, :] \
         < q_len[:, None]
     return jnp.where(alive[:, :, None, None], out,
@@ -543,34 +822,65 @@ def _ragged_attention_kernel(q, k_pool, v_pool, page_table, pos, q_len,
         specs["k_scale"] = specs["v_scale"] = P(None, None, "heads")
     if group is not None:
         ops["group"], specs["group"] = tuple(group), (rows, rows, rows)
-    extra = {} if window is None else {"window": window}
+    # what a trace of the walk reads beside its operands (tests set
+    # them): a trace made under other values must not be reused
+    extra = {"window": window,
+             "traced_for": (K_BLOCK, _Q_ROWS, _INTERPRET)}
     return _per_device(
         lambda o: _ragged_attention_local(**{"mask": None, **o}, **extra),
         (specs,), heads)(ops)
 
 
+def _row_view(pool, page_table, n_keys):
+    """[P, ps, ...] pages -> each row's in position order
+    [B, n_keys, ...], zeros behind the table's last page: how the walk
+    takes what Mosaic cannot cut a page of out of HBM (it cuts HBM by
+    whole tiles of 128 lanes), a block of keys a grid step."""
+    g = jnp.take(pool, page_table, axis=0)
+    g = g.reshape((g.shape[0], -1) + pool.shape[2:])
+    return jnp.pad(g, ((0, 0), (0, n_keys - g.shape[1]))
+                   + ((0, 0),) * (pool.ndim - 2))
+
+
+def _walk_scratch(pools, ppb):
+    """The scratch every kernel over `_walk_paged` leads with: for
+    `pools` read in place a two-slot buffer of ppb pages each and a DMA
+    semaphore a pool and slot; the count of the steps that computed."""
+    in_place = [pltpu.VMEM((2, ppb) + p.shape[1:], p.dtype) for p in pools]
+    if pools:
+        in_place.append(pltpu.SemaphoreType.DMA((len(pools), 2)))
+    return in_place + [pltpu.SMEM((1,), jnp.int32)]
+
+
+@functools.partial(jax.jit, static_argnames=("window", "traced_for"))
 def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
                             mask, k_scale=None, v_scale=None,
-                            group=None, window=None):
+                            group=None, window=None, traced_for=None):
     """q [B, lq, H, D]; pools [P, ps, H_kv, D]; page_table
     [B, max_pages] int32; pos/q_len [B] int32; mask None | additive f32
     [B, H, lq, lmax]. lq is padded up to a multiple of the query block
     so the grid tiles evenly; padded queries are dead by q_len.
     k_scale/v_scale (int8 lane): rowwise dequant scale pages
-    [P, ps, H_kv] f32 streamed next to the int8 code pools — dequant
+    [P, ps, H_kv] f32 brought in next to the int8 code pages — dequant
     fuses into the in-VMEM compute.
 
-    POOL LAYOUT AND BLOCKS. Mosaic requires the last two dimensions of
-    a block to be (8, 128)-divisible or to span the array's, so a page
-    block cannot take one head out of the pool's [H_kv, D] minor
-    dimensions. The pool keeps its [P, ps, H_kv, D] layout (one
-    decision for the fp, int8, fp8 and grouped lanes, the scatter
-    write, COW/swap/PKVF frames and the tensor-parallel head shard)
-    and a grid step streams the WHOLE page — block (1, ps, H_kv, D),
-    scale block (1, ps, H_kv) — with the kv heads as the batch
-    dimension of the in-kernel dots. Queries are regrouped outside the
-    kernel to [B, n_qblk, H_kv, qblk * rep, D] so a block's minor
-    dimensions are whole too.
+    POOL LAYOUT AND BLOCKS. The pool keeps its [P, ps, H_kv, D] layout
+    (one decision for the fp, int8, fp8 and grouped lanes, the scatter
+    write, COW/swap/PKVF frames and the tensor-parallel head shard) and
+    stays in HBM: a grid step's key block is `K_BLOCK` keys, whole
+    pages each with ALL its kv heads, copied into VMEM a page a DMA
+    through the page table and double-buffered under the step before
+    (`_walk_paged`). The kv heads are the batch dimension of the
+    in-kernel dots, over the block relaid [H_kv, keys, D] in VMEM.
+    Queries are regrouped outside the kernel to
+    [B, n_qblk, H_kv, qblk * rep, D]: a query block (`_query_blocks`,
+    from lq and the heads a kv head serves) is as wide as a kv head's
+    matmul wants rows, so a chunk's context is read once where it used
+    to be read once for every 8 queries. What Mosaic cannot cut a page
+    out of (it cuts HBM by whole tiles of 128 lanes: heads narrower
+    than that, the int8 lane's [ps, H_kv] scale pages) reaches the
+    same kernel as each row's gathered view, a block of `K_BLOCK` keys
+    a grid step through a BlockSpec (`_row_view`).
 
     group = (group_id, group_leader, group_cnt) selects the grouped
     two-phase walk (see the module doc). Operand contract
@@ -580,35 +890,45 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
     (shared pages hold committed KV). group_leader[g] names a member
     row whose table phase 1 walks; singleton rows ride with group_cnt
     0 and take phase 2 only, which is exactly the ungrouped walk. On a
-    step where every group_cnt is 0 phase 1 shrinks to one grid step a
-    q_block (`_grouped_phase1`).
+    step where every group_cnt is 0 phase 1 shrinks to one grid step
+    (`_grouped_phase1`).
 
-    window (a sliding-window layer; None for full attention, which
-    leaves this function's program as it was): query i of row b sees
-    keys pos + i - window < j <= pos + i. The grid's page axis shrinks
-    to `_window_pages` steps, counted from the page that holds the
-    block's lowest visible key (`_window_first_page`), so a page wholly
-    below the window is neither fetched nor computed, and the partial
-    page at the edge is masked in `_live_window`. The page table may be
-    a ring over fewer physical pages than it has columns (the serving
-    engine's table for such layers is): only the pages of the window
-    are ever read. Neither groups nor a user mask combine with it.
+    window (a sliding-window layer; None for full attention): query i
+    of row b sees keys pos + i - window < j <= pos + i. The grid's
+    key-block axis shrinks to `_window_blocks` steps, counted from the
+    block that holds the query block's lowest visible key
+    (`_window_first_page`), so a page wholly below the window is
+    neither fetched nor computed, and the partial block at the edge is
+    masked in `_live_block`. The page table may be a ring over fewer
+    physical pages than it has columns (the serving engine's table for
+    such layers is): only the pages of the window are ever read.
+    Neither groups nor a user mask combine with it.
 
     The grid is as long as the step's rows ask, never as long as the
-    step's SHAPE allows: a predicated-off grid step still costs a grid
-    step (0.12-0.16 us on a v5e; PERF.md section 6, PR 28 and 30), and
-    8 rows x 16 q-blocks x 128 pages are 16384 of them a layer where a
-    step of decode rows needs a few hundred. So the q-block and page
-    axes are DYNAMIC bounds (`walk_grid_bounds`): the q-blocks of the
-    row with most live queries and the pages of the longest live
-    context (of the window, statically, in a window layer), decided
-    inside the one compiled step from `pos` and `q_len`. Groups, a
-    user mask and the int8 / fp8 lanes take the same bounds: a page
-    past the longest context is past every row's causal horizon
-    whatever else selects pages, and phase 1 of the grouped walk runs
-    over the same q-blocks, so phase 2 never reads a partial phase 1
-    did not write. The q-blocks past the bound are never written, so
-    the dead queries' outputs are zeroed after the call."""
+    step's SHAPE allows (a grid step with nothing to do still costs a
+    grid step; PERF.md section 6, PR 28 and 30): its first axis runs
+    over the step's LIVE (row, query block) pairs, which ride in as
+    scalar-prefetch operands with the live ones first (`_work_items`),
+    its second over the key blocks of the longest live context (of the
+    window, statically, in a window layer). Both are DYNAMIC bounds
+    (`walk_grid_bounds`), decided inside the one compiled step from
+    `pos` and `q_len`. Groups, a user mask and the int8 / fp8 lanes
+    take the same bounds. The query blocks of no work item are never
+    written, so the dead queries' outputs are zeroed after the call.
+
+    ROWS WITH FEW LIVE QUERIES do not pay a chunk's rows: a query block
+    whose live queries fit `_NARROW_ROWS` rows of a kv head's matmul (a
+    decoding row's one query always; the 2-16 of a verify row where
+    every head has its own kv head, fewer where heads share one) runs
+    the same kernel body over those rows alone, chosen in the kernel
+    from q_len (`_by_width`); its block of the output holds nothing
+    else that lives.
+
+    A program of its own inside the step's (`jax.jit`): a model's
+    layers call it with the same shapes, so JAX traces and lowers the
+    walk once a step program and not once a layer, which is most of
+    what a serving cell's set-up waits for (PERF.md section 6)."""
+    del traced_for
     if window is not None and (group is not None or mask is not None):
         raise NotImplementedError(
             "the page walk of a sliding-window layer takes neither "
@@ -618,175 +938,185 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
     mp = page_table.shape[1]
     rep = h // hkv
     scale = 1.0 / math.sqrt(d)
-    qblk, nqb = _query_blocks(lq)
+    qblk, nqb = _query_blocks(lq, rep)
+    ppb, n_blocks = _key_blocks(ps, mp)
+    kb = ppb * ps
     lq_pad = nqb * qblk
-    rows = qblk * rep
+    # a query block's rows, in whole tiles of sublanes (Mosaic cuts HBM
+    # by whole tiles, and phase 1 brings a member's queries in by DMA):
+    # more than qblk * rep only where lq is 1 or odd
+    rows = -(-qblk * rep // _NARROW_ROWS) * _NARROW_ROWS
+    pad_rows = ((0, 0),) * 3 + ((0, rows - qblk * rep),)
     if lq_pad != lq:
         padq = jnp.zeros((b, lq_pad - lq, h, d), q.dtype)
         q = jnp.concatenate([q, padq], axis=1)
-        if mask is not None:
-            padm = jnp.zeros((b, h, lq_pad - lq, mp * ps), jnp.float32)
-            mask = jnp.concatenate([mask, padm], axis=2)
-    q5 = q.reshape(b, nqb, qblk, hkv, rep, d) \
-        .transpose(0, 1, 3, 2, 4, 5).reshape(b, nqb, hkv, rows, d)
+    q5 = jnp.pad(q.reshape(b, nqb, qblk, hkv, rep, d)
+                 .transpose(0, 1, 3, 2, 4, 5)
+                 .reshape(b, nqb, hkv, qblk * rep, d), pad_rows + ((0, 0),))
     has_scale = k_scale is not None
     grouped = group is not None
     fp8 = _is_fp8(k_pool.dtype)
-    prefetch = (page_table, pos, q_len) + (tuple(group) if grouped
-                                           else ())
+    # the pools are read in place where Mosaic can cut a page out of
+    # them: it cuts HBM by whole tiles of 128 lanes, so heads narrower
+    # than that, and the int8 lane's [ps, H_kv] f32 scale pages (1/32
+    # of the bytes the code pages are), come as each row's gathered
+    # view instead (`_row_view`), a block of kb keys a grid step
+    in_place = d % _LANES == 0
+    if not in_place:
+        # a row's view holds its group's shared pages anyway: phase 1
+        # has nothing to save (and its queries could not be cut either)
+        group, grouped = None, False
+    pools = [k_pool, v_pool]
+    views = ([] if in_place else pools) + (
+        [k_scale, v_scale] if has_scale else [])
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    item = functools.partial(_walk_item, ps=ps, qblk=qblk, grouped=grouped,
+                             window=window)
 
-    def live_page(bi, t, p, tab, posr, qlr, *grp):
-        # clamp dead steps (block-dead rows, pages past the block's
-        # causal horizon and — grouped — pages below the row's shared
-        # span, which is phase-1 territory) to a live page: unchanged
-        # block index, no re-fetch, compute predicated off in-kernel
-        last_qi = jnp.minimum((t + 1) * qblk, qlr[bi]) - 1
-        lp = jnp.clip((posr[bi] + last_qi) // ps, 0, mp - 1)
-        lo = 0
-        if grouped:
-            gid, _, gcn = grp
-            lo = jnp.minimum(gcn[gid[bi]], lp)
-        if window is not None:
-            # the walk starts at the window's first page
-            lo = jnp.minimum(_window_first_page(
-                posr[bi], t, ps=ps, qblk=qblk, window=window), lp)
-            p = p + lo
-        return tab[bi, jnp.clip(p, lo, lp)]
+    def q_idx(i, k, ib, it, *_):
+        return (ib[i], it[i], 0, 0, 0)
 
-    def kv_idx(bi, t, p, *pre):
-        return (live_page(bi, t, p, *pre), 0, 0, 0)
+    def block(i, k, *pre):
+        # the key block grid step (i, k) stands for, held at the item's
+        # last where the step has nothing to do: an unchanged block
+        # index, so nothing is fetched again
+        row, t, _, lo, hi = item(i, pre)
+        return row, t, jnp.minimum(lo // ppb + k, hi // ppb)
 
-    def sc_idx(bi, t, p, *pre):
-        # int8 lane: the scale pages chase the SAME clamped page-table
-        # walk as the code pages, so dead grid steps skip their DMA too
-        return (live_page(bi, t, p, *pre), 0, 0)
+    def view_spec(view):
+        def idx(*a):
+            row, _, blk = block(*a)
+            return (row, blk) + (0,) * (view.ndim - 2)
+        return pl.BlockSpec((1, kb) + view.shape[2:], idx)
 
-    q_spec = pl.BlockSpec((1, 1, hkv, rows, d),
-                          lambda bi, t, p, *_: (bi, t, 0, 0, 0))
-    in_specs = [q_spec,
-                pl.BlockSpec((1, ps, hkv, d), kv_idx),
-                pl.BlockSpec((1, ps, hkv, d), kv_idx)]
-    ops = [q5, k_pool, v_pool]
-    if has_scale:
-        ops.extend([k_scale, v_scale])
-        in_specs.extend([pl.BlockSpec((1, ps, hkv), sc_idx),
-                         pl.BlockSpec((1, ps, hkv), sc_idx)])
+    views = [_row_view(v, page_table, n_blocks * kb) for v in views]
+    q_spec = pl.BlockSpec((1, 1, hkv, rows, d), q_idx)
+    in_specs = [q_spec] + ([hbm, hbm] if in_place else []) \
+        + [view_spec(v) for v in views]
+    ops = [q5] + (pools if in_place else []) + views
     if mask is not None:
-        # [B, H, lq, lmax] -> [B, n_qblk, max_pages, H_kv, rows, ps]:
-        # one block per (row, q_block, page), its minor dims whole and
-        # its rows in the kernel's (qblk, rep) score order
-        m7 = mask.reshape(b, hkv, rep, nqb, qblk, mp, ps)
-        ops.append(m7.transpose(0, 3, 5, 1, 4, 2, 6)
-                   .reshape(b, nqb, mp, hkv, rows, ps))
+        # [B, H, lq, lmax] -> [B, n_qblk, key blocks, H_kv, rows, kb]:
+        # one block per (row, q_block, key block), its minor dims whole
+        # and its rows in the kernel's (qblk, rep) score order
+        mask = jnp.pad(mask, ((0, 0), (0, 0), (0, lq_pad - lq),
+                              (0, n_blocks * kb - mp * ps)))
+        m7 = mask.reshape(b, hkv, rep, nqb, qblk, n_blocks, kb)
+        ops.append(jnp.pad(
+            m7.transpose(0, 3, 5, 1, 4, 2, 6)
+            .reshape(b, nqb, n_blocks, hkv, qblk * rep, kb),
+            ((0, 0),) + pad_rows + ((0, 0),)))
         in_specs.append(pl.BlockSpec(
-            (1, 1, 1, hkv, rows, ps),
-            lambda bi, t, p, *_: (bi, t, p, 0, 0, 0)))
+            (1, 1, 1, hkv, rows, kb), lambda *a: block(*a) + (0, 0, 0)))
+    part_shapes = [pltpu.VMEM((hkv, rows, w), jnp.float32)
+                   for w in (_LANES, _LANES, d)]
     with _trace32():
-        n_qblk, n_pages = walk_grid_bounds(
-            pos, q_len, lq=lq, page_size=ps, max_pages=mp)
+        ib, it = _work_items(q_len, qblk, nqb)
+        n_items, n_kblk = walk_grid_bounds(
+            pos, q_len, lq=lq, rep=rep, page_size=ps, max_pages=mp)
         if window is not None:
-            n_pages = min(mp, _window_pages(window, qblk, ps))
+            n_kblk = min(n_blocks, _window_blocks(window, qblk, kb))
+        prefetch = (ib, it, pos, q_len, page_table.reshape(-1))
+        scratch = _walk_scratch(pools if in_place else [], ppb) \
+            + part_shapes
         if grouped:
+            gid, _, gcn = group
+            prefetch += (gid, gcn)
             ops.extend(_grouped_phase1(
-                prefetch, ops, n_qblk, b=b, mp=mp, ps=ps, hkv=hkv, d=d,
-                qblk=qblk, nqb=nqb, rep=rep, scale=scale,
+                q5, pools if in_place else [], views, page_table, pos,
+                q_len, group, ps=ps, qblk=qblk, rep=rep, scale=scale,
                 has_scale=has_scale, fp8=fp8))
-            in_specs.extend(
-                pl.BlockSpec((1, 1, hkv, rows, w),
-                             lambda bi, t, p, *_: (t, bi, 0, 0, 0))
-                for w in (_LANES, _LANES, d))
+            in_specs.extend([hbm] * 3)
+            scratch.append(pltpu.SemaphoreType.DMA((1,)))
         kernel = functools.partial(
-            _ragged_kernel, ps=ps, qblk=qblk, rep=rep, scale=scale,
-            has_mask=mask is not None, has_scale=has_scale, fp8=fp8,
-            grouped=grouped,
+            _ragged_kernel, ps=ps, ppb=ppb, qblk=qblk, rep=rep,
+            scale=scale, has_mask=mask is not None, has_scale=has_scale,
+            fp8=fp8, grouped=grouped, in_place=in_place,
             **({} if window is None else {"window": window}))
         out = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(prefetch),
-                grid=(b, n_qblk, n_pages),
+                grid=(n_items, n_kblk),
                 in_specs=in_specs,
                 out_specs=q_spec,
-                scratch_shapes=[
-                    pltpu.VMEM((hkv, rows, _LANES), jnp.float32),
-                    pltpu.VMEM((hkv, rows, _LANES), jnp.float32),
-                    pltpu.VMEM((hkv, rows, d), jnp.float32),
-                ]),
+                scratch_shapes=scratch),
             out_shape=jax.ShapeDtypeStruct(q5.shape, q.dtype),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary",
-                                     "arbitrary")),
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT),
             interpret=_INTERPRET,
             **KERNELS["ragged_walk"],
         )(*prefetch, *ops)
-    out = out.reshape(b, nqb, hkv, qblk, rep, d) \
+    out = out[:, :, :, :qblk * rep].reshape(b, nqb, hkv, qblk, rep, d) \
         .transpose(0, 1, 3, 2, 4, 5).reshape(b, lq_pad, h, d)[:, :lq]
     return _zero_dead_queries(out, q_len)
 
 
-def _grouped_phase1(prefetch, ops, n_qblk, *, b, mp, ps, hkv, d, qblk,
-                    nqb, rep, scale, has_scale, fp8):
-    """Run phase 1 of the grouped walk over `ops` (q5, pools and, on
-    the int8 lane, scale pools — the operands phase 2 takes too) and
-    return the per-row partials (m, l, acc), each
-    [nqb, B, H_kv, qblk * rep, 128 | D] f32.
+def _grouped_phase1(q5, pools, views, page_table, pos, q_len, group, *,
+                    ps, qblk, rep, scale, has_scale, fp8):
+    """Run phase 1 of the grouped walk over q5, the `pools` read in
+    place and the rows' `views` of what is not (the operands phase 2
+    takes too) and return the per-row partials (m, l, acc), each
+    [nqb, B, H_kv, rows, 128 | D] f32, in HBM: written for the rows of a
+    group that shares and nowhere else (phase 2 reads no other).
 
-    Both axes of the grid are dynamic bounds. The q-block axis is
-    `n_qblk`, the walk proper's own bound (`walk_grid_bounds`, computed
-    once by the caller and used by both phases): the partials of the
-    q-blocks at or past it are never written, and phase 2, over the
-    same q-blocks, never reads them. The (group x page) axis is B * mp
-    steps when some group has a shared span, ONE when none has. That
-    one step is predicated off like every step of a sweep with nothing
-    to do, after its `_init` has written the virgin partials, so the
-    results are the full sweep's bit for bit, without its B * mp - 1
-    idle grid steps a q_block (0.12 us each on a v5e: a third of the
-    serving step's device time where nothing is shared, PERF.md §6)."""
-    rows = qblk * rep
-    *_, gcn = prefetch
-    sweep = jnp.where(jnp.any(gcn > 0), b * mp, 1)
-
-    def shared_page(t, u, tab, posr, qlr, gid, gld, gcn):
-        # shared page sp of group grp via the LEADER's page table;
-        # dead steps (groups with fewer shared pages, or none) clamp
-        # to the last live shared page — unchanged block index, DMA
-        # skipped — and empty groups to the trash page 0
-        grp = u // mp
-        cnt = gcn[grp]
-        live = jnp.clip(u % mp, 0, jnp.maximum(cnt - 1, 0))
-        return jnp.where(cnt > 0, tab[gld[grp], live], 0)
-
-    kv_spec = pl.BlockSpec(
-        (1, ps, hkv, d), lambda t, u, *pre: (shared_page(t, u, *pre),
-                                             0, 0, 0))
-    in_specs = [pl.BlockSpec((b, 1, hkv, rows, d),
-                             lambda t, u, *_: (0, t, 0, 0, 0)),
-                kv_spec, kv_spec]
-    if has_scale:
-        sc_spec = pl.BlockSpec(
-            (1, ps, hkv), lambda t, u, *pre: (shared_page(t, u, *pre),
-                                              0, 0))
-        in_specs.extend([sc_spec, sc_spec])
+    Both axes of the grid are dynamic bounds. The work items are the
+    (q-block, group) pairs of the groups that share, over the q-blocks
+    of the row with most live queries; the key-block axis is as long as
+    the longest shared span. On a step where no group shares that is
+    ONE grid step, which does nothing: no query, page or partial moves
+    (PERF.md §6: an idle sweep was a third of the serving step's device
+    time once)."""
+    b, nqb, hkv, rows, d = q5.shape
+    mp = page_table.shape[1]
+    ppb, n_blocks = _key_blocks(ps, mp)
+    gid, gld, gcn = group
+    shares = gcn > 0
+    # the groups that share first, in order, as `_work_items` puts the
+    # live query blocks first
+    order = jnp.argsort(jnp.logical_not(shares), stable=True) \
+        .astype(jnp.int32)
+    n_groups = jnp.maximum(jnp.sum(shares, dtype=jnp.int32), 1)
+    n_qblk = jnp.clip(jnp.max((q_len + qblk - 1) // qblk), 1, nqb)
+    slot = jnp.arange(nqb * b, dtype=jnp.int32)
+    prefetch = (order[slot % n_groups], slot // n_groups, pos, q_len,
+                page_table.reshape(-1), gid, gld, gcn)
+    n_kblk = jnp.clip(jnp.max((gcn + ppb - 1) // ppb), 1, n_blocks)
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     widths = (_LANES, _LANES, d)
+
+    def view_spec(view):
+        # a group's block of a view: its leader's, held at the span's
+        # last block
+        def idx(i, k, gi, ti, posr, qlr, pt, gid, gld, gcn):
+            last = jnp.maximum(gcn[gi[i]] - 1, 0) // ppb
+            return (gld[gi[i]], jnp.minimum(k, last)) \
+                + (0,) * (view.ndim - 2)
+        return pl.BlockSpec((1, ppb * ps) + view.shape[2:], idx)
+
     return pl.pallas_call(
         functools.partial(
-            _grouped_phase1_kernel, b=b, mp=mp, ps=ps, qblk=qblk,
-            rep=rep, scale=scale, has_scale=has_scale, fp8=fp8),
+            _grouped_phase1_kernel, b=b, ps=ps, ppb=ppb, qblk=qblk,
+            rep=rep, scale=scale, has_scale=has_scale, fp8=fp8,
+            in_place=bool(pools)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6,
-            grid=(n_qblk, sweep),
-            in_specs=in_specs,
-            out_specs=[pl.BlockSpec((1, b, hkv, rows, w),
-                                    lambda t, u, *_: (t, 0, 0, 0, 0))
-                       for w in widths]),
+            num_scalar_prefetch=len(prefetch),
+            grid=(n_qblk * n_groups, n_kblk),
+            in_specs=[hbm] * (1 + len(pools))
+            + [view_spec(v) for v in views],
+            out_specs=[hbm] * 3,
+            scratch_shapes=_walk_scratch(pools, ppb) + [
+                pltpu.VMEM((b, hkv, rows, d), q5.dtype)] + [
+                pltpu.VMEM((b, hkv, rows, w), jnp.float32)
+                for w in widths] + [pltpu.SemaphoreType.DMA((1,))]),
         out_shape=[jax.ShapeDtypeStruct((nqb, b, hkv, rows, w),
                                         jnp.float32) for w in widths],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_INTERPRET,
         **KERNELS["grouped_phase1"],
-    )(*prefetch, *ops)
-
+    )(*prefetch, q5, *pools, *views)
 
 
 def gqa_attend_reference(q, k, v, mask):
@@ -1545,28 +1875,36 @@ def count_window_page_reads(pos, q_len, *, page_size, window):
             int(np.where(live, last + 1, 0).sum()))
 
 
-def count_walk_grid_steps(pos, q_len, *, lq, page_size, max_pages):
+def count_walk_grid_steps(pos, q_len, *, lq, rep, page_size, max_pages):
     """Host-side (numpy) count of one full-attention layer's walk over
     one step: (grid steps its dynamically bounded grid has, grid steps
-    the grid the step's shape alone would give has). The bounds are
-    `walk_grid_bounds`, the expression the compiled step evaluates on
-    the same `pos` and `q_len`."""
+    the grid the step's shape alone would give has). A grid step is one
+    (row, query block) pair over one key block of `K_BLOCK` keys, the
+    query block sized from lq and `rep`, the query heads a kv head
+    serves (`_query_blocks`). The bounds are `walk_grid_bounds`, the
+    expression the compiled step evaluates on the same `pos` and
+    `q_len`."""
     pos = np.asarray(pos, np.int64)
     q_len = np.asarray(q_len, np.int64)
-    n_qblk, n_pages = walk_grid_bounds(
-        pos, q_len, lq=lq, page_size=page_size, max_pages=max_pages,
-        xp=np)
-    rows = int(q_len.shape[0])
-    return (rows * int(n_qblk) * int(n_pages),
-            rows * _query_blocks(lq)[1] * int(max_pages))
+    n_items, n_kblk = walk_grid_bounds(
+        pos, q_len, lq=lq, rep=rep, page_size=page_size,
+        max_pages=max_pages, xp=np)
+    return (int(n_items) * int(n_kblk),
+            int(q_len.shape[0]) * _query_blocks(lq, rep)[1]
+            * _key_blocks(page_size, max_pages)[1])
 
 
 def count_page_block_reads(page_table, pos, q_len, group_id=None,
                            group_cnt=None, *, page_size, n_kv=1,
                            mp=1, fused=None):
-    """Host-side (numpy) model of the kernels' page-block DMA traffic
-    for ONE (kv_head, layer) walk — the number the serving metrics and
-    the `--prefix-share` bench A/B report, and what tests pin.
+    """Host-side (numpy) model of the kernels' page DMA traffic for ONE
+    (kv_head, layer) walk, in PAGES (a key block of the walk is several
+    pages, each its own DMA: the unit here is the page, as it always
+    was) — the number the serving metrics and the `--prefix-share`
+    bench A/B report, and what tests pin. A row whose chunk is more
+    than one query block (heads that share a kv head) reads its pages
+    once a query block; the model counts them once a row, as it did
+    when every 8 queries re-read them.
 
     Per live row (q_len > 0) the ungrouped walk streams its pages
     0..floor((pos + q_len - 1)/page_size); the grouped walk streams
@@ -1580,8 +1918,8 @@ def count_page_block_reads(page_table, pos, q_len, group_id=None,
     model's `n_kv` and the mesh's `mp` degree and the counts become
     what ONE CHIP issues per layer — each of the mp shards walks only
     its n_kv/mp local heads (the heads are the batch dimension of
-    the in-kernel dots; the count stays per head though one block now
-    carries a page's local heads together), and each block read moves
+    the in-kernel dots; the count stays per head though one DMA
+    carries a page's local heads together), and each page read moves
     a 1/mp page slice, so per-chip
     reads (and the grouped walk's per-chip reads SAVED) drop by mp.
     The defaults (n_kv=1, mp=1) keep the single-walk numbers every

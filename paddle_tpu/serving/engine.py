@@ -463,6 +463,12 @@ class ServingEngine:
                            if w is not None}
         # the latent kind: one pool a layer, no V (the contract above)
         self.kv_latent = len(cache_spec) > 4
+        # query heads a kv head serves in a full-attention layer: the
+        # page walk sizes its query block from it, and the host counts
+        # that walk's grid (`count_walk_grid_steps`)
+        self._walk_rep = max(1, int(getattr(
+            getattr(model, "config", None), "num_attention_heads",
+            self.n_kv)) // self.n_kv)
         if self.kv_latent and (self.kv_windows or self.n_kv != 1
                                or cache_spec[4] != "latent"):
             raise ValueError(
@@ -2659,8 +2665,8 @@ class ServingEngine:
                 self._host_phases[name] += count * self.n_layers
         else:
             steps, full = count_walk_grid_steps(
-                pos_host, q_len, lq=W, page_size=self.page_size,
-                max_pages=self.max_pages)
+                pos_host, q_len, lq=W, rep=self._walk_rep,
+                page_size=self.page_size, max_pages=self.max_pages)
             self._host_phases["walk_grid_steps_total"] += steps
             self._host_phases["walk_grid_steps_full_total"] += full
         for window in self.kv_windows.values():

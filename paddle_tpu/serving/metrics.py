@@ -299,8 +299,8 @@ class ServingMetrics:
         # issues (CPU-reference count, one (layer, kv-head) sweep per
         # step), and how many reads grouping saved vs the flat walk
         # (flat - grouped; 0 with grouping off), and the steps whose
-        # group_cnt had a non-zero entry: those on which the compiled
-        # step runs the whole sweep of the walk's phase 1
+        # group_cnt had a non-zero entry: those on which the walk's
+        # phase 1 has a sharing group to serve (one idle grid step else)
         self.grouped: Optional[bool] = None
         self.page_block_reads = 0
         self.shared_page_reads_saved = 0

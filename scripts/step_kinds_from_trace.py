@@ -8,10 +8,11 @@ A `--trace 1` run of a serving cell leaves its trace under
 event an executed program; the `XLA Ops` inside its interval are summed
 into: busy ms (union), ms under `ptk:ragged_walk` and under
 `ptk:grouped_phase1`. A step is taken to HOLD A CHUNK where its walk is
-more than `CHUNK_FACTOR` times the median step's (the walk's grid is 1
-q-block on a step of decode rows and up to 16 on a step with a chunk);
-the split is by what it measures, so read the lists, not only the two
-medians. Needs no chip: run it with JAX_PLATFORMS=cpu.
+more than `CHUNK_FACTOR` times the median step's (a decoding row's
+query block computes over 16 rows of its key blocks, a chunk's over all
+its rows); the split is by what it measures, so read the lists, not only
+the two medians: where every step holds a chunk (`docs_backlog`) the
+median step is one of them and the split says nothing. Needs no chip: run it with JAX_PLATFORMS=cpu.
 """
 import bisect
 import json

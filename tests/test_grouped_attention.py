@@ -118,11 +118,15 @@ class TestGroupedKernel:
     @pytest.fixture(autouse=True)
     def _interpret(self, monkeypatch):
         monkeypatch.setattr(pa, "_INTERPRET", True)
+        # key blocks of two pages of 8: the shared spans here (two
+        # pages) end on a block's edge, where the grouped walk folds
+        # the same blocks in the same order as the ungrouped one
+        monkeypatch.setattr(pa, "K_BLOCK", 16)
 
     @pytest.mark.parametrize("rep", [1, 2])
     def test_matches_reference_and_ungrouped_bit_identical(self, rep):
         rng = np.random.RandomState(rep)
-        ps, mp, hkv, d = 8, 6, 2, 16
+        ps, mp, hkv, d = 8, 6, 2, 128    # heads of 128: pools read in place
         kp, vp, pt, pos, q_len, gid, gld, gcnt = build_shared(
             rng, ps, mp, hkv, d, n_shared=2, members=3, extra=2)
         h = hkv * rep
@@ -148,7 +152,7 @@ class TestGroupedKernel:
         ungrouped walk: phase 1 touches nothing, phase 2 starts from
         the virgin partials at page 0."""
         rng = np.random.RandomState(3)
-        ps, mp, hkv, d = 8, 5, 2, 16
+        ps, mp, hkv, d = 8, 5, 2, 128    # heads of 128: pools read in place
         kp, vp, pt, pos, q_len, *_ = build_shared(
             rng, ps, mp, hkv, d, n_shared=0, members=0, extra=4)
         lq = int(q_len.max())
@@ -166,40 +170,56 @@ class TestGroupedKernel:
             np.testing.assert_array_equal(grp[r, :ql], ung[r, :ql])
 
     @pytest.mark.parametrize("lane", ["fp", "q8"])
-    def test_no_group_sweep_leaves_virgin_partials(self, lane):
-        """With no group to serve, phase 1's one-step sweep leaves
-        every row the virgin partials (-inf, 0, 0) the ungrouped walk
-        starts from: cutting the sweep changes no bit."""
+    def test_no_group_phase1_is_one_idle_grid_step(self, lane,
+                                                   monkeypatch):
+        """With no group to serve, phase 1 is ONE grid step that moves
+        nothing (its work items are the groups that share), and phase
+        2 starts every row from the virgin partials the ungrouped walk
+        starts from: the step equals the ungrouped one bit for bit.
+        With a group it has a work item a sharing group and q-block,
+        over the key blocks of the longest shared span."""
         rng = np.random.RandomState(7)
-        b, ps, mp, hkv, d, rows, nqb = 4, 8, 5, 2, 16, 8, 2
-        kp, vp, pt, pos, q_len, *_ = build_shared(
-            rng, ps, mp, hkv, d, n_shared=0, members=0, extra=b)
+        ps, mp, hkv, d = 8, 5, 2, 128    # heads of 128: pools read in place
+        kp, vp, pt, pos, q_len, gid, gld, gcnt = build_shared(
+            rng, ps, mp, hkv, d, n_shared=3, members=2, extra=2)
+        b = len(q_len)
         pools = (kp, vp) if lane == "fp" else q8_pools(rng, kp.shape)
-        q5 = rng.randn(b, nqb, hkv, rows, d).astype(np.float32)
-        prefetch = tuple(jnp.asarray(a, jnp.int32) for a in (
-            pt, pos, q_len, np.arange(b), np.zeros(b), np.zeros(b)))
-        m, l, acc = pa._grouped_phase1(
-            prefetch, [jnp.asarray(a) for a in (q5, *pools)], nqb,
-            b=b, mp=mp, ps=ps, hkv=hkv, d=d, qblk=rows, nqb=nqb, rep=1,
-            scale=0.25, has_scale=lane == "q8", fp8=False)
-        assert m.shape == l.shape == (nqb, b, hkv, rows, 128)
-        assert acc.shape == (nqb, b, hkv, rows, d)
-        assert m.dtype == l.dtype == acc.dtype == jnp.float32
-        np.testing.assert_array_equal(
-            np.asarray(m), np.full(m.shape, pa._NEG_INF, np.float32))
-        assert not np.asarray(l).any() and not np.asarray(acc).any()
+        q = rng.randn(b, int(q_len.max()), hkv, d).astype(np.float32)
+        ung_op, grp_op = LANE_OPS[lane]
+        args = tuple(jnp.asarray(a) for a in (q, *pools, pt, pos, q_len))
+        grids = []
+        real = pa.pl.pallas_call
+
+        def spy(kernel, **kw):
+            if kw["name"] == "grouped_phase1":
+                grids.append(tuple(int(g) for g in kw["grid_spec"].grid))
+            return real(kernel, **kw)
+
+        monkeypatch.setattr(pa.pl, "pallas_call", spy)
+        with jax.disable_jit():           # the bounds as numbers
+            alone = np.asarray(grp_op(
+                *args, jnp.arange(b, dtype=jnp.int32),
+                jnp.zeros(b, jnp.int32), jnp.zeros(b, jnp.int32)))
+            grp_op(*args, *(jnp.asarray(g) for g in (gid, gld, gcnt)))
+        # one sharing group, one q-block; three shared pages are two
+        # key blocks of two
+        assert grids == [(1, 1), (1, 2)]
+        ung = np.asarray(ung_op(*args))
+        for r in range(b):
+            ql = int(q_len[r])
+            np.testing.assert_array_equal(alone[r, :ql], ung[r, :ql])
 
     @pytest.mark.parametrize("leader_group", ["first", "last"])
     @pytest.mark.parametrize("lane", ["fp", "q8"])
     def test_one_trace_serves_steps_with_and_without_a_group(
             self, lane, leader_group):
         """Sharing comes and goes as operand DATA: one jitted call
-        runs phase 1's whole sweep on a step with a group (also when
-        the sharing group is the LAST of the sweep) and one grid step a
-        q_block on a step without, with one trace, and every step
-        equals the ungrouped kernel bit for bit."""
+        runs phase 1 over the sharing group on a step with one (also
+        when it carries the LAST group id) and one idle grid step on a
+        step without, with one trace, and every step equals the
+        ungrouped kernel bit for bit."""
         rng = np.random.RandomState(8)
-        ps, mp, hkv, d = 8, 6, 2, 16
+        ps, mp, hkv, d = 8, 6, 2, 128    # heads of 128: pools read in place
         members, extra = 3, 2
         kp, vp, pt, pos, q_len, gid, gld, gcnt = build_shared(
             rng, ps, mp, hkv, d, n_shared=2, members=members,
@@ -237,7 +257,11 @@ class TestGroupedKernel:
         # both phases in the one program, no branch around either:
         # phase 1's q-blocks and sweep, the walk's q-blocks and pages
         # are dynamic grid bounds
-        top = jax.make_jaxpr(grp_op)(*args, *shared).jaxpr.eqns
+        # (the walk is a program of its own inside the caller's)
+        (walk,) = [e for e in
+                   jax.make_jaxpr(grp_op)(*args, *shared).jaxpr.eqns
+                   if e.primitive.name in ("pjit", "jit")]
+        top = walk.params["jaxpr"].jaxpr.eqns
         assert "cond" not in [e.primitive.name for e in top]
         grids = {e.params["name"]:
                  e.params["grid_mapping"].num_dynamic_grid_bounds
@@ -248,7 +272,7 @@ class TestGroupedKernel:
         """Code AND scale pages chase the same grouped walk; results
         match the q8 reference and the ungrouped q8 kernel."""
         rng = np.random.RandomState(4)
-        ps, mp, hkv, d = 8, 5, 2, 16
+        ps, mp, hkv, d = 8, 5, 2, 128    # heads of 128: pools read in place
         _, _, pt, pos, q_len, gid, gld, gcnt = build_shared(
             rng, ps, mp, hkv, d, n_shared=2, members=3, extra=1)
         pools = q8_pools(rng, (int(pt.max()) + 1, ps, hkv, d))

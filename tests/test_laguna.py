@@ -234,9 +234,33 @@ def test_window_walk_kernel_matches_reference_over_a_ring(pos, q_len,
                           - np.asarray(unwindowed[row, :n])).max() > 1e-3
 
 
-def test_window_walk_grid_is_the_window_not_the_context():
-    assert pa._window_pages(512, 8, 16) == 34      # of 512 at max_len 8192
-    assert pa._window_pages(8, 8, 4) == 5
+def test_window_walk_grid_is_the_window_not_the_context(monkeypatch):
+    # Laguna's window layers: 9 query heads a kv head, so q-blocks of 16,
+    # over key blocks of 256: 4 of the 32 a context of 8192 has
+    assert pa._query_blocks(128, 9) == (16, 8)
+    assert pa._window_blocks(512, 16, 256) == 4
+    assert pa._window_blocks(8, 8, 4) == 5
+    # and the grid the wrapper asks for has that many whatever the rows'
+    # positions: 8 + 16 - 1 positions lie on 4 key blocks of 8 at most
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    monkeypatch.setattr(pa, "K_BLOCK", 8)
+    grids = []
+    real = pa.pl.pallas_call
+
+    def spy(kernel, **kw):
+        grids.append(tuple(int(g) for g in kw["grid_spec"].grid))
+        return real(kernel, **kw)
+
+    monkeypatch.setattr(pa.pl, "pallas_call", spy)
+    pools = [jnp.zeros((41, 4, 2, 16), jnp.float32)] * 2
+    tab = jnp.asarray(1 + np.arange(40).reshape(2, 20), jnp.int32)
+    q = jnp.zeros((2, 16, 6, 16), jnp.float32)
+    with jax.disable_jit():               # the bounds as numbers
+        for pos in ([0, 3], [40, 64]):
+            pa.ragged_paged_attention(
+                q, *pools, tab, jnp.asarray(pos, jnp.int32),
+                jnp.asarray([16, 1], jnp.int32), window=8)
+    assert grids == [(2, 4), (2, 4)]
     walked, unwindowed = pa.count_window_page_reads(
         [8000, 0, 100], [1, 128, 0], page_size=16, window=512)
     assert (walked, unwindowed) == (33 + 8, 501 + 8)

@@ -15,6 +15,7 @@ Contracts:
 """
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 import paddle_tpu as paddle
@@ -441,11 +442,12 @@ class TestDenseGQAGrouped:
         np.testing.assert_array_equal(out_new.numpy(), out_old.numpy())
 
 
-# (pos, q_len) of three rows over 20 pages of 4 positions, 16 query
-# positions (2 q-blocks): what the grid's dynamic bounds come to
-DECODE_ONLY = ([37, 20, 0], [1, 1, 1])         # 1 q-block, 10 pages
-WITH_A_CHUNK = ([14, 60, 5], [3, 16, 0])       # 2 q-blocks, 19 pages
-ALL_DEAD = ([13, 20, 0], [0, 0, 0])            # 1 q-block, 1 page
+# (pos, q_len) of three rows over 20 pages of 4 positions (7 key blocks
+# of 3 pages), 16 query positions (2 q-blocks of 8): what the grid's
+# dynamic bounds come to, in live (row, q-block) items and key blocks
+DECODE_ONLY = ([37, 20, 0], [1, 1, 1])         # 3 items, 4 key blocks
+WITH_A_CHUNK = ([14, 60, 5], [3, 16, 0])       # 1 + 2 items, 7 key blocks
+ALL_DEAD = ([13, 20, 0], [0, 0, 0])            # 1 item (dead), 1 key block
 
 
 class TestDynamicGridBounds:
@@ -455,11 +457,16 @@ class TestDynamicGridBounds:
     phases). Live queries equal the reference, which knows no grid;
     dead queries, whose q-blocks the grid may never write, read zero."""
 
-    B, W, H, HKV, D, PS, MP = 3, 16, 6, 2, 16, 4, 20
+    B, W, H, HKV, D, PS, MP = 3, 16, 6, 2, 128, 4, 20  # pools read in place
 
     @pytest.fixture(autouse=True)
     def _interpret(self, monkeypatch):
         monkeypatch.setattr(pa, "_INTERPRET", True)
+        # blocks small enough for these rows to have several: key blocks
+        # of 3 pages (12 keys: `_grouped`'s shared span ends on a block's
+        # edge) and q-blocks of 8 queries (24 rows a kv head of three)
+        monkeypatch.setattr(pa, "K_BLOCK", 12)
+        monkeypatch.setattr(pa, "_Q_ROWS", 24)
 
     def _operands(self, pos, q_len, seed=3):
         rng = np.random.default_rng(seed)
@@ -545,8 +552,8 @@ class TestDynamicGridBounds:
         self._check(got, plain, q_len, exact=True)
 
     @pytest.mark.parametrize("pos,q_len,bounds", [
-        (*DECODE_ONLY, (1, 10)), (*WITH_A_CHUNK, (2, 19)),
-        (*ALL_DEAD, (1, 1)), ([0, 79, 3], [16, 16, 16], (2, 20))])
+        (*DECODE_ONLY, (3, 4, 1)), (*WITH_A_CHUNK, (3, 7, 2)),
+        (*ALL_DEAD, (1, 1, 1)), ([0, 79, 3], [16, 16, 16], (6, 7, 2))])
     def test_host_count_is_the_grid_the_wrapper_asks_for(
             self, pos, q_len, bounds, monkeypatch):
         """`count_walk_grid_steps`, which the engine counts a step's
@@ -564,17 +571,20 @@ class TestDynamicGridBounds:
             return real(kernel, **kw)
 
         monkeypatch.setattr(pa.pl, "pallas_call", spy)
-        pa.ragged_paged_attention_grouped(q, *pools, tab, pos, q_len,
-                                          *group)
-        pa.ragged_paged_attention(q, *pools, tab, pos, q_len)
-        n_qblk, n_pages = bounds
-        walk = (self.B, n_qblk, n_pages)
-        assert seen == [("grouped_phase1", (n_qblk, self.B * self.MP)),
+        with jax.disable_jit():           # the bounds as numbers
+            pa.ragged_paged_attention_grouped(q, *pools, tab, pos, q_len,
+                                              *group)
+            pa.ragged_paged_attention(q, *pools, tab, pos, q_len)
+        # live items, key blocks of the longest context; phase 1: the
+        # one sharing group a bounded q-block, over its span's one block
+        n_items, n_kblk, n_qblk = bounds
+        walk = (n_items, n_kblk)
+        assert seen == [("grouped_phase1", (n_qblk, 1)),
                         ("ragged_walk", walk), ("ragged_walk", walk)]
         assert pa.count_walk_grid_steps(
             np.asarray(pos), np.asarray(q_len), lq=self.W,
-            page_size=self.PS, max_pages=self.MP) \
-            == (self.B * n_qblk * n_pages, self.B * 2 * self.MP)
+            rep=self.H // self.HKV, page_size=self.PS,
+            max_pages=self.MP) == (n_items * n_kblk, self.B * 2 * 7)
 
     def test_engine_counts_the_grid_of_every_step(self, monkeypatch):
         """`walk_grid_steps_total` and `walk_grid_steps_full_total`
@@ -589,7 +599,7 @@ class TestDynamicGridBounds:
 
         def spy(pos, q_len, **kw):
             seen.append(pa.count_walk_grid_steps(pos, q_len, **kw))
-            assert kw == dict(lq=8, page_size=8, max_pages=8)
+            assert kw == dict(lq=8, rep=2, page_size=8, max_pages=8)
             return seen[-1]
 
         monkeypatch.setattr(engine_mod, "count_walk_grid_steps", spy)
@@ -616,6 +626,110 @@ class TestDynamicGridBounds:
                         ("walk_grid_steps_full_total", full)):
             assert f"# TYPE paddle_serving_{name} counter" in text
             assert f'paddle_serving_{name}{{replica="r0"}} {n}' in text
+
+
+# The boundaries of the walk's blocks (a query block as wide as a chunk
+# over key blocks of several pages, `_walk_paged`): rows of 4 slots over
+# 24 pages of 8 keys, key blocks of 4 pages (32 keys), 128 query
+# positions in q-blocks sized by `_query_blocks` from the heads a kv head
+# serves. Each case: (pos, q_len) a row, then what differs from the plain
+# float lane.
+WIDE = {
+    # a chunk beside decoding rows and a dead row in one step
+    "chunk_decode_dead": dict(pos=[40, 63, 9, 100], q_len=[128, 1, 0, 1]),
+    # contexts whose last key lies one before, on and one after the first
+    # key of a key block (a decoding row and a chunk each)
+    "ends_before_edge": dict(pos=[30, 62, 0, 0], q_len=[1, 1, 31, 63]),
+    "ends_on_edge": dict(pos=[31, 63, 0, 0], q_len=[1, 1, 32, 64]),
+    "ends_after_edge": dict(pos=[32, 64, 0, 0], q_len=[1, 1, 33, 65]),
+    "q_len_1": dict(pos=[5, 50, 100, 150], q_len=[1, 1, 1, 1]),
+    "q_len_2": dict(pos=[5, 50, 100, 150], q_len=[2, 2, 2, 2]),
+    "q_len_8": dict(pos=[5, 50, 100, 150], q_len=[8, 8, 8, 1]),
+    "q_len_127": dict(pos=[0, 50, 31, 60], q_len=[127, 127, 1, 127]),
+    "q_len_128": dict(pos=[0, 50, 31, 64], q_len=[128, 128, 128, 128]),
+    # query heads a kv head: q-blocks of 128, 32 and 16 queries
+    "rep_1": dict(pos=[40, 63, 9, 100], q_len=[128, 1, 17, 2], rep=1),
+    "rep_6": dict(pos=[40, 63, 9, 100], q_len=[128, 1, 33, 2], rep=6),
+    "rep_9": dict(pos=[40, 63, 9, 100], q_len=[128, 1, 17, 2], rep=9),
+    # rows 0-2 share 5 pages: the span ends inside the second key block
+    "shared_span_inside_a_block": dict(
+        pos=[40, 63, 47, 100], q_len=[128, 1, 2, 1],
+        group=([0, 0, 0, 1], [0, 3, 0, 0], [5, 0, 0, 0])),
+    # a window whose lower edge falls inside a key block, and one whose
+    # keys lie on three
+    "window_edge_inside_a_block": dict(
+        pos=[40, 63, 9, 100], q_len=[128, 1, 0, 16], window=24, rep=9),
+    "window_spans_three_blocks": dict(
+        pos=[40, 75, 9, 100], q_len=[128, 1, 0, 16], window=70, rep=6),
+    # the tensor-parallel shard's one local kv head
+    "local_hkv_1": dict(pos=[40, 63, 9, 100], q_len=[128, 1, 0, 1], hkv=1,
+                        rep=2),
+    "int8_pool": dict(pos=[40, 63, 9, 100], q_len=[128, 1, 0, 2],
+                      lane="int8"),
+    "int8_pool_shared_span": dict(
+        pos=[40, 63, 47, 100], q_len=[128, 1, 2, 1], lane="int8",
+        group=([0, 0, 0, 1], [0, 3, 0, 0], [5, 0, 0, 0])),
+    "fp8_pool": dict(pos=[40, 63, 9, 100], q_len=[128, 1, 0, 2],
+                     lane="fp8"),
+    # heads narrower than the 128 lanes Mosaic cuts HBM by: K and V come
+    # as the rows' gathered views, as the int8 lane's scales always do
+    "heads_of_64": dict(pos=[40, 63, 9, 100], q_len=[128, 1, 0, 2], d=64),
+    "heads_of_64_shared_span": dict(
+        pos=[40, 63, 47, 100], q_len=[128, 1, 2, 1], d=64,
+        group=([0, 0, 0, 1], [0, 3, 0, 0], [5, 0, 0, 0])),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WIDE))
+def test_wide_blocks_match_the_reference(case, monkeypatch):
+    """The walk at its real query blocks, over key blocks of several
+    pages, against `ragged_attention_reference`, which knows neither:
+    live queries equal it, dead ones read zero."""
+    c = {**dict(rep=1, hkv=2, lane="fp", window=None, group=None, d=128),
+         **WIDE[case]}
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    monkeypatch.setattr(pa, "K_BLOCK", 32)
+    rng = np.random.default_rng(sorted(WIDE).index(case))
+    b, lq, d, ps, mp = 4, 128, c["d"], 8, 24  # heads of 128: read in place
+    hkv, h = c["hkv"], c["hkv"] * c["rep"]
+    shape = (b * mp + 1, ps, hkv, d)
+    tab = 1 + np.arange(b * mp).reshape(b, mp)
+    if c["group"]:
+        gid, gld, gcnt = c["group"]
+        for row in range(b):
+            n = gcnt[gid[row]]
+            tab[row, :n] = tab[gld[gid[row]], :n]
+    tab = jnp.asarray(tab, jnp.int32)
+    q = jnp.asarray(rng.normal(size=(b, lq, h, d)), jnp.float32)
+    pos = jnp.asarray(c["pos"], jnp.int32)
+    q_len = jnp.asarray(c["q_len"], jnp.int32)
+    group = [jnp.asarray(g, jnp.int32) for g in c["group"] or ()]
+    if c["lane"] == "int8":
+        pools = [jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+                 for _ in range(2)]
+        pools += [jnp.asarray(np.abs(rng.normal(size=shape[:3])) / 127,
+                              jnp.float32) for _ in range(2)]
+        want = pa.ragged_attention_reference_q8(q, *pools, tab, pos, q_len)
+        op = (pa.ragged_paged_attention_grouped_q8 if group
+              else pa.ragged_paged_attention_q8)
+        got = op(q, *pools, tab, pos, q_len, *group)
+    else:
+        dt = pa.FP8_DTYPE if c["lane"] == "fp8" else jnp.float32
+        pools = [jnp.asarray(rng.normal(size=shape), jnp.float32).astype(dt)
+                 for _ in range(2)]
+        want = pa.ragged_attention_reference(q, *pools, tab, pos, q_len,
+                                             None, c["window"])
+        if group:
+            got = pa.ragged_paged_attention_grouped(q, *pools, tab, pos,
+                                                    q_len, *group)
+        else:
+            got = pa.ragged_paged_attention(q, *pools, tab, pos, q_len,
+                                            window=c["window"])
+    for row in range(b):
+        n = int(q_len[row])
+        np.testing.assert_allclose(got[row, :n], want[row, :n], atol=5e-6,
+                                   rtol=2e-5)
+        assert not np.asarray(got[row, n:]).any()
 
 
 class TestServingEngineAB:

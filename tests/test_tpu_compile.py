@@ -101,15 +101,31 @@ def test_paged_walk_bf16(one_chip, lq):
 
 
 @pytest.mark.parametrize("lq", [CHUNK, 1])
+@pytest.mark.parametrize("grouped", [False, True])
+def test_paged_walk_heads_of_64(one_chip, lq, grouped):
+    """GPT-2 small's heads (12 x 64): narrower than the 128 lanes Mosaic
+    cuts HBM by, so K and V reach the walk as the rows' gathered views
+    (`pa._row_view`) and not by a DMA a page; groups, which such views
+    leave nothing to save for, take the ungrouped walk."""
+    text = _compiles_to_kernel(
+        lambda q, k, v, t, p, n: pa._ragged_attention_kernel(
+            q, k, v, t, p, n, None, group=(n, n, n) if grouped else None),
+        one_chip, ((B, lq, 12, 64), BF16), ((PAGES, PS, 12, 64), BF16),
+        ((PAGES, PS, 12, 64), BF16), ((B, 64), I32), ROW, ROW)
+    assert "ptk:grouped_phase1" not in text
+
+
+@pytest.mark.parametrize("lq", [CHUNK, 1])
 @pytest.mark.parametrize("heads,window", [(72, 512), (48, None)],
                          ids=["window72", "full48"])
 def test_paged_walk_laguna(one_chip, lq, heads, window):
     """Laguna-S-2.1's two layer kinds at its serving shape: 16 slots,
     8 KV heads under 72 (window 512, over the per-slot ring's table) or
     48 query heads, max_len 8192. Every walk's grid has the dynamic
-    bounds (`pa.walk_grid_bounds`): the full layer's q-blocks and pages
-    (512 at most), the window layer's q-blocks over a page axis that is
-    statically its window's 34."""
+    bounds (`pa.walk_grid_bounds`): the full layer's live (row, q-block
+    of 32) items and key blocks (32 of 256 keys at most), the window
+    layer's live items (q-blocks of 16) over a key-block axis that is
+    statically its window's 4."""
     slots, mp, ring = 16, 512, 41
     text = _compiles_to_kernel(
         lambda q, k, v, t, p, n: pa._ragged_attention_kernel(
@@ -208,8 +224,10 @@ def test_grouped_walk_bf16(one_chip, on_tpu_branch, lq):
     """The grouped walk at the GPT-3 serving shape (8 slots, 16 heads x
     128, 128 pages; a chunk and one token), through the public op: both
     phases are in the program, and each takes its grid's dynamic bounds
-    as leading scalar operands: phase 1 the q-blocks and the sweep,
-    phase 2 the q-blocks and the pages."""
+    as leading scalar operands (phase 1 the sharing groups' items and
+    the key blocks of the longest shared span, phase 2 the live (row,
+    q-block) items and the key blocks of the longest context), then
+    its work items and the rows' operands, the page table flat."""
     import re
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
             (_q(lq), _pool(BF16), _pool(BF16), TABLE, ROW, ROW, ROW, ROW,
@@ -219,7 +237,8 @@ def test_grouped_walk_bf16(one_chip, on_tpu_branch, lq):
         (call,) = [ln for ln in lowered.as_text().splitlines()
                    if f"ptk:{name}" in ln]
         assert re.search(
-            rf": \(tensor<i32>, tensor<i32>, tensor<{B}x{MP}xi32>, ", call)
+            rf": \(tensor<i32>, tensor<i32>(, tensor<{B}xi32>){{4}}, "
+            rf"tensor<{B * MP}xi32>, ", call)
     text = lowered.compile().as_text()
     assert text.count("tpu_custom_call") >= 2
 
@@ -440,11 +459,11 @@ def test_unified_step_names_its_kernels(one_chip, monkeypatch):
 def test_unified_step_sizes_phase1_sweep_from_its_operands(one_chip,
                                                            monkeypatch):
     """Both phases of the grouped walk take their grids' lengths as
-    operands (dynamic bounds: phase 1 the q-blocks of the row with most
-    live queries and the whole (group x page) sweep only on a step
-    where some rows share a prefix; the walk proper the same q-blocks
-    and the pages of the longest live context), under no conditional;
-    and that costs no copy of a pool:
+    operands (dynamic bounds: phase 1 the (q-block, group) items of the
+    groups that share and the key blocks of the longest shared span,
+    ONE step where none does; the walk proper the live (row, q-block)
+    items and the key blocks of the longest live context), under no
+    conditional; and reading the pools in place costs no copy of one:
     the compiled step copies no more pool-shaped arrays than the step
     of an engine without a prefix cache, whose walk has no phase 1."""
     import re
@@ -454,12 +473,16 @@ def test_unified_step_sizes_phase1_sweep_from_its_operands(one_chip,
     calls = {name: [ln for ln in text.splitlines()
                     if f"ptk:{name}" in ln]
              for name in ("grouped_phase1", "ragged_walk")}
-    assert [len(v) for v in calls.values()] == [2, 2]     # one a layer
+    # once a step program: the walk is a program of its own that both
+    # layers call, so the step's set-up traces and lowers it once
+    assert [len(v) for v in calls.values()] == [1, 1]
+    assert text.count("call @_ragged_attention_local") == 2   # a layer
     # operand types close the line: two scalar bounds lead, then the
-    # page table
+    # work items, pos, q_len and the flat page table
     for ln in calls["grouped_phase1"] + calls["ragged_walk"]:
         assert re.search(
-            r": \(tensor<i32>, tensor<i32>, tensor<8x16xi32>, ", ln)
+            r": \(tensor<i32>, tensor<i32>(, tensor<8xi32>){4}, "
+            r"tensor<128xi32>, ", ln)
 
     shape = ",".join(map(str, pool))
     pool_copy = re.compile(
