@@ -14,5 +14,7 @@ from .laguna import (LagunaConfig, LagunaModel,  # noqa: F401
                      LagunaForCausalLM)
 from .deepseek_v2 import (DeepseekV2Config, DeepseekV2Model,  # noqa: F401
                           DeepseekV2ForCausalLM)
+from .keye_vl2 import (KeyeVL2Config, KeyeVL2Model,  # noqa: F401
+                       KeyeVL2ForCausalLM)
 from .generation import (DecodeCache, init_decode_caches,  # noqa: F401
                          update_and_attend, CompiledGenerator)

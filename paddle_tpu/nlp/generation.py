@@ -35,6 +35,7 @@ from ..core import dtype as dtypes
 from ..ops._helpers import apply_op, as_tensor
 from ..ops.pallas import kernel_mesh
 from ..ops.pallas.mla import latent_attend
+from ..ops.pallas.sparse import sparse_attend
 from ..ops.pallas.paged_attention import (dequantize_paged_q8,
                                           gqa_attend_reference,
                                           paged_decode_attention,
@@ -54,7 +55,7 @@ from ..ops.pallas.paged_attention import (dequantize_paged_q8,
                                           spec_verify_accept)
 
 __all__ = ["DecodeCache", "init_decode_caches", "update_and_attend",
-           "update_and_attend_latent",
+           "update_and_attend_latent", "update_and_attend_sparse",
            "CompiledGenerator", "decode_model_step", "sample_logits",
            "resolve_paged_attn_impl", "PAGED_ATTN_IMPLS",
            "quantize_kv_rowwise"]
@@ -100,14 +101,18 @@ class DecodeCache:
 
     __slots__ = ("k", "v", "pos", "k_scale", "v_scale", "fresh",
                  "page_table", "attn_impl", "q_len", "group",
-                 "out_shard", "lora", "lora_paged", "megakernel")
+                 "out_shard", "lora", "lora_paged", "megakernel", "rows")
 
     def __init__(self, k, v, pos, k_scale=None, v_scale=None,
                  fresh=False, page_table=None, attn_impl=None,
                  q_len=None, group=None, out_shard=None, lora=None,
-                 lora_paged=None, megakernel=False):
+                 lora_paged=None, megakernel=False, rows=None):
         self.k = k
         self.v = v
+        # a layer of the SPARSE kind (the engine's cache-spec contract):
+        # the pool of its indexer's rows [num_pages, page_size, row],
+        # one a token, beside k and v and under the same page table
+        self.rows = rows
         self.pos = pos
         # paged mode: [B, max_pages] int32 page ids into the k/v pools
         self.page_table = page_table
@@ -878,15 +883,57 @@ def update_and_attend_latent(q, row_new, cache: DecodeCache, *, d_v,
                             attn_impl=cache.attn_impl, q_len=cache.q_len)
 
 
+# Learned sparse attention over the paged pools of a layer's keys,
+# values and indexer rows (pallas/sparse.py): the pages read in place by
+# the three kernels; the dense jnp forms over gathered views off-TPU.
+register_op("sparse_paged_attention", sparse_attend, nondiff=True)
+
+
+def update_and_attend_sparse(q, k_new, v_new, q_idx, w_idx, row_new,
+                             cache: DecodeCache, *, topk):
+    """`update_and_attend` for a layer of the SPARSE kind (the engine's
+    cache-spec contract): `cache.k` / `cache.v` are the ordinary pools
+    [num_pages, page_size, n_kv, head_dim], `cache.rows` the pool of the
+    layer's indexer rows [num_pages, page_size, row], all three under
+    the slot's one page table. Writes k_new / v_new [B, l, n_kv, D] and
+    row_new [B, l, row] at cache.pos, ALL THREE BEFORE ANY IS READ (a
+    chunk's own new keys are scored and may be selected), then attends
+    q [B, l, H, D] over the `topk` positions at or below each query
+    that q_idx [B, l, Hi, row] / w_idx [B, l, Hi] score highest against
+    the rows. Returns (out [B, l, H, D], advanced cache). Served in the
+    unified ragged step only (paged, per-row q_len); the writes are the
+    XLA row scatter."""
+    if cache.page_table is None or cache.q_len is None \
+            or cache.rows is None or cache.k_scale is not None \
+            or cache.megakernel or cache.group is not None:
+        raise NotImplementedError(
+            "a sparse-attention layer is served by the unified ragged "
+            "step over float paged pools (ServingEngine)")
+    k_buf, v_buf, rows = (
+        apply_op("kv_cache_update_paged", pool, new, cache.pos,
+                 cache.page_table)
+        for pool, new in ((cache.k, k_new), (cache.v, v_new),
+                          (cache.rows, row_new)))
+    out = apply_op("sparse_paged_attention", q, q_idx, w_idx, k_buf, v_buf,
+                   rows, cache.page_table, cache.pos, cache.q_len,
+                   attrs=dict(topk=int(topk)))
+    return out, DecodeCache(k_buf, v_buf, cache.pos + cache.q_len,
+                            page_table=cache.page_table,
+                            attn_impl=cache.attn_impl, q_len=cache.q_len,
+                            rows=rows)
+
+
 def _pack_caches(caches):
     """DecodeCache list -> loop-carry pytree: per layer
     (k, v, k_scale|None, v_scale|None). None entries keep the pytree
     structure identical whether or not the int8 cache is active (and
-    v is None for a layer of the latent kind: its rows are `k`)."""
+    v is None for a layer of the latent kind: its rows are `k`). A
+    layer of the sparse kind has a fifth entry, its indexer's rows."""
     return tuple(
         (c.k._value, None if c.v is None else c.v._value,
          None if c.k_scale is None else c.k_scale._value,
          None if c.v_scale is None else c.v_scale._value)
+        + (() if c.rows is None else (c.rows._value,))
         for c in caches)
 
 
@@ -929,8 +976,9 @@ def _unpack_caches(ct, pos, page_table=None, attn_impl=None,
                         None if vs is None else Tensor(vs),
                         page_table=pt, attn_impl=attn_impl, q_len=ql,
                         group=grp, out_shard=out_shard, lora=lo,
-                        lora_paged=lp, megakernel=megakernel)
-            for (k, v, ks, vs), lo, lp in zip(ct, lora, lora_paged)]
+                        lora_paged=lp, megakernel=megakernel,
+                        rows=Tensor(rows[0]) if rows else None)
+            for (k, v, ks, vs, *rows), lo, lp in zip(ct, lora, lora_paged)]
 
 
 def decode_model_step(model, tokens, caches):

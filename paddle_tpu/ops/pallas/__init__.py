@@ -14,6 +14,11 @@ Kernels:
 - paged_attention.py — ragged paged-attention decode for the serving
   engine's paged KV pool (scalar-prefetched page-table walk, streams
   only live pages)
+- moe.py — routed experts, dropless (`moe_experts`)
+- mla.py — latent attention over one cached row a token (`mla_walk`)
+- sparse.py — learned sparse attention: an indexer over its own row
+  pool, an exact top-k by counting passes, the walk over the selected
+  keys (`sparse_index`, `sparse_select`, `sparse_walk`)
 """
 import contextlib as _contextlib
 import contextvars as _contextvars
@@ -35,8 +40,12 @@ def trace32():
 # `metadata=` into `frontend_attributes={kernel_metadata=...}` of the
 # compiled instruction, which is the text a trace reader searches for
 # `ptk:<name>`. Names live in one `KERNELS` table at the top of each
-# kernel file; none may contain another (a reader's needle is a
-# substring). `fn` keeps the kernel function's own name in the lowered
+# kernel file (16 names: `ragged_walk`, `grouped_phase1`,
+# `scatter_write`, `scatter_q8_write`, `lora_paged`, `argmax_epilogue`,
+# `flash_fwd`, `flash_dq`, `flash_dkv`, `layer_norm_fwd`,
+# `layer_norm_bwd`, `moe_experts`, `mla_walk`, `sparse_index`,
+# `sparse_select`, `sparse_walk`); none may contain another (a reader's
+# needle is a substring). `fn` keeps the kernel function's own name in the lowered
 # text, where start-up checks look for it.
 KERNEL_TAG = "ptk:"
 

@@ -249,7 +249,16 @@ def moe_experts(xs, row_weight, tile_expert, n_tiles, w_gate, w_up,
     them."""
     m, h = xs.shape
     f = w_gate.shape[2]
+    # the widest block of whole lanes, `_F_BLOCK` at most, that DIVIDES
+    # the experts' width: the grid's second axis is f // fb blocks, and
+    # columns past them would simply not be computed (512 of a width of
+    # 768: my chip run, PR 36, where the roofline share read 113%)
     fb = min(f, _F_BLOCK)
+    while f % fb and fb > 128:
+        fb -= 128
+    if f % fb:
+        raise ValueError(f"experts of width {f}: neither at most "
+                         f"{_F_BLOCK} nor a multiple of 128")
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         # one tile at least: a grid axis of 0 steps is not worth finding

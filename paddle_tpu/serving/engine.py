@@ -208,6 +208,7 @@ from ..nlp.generation import (_StepProgram, _pack_caches,
                               _unpack_caches, resolve_paged_attn_impl,
                               FP8_DTYPE)
 from ..ops.pallas.mla import count_latent_keys
+from ..ops.pallas.sparse import count_sparse_work
 from ..ops.pallas.paged_attention import (count_page_block_reads,
                                           count_walk_grid_steps,
                                           count_window_page_reads,
@@ -218,7 +219,7 @@ from .draft import DraftConfig, DraftEngine, make_draft_model
 from .errors import DeadlineExceeded, EngineClosed, PoisonedRequest
 from .fabric import decode_frame, encode_frame, frame_header
 from .grammar import (NEG_BIAS, TokenGrammar, resolve_grammar_flag)
-from .metrics import LATENT_COUNTERS, ServingMetrics
+from .metrics import LATENT_COUNTERS, SPARSE_COUNTERS, ServingMetrics
 from .obs import EngineObs, resolve_obs_flag
 from .paging import HostPagePool, PagePool, TRASH_PAGE, pages_needed
 from .prefix import (RadixPrefixCache, resolve_prefix_cache_flag,
@@ -440,7 +441,20 @@ class ServingEngine:
         # slot's ONE page table like any other, with no head axis (a
         # head axis of one would pad every row to a whole tile). The
         # attention module writes and reads it through
-        # `update_and_attend_latent`. `windows` must all be None.
+        # `update_and_attend_latent`. `windows` must all be None; or
+        #   (n_layers, n_kv_heads, head_dim, windows, "sparse",
+        #    (row, topk))
+        # for a model whose layers are all of the SPARSE kind
+        # (nlp/keye_vl2.py): a token's cached state in a layer is a key
+        # and a value of `n_kv_heads` heads, in the two pools every
+        # full-attention layer has, AND one row of `row` values for the
+        # layer's indexer (whole tiles of 128 lanes, as a latent row),
+        # in a third pool [num_pages, page_size, row] with no head axis:
+        # two kinds of state side by side, all three pools page-shaped
+        # and under the slot's ONE page table. A query attends the
+        # `topk` positions its indexer scores highest against the rows.
+        # The attention module writes all three and reads them through
+        # `update_and_attend_sparse`. `windows` must all be None.
         if cache_spec is None:
             if not hasattr(model, "_decode_cache_spec"):
                 raise ValueError(
@@ -449,7 +463,9 @@ class ServingEngine:
                     "head_dim), or (n_layers, n_kv_heads, head_dim, "
                     "windows) with each layer's sliding window or None, "
                     "or (n_layers, 1, row, windows, 'latent') for one "
-                    "latent row a token, explicitly")
+                    "latent row a token, or (n_layers, n_kv_heads, "
+                    "head_dim, windows, 'sparse', (row, topk)) for keys, "
+                    "values and an indexer row a token, explicitly")
             cache_spec = model._decode_cache_spec()
         self.model = model
         self.n_layers, self.n_kv, self.head_dim = cache_spec[:3]
@@ -461,8 +477,17 @@ class ServingEngine:
         # layer index -> window, for the layers that have one
         self.kv_windows = {i: int(w) for i, w in enumerate(windows)
                            if w is not None}
-        # the latent kind: one pool a layer, no V (the contract above)
-        self.kv_latent = len(cache_spec) > 4
+        # the latent kind: one pool a layer, no V; the sparse kind: a
+        # third pool a layer, of indexer rows (the contract above)
+        self.kv_sparse = len(cache_spec) > 4 and cache_spec[4] == "sparse"
+        self.kv_latent = len(cache_spec) > 4 and not self.kv_sparse
+        if self.kv_sparse and (len(cache_spec) != 6 or self.kv_windows):
+            raise ValueError(
+                f"a sparse cache_spec is (n_layers, n_kv_heads, head_dim, "
+                f"all-None windows, 'sparse', (row, topk)), got "
+                f"{cache_spec}")
+        self.index_row, self.sparse_topk = \
+            (int(n) for n in cache_spec[5]) if self.kv_sparse else (0, 0)
         # query heads a kv head serves in a full-attention layer: the
         # page walk sizes its query block from it, and the host counts
         # that walk's grid (`count_walk_grid_steps`)
@@ -607,9 +632,11 @@ class ServingEngine:
         self.megakernel = (resolve_megakernel_flag(megakernel)
                            and self.attn_impl == "kernel"
                            and self.tp is None)
-        if self.kv_windows or self.kv_latent:
-            # WINDOW LAYERS AND LATENT ROWS: what the engine does not
-            # do for them yet. (Latent rows: their pool is page-shaped
+        if self.kv_windows or self.kv_latent or self.kv_sparse:
+            # WINDOW LAYERS, LATENT ROWS AND INDEXER ROWS: what the
+            # engine does not do for them yet. (Latent rows, and the
+            # indexer rows a sparse layer keeps BESIDE its K and V:
+            # their pool is page-shaped
             # and would move as any page does, but every program that
             # moves pages -- COW copy, spill gather, restore, the PKVF
             # frame -- is built for a K and a V pool with a head axis;
@@ -636,6 +663,8 @@ class ServingEngine:
                 ("spec", self.spec is not None)) if on]
             kind = (f"sliding-window layers {sorted(self.kv_windows)}"
                     if self.kv_windows else
+                    f"indexer rows of {self.index_row} values in a lone "
+                    f"pool beside K and V" if self.kv_sparse else
                     f"latent rows of {self.head_dim} values and no V pool")
             if wanted or unbuilt:
                 raise ValueError(
@@ -643,9 +672,9 @@ class ServingEngine:
                     f"cannot be had with them yet (the prefix cache, "
                     f"the host tier and preemption would reuse or move "
                     f"pages whose window-layer KV is gone, or whose lone "
-                    f"latent pool no page program knows; the others "
-                    f"have no window and no latent row in their "
-                    f"attention path)")
+                    f"latent or indexer pool no page program knows; the "
+                    f"others have no window, no latent row and no "
+                    f"selection by an indexer in their attention path)")
             warnings.warn(
                 f"ServingEngine: {kind}: the prefix cache, the "
                 f"host page tier, preemption and the grouped walk are "
@@ -789,6 +818,17 @@ class ServingEngine:
                 (jnp.zeros((self.num_pages, self.page_size,
                             self.head_dim), self._fp), None, None, None)
                 for _ in range(self.n_layers))
+        elif self.kv_sparse:
+            # K and V as every full-attention layer has them, and the
+            # indexer's rows behind (the contract above)
+            self._ct = tuple(
+                (jnp.zeros((self.num_pages, self.page_size, self.n_kv,
+                            self.head_dim), self._fp),
+                 jnp.zeros((self.num_pages, self.page_size, self.n_kv,
+                            self.head_dim), self._fp), None, None,
+                 jnp.zeros((self.num_pages, self.page_size,
+                            self.index_row), self._fp))
+                for _ in range(self.n_layers))
         else:
             # fp8: pure-convert e4m3 pages ride the fp container shape
             # (no scale pools) — every whole-page program (COW, swap,
@@ -824,12 +864,15 @@ class ServingEngine:
                        else jnp.dtype(self._fp).itemsize)
         scale_bytes = 4 if self.kv_dtype == "int8" else 0
         # (a page of the shared pool: the full-attention layers only;
-        # one pool a layer, not two, for latent rows)
+        # one pool a layer, not two, for latent rows; a sparse layer's
+        # indexer rows besides)
         self.page_bytes = ((self.n_layers - len(self.kv_windows))
                            * (1 if self.kv_latent else 2)
                            * self.page_size * self.n_kv
                            * (self.head_dim * kv_itemsize
-                              + scale_bytes))
+                              + scale_bytes)
+                           + self.n_layers * self.page_size
+                           * self.index_row * kv_itemsize)
         self.metrics.kv_dtype = self.kv_dtype
         self.metrics.pool_bytes_per_page = self.page_bytes
         self.metrics.adapters_enabled = self.adapters is not None
@@ -2669,6 +2712,12 @@ class ServingEngine:
                 page_size=self.page_size, max_pages=self.max_pages)
             self._host_phases["walk_grid_steps_total"] += steps
             self._host_phases["walk_grid_steps_full_total"] += full
+        if self.kv_sparse:
+            # what the indexer scores and the selection leaves, a layer
+            # (the masked walk's grid is the pair above)
+            for name, count in zip(SPARSE_COUNTERS, count_sparse_work(
+                    pos_host, q_len, self.sparse_topk)):
+                self._host_phases[name] += count * self.n_layers
         for window in self.kv_windows.values():
             walked, unwindowed = count_window_page_reads(
                 pos_host, q_len, page_size=self.page_size,

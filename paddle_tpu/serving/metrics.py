@@ -28,6 +28,7 @@ from typing import Optional, Sequence
 
 __all__ = ["Histogram", "ServingMetrics", "prometheus_render",
            "HOST_PHASE_COUNTERS", "STEP_WORK_COUNTERS", "LATENT_COUNTERS",
+           "SPARSE_COUNTERS",
            "TTFT_BUCKETS", "LATENCY_BUCKETS", "PACKED_TOKEN_BUCKETS",
            "SPEC_TOKEN_BUCKETS", "GROUP_SIZE_BUCKETS", "UTIL_BUCKETS"]
 
@@ -111,11 +112,25 @@ HOST_PHASE_COUNTERS = (
 # layers: the (query, key) PAIRS the live query rows attend over, what
 # the arithmetic is proportional to; what has to be READ at least once
 # however a slot's queries share their reads, a slot's context once a
-# step; and the live query rows.
+# step; and the live query rows. `sparse_*`: the same for a model of
+# the sparse kind (`ops/pallas/sparse.count_sparse_work`, in its order),
+# summed over its layers: the VISIBLE (query, key) pairs, which the
+# indexer scores; the SELECTED pairs, min(visible, topk) a query, which
+# the attention weighs; the distinct keys and values any form of the
+# attention must read, min(context, live queries x topk) a slot; the
+# indexer rows any form must read, a slot's context once; and the live
+# query rows.
 LATENT_COUNTERS = (
     "mla_pairs_total",
     "mla_keys_distinct_total",
     "mla_rows_total",
+)
+SPARSE_COUNTERS = (
+    "sparse_pairs_visible_total",
+    "sparse_pairs_selected_total",
+    "sparse_keys_floor_total",
+    "sparse_keys_context_total",
+    "sparse_rows_total",
 )
 STEP_WORK_COUNTERS = (
     "moe_assignments_total",
@@ -126,7 +141,7 @@ STEP_WORK_COUNTERS = (
     "kv_window_pages_skipped_total",
     "walk_grid_steps_total",
     "walk_grid_steps_full_total",
-) + LATENT_COUNTERS
+) + LATENT_COUNTERS + SPARSE_COUNTERS
 
 
 class Histogram:

@@ -12,6 +12,7 @@ on the CPU. It reads the metric files and edits nothing there.
   that `benchmark/kinds/serve_http.py` `EngineWindow` takes;
 - `moe_roofline`: its `hit` and `here` counters likewise;
   `latent_roofline`: its `pairs` and `distinct` counters;
+  `sparse_roofline`: the counters its `flops` and `bytes` name;
 - `span_idle`: every span under `spans` and `excluding` is a `SPAN_*`
   constant that the engine or the HTTP driver opens.
 """
@@ -62,6 +63,9 @@ def _metric_files():
             counters += [spec["args"]["hit"], spec["args"]["here"]]
         if spec["reader"] == "latent_roofline":
             counters += [spec["args"]["pairs"], spec["args"]["distinct"]]
+        if spec["reader"] == "sparse_roofline":
+            counters += [*spec["args"]["flops"].values(),
+                         *spec["args"]["bytes"].values()]
         spans = (_listed(spec["args"].get("spans"))
                  + _listed(spec["args"].get("excluding"))
                  if spec["reader"] == "span_idle" else [])
@@ -104,11 +108,11 @@ def opened_spans():
 
 
 def test_the_yardstick_names_something():
-    """27 metrics read the engine's counters and histograms at the top
-    of their arguments, 2 more in an operand, 2 through the roofline
+    """29 metrics read the engine's counters and histograms at the top
+    of their arguments, 2 more in an operand, 4 through the roofline
     readers; 8 read spans."""
     cases = [p.values for p in _metric_files()]
-    assert sum(1 for c, s, sp in cases if c or s) == 31
+    assert sum(1 for c, s, sp in cases if c or s) == 35
     assert sum(1 for c, s, sp in cases if sp) == 8
 
 

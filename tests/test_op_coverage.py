@@ -959,6 +959,12 @@ ELSEWHERE = {
     **{n: EW("test_serving_deepseek_v2.py", "chunked_prefill|absorbed")
        for n in ["mla_absorb_q", "mla_expand_v",
                  "latent_paged_attention"]},
+    # Keye-VL-2.0's ops (nlp/keye_vl2.py, nlp/generation.py): against the
+    # plain float32 reference, eagerly and through the engine's cache
+    **{n: EW("test_keye_vl2.py", "eager_forward")
+       for n in ["keye_layer_norm", "keye_published_attention"]},
+    **{n: EW("test_serving_keye_vl2.py", "chunked_prefill|published")
+       for n in ["keye_pad_last", "sparse_paged_attention"]},
     # quantization — tests/test_inference_quant.py
     "fake_quantize_dequantize": EW("test_inference_quant.py",
                                    "quant"),

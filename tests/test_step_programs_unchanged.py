@@ -1,0 +1,86 @@
+"""The unified step of the models the benchmark already measures is the
+PARENT's program, byte for byte: a small GPT, Laguna and DeepSeek-V2
+engine each serve one request on the CPU (the jnp forms: the text holds
+every operation of the step, its operands' shapes and the pytrees'
+arity), the lowered step's text is hashed, and the hashes are those of
+`tests/step_digests.json`, which was written by THIS file run on the
+parent commit's tree (ISSUE 36: a new model adds nothing to what the
+accepted cells trace and lower).
+
+A PR that changes one of these steps on purpose writes the file anew on
+its own tree and says so:
+
+    STEP_DIGESTS_WRITE=1 JAX_PLATFORMS=cpu python -m pytest \\
+        tests/test_step_programs_unchanged.py -q
+"""
+import hashlib
+import json
+import os
+import warnings
+
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.nlp import GPTConfig, GPTForCausalLM
+from paddle_tpu.serving import SamplingParams, ServingEngine
+
+from test_deepseek_v2 import tiny_dsv2
+from test_laguna import tiny_laguna
+
+
+
+@pytest.fixture(autouse=True)
+def jnp_forms(monkeypatch):
+    """Not interpret mode, whatever an earlier test file of this worker
+    asked for at its import (as tests/test_tpu_compile.py): on the CPU
+    the step then holds the jnp forms, which is what was hashed."""
+    from paddle_tpu.ops.pallas import (flash_attention, layer_norm, mla,
+                                       moe, paged_attention)
+    for mod in (flash_attention, layer_norm, mla, moe, paged_attention):
+        monkeypatch.setattr(mod, "_INTERPRET", False)
+
+
+DIGESTS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                       "step_digests.json")
+
+
+def _tiny_gpt():
+    paddle.seed(3)
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=97, hidden_size=32, num_hidden_layers=2,
+        num_attention_heads=4, intermediate_size=64,
+        max_position_embeddings=64, hidden_dropout_prob=0.0,
+        attention_probs_dropout_prob=0.0))
+    model.eval()
+    return model
+
+
+MODELS = {"gpt": _tiny_gpt, "laguna": tiny_laguna, "deepseek_v2": tiny_dsv2}
+
+
+def step_text(name):
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        eng = ServingEngine(MODELS[name](), num_slots=2, max_len=64,
+                            page_size=4, chunk_len=16)
+    eng.generate([np.arange(1, 24, dtype=np.int64)],
+                 SamplingParams(max_new_tokens=3))
+    return eng.lowered_unified_step().as_text()
+
+
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_lowered_step_is_the_parents(name):
+    digest = hashlib.sha256(step_text(name).encode()).hexdigest()
+    if os.environ.get("STEP_DIGESTS_WRITE"):
+        have = {}
+        if os.path.exists(DIGESTS):
+            with open(DIGESTS) as f:
+                have = json.load(f)
+        have[name] = digest
+        with open(DIGESTS, "w") as f:
+            json.dump(have, f, indent=1, sort_keys=True)
+            f.write("\n")
+        return
+    with open(DIGESTS) as f:
+        assert json.load(f)[name] == digest

@@ -24,6 +24,7 @@ from paddle_tpu.ops.pallas import layer_norm as pln
 from paddle_tpu.ops.pallas import mla
 from paddle_tpu.ops.pallas import moe
 from paddle_tpu.ops.pallas import paged_attention as pa
+from paddle_tpu.ops.pallas import sparse as sp
 
 B, H, D, PS, PAGES, MP, CHUNK, VOCAB = 8, 16, 128, 16, 2048, 128, 128, 50304
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
@@ -60,7 +61,7 @@ def real_kernels(monkeypatch):
     """Not interpret mode, whatever an earlier test file asked for
     (test_pallas_layer_norm.py sets PADDLE_TPU_PALLAS_INTERPRET at
     import, and the kernel modules read it when THEY are imported)."""
-    for mod in (fa, pln, pa, moe, mla):
+    for mod in (fa, pln, pa, moe, mla, sp):
         monkeypatch.setattr(mod, "_INTERPRET", False)
 
 
@@ -175,6 +176,48 @@ def test_latent_mla_walk(one_chip, lq):
     assert "ptk:mla_walk" in text
 
 
+# the Keye-VL-2.0 cell's shapes: 8 slots, chunks of 128, 32 query heads
+# over 4 kv heads of 128, an indexer of 16 heads over rows of 64 values
+# padded to 128, contexts of 32768 in pages of 16, topk 2048
+KY = dict(slots=8, chunk=128, heads=32, kv=4, d=128, ih=16, row=128,
+          n=32768, pages=16385, ps=16, topk=2048)
+
+
+def _ky(*dims):
+    return tuple(KY.get(d, d) for d in dims)
+
+
+def test_sparse_index(one_chip):
+    text = _compiles_to_kernel(
+        sp.sparse_index, one_chip,
+        (_ky("slots", "chunk", "ih", "row"), BF16),
+        (_ky("slots", "chunk", "ih"), BF16), (_ky("pages", "ps", "row"), BF16),
+        ((KY["slots"], KY["n"] // KY["ps"]), I32), (_ky("slots"), I32),
+        (_ky("slots"), I32))
+    assert "ptk:sparse_index" in text
+
+
+def test_sparse_select(one_chip):
+    text = _compiles_to_kernel(
+        lambda k, p, n: sp.sparse_select(k, p, n, topk=KY["topk"]), one_chip,
+        ((KY["slots"], KY["n"] // 512, KY["chunk"], 512), I32),
+        (_ky("slots"), I32), (_ky("slots"), I32))
+    assert "ptk:sparse_select" in text
+
+
+def test_sparse_walk(one_chip):
+    per_query = (_ky("slots", "chunk", 128), I32)
+    text = _compiles_to_kernel(
+        sp.sparse_walk, one_chip, (_ky("slots", "chunk", "heads", "d"), BF16),
+        (_ky("pages", "ps", "kv", "d"), BF16),
+        (_ky("pages", "ps", "kv", "d"), BF16),
+        ((KY["slots"], KY["n"] // KY["ps"]), I32), (_ky("slots"), I32),
+        (_ky("slots"), I32),
+        ((KY["slots"], KY["n"] // 512, KY["chunk"], 512), I32), per_query,
+        per_query)
+    assert "ptk:sparse_walk" in text
+
+
 @pytest.mark.parametrize("rows", [16 * 128, 16], ids=["step", "decode"])
 def test_moe_routed_experts_group_limited(one_chip, rows, monkeypatch):
     """One DeepSeek-V2 expert layer, this chip's 20 of 160 experts at
@@ -189,6 +232,23 @@ def test_moe_routed_experts_group_limited(one_chip, rows, monkeypatch):
         one_chip, ((rows, h), BF16), ((rows,), jnp.bool_),
         ((h, 160), BF16), ((held, h, f), BF16),
         ((held, h, f), BF16), ((held, f, h), BF16))
+    assert "ptk:moe_experts" in text
+
+
+@pytest.mark.parametrize("rows", [8 * 128, 8], ids=["step", "decode"])
+def test_moe_routed_experts_of_width_768(one_chip, rows, monkeypatch):
+    """One Keye-VL-2.0 expert layer, this chip's 16 of 128 experts at
+    hidden 2048 / width 768, top-8 renormalised: the expert kernel's
+    block is 384 columns, twice, not 512 once."""
+    h, f, held = 2048, 768, 16
+    monkeypatch.setattr(moe, "_use_kernel", lambda: True)   # as the chip would
+    text = _compiles_to_kernel(
+        lambda x, v, wr, wg, wu, wd: moe.routed_experts(
+            x, v, wr, wg, wu, wd, top_k=8, scale=1.0, norm_topk=True,
+            first=0),
+        one_chip, ((rows, h), BF16), ((rows,), jnp.bool_),
+        ((h, 128), BF16), ((held, h, f), BF16), ((held, h, f), BF16),
+        ((held, f, h), BF16))
     assert "ptk:moe_experts" in text
 
 
@@ -401,9 +461,9 @@ def _tiny_gpt(hidden, heads, positions):
 
 def test_kernel_names_are_distinct_and_on_every_site():
     import re
-    tables = {mod: mod.KERNELS for mod in (pa, fa, pln, moe, mla)}
+    tables = {mod: mod.KERNELS for mod in (pa, fa, pln, moe, mla, sp)}
     names = [n for t in tables.values() for n in t]
-    assert len(names) == len(set(names)) == 13
+    assert len(names) == len(set(names)) == 16
     assert not [(a, b) for a in names for b in names
                 if a != b and a in b]
     for mod, table in tables.items():
@@ -552,6 +612,64 @@ def test_unified_step_of_a_latent_model_compiles_with_its_kernels(
     # max_len view of it (rows of 192 + 64 values fill 256)
     assert "bf16[8,2048,256]" not in compiled
     assert "bf16[8,128,16,256]" not in compiled
+
+
+def test_unified_step_of_a_sparse_model_compiles_with_its_kernels(
+        one_chip, monkeypatch):
+    """The serving step of a small Keye-VL-2.0 language model in
+    bfloat16 (real head and indexer sizes: 8 query heads to each of two
+    kv heads of 128, an indexer of 16 heads x 64, topk 256; pages of 16,
+    chunk 128, rows of 2048 keys), lowered and COMPILED for the
+    described v5e as the chip traces it: the three kernels once a step
+    program (a jit of their own, called by both layers), the expert
+    kernel, no conditional, no ragged walk, and no `[slots, max_len,
+    ...]` view of any of the three pools anywhere in it."""
+    import warnings
+    import numpy as np
+    import paddle_tpu as paddle
+    from paddle_tpu.nlp import KeyeVL2Config, KeyeVL2ForCausalLM
+    from paddle_tpu.serving import ServingEngine, SamplingParams
+    paddle.seed(0)
+    model = KeyeVL2ForCausalLM(KeyeVL2Config(
+        vocab_size=512, hidden_size=256, moe_intermediate_size=128,
+        num_hidden_layers=2, num_attention_heads=16, num_key_value_heads=2,
+        head_dim=128, num_experts=16, num_experts_per_tok=3, ep_size=4,
+        dtype="bfloat16",
+        sa_config={"indexer_head_dim": 64, "indexer_num_heads": 16,
+                   "indexer_num_kv_heads": 1, "topk": 256}))
+    model.eval()
+    for mod in (sp, moe):
+        monkeypatch.setattr(mod, "_use_kernel", lambda: False)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        eng = ServingEngine(model, num_slots=8, max_len=2048, page_size=16,
+                            chunk_len=128)
+    eng.add_request(np.arange(1, 40, dtype=np.int64),
+                    SamplingParams(max_new_tokens=2))
+    eng.run()
+    for mod in (sp, moe):
+        monkeypatch.setattr(mod, "_use_kernel", lambda: True)
+    prog = eng._build_unified()
+    lowered = prog._jit.lower(*_shaped(
+        (prog._state_vals, eng._ct, *eng._unified_args_tail), one_chip))
+    text = lowered.as_text()
+    assert "stablehlo.case" not in text
+    for name, calls in (("sparse_index", 1), ("sparse_select", 1),
+                        ("sparse_walk", 1), ("moe_experts", 2)):
+        assert sum(f'ptk:{name}' in ln
+                   for ln in text.splitlines()) == calls, name
+    assert "ptk:ragged_walk" not in text and "ptk:mla_walk" not in text
+    compiled = lowered.compile().as_text()
+    assert " conditional(" not in compiled
+    for name in ("sparse_index", "sparse_select", "sparse_walk"):
+        assert f"%{name}." in compiled, name
+    # the pools' pages are read in place: nothing gathers a slot's
+    # max_len view of K, V or the indexer's rows
+    assert "bf16[8,2048,2,128]" not in compiled
+    assert "bf16[8,2048,128]" not in compiled
+    assert "bf16[8,128,16,2,128]" not in compiled
+    # ([8, 128, 16, 128] is also the indexer's padded queries: 8 slots
+    # x 128 positions x 16 heads x 128 lanes)
 
 
 def test_names_reach_the_compiled_instruction(one_chip, on_tpu_branch):
