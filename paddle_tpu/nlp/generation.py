@@ -828,10 +828,14 @@ class _StepProgram:
     """One jitted engine program whose leading operand is the
     engine's weight list. Callers pass the remaining operands; `lower`
     and `_cache_size` (the retrace probes' view) go to the one
-    underlying `jax.jit`."""
+    underlying `jax.jit`. `donate` names the caller's operands (0 is
+    the first after the weights) whose buffers the program may write
+    its outputs into: the caller hands them over, must not read them
+    again, and takes back what the program returns in their place."""
 
-    def __init__(self, fn, state_vals, mesh=None):
-        self._jit = jax.jit(fn)
+    def __init__(self, fn, state_vals, mesh=None, donate=()):
+        self._jit = jax.jit(fn,
+                            donate_argnums=tuple(1 + i for i in donate))
         self._state_vals = state_vals
         # the tensor-parallel replica's device mesh: named while the
         # program is traced, so its Pallas kernels run per device

@@ -187,7 +187,9 @@ import collections
 import contextlib
 import functools
 import itertools
+import math
 import os
+import sys
 import threading
 import time
 import warnings
@@ -1367,7 +1369,10 @@ class ServingEngine:
                 gver = rest[i]
             return ustep(state_vals, ct, *base, group=group,
                          lora=lora, gsamp=gsamp, gver=gver)
-        return _StepProgram(call, state_vals, self._mesh)
+        # the pools, and only they, are donated: a step writes its rows
+        # into them in place (`_gather_pages` says why the host tier's
+        # reads stay correct)
+        return _StepProgram(call, state_vals, self._mesh, donate=(0,))
 
     def _build_embed(self):
         """Embeddings-lane epilogue: ONE jitted batched single-token
@@ -1377,11 +1382,12 @@ class ServingEngine:
         re-feeding the LAST prompt token at pos plen-1 recomputes
         exactly the final position's post-norm hidden state — the
         pooled last-hidden-state — at one token of compute, reusing
-        the pages the prefill already wrote. The returned caches are
-        DISCARDED (this is a pure read: `self._ct` is never
-        reassigned), and non-embed rows ride trash-masked page-table
-        rows, so the fixed [S, 1] shape serves any retiring subset
-        with zero retrace and zero state mutation."""
+        the pages the prefill already wrote. The caches the backbone
+        returns are DISCARDED and the pools are NOT donated (this is a
+        pure read: `self._ct` stays alive and is never reassigned), and
+        non-embed rows ride trash-masked page-table rows, so the fixed
+        [S, 1] shape serves any retiring subset with zero retrace and
+        zero state mutation."""
         backbone = self._model_backbone()
         state_vals = self._state_vals
 
@@ -1437,18 +1443,12 @@ class ServingEngine:
         cache hit/miss/eviction transitions). On the int8 pool the
         rowwise SCALE pages copy alongside the code pages — a COW'd
         partial page dequantizes to exactly the floats its source
-        held (the None check is pytree-static: still one program)."""
+        held (a None pool is an empty pytree node: still one program).
+        The pools are donated: the page is copied in place."""
         def cp(ct, src, dst):
-            out = []
-            for k, v, ks, vs in ct:
-                out.append((k.at[dst].set(k[src]),
-                            v.at[dst].set(v[src]),
-                            ks if ks is None else
-                            ks.at[dst].set(ks[src]),
-                            vs if vs is None else
-                            vs.at[dst].set(vs[src])))
-            return tuple(out)
-        return jax.jit(cp)
+            return jax.tree_util.tree_map(
+                lambda pool: pool.at[dst].set(pool[src]), ct)
+        return jax.jit(cp, donate_argnums=0)
 
     def _copy_page(self, src: int, dst: int):
         if self._copy_page_fn is None:
@@ -1490,7 +1490,8 @@ class ServingEngine:
         """ONE compiled host->device page write: scatters a
         [n_layers, 2, page_size, H, D] block (plus, on the int8 pool,
         its scale block) back into page `dst` of every layer's pools.
-        dst is a traced scalar — one trace serves every restore."""
+        dst is a traced scalar — one trace serves every restore. The
+        pools are donated: the page is written in place."""
         if self.kv_dtype == "int8":
             def si(ct, codes, scales, dst):
                 out = []
@@ -1510,19 +1511,26 @@ class ServingEngine:
                         v.at[dst].set(data[i, 1].astype(v.dtype)),
                         ks, vs))
                 return tuple(out)
-        return jax.jit(si)
+        return jax.jit(si, donate_argnums=0)
 
     def _gather_pages(self, pages):
-        """Dispatch the read of `pages` (device page ids) off the pools
-        AS THEY ARE NOW — the pools are never donated, so whatever a
-        later step, restore or COW writes to those pages lands in new
-        buffers. Returns the pieces in page order, each a list of
-        device payloads (`_build_swap_out`), for `_start_pages` and
-        `_fetch_pages`."""
+        """Read `pages` (device page ids) off the pools AS THEY ARE NOW,
+        and return only once the read is done. The step, the COW copy
+        and the restore are handed the pools (donated) and write them
+        IN PLACE, and the caller frees these device pages at once for
+        reuse in the same round: a gather still pending when such a
+        program runs could read bytes it wrote. So no donating program
+        is dispatched while a gather is in flight, however the runtime
+        orders a read against a later in-place write. The wait is the
+        gather's own (device idle between steps, ~0.1 GB for a round's
+        pages); the copies to host RAM still cross behind the next step
+        (`_start_pages`, `_fetch_pages`). Returns the pieces in page
+        order, each a list of device payloads (`_build_swap_out`)."""
         if self._swap_out_fn is None:
             self._swap_out_fn = self._build_swap_out()
             for w in SPILL_WIDTHS:      # every width, now and never again
-                self._swap_out_fn(self._ct, np.zeros((w,), np.int32))
+                jax.block_until_ready(self._swap_out_fn(
+                    self._ct, np.zeros((w,), np.int32)))
         ids = np.asarray(pages, np.int32)
         pieces, at = [], 0
         for w in SPILL_WIDTHS:
@@ -1531,7 +1539,7 @@ class ServingEngine:
                 at += w
         self._host_phases["kv_spill_pages_total"] += int(ids.size)
         self._host_phases["kv_spill_batches_total"] += len(pieces)
-        return pieces
+        return jax.block_until_ready(pieces)
 
     def _start_pages(self, piece):
         """Set a gathered piece's copies to host RAM off, all at once.
@@ -2586,6 +2594,8 @@ class ServingEngine:
                     self._ct, self._pos, self._last_logits, toks, \
                         accept = self._unified_fn(self._ct,
                                                   *plan.args_tail)
+                if not self.metrics.kv_pool_bytes:
+                    self._read_pool_alias(plan.args_tail)
                 # the round's spills were gathered before this launch:
                 # their copies cross the link while the step runs
                 self.host_pool.start_pending()
@@ -2604,6 +2614,22 @@ class ServingEngine:
             set_dispatch_probe(prev_probe)
         with self._phase(SPAN_COMMIT, "step_commit_s_total"):
             self._commit_unified(plan, counts, toks, accept, finished)
+
+    def _read_pool_alias(self, args_tail):
+        """Once, after the step's first launch: the pools' bytes on one
+        chip beside the bytes the compiled step writes in place of its
+        inputs (the jit's own executable: lowering and compiling again
+        are cache hits), on `/metrics` and in one line of set-up."""
+        pool = sum(math.prod(x.sharding.shard_shape(x.shape))
+                   * x.dtype.itemsize
+                   for x in jax.tree_util.tree_leaves(self._ct))
+        aliased = self._unified_fn.lower(self._ct, *args_tail).compile() \
+            .memory_analysis().alias_size_in_bytes
+        self.metrics.kv_pool_bytes = pool
+        self.metrics.kv_pool_aliased_bytes = aliased
+        print(f"ServingEngine: the unified step writes {aliased} bytes in "
+              f"place of the KV pools' {pool}: {aliased / pool:.1%}",
+              file=sys.stderr, flush=True)
 
     def _plan_unified(self, suppress) -> Optional["_StepPlan"]:
         """The host's work before the launch (`serving::plan`): pack

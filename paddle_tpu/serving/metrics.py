@@ -306,6 +306,12 @@ class ServingMetrics:
             HOST_PHASE_COUNTERS + STEP_WORK_COUNTERS, 0)
         # unified-step counters: steps run, and the packed token split
         self.unified_steps = 0
+        # the KV pools' bytes on one chip, and the bytes the compiled
+        # step writes in place of its donated inputs (XLA's alias size;
+        # both read once, after the step's first launch): a ratio of 1
+        # is a step that copies no pool
+        self.kv_pool_bytes = 0
+        self.kv_pool_aliased_bytes = 0
         self.packed_prefill_tokens = 0
         self.packed_decode_tokens = 0
         self.packed_draft_tokens = 0
@@ -780,6 +786,8 @@ class ServingMetrics:
             "mp": self.mp,
             "dp": self.dp,
             "unified_steps": self.unified_steps,
+            "kv_pool_bytes": self.kv_pool_bytes,
+            "kv_pool_aliased_bytes": self.kv_pool_aliased_bytes,
             **self.host_phases,
             "packed_prefill_tokens": self.packed_prefill_tokens,
             "packed_decode_tokens": self.packed_decode_tokens,
@@ -957,6 +965,8 @@ def prometheus_render(snapshots: dict, namespace: str = "paddle_serving",
                        ("host_bytes_total", "gauge"),
                        ("swap_in_seconds", "histogram"),
                        ("unified_steps_total", "counter"),
+                       ("kv_pool_bytes", "gauge"),
+                       ("kv_pool_aliased_bytes", "gauge"),
                        ("grouped_walk_steps_total", "counter"),
                        ("spec_drafted_total", "counter"),
                        ("spec_accepted_total", "counter"),
@@ -1055,6 +1065,9 @@ def prometheus_render(snapshots: dict, namespace: str = "paddle_serving",
         lines.append(f"{namespace}_unified_steps_total"
                      + _fmt_labels(lab)
                      + f" {snap.get('unified_steps', 0)}")
+        for name in ("kv_pool_bytes", "kv_pool_aliased_bytes"):
+            lines.append(f"{namespace}_{name}" + _fmt_labels(lab)
+                         + f" {snap.get(name, 0)}")
         lines.append(f"{namespace}_grouped_walk_steps_total"
                      + _fmt_labels(lab)
                      + f" {snap.get('grouped_walk_steps_total', 0)}")

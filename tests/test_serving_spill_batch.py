@@ -129,8 +129,9 @@ def test_payloads_are_buffers_of_their_own():
 # -- (b) read before overwrite -----------------------------------------------
 def test_copy_holds_what_the_pages_held_before_steps_rewrote_them():
     """Spill, let steps rewrite the freed device pages, THEN collect:
-    the gather was dispatched before the steps, and the pools are not
-    donated, so the payloads are the old contents."""
+    the gather was read before the steps were dispatched (the pools are
+    donated to them and rewritten in place), so the payloads are the old
+    contents."""
     eng = engine(num_pages=9)
     eng.add_request(np.arange(1, 41, dtype=np.int64) % 97,
                     SamplingParams(max_new_tokens=4))
