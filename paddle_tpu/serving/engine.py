@@ -250,18 +250,22 @@ _TERMINAL_EVENT = {"stop": "finish", "length": "finish",
 # Host spans (`profiler.RecordEvent`): names are constants, ids ride as
 # arguments. Each span is also a `jax.profiler.TraceAnnotation`, so a JAX
 # profiler session against the running engine holds them on the clock of
-# the device's `XLA Ops`. One scheduler round nests as
-#   round(step) > admit > {spill(need | pages), restore(page), cow_copy}
-#               > plan > {spill, restore}
-#               > unified_step > launch, spill(pages), fetch, spill
-#               > commit > embed_epilogue
-#               > report
-# and the seconds of plan/launch/fetch/commit/admit/report/spill also
-# feed `metrics.HOST_PHASE_COUNTERS`, one `on_host_phases` call a round.
-SPAN_ROUND = "serving::round"
+# the device's `XLA Ops`. One scheduler round is a row of leaves, with
+# no span around the round or the step:
+#   admit(step) > {spill(need | pages), restore(page), cow_copy}
+#   plan > {spill, restore}
+#   launch(tokens), spill > spill(pages), fetch, spill > spill(pages)
+#   commit > embed_epilogue
+#   spill > spill(pages)
+#   report
+# and with the HTTP driver's `serving::inbox` and `serving::wait`
+# (http/driver.py) they tile the engine's thread. Each leaf's seconds
+# feed one of `metrics.HOST_PHASE_COUNTERS`, a spill between the
+# round's other leaves `round_spill_s_total` (`_spill_leaf`, the spills
+# of its pieces nested in it); what nests in a leaf is counted in it.
+# One `on_host_phases` call a round.
 SPAN_ADMIT = "serving::admit"
 SPAN_PLAN = "serving::plan"
-SPAN_STEP = "serving::unified_step"
 SPAN_LAUNCH = "serving::launch"
 SPAN_FETCH = "serving::fetch"
 SPAN_COMMIT = "serving::commit"
@@ -1067,6 +1071,30 @@ class ServingEngine:
         with RecordEvent(span, **args) as ev:
             yield
         self._host_phases[counter] += ev.elapsed_s
+
+    def note_host_phase(self, counter: str, seconds: float):
+        """Add to the round's account from outside the engine: the HTTP
+        driver's leaves of the engine's thread (http/driver.py)."""
+        self._host_phases[counter] += seconds
+
+    def flush_host_phases(self):
+        """Hand the account to `metrics.on_host_phases`: at the end of
+        each round, and by the driver after a pump iteration that ran
+        none."""
+        self.metrics.on_host_phases(self._host_phases)
+        self._host_phases.clear()
+
+    def _spill_leaf(self, settle):
+        """`host_pool.start_pending` or `collect_pending` between the
+        round's other leaves, where anything is pending: one
+        `serving::spill` around the whole call, the bookkeeping and the
+        release of the gathered pages included, counted in
+        `round_spill_s_total`, which holds none of the spills that
+        admit or plan count already. The spans of its pieces nest
+        inside it and stay in `kv_spill_s_total`."""
+        if self.host_pool.pending_pages:
+            with self._phase(SPAN_SPILL, "round_spill_s_total"):
+                settle()
 
     def _obs_event(self, req: "Request", kind: str, **detail):
         """Record one request-timeline event (no-op with obs off)."""
@@ -1913,7 +1941,7 @@ class ServingEngine:
         duplicate-id guard). A request's residency is no profiler
         span (it crosses rounds, so it cannot nest in a trace): its
         timeline is `obs.RequestTracer`'s, joined to the trace by the
-        step index that `serving::round` carries."""
+        step index that `serving::admit` carries."""
         self._prefill_cursor.pop(req.request_id, None)
         self._drafters.pop(req.request_id, None)
         self._grammars.pop(req.request_id, None)
@@ -2589,31 +2617,32 @@ class ServingEngine:
             lambda name: counts.__setitem__(name,
                                             counts.get(name, 0) + 1))
         try:
-            with RecordEvent(SPAN_STEP):
-                with self._phase(SPAN_LAUNCH, "step_launch_s_total"):
-                    self._ct, self._pos, self._last_logits, toks, \
-                        accept = self._unified_fn(self._ct,
-                                                  *plan.args_tail)
-                if not self.metrics.kv_pool_bytes:
-                    self._read_pool_alias(plan.args_tail)
-                # the round's spills were gathered before this launch:
-                # their copies cross the link while the step runs
-                self.host_pool.start_pending()
-                # sync: the host waits for the device, then sees the
-                # tokens
-                with self._phase(SPAN_FETCH, "step_fetch_s_total"):
-                    toks = np.asarray(toks)
-                    accept = np.asarray(accept)
-                # the copies have had the step to arrive in: take them
-                # into host RAM and drop their device side
-                self.host_pool.collect_pending()
-                for name, n in zip(self._step_stat_names,
-                                   accept[self.num_slots:]):
-                    self._host_phases[name] += int(n)
+            with self._phase(SPAN_LAUNCH, "step_launch_s_total",
+                             tokens=self.step_tokens_inflight):
+                self._ct, self._pos, self._last_logits, toks, accept = \
+                    self._unified_fn(self._ct, *plan.args_tail)
+            if not self.metrics.kv_pool_bytes:
+                self._read_pool_alias(plan.args_tail)
+            # the round's spills were gathered before this launch: their
+            # copies cross the link while the step runs
+            self._spill_leaf(self.host_pool.start_pending)
+            # sync: the host waits for the device, then sees the tokens
+            with self._phase(SPAN_FETCH, "step_fetch_s_total"):
+                toks = np.asarray(toks)
+                accept = np.asarray(accept)
+            # the copies have had the step to arrive in: take them into
+            # host RAM and drop their device side
+            self._spill_leaf(self.host_pool.collect_pending)
+            for name, n in zip(self._step_stat_names,
+                               accept[self.num_slots:]):
+                self._host_phases[name] += int(n)
         finally:
             set_dispatch_probe(prev_probe)
         with self._phase(SPAN_COMMIT, "step_commit_s_total"):
             self._commit_unified(plan, counts, toks, accept, finished)
+            # the step's operands are released under the span, not in
+            # the gap before the next leaf
+            del plan
 
     def _read_pool_alias(self, args_tail):
         """Once, after the step's first launch: the pools' bytes on one
@@ -3052,16 +3081,9 @@ class ServingEngine:
                              "reads_saved": 0, "collectives": 0,
                              "constrained_rows": 0,
                              "grammar_rejected": 0, "wall_s": 0.0}
-        with RecordEvent(SPAN_ROUND, step=self._step_idx):
-            self._round(finished)
-        self.metrics.on_host_phases(self._host_phases)
-        self._host_phases.clear()
-        return finished
-
-    def _round(self, finished: List[RequestOutput]):
-        """`step()`'s body, inside `serving::round`."""
         now = self._clock()
-        with self._phase(SPAN_ADMIT, "round_admit_s_total"):
+        with self._phase(SPAN_ADMIT, "round_admit_s_total",
+                         step=self._step_idx):
             self._evict(now, finished)
             self._admit(now)
             self._preempt_for_overload(now)
@@ -3088,9 +3110,12 @@ class ServingEngine:
                                          detail=repr(exc),
                                          step=self._step_idx,
                                          slo=self._slo_snap())
-        self.host_pool.collect_pending()    # a round without a launch
+        # what a round without a launch left pending
+        self._spill_leaf(self.host_pool.collect_pending)
         with self._phase(SPAN_REPORT, "round_report_s_total"):
             self._report_round()
+        self.flush_host_phases()
+        return finished
 
     def _report_round(self):
         """What the observability costs each round (`serving::report`):

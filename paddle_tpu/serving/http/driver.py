@@ -52,6 +52,14 @@ __all__ = ["EngineDriver", "ReplicaDead", "ReplicaHung"]
 # back). The wait itself is counted by the pump thread, from the stamp
 # on the `_Submission`: `metrics.submit_wait_s_total`.
 SPAN_SUBMIT = "http::submit"
+# the pump thread's leaves beside the engine's round (engine.py lists
+# those): the service of an inbox that holds anything, and a stretch in
+# which the engine has no work, a span for each WAIT_SPAN_S of it
+# however many polls that holds. Their seconds, and the loop's own
+# (`pump_s_total`), go to the engine's round account.
+SPAN_INBOX = "serving::inbox"
+SPAN_WAIT = "serving::wait"
+WAIT_SPAN_S = 0.025
 
 
 class ReplicaDead(ServingError):
@@ -89,6 +97,50 @@ class _Call:
         self.done = threading.Event()
         self.result = None
         self.error: Optional[BaseException] = None
+
+
+class _Wait:
+    """The pump's `serving::wait` over a stretch of polls in which the
+    engine has no work: one span for each WAIT_SPAN_S of it, not one a
+    poll, and no longer, because a profiler session keeps no span that
+    is open when it starts or stops. Its seconds go to the engine's
+    account (`engine_wait_s_total`) as they pass, so a window's counter
+    difference holds the part of a stretch that lies inside it."""
+
+    __slots__ = ("engine", "span", "t", "t_span")
+
+    def __init__(self, engine):
+        self.engine = engine
+        self.span: Optional[RecordEvent] = None
+        self.t = self.t_span = 0.0
+
+    def begin(self):
+        if self.span is None:
+            self.span = RecordEvent(SPAN_WAIT)
+            self.span.begin()
+            self.t = self.t_span = time.perf_counter()
+
+    def _account(self) -> float:
+        now = time.perf_counter()
+        self.engine.note_host_phase("engine_wait_s_total", now - self.t)
+        self.t = now
+        return now
+
+    def note(self):
+        """Account the seconds since the last note, and start the next
+        span where this one has run WAIT_SPAN_S."""
+        if self.span is not None:
+            now = self._account()
+            if now - self.t_span >= WAIT_SPAN_S:
+                self.span.end()
+                self.span.begin()
+                self.t_span = now
+
+    def end(self):
+        if self.span is not None:
+            self._account()
+            self.span.end()
+            self.span = None
 
 
 class EngineDriver:
@@ -341,6 +393,9 @@ class EngineDriver:
 
     # -- pump thread -------------------------------------------------------
     def _pump(self):
+        eng = self.engine
+        wait = _Wait(eng)
+        t_loop = time.perf_counter()
         try:
             while True:
                 if self._fault is not None:
@@ -357,10 +412,11 @@ class EngineDriver:
                     spike_n = self._faults.take_spike(self.name,
                                                       self.steps)
                 if self._draining:
+                    wait.end()
                     self._fail_pending(EngineClosed(
                         f"{self.name} draining"))
                     with self._mutate_lock:
-                        self.engine.drain()
+                        eng.drain()
                     return
                 worked = False
                 with self._mutate_lock:
@@ -370,18 +426,32 @@ class EngineDriver:
                         return
                     if spike_n:
                         self._inject_spike(spike_n)
-                    self._service_inbox()
-                    if self.engine.has_work:
-                        self.engine.step()
+                    if not self._inbox.empty():
+                        wait.end()
+                        with RecordEvent(SPAN_INBOX) as ev:
+                            self._service_inbox()
+                        eng.note_host_phase("inbox_s_total", ev.elapsed_s)
+                    if eng.has_work:
+                        wait.end()
+                        eng.step()
                         self.steps += 1
                         worked = True
                 if not worked:
+                    wait.begin()
                     self._wake.wait(self.poll_interval_s)
                     self._wake.clear()
                 self.last_beat = time.monotonic()
+                wait.note()
+                now = time.perf_counter()
+                eng.note_host_phase("pump_s_total", now - t_loop)
+                t_loop = now
+                if not worked:
+                    # a round flushes its own account
+                    eng.flush_host_phases()
         except BaseException as exc:   # replica death path
             self._do_die(exc)
         finally:
+            wait.end()
             self._stopped.set()
 
     def _inject_spike(self, n: int):

@@ -6,8 +6,9 @@ Three consumers, one source of truth:
 - `prometheus_render(...)` — the same snapshot as Prometheus text
   exposition for the HTTP server's `/metrics` endpoint, including
   fixed-bucket `_bucket` series for TTFT and inter-token latency.
-- `profiler.RecordEvent` spans emitted by the engine around each
-  scheduler round and its phases (engine.py lists them) — in a Chrome
+- `profiler.RecordEvent` spans emitted by the engine around each phase
+  of a scheduler round and by the HTTP driver around its intake and
+  its waits (engine.py lists them) — in a Chrome
   trace from a serving run (profiler.Profiler + export) and, while a JAX
   profiler session runs, in the device's own trace. The seconds of the
   same spans are the `HOST_PHASE_COUNTERS` here; a request's residency
@@ -75,7 +76,12 @@ ADAPTER_IDS_MAX = 8
 # acquired), the copies are set off after the step's launch and
 # collected after its fetch, `kv_spill_wait_s_total` being the part of
 # the collection the host spent blocked; `submit_wait_s_total` on the
-# front-end's handler threads, outside the round.
+# front-end's handler threads, outside the round. The engine thread's
+# leaves are disjoint: `inbox_s_total`, `engine_wait_s_total`,
+# `round_admit_s_total`, the four `step_*`, `round_report_s_total` and
+# `round_spill_s_total` (a spill between the others, not one inside
+# admit or plan) add up to `pump_s_total`, the HTTP driver's loop from
+# end to end, less the few clock reads between the spans.
 HOST_PHASE_COUNTERS = (
     "step_plan_s_total",        # serving::plan
     "step_launch_s_total",      # serving::launch
@@ -89,6 +95,10 @@ HOST_PHASE_COUNTERS = (
     "kv_spill_wait_s_total",    # serving::spill, blocked on a copy
     "submit_wait_s_total",      # http::submit until add_request
     "submits_serviced_total",   # submissions the pump thread took
+    "round_spill_s_total",      # serving::spill outside admit and plan
+    "inbox_s_total",            # serving::inbox
+    "engine_wait_s_total",      # serving::wait
+    "pump_s_total",             # the driver's pump loop, every iteration
 )
 
 

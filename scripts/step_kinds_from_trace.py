@@ -1,5 +1,6 @@
 """Dev aid: the device time of each executed step program in a profiler
-trace of a running engine, and the page walk's part of it.
+trace of a running engine, split by what the host packed into the step,
+and whether the trace's two planes agree.
 
     python scripts/step_kinds_from_trace.py <file.xplane.pb> [out.json]
 
@@ -7,12 +8,20 @@ A `--trace 1` run of a serving cell leaves its trace under
 `.bench_trace/<cell>/`. The device plane's `XLA Modules` line holds one
 event an executed program; the `XLA Ops` inside its interval are summed
 into: busy ms (union), ms under `ptk:ragged_walk` and under
-`ptk:grouped_phase1`. A step is taken to HOLD A CHUNK where its walk is
-more than `CHUNK_FACTOR` times the median step's (a decoding row's
-query block computes over 16 rows of its key blocks, a chunk's over all
-its rows); the split is by what it measures, so read the lists, not only
-the two medians: where every step holds a chunk (`docs_backlog`) the
-median step is one of them and the split says nothing. Needs no chip: run it with JAX_PLATFORMS=cpu.
+`ptk:grouped_phase1`. Each execution is joined to the host's
+`serving::launch` that started last before it, and that launch's
+`tokens` argument (the packed tokens of the step, as the host planned
+them) splits the steps: one that packs more than `DECODE_MAX_TOKENS`
+HOLDS A CHUNK; one that packs fewer is decode-only or holds a prompt's
+short tail. The step program is the module most of whose executions a
+launch and the fetch after it bracket.
+
+`clock` says whether the planes share one clock and how far the device
+plane reaches: of the step's executions, how many lie between their
+launch's start and the end of the fetch that follows it; the host's
+launches against the device's executions; and the last device
+operation's time against the end of the window (the last event of any
+plane). Needs no chip: run it with JAX_PLATFORMS=cpu.
 """
 import bisect
 import json
@@ -24,13 +33,17 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark import trace  # noqa: E402
 
-CHUNK_FACTOR = 2.0
+# the most slots of a serving cell's engine: a step that packs more
+# tokens than this holds a prefill chunk
+DECODE_MAX_TOKENS = 16
 NEEDLES = {"walk_ms": "ptk:ragged_walk", "phase1_ms": "ptk:grouped_phase1"}
+LAUNCH, FETCH = "serving::launch", "serving::fetch"
 
 
 def steps_of(pd):
-    """-> {module name: [{"start_ms", "busy_ms", "walk_ms", "phase1_ms"}]}
-    for the first device plane that ran anything."""
+    """-> ({module name: [{"start", "end", "busy_ms", "walk_ms",
+    "phase1_ms"}]}, last device op's end in ns) for the first device
+    plane that ran anything."""
     for plane in pd.planes:
         if not plane.name.startswith("/device:TPU:"):
             continue
@@ -50,7 +63,7 @@ def steps_of(pd):
         out = {}
         for ev in lines["XLA Modules"].events:
             s, e = ev.start_ns, ev.start_ns + ev.duration_ns
-            rec = {"start_ms": s / 1e6, "busy_ms": 0.0, "walk_ms": 0.0,
+            rec = {"start": s, "end": e, "busy_ms": 0.0, "walk_ms": 0.0,
                    "phase1_ms": 0.0}
             edge = s
             for o0, o1, tags in ops[bisect.bisect_left(starts, s):
@@ -61,34 +74,85 @@ def steps_of(pd):
                     rec[tag] += (o1 - o0) / 1e6
             out.setdefault(ev.name, []).append(rec)
         if out:
-            return out
-    return {}
+            return out, max(o[1] for o in ops)
+    return {}, None
+
+
+def host_spans(pd):
+    """-> ({name: sorted [(start, end, args)]} of the launches and
+    fetches on the host planes, (lo, hi): the window over every event of
+    every plane, as `trace.reduce_xspace` takes it)."""
+    out, lo, hi = {LAUNCH: [], FETCH: []}, None, None
+    for plane in pd.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                s, e = ev.start_ns, ev.start_ns + ev.duration_ns
+                lo = s if lo is None else min(lo, s)
+                hi = e if hi is None else max(hi, e)
+                if plane.name.startswith("/host:") and ev.name in out:
+                    out[ev.name].append((s, e, dict(ev.stats)))
+    return {n: sorted(v) for n, v in out.items()}, (lo, hi)
+
+
+def join(recs, spans):
+    """Give each execution its launch's `tokens` and whether that launch
+    and the fetch after it bracket the execution."""
+    launches, fetches = spans[LAUNCH], spans[FETCH]
+    l_starts = [s for s, _, _ in launches]
+    f_starts = [s for s, _, _ in fetches]
+    for r in recs:
+        i = bisect.bisect_right(l_starts, r["start"]) - 1
+        r["tokens"], r["bracketed"] = None, False
+        if i < 0:
+            continue
+        ls, _, args = launches[i]
+        r["tokens"] = args.get("tokens")
+        j = bisect.bisect_left(f_starts, ls)
+        r["bracketed"] = j < len(fetches) and fetches[j][1] >= r["end"]
+    return recs
 
 
 def summary(recs):
     def med(rows, key):
         return statistics.median(r[key] for r in rows) if rows else None
-    cut = CHUNK_FACTOR * med(recs, "walk_ms")
-    split = {"decode_only": [r for r in recs if r["walk_ms"] <= cut],
-             "with_chunk": [r for r in recs if r["walk_ms"] > cut]}
-    out = {"steps": len(recs), "walk_ms_cut": cut}
+    known = [r for r in recs if r["tokens"] is not None]
+    split = {"decode_only": [r for r in known
+                             if r["tokens"] <= DECODE_MAX_TOKENS],
+             "with_chunk": [r for r in known
+                            if r["tokens"] > DECODE_MAX_TOKENS]}
+    out = {"steps": len(recs), "without_launch": len(recs) - len(known)}
     for name, rows in split.items():
         out[name] = {"steps": len(rows),
-                     **{k: med(rows, k)
-                        for k in ("busy_ms", "walk_ms", "phase1_ms")},
+                     **{k: med(rows, k) for k in
+                        ("tokens", "busy_ms", "walk_ms", "phase1_ms")},
                      "busy_ms_max": max((r["busy_ms"] for r in rows),
                                         default=None)}
-    out["each"] = [[round(r[k], 3) for k in ("busy_ms", "walk_ms",
-                                              "phase1_ms")] for r in recs]
+    out["each"] = [[r["tokens"]] + [round(r[k], 3) for k in
+                                    ("busy_ms", "walk_ms", "phase1_ms")]
+                   for r in recs]
     return out
 
 
 def main(argv):
     from jax.profiler import ProfileData
-    mods = steps_of(ProfileData.from_file(argv[0]))
-    # the step program is the one that ran the walk
-    res = {name: summary(recs) for name, recs in mods.items()
-           if any(r["walk_ms"] for r in recs)}
+    pd = ProfileData.from_file(argv[0])
+    mods, last_op = steps_of(pd)
+    spans, (lo, hi) = host_spans(pd)
+    mods = {name: join(recs, spans) for name, recs in mods.items()}
+    step = max(mods, default=None,
+               key=lambda n: sum(r["bracketed"] for r in mods[n]))
+    res = {}
+    if step is not None:
+        recs = mods[step]
+        res[step] = summary(recs)
+        res["clock"] = {
+            "step_executions": len(recs),
+            "bracketed": sum(r["bracketed"] for r in recs),
+            "host_launches": len(spans[LAUNCH]),
+            "launches_after_last_op": sum(
+                s > last_op for s, _, _ in spans[LAUNCH]),
+            "window_ms": (hi - lo) / 1e6,
+            "last_op_to_window_end_ms": (hi - last_op) / 1e6}
     text = json.dumps(res)
     if len(argv) > 1:
         with open(argv[1], "w") as f:
@@ -96,7 +160,7 @@ def main(argv):
     for name, s in res.items():
         print(name, json.dumps({k: v for k, v in s.items() if k != "each"}))
     if not res:
-        print("no module ran a page walk; modules:",
+        print("no step program on a device plane; modules:",
               {n: len(r) for n, r in mods.items()})
 
 

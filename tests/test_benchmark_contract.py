@@ -14,7 +14,9 @@ on the CPU. It reads the metric files and edits nothing there.
   `latent_roofline`: its `pairs` and `distinct` counters;
   `sparse_roofline`: the counters its `flops` and `bytes` name;
 - `span_idle`: every span under `spans` and `excluding` is a `SPAN_*`
-  constant that the engine or the HTTP driver opens.
+  constant that the engine or the HTTP driver opens; `idle_outside`
+  and `leaf_idle`: every span under `spans` likewise, and
+  `leaf_idle`'s `counter` a counter as above.
 """
 import glob
 import json
@@ -63,12 +65,16 @@ def _metric_files():
             counters += [spec["args"]["hit"], spec["args"]["here"]]
         if spec["reader"] == "latent_roofline":
             counters += [spec["args"]["pairs"], spec["args"]["distinct"]]
+        if spec["reader"] == "leaf_idle":
+            counters.append(spec["args"]["counter"])
         if spec["reader"] == "sparse_roofline":
             counters += [*spec["args"]["flops"].values(),
                          *spec["args"]["bytes"].values()]
         spans = (_listed(spec["args"].get("spans"))
                  + _listed(spec["args"].get("excluding"))
-                 if spec["reader"] == "span_idle" else [])
+                 if spec["reader"] in ("span_idle", "idle_outside",
+                                       "leaf_idle")
+                 else [])
         if counters or series or spans:
             out.append(pytest.param(
                 counters, series, spans,
@@ -108,12 +114,13 @@ def opened_spans():
 
 
 def test_the_yardstick_names_something():
-    """29 metrics read the engine's counters and histograms at the top
+    """32 metrics read the engine's counters and histograms at the top
     of their arguments, 2 more in an operand, 4 through the roofline
-    readers; 8 read spans."""
+    readers, 1 through `leaf_idle`; 14 read spans, 2 of them through
+    `idle_outside` and 1 through `leaf_idle`."""
     cases = [p.values for p in _metric_files()]
-    assert sum(1 for c, s, sp in cases if c or s) == 35
-    assert sum(1 for c, s, sp in cases if sp) == 8
+    assert sum(1 for c, s, sp in cases if c or s) == 39
+    assert sum(1 for c, s, sp in cases if sp) == 14
 
 
 @pytest.mark.parametrize("counters,series,spans", _metric_files())

@@ -97,11 +97,15 @@ def test_engine_rounds_reach_the_jax_trace(tmp_path):
     finally:
         jax.profiler.stop_trace()
     host = _host_events(str(tmp_path))
-    steps = [args["step"] for _, _, args in host["serving::round"]]
+    steps = [args["step"] for _, _, args in host["serving::admit"]]
     assert steps == list(range(2, eng._step_idx + 1))
-    for name in ("admit", "plan", "unified_step", "launch", "fetch",
-                 "commit", "report"):
+    for name in ("plan", "launch", "fetch", "commit", "report"):
         assert len(host[f"serving::{name}"]) == len(steps), name
+    # one decoding row a step, as the host planned it
+    assert [args["tokens"] for _, _, args in host["serving::launch"]] \
+        == [1] * len(steps)
+    assert "serving::round" not in host
+    assert "serving::unified_step" not in host
 
 
 SPAN_USERS = (engine_mod, driver_mod, trainer)
@@ -176,7 +180,8 @@ def test_spills_are_counted_with_their_seconds():
     host tier, and the counters say how many pages, in how many gathers,
     and how long: the tree walk and the dispatch inside `serving::admit`
     or `serving::plan`, the copies set off after the step's launch and
-    collected after its fetch, all under `serving::spill`."""
+    collected after its fetch, all under `serving::spill`; the last two
+    also inside a `serving::spill` of the round's own."""
     eng = ServingEngine(tiny_gpt(), num_slots=2, max_len=64,
                         page_size=8, num_pages=13, chunk_len=8)
     wall = 0.0
@@ -198,13 +203,21 @@ def test_spills_are_counted_with_their_seconds():
     assert 0 < snap["kv_spill_s_total"] <= wall - sum(
         snap[k] for k in ("step_launch_s_total", "step_fetch_s_total",
                           "step_commit_s_total", "round_report_s_total"))
+    # the copies set off and collected between the round's other
+    # leaves, one span around each such call: the leaves tile the rounds
+    assert snap["round_spill_s_total"] > 0
+    assert sum(snap[k] for k in (
+        "round_admit_s_total", "step_plan_s_total", "step_launch_s_total",
+        "round_spill_s_total", "step_fetch_s_total", "step_commit_s_total",
+        "round_report_s_total")) == pytest.approx(wall, rel=0.10)
     spans = p.aggregate()["serving::spill"]
     # a spill's tree walk and its dispatch; a gather's copies set off
-    # and collected
+    # and collected; the round's calls that set them off and collect
+    # them, around those
     batches = snap["kv_spill_batches_total"]
-    assert 2 * batches < spans["calls"] <= 4 * batches
+    assert 2 * batches < spans["calls"] <= 6 * batches
     assert spans["total"] / 1e9 == pytest.approx(
-        snap["kv_spill_s_total"], rel=0.05)
+        snap["kv_spill_s_total"] + snap["round_spill_s_total"], rel=0.05)
 
 
 def test_submit_wait_is_counted_by_the_pump_thread():
@@ -230,6 +243,70 @@ def test_submit_wait_is_counted_by_the_pump_thread():
     assert spans["calls"] == 5
     # the handler's span holds the wait and the reply's way back
     assert spans["total"] / 1e9 >= snap["submit_wait_s_total"]
+
+
+# the leaves of the engine's thread and the counter each feeds
+LEAVES = {"serving::inbox": "inbox_s_total",
+          "serving::wait": "engine_wait_s_total",
+          "serving::admit": "round_admit_s_total",
+          "serving::plan": "step_plan_s_total",
+          "serving::launch": "step_launch_s_total",
+          "serving::fetch": "step_fetch_s_total",
+          "serving::commit": "step_commit_s_total",
+          "serving::report": "round_report_s_total",
+          "serving::spill": "round_spill_s_total"}
+
+
+def test_leaves_tile_the_pump_thread(tmp_path):
+    """Under an EngineDriver the engine's thread is a row of disjoint
+    leaf spans, busy rounds and idle stretches alike, with no span
+    around a round or a step; their counters add up to the pump loop's
+    own seconds."""
+    eng = ServingEngine(tiny_gpt(), num_slots=2, max_len=48)
+    eng.generate([np.array([1, 2, 3], np.int64)],
+                 SamplingParams(max_new_tokens=2))   # compiles first
+    drv = driver_mod.EngineDriver(eng).start()
+    try:
+        time.sleep(0.1)                  # idle: the account is flushed
+        before = eng.metrics.snapshot()
+        with profiler.Profiler(
+                targets=[profiler.ProfilerTarget.CPU]) as p:
+            for i in range(3):
+                reqs = [drv.submit(np.array([1, 2, 3 + i + j], np.int64),
+                                   SamplingParams(max_new_tokens=3 + j))
+                        for j in range(2)]
+                assert all(r.wait(timeout=60.0) for r in reqs)
+                time.sleep(0.5)          # an idle stretch between them
+        time.sleep(0.1)
+        snap = eng.metrics.snapshot()
+    finally:
+        assert drv.drain(timeout=60.0)
+    path = p.export(str(tmp_path / "pump.json"))
+    events = profiler.load_profiler_result(path)["traceEvents"]
+    names = {e["name"] for e in events}
+    assert not names & {"serving::round", "serving::unified_step"}
+    pump = sorted((e["ts"], e["ts"] + e["dur"], e["name"], e.get("args"))
+                  for e in events if e["tid"] == drv._thread.ident
+                  and e["name"] in LEAVES)
+    # a spill inside admit or plan is no leaf: none happens here
+    assert "serving::spill" not in names
+    for (_, end, a, _), (start, _, b, _) in zip(pump, pump[1:]):
+        assert start >= end - 1e-3, (a, b)          # us, as exported
+    covered = sum(e - s for s, e, _, _ in pump)
+    assert covered >= 0.99 * (pump[-1][1] - pump[0][0])
+    assert {n for _, _, n, _ in pump} >= set(LEAVES) - {"serving::spill"}
+    # an idle stretch is cut into spans of WAIT_SPAN_S, not one a poll
+    waits = [e - s for s, e, n, _ in pump if n == "serving::wait"]
+    assert len(waits) >= 3 * 3
+    assert max(waits) <= 1e6 * (driver_mod.WAIT_SPAN_S + 0.05)
+    assert all(isinstance(a["step"], int)
+               for _, _, n, a in pump if n == "serving::admit")
+    assert all(a["tokens"] >= 1
+               for _, _, n, a in pump if n == "serving::launch")
+    spent = {k: snap[k] - before[k] for k in
+             set(LEAVES.values()) | {"pump_s_total"}}
+    leaves = sum(v for k, v in spent.items() if k != "pump_s_total")
+    assert 0.99 * spent["pump_s_total"] <= leaves <= spent["pump_s_total"]
 
 
 def test_train_step_spans(tmp_path):

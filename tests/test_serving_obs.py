@@ -384,7 +384,7 @@ class TestEngineObs:
                 eng.abort_all("replica_failure")
         assert eng.closed
         spans = p.aggregate()
-        assert spans["serving::round"]["calls"] == 1
+        assert spans["serving::admit"]["calls"] == 1
         assert not any(n.startswith("serving::request") for n in spans)
         assert not hasattr(eng, "_spans")
         kinds = [e["kind"] for e in eng.obs.tracer.timeline(r.request_id)]

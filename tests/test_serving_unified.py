@@ -208,10 +208,11 @@ class TestUnifiedMetrics:
 
 
 def test_chrome_trace_has_unified_step_and_request_spans(tmp_path):
-    """Profiler spans of a round: fixed names, ids in the
-    arguments. One serving::round per engine step holding admit, plan,
-    unified_step (launch + fetch), commit and report; the per-request
-    part is RequestTracer's timeline, joined by the round's step."""
+    """Profiler spans of a round: fixed names, ids in the arguments. One
+    row of leaves per engine step, admit (with the round's step index),
+    plan, launch (with the step's packed tokens), fetch, commit and
+    report, and no span around the round or the step; the per-request
+    part is RequestTracer's timeline, joined by admit's step."""
     from paddle_tpu import profiler
     model = tiny_gpt()
     eng = ServingEngine(model, num_slots=2, max_len=48)
@@ -225,24 +226,24 @@ def test_chrome_trace_has_unified_step_and_request_spans(tmp_path):
         trace = json.load(f)
     events = trace["traceEvents"]
     names = [e["name"] for e in events]
-    assert names.count("serving::unified_step") >= 3
-    rounds = [e for e in events if e["name"] == "serving::round"]
-    assert [e["args"]["step"] for e in rounds] == \
+    assert "serving::unified_step" not in names
+    assert "serving::round" not in names
+    admits = [e for e in events if e["name"] == "serving::admit"]
+    assert [e["args"]["step"] for e in admits] == \
         list(range(1, eng._step_idx + 1))
-    for phase in ("admit", "plan", "launch", "fetch", "commit",
-                  "report"):
-        assert names.count(f"serving::{phase}") >= 3, phase
-
-    def inside(child, parent):
-        return parent["ts"] <= child["ts"] and \
-            child["ts"] + child["dur"] <= parent["ts"] + parent["dur"]
+    for phase in ("plan", "launch", "fetch", "commit", "report"):
+        assert names.count(f"serving::{phase}") == len(admits), phase
+    # the prompt's three tokens, then one decoding row a step
+    assert [e["args"]["tokens"] for e in events
+            if e["name"] == "serving::launch"] == \
+        [3] + [1] * (len(admits) - 1)
     first = {n: next(e for e in events if e["name"] == f"serving::{n}")
-             for n in ("round", "admit", "plan", "unified_step",
-                       "launch", "fetch", "commit", "report")}
-    for n in ("admit", "plan", "unified_step", "commit", "report"):
-        assert inside(first[n], first["round"]), n
-    assert inside(first["launch"], first["unified_step"])
-    assert inside(first["fetch"], first["unified_step"])
+             for n in ("admit", "plan", "launch", "fetch", "commit",
+                       "report")}
+    # a row: each leaf ends before the next one starts
+    row = list(first.values())
+    for a, b in zip(row, row[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"], (a["name"], b["name"])
     # no name is built per call: nothing carries an id in brackets
     assert not any("[" in n for n in names if n.startswith("serving::"))
     # the request's own timeline, on the rounds' step index
@@ -250,5 +251,5 @@ def test_chrome_trace_has_unified_step_and_request_spans(tmp_path):
     kinds = [e["kind"] for e in tl]
     assert kinds[0] == "submit" and kinds[-1] == "finish"
     assert "admit" in kinds and "first_token" in kinds
-    steps = {e["args"]["step"] for e in rounds}
+    steps = {e["args"]["step"] for e in admits}
     assert {e["step"] for e in tl if e["kind"] != "submit"} <= steps
