@@ -258,6 +258,10 @@ _TERMINAL_EVENT = {"stop": "finish", "length": "finish",
 #   commit > embed_epilogue
 #   spill > spill(pages)
 #   report
+# where the launch is step n + 1's and the fetch and the commit step n's
+# while the chip runs them one step ahead (`_unified_step`); a round
+# that must commit step n before its admit opens with its fetch, spill
+# and commit (`step`).
 # and with the HTTP driver's `serving::inbox` and `serving::wait`
 # (http/driver.py) they tile the engine's thread. Each leaf's seconds
 # feed one of `metrics.HOST_PHASE_COUNTERS`, a spill between the
@@ -289,6 +293,11 @@ SPAN_EMBED = "serving::embed_epilogue"
 # beyond that collects first.
 SPILL_WIDTHS = (32, 16, 8, 4, 2, 1)
 SPILL_IN_FLIGHT_PAGES = 128
+
+# `abort_all` commits the step in flight if its outputs arrive within
+# this many seconds, and otherwise leaves it: a chip that hung must not
+# hang the watchdog that condemns the replica too
+ABORT_SETTLE_S = 5.0
 
 PREEMPT_MODES = ("on", "off")
 KV_DTYPE_MODES = ("fp", "int8", "fp8")
@@ -347,6 +356,25 @@ class _StepPlan(NamedTuple):
     proposals: dict
     args_tail: tuple
     t0: float           # where `decode_step_s` starts: before the uploads
+    reqs: dict          # slot -> the request its row ran for
+    cursors: dict       # prefill slot -> its cursor after this chunk
+    completes: frozenset    # prefill slots whose prompt this chunk ends
+    tokens: int         # the packed tokens (`serving::launch`'s argument)
+
+
+class _InFlight:
+    """A launched step the host has not committed: its plan, the
+    dispatch census of its launch, and its outputs, device arrays until
+    `_fetch_step` turns them into host arrays."""
+
+    __slots__ = ("plan", "counts", "toks", "accept", "fetched")
+
+    def __init__(self, plan, counts, toks, accept):
+        self.plan = plan
+        self.counts = counts
+        self.toks = toks
+        self.accept = accept
+        self.fetched = False
 
 
 class _SwapHandle:
@@ -904,6 +932,15 @@ class ServingEngine:
         self._pos = jnp.zeros((self.num_slots,), jnp.int32)
         if self.tp is not None:
             self._pos = self.tp.replicate(self._pos)
+        # the host's copy of `_pos` as of the last launched step: a plan
+        # reads it while that step may still run (a row advances by its
+        # q_len at the launch; accepted drafts are added at the commit)
+        self._pos_host = np.zeros((self.num_slots,), np.int32)
+        # the launched step not yet committed (`_InFlight`), and whether
+        # the running round committed or waited for it before its own
+        # launch (a serial round, `serial_fallback_steps_total`)
+        self._inflight: Optional[_InFlight] = None
+        self._round_serial = False
         self._last_logits = None      # [S, V] f32, lazy (V from prefill)
         # host page state: allocator, per-slot page lists, page table
         self.pool = PagePool(self.num_pages)
@@ -1084,15 +1121,15 @@ class ServingEngine:
         self.metrics.on_host_phases(self._host_phases)
         self._host_phases.clear()
 
-    def _spill_leaf(self, settle):
+    def _spill_leaf(self, settle, pages: int):
         """`host_pool.start_pending` or `collect_pending` between the
-        round's other leaves, where anything is pending: one
-        `serving::spill` around the whole call, the bookkeeping and the
-        release of the gathered pages included, counted in
-        `round_spill_s_total`, which holds none of the spills that
-        admit or plan count already. The spans of its pieces nest
+        round's other leaves, where it has `pages` to set off or to
+        collect: one `serving::spill` around the whole call, the
+        bookkeeping and the release of the gathered pages included,
+        counted in `round_spill_s_total`, which holds none of the spills
+        that admit or plan count already. The spans of its pieces nest
         inside it and stay in `kv_spill_s_total`."""
-        if self.host_pool.pending_pages:
+        if pages:
             with self._phase(SPAN_SPILL, "round_spill_s_total"):
                 settle()
 
@@ -1446,6 +1483,7 @@ class ServingEngine:
         epilogue, results stored on each request before retirement."""
         if not rows:
             return
+        self._quiet_device()
         if self._embed_fn is None:
             self._embed_fn = self._build_embed()
         S = self.num_slots
@@ -1479,6 +1517,7 @@ class ServingEngine:
         return jax.jit(cp, donate_argnums=0)
 
     def _copy_page(self, src: int, dst: int):
+        self._quiet_device()
         if self._copy_page_fn is None:
             self._copy_page_fn = self._build_copy_page()
         with RecordEvent(SPAN_COW_COPY, src=src, dst=dst):
@@ -1554,6 +1593,7 @@ class ServingEngine:
         pages); the copies to host RAM still cross behind the next step
         (`_start_pages`, `_fetch_pages`). Returns the pieces in page
         order, each a list of device payloads (`_build_swap_out`)."""
+        self._quiet_device()
         if self._swap_out_fn is None:
             self._swap_out_fn = self._build_swap_out()
             for w in SPILL_WIDTHS:      # every width, now and never again
@@ -1604,6 +1644,7 @@ class ServingEngine:
     def _restore_page(self, data, dst: int):
         """Write one host-RAM page payload back into device page
         `dst`."""
+        self._quiet_device()
         if self._swap_in_fn is None:
             self._swap_in_fn = self._build_swap_in()
         with RecordEvent(SPAN_RESTORE, page=dst):
@@ -1918,10 +1959,13 @@ class ServingEngine:
         the mesh (page tables, tokens, q_len, sampling vectors — the
         control plane never shards), plain jnp.asarray without one.
         Committed placement keeps the jit cache key stable, so the
-        one-trace discipline holds on the mesh too."""
+        one-trace discipline holds on the mesh too. The upload is of a
+        COPY: the host rewrites its page table and sampling vectors in
+        place while the step they went to may still run, and a device
+        array may alias the host buffer it came from (the CPU's does)."""
         if self.tp is not None:
-            return self.tp.replicate(np.asarray(x))
-        return jnp.asarray(x)
+            return self.tp.replicate(np.array(x))
+        return jnp.asarray(np.array(x))
 
     def _page_table(self):
         """The device copy of the host page table, uploaded anew when a
@@ -2316,6 +2360,7 @@ class ServingEngine:
             # token (0 on a prefix miss): the unified step reads it as
             # the row's pos
             self._pos = self._pos.at[slot].set(req.cached_tokens)
+            self._pos_host[slot] = req.cached_tokens
             # prefix-cache hit: the matched span's KV is already in the
             # attached pages — prefill starts at the first uncached
             # token. A mid-page match first copies the shared partial
@@ -2585,7 +2630,7 @@ class ServingEngine:
                 int(v.size) for v in entries.values())
 
     def _unified_step(self, finished: List[RequestOutput],
-                      suppress=frozenset()):
+                      suppress=frozenset(), *, serial: bool = False):
         """One UNIFIED ragged step: pack this round's tokens — every
         decoding slot's next token, its granted speculative drafts,
         plus as many prefill prompt tokens as the spare token budget
@@ -2597,12 +2642,32 @@ class ServingEngine:
         (quarantine probes): positions, cursors and held logits
         untouched by construction, and no drafted-but-unverified token
         can leak — drafts are only ever emitted through the verify
-        pass of a step their slot participated in."""
-        if not self.scheduler.running:
-            return
-        with self._phase(SPAN_PLAN, "step_plan_s_total"):
-            plan = self._plan_unified(suppress)
+        pass of a step their slot participated in.
+
+        ONE STEP AHEAD: the step launched here is left running and
+        fetched by the next round, after that round has launched its
+        own. So while step n runs the host plans and launches n + 1
+        (its operands are n's outputs, `_ct`, `_pos`, `_last_logits`,
+        chained on the device; a decoding row's next token is sampled
+        there from the held logits), and only then fetches and commits
+        n. The host's own copy of what a plan needs moves at the
+        launch (`_advance`); a row whose budget ends at step n is not
+        planned into n + 1 (`_finishing`); a row that ends on what the
+        host cannot foresee (EOS, a cancel) rides one step more, which
+        its commit drops. `serial` (quarantine probes, rounds whose
+        plan needs the tokens on the host) fetches and commits this
+        round's own step before returning, as a round always did."""
+        prev = self._inflight
+        if serial and prev is not None:
+            self._complete(prev, finished)
+            prev = None
+        plan = None
+        if self.scheduler.running:
+            with self._phase(SPAN_PLAN, "step_plan_s_total"):
+                plan = self._plan_unified(suppress | self._finishing(prev))
         if plan is None:
+            if prev is not None:
+                self._complete(prev, finished)
             return
         # launch-count probe: count registered-op dispatches while the
         # launch runs. Only a (re)trace walks the Python op layer —
@@ -2618,31 +2683,133 @@ class ServingEngine:
                                             counts.get(name, 0) + 1))
         try:
             with self._phase(SPAN_LAUNCH, "step_launch_s_total",
-                             tokens=self.step_tokens_inflight):
+                             tokens=plan.tokens):
                 self._ct, self._pos, self._last_logits, toks, accept = \
                     self._unified_fn(self._ct, *plan.args_tail)
+                self._advance(plan)
             if not self.metrics.kv_pool_bytes:
                 self._read_pool_alias(plan.args_tail)
-            # the round's spills were gathered before this launch: their
-            # copies cross the link while the step runs
-            self._spill_leaf(self.host_pool.start_pending)
-            # sync: the host waits for the device, then sees the tokens
-            with self._phase(SPAN_FETCH, "step_fetch_s_total"):
-                toks = np.asarray(toks)
-                accept = np.asarray(accept)
-            # the copies have had the step to arrive in: take them into
-            # host RAM and drop their device side
-            self._spill_leaf(self.host_pool.collect_pending)
-            for name, n in zip(self._step_stat_names,
-                               accept[self.num_slots:]):
-                self._host_phases[name] += int(n)
         finally:
             set_dispatch_probe(prev_probe)
+        if prev is not None and not prev.fetched:
+            self._host_phases["overlapped_steps_total"] += 1
+        elif self._round_serial or serial:
+            self._host_phases["serial_fallback_steps_total"] += 1
+        self._inflight = _InFlight(plan, counts, toks, accept)
+        del plan
+        # the round's spills were gathered before this launch: their
+        # copies cross the link while the step runs, and the collection
+        # that follows the round's next fetch (`_complete`) waits for
+        # what has not arrived
+        self._spill_leaf(self.host_pool.start_pending,
+                         self.host_pool.unstarted_pages)
+        if prev is not None:
+            self._complete(prev, finished)
+        if serial:
+            self._complete(self._inflight, finished)
+
+    def _fetch_step(self, rec: _InFlight, span: bool = True):
+        """Wait for the step `rec` holds and take its outputs into host
+        memory (`serving::fetch`; inside another leaf, `span=False`, the
+        wait is that leaf's)."""
+        if rec.fetched:
+            return
+        with (self._phase(SPAN_FETCH, "step_fetch_s_total") if span
+              else contextlib.nullcontext()):
+            rec.toks = np.asarray(rec.toks)
+            rec.accept = np.asarray(rec.accept)
+        rec.fetched = True
+        for name, n in zip(self._step_stat_names,
+                           rec.accept[self.num_slots:]):
+            self._host_phases[name] += int(n)
+
+    def _complete(self, rec: _InFlight, finished: List[RequestOutput]):
+        """Fetch and commit the step `rec` holds: it is no longer in
+        flight (also when the fetch raises: its outputs are lost), the
+        spill copies set off beside it are collected after it, and the
+        watchdog's grace follows what is left running."""
+        if rec is self._inflight:
+            self._inflight = None
+        self._fetch_step(rec)
+        # the copies have had the step to arrive in: take them into
+        # host RAM and drop their device side
+        self._spill_leaf(self.host_pool.collect_pending,
+                         self.host_pool.pending_pages)
         with self._phase(SPAN_COMMIT, "step_commit_s_total"):
-            self._commit_unified(plan, counts, toks, accept, finished)
+            self._commit_unified(rec.plan, rec.counts, rec.toks,
+                                 rec.accept, finished)
             # the step's operands are released under the span, not in
             # the gap before the next leaf
-            del plan
+            rec.plan = rec.toks = rec.accept = None
+        self.step_tokens_inflight = (0 if self._inflight is None
+                                     else self._inflight.plan.tokens)
+
+    def _quiet_device(self):
+        """Before a program other than the step reads or rewrites the
+        pools (COW copy, restore, spill gather, embed epilogue, a
+        fabric export or graft): the step in flight is fetched first,
+        so such a program runs on a chip with no step, as it did before
+        steps overlapped. Its commit stays where it was; the round is
+        counted a serial one."""
+        rec = self._inflight
+        if rec is not None and not rec.fetched:
+            self._round_serial = True
+            self._fetch_step(rec, span=False)
+
+    def _finishing(self, rec: Optional[_InFlight]) -> frozenset:
+        """Decoding rows whose budget ends at the step `rec` holds: it
+        samples their last token, so no later step is planned for
+        them."""
+        if rec is None:
+            return frozenset()
+        return frozenset(
+            slot for slot in rec.plan.decode_slots
+            if self._holds(slot, rec.plan.reqs[slot])
+            and len(rec.plan.reqs[slot].output_tokens) + 1
+            >= rec.plan.reqs[slot].sampling.max_new_tokens)
+
+    def _holds(self, slot: int, req: Request) -> bool:
+        """Whether `req` still runs in `slot` (a commit skips a row whose
+        request finished, was cancelled or preempted since its plan)."""
+        return self.scheduler.running.get(slot) is req and not req.finished
+
+    def _needs_commit(self, rec: _InFlight) -> bool:
+        """Whether this round commits the step in flight before its
+        admit, decided from what the round can see: the step ends an
+        embed row's prompt (its epilogue runs at the commit), or the
+        queue's head preempts a resident (which banks what that step
+        emits) or could use the slot and pages of a row that step ends,
+        as a serial round would. A program on the pools that the admit
+        runs (a spill's gather, a COW copy, a swap-in) waits for the
+        step in flight all the same (`_quiet_device`), and the step is
+        committed after the launch, as in any round."""
+        if any(getattr(rec.plan.reqs[s].sampling, "embed", False)
+               for s in rec.plan.completes):
+            return True
+        head = self.scheduler.peek_queued()
+        if head is None:
+            return False
+        if self.preempt \
+                and self.scheduler.preemption_victim(head) is not None:
+            return True
+        return bool(self._finishing(rec))
+
+    def _advance(self, plan: "_StepPlan"):
+        """What the launched step does to each row, on the host at once:
+        the position mirror, each prefill cursor, PREFILL -> DECODE
+        after a row's last chunk (an embed row keeps its cursor at the
+        prompt's end for the epilogue its commit runs)."""
+        for slot in plan.decode_slots:
+            self._pos_host[slot] += 1
+        for slot, take in plan.grants.items():
+            req = plan.reqs[slot]
+            self._pos_host[slot] += take
+            self._prefill_cursor[req.request_id] = plan.cursors[slot]
+            if slot in plan.completes \
+                    and not getattr(req.sampling, "embed", False):
+                del self._prefill_cursor[req.request_id]
+                req.state = RequestState.DECODE
+                self._vec_dirty = True
 
     def _read_pool_alias(self, args_tail):
         """Once, after the step's first launch: the pools' bytes on one
@@ -2707,11 +2874,15 @@ class ServingEngine:
                 tokens[slot, 1:1 + m] = proposals[slot][:m]
             q_len[slot] = 1 + m
             is_decode[slot] = True
+        cursors = {}
         for slot, take in grants.items():
             req = running[slot]
             cur = self._prefill_cursor[req.request_id]
             tokens[slot, :take] = req.prefill_ids[cur:cur + take]
             q_len[slot] = take
+            cursors[slot] = cur + take
+        completes = frozenset(slot for slot, cur in cursors.items()
+                              if cur >= running[slot].prefill_ids.size)
         self._ensure_last_logits(next(iter(running.values())))
         if self._unified_fn is None:
             self._unified_fn = self._build_unified()
@@ -2721,8 +2892,9 @@ class ServingEngine:
         # prefix-sharing groups for this step's walk (host-side, from
         # the page tables — pure operand data) + the modeled page-block
         # read count both walks would issue (the number the
-        # saved-reads counter reports)
-        pos_host = np.asarray(self._pos)
+        # saved-reads counter reports), at the positions the last
+        # launched step leaves (the host's copy: that step may still run)
+        pos_host = self._pos_host
         # on a mesh the DMA model counts what ONE CHIP issues per
         # layer (n_kv/mp local head walks over 1/mp page slices) —
         # per-chip reads AND per-chip reads saved drop by mp
@@ -2888,17 +3060,23 @@ class ServingEngine:
         # operand pytree (the live self._ct stands in for the pools)
         # the one trace lowers against — [S]-sized arrays, not pools
         self._unified_args_tail = args_tail
+        reqs = {slot: running[slot]
+                for slot in (*decode_slots, *grants)}
         return _StepPlan(decode_slots, grants, draft_grants, proposals,
-                         args_tail, t0)
+                         args_tail, t0, reqs, cursors, completes,
+                         int(q_len.sum()))
 
     def _commit_unified(self, plan: "_StepPlan", counts: Dict[str, int],
                         toks, accept,
                         finished: List[RequestOutput]):
         """The host's work after the fetch (`serving::commit`): the
-        step's wall time and counters, prefill cursors, token emission,
-        finish and free."""
-        decode_slots, grants, draft_grants, proposals, _, t0 = plan
-        running = self.scheduler.running
+        step's wall time and counters, token emission, an embed row's
+        epilogue, finish and free. A row whose request no longer runs
+        in its slot (finished, cancelled, preempted since the plan:
+        the step rode it one step past its end) is skipped, its token
+        dropped."""
+        decode_slots, grants = plan.decode_slots, plan.grants
+        draft_grants, proposals = plan.draft_grants, plan.proposals
         if counts:
             self._dispatch_counts = {
                 "total": int(sum(counts.values())),
@@ -2906,11 +3084,10 @@ class ServingEngine:
             }
             self.metrics.unified_dispatch_ops = \
                 self._dispatch_counts["total"]
-        self.step_tokens_inflight = 0
         self._beat()
         n_prefill = int(sum(grants.values()))
         n_drafts = int(sum(draft_grants.values()))
-        wall = time.perf_counter() - t0
+        wall = time.perf_counter() - plan.t0
         self.metrics.on_unified_step(n_prefill, len(decode_slots),
                                      wall, draft_tokens=n_drafts)
         rs = self._round_stats
@@ -2924,26 +3101,26 @@ class ServingEngine:
             # against compiled HLO): one output all-gather per layer
             rs["collectives"] += self.tp.step_collectives(self.n_layers)
         now = self._clock()
-        # prefill bookkeeping: advance cursors, flip finished rows to
-        # DECODE (their last real token's logits are now held — they
-        # sample their first token next step). Embed rows never flip:
-        # at cursor end they take the pooled last-hidden-state through
-        # the embed epilogue and retire on the spot (prefill-only).
+        # prefill bookkeeping (the cursors moved and the rows whose
+        # prompt ended flipped to DECODE at the launch, `_advance`: they
+        # sample their first token from the held logits next step).
+        # Embed rows never flip: at cursor end they take the pooled
+        # last-hidden-state through the embed epilogue and retire on
+        # the spot (prefill-only).
         embed_rows = []
         for slot, take in grants.items():
-            req = running[slot]
-            cur = self._prefill_cursor[req.request_id] + take
-            self._prefill_cursor[req.request_id] = cur
+            req = plan.reqs[slot]
+            if not self._holds(slot, req):
+                continue
             self.metrics.on_prefill_chunk(take)
             self._obs_event(req, "prefill_chunk", tokens=take,
-                            cursor=cur)
-            if cur >= req.prefill_ids.size:
+                            cursor=plan.cursors[slot])
+            if slot not in plan.completes:
+                continue
+            if getattr(req.sampling, "embed", False):
                 self._prefill_cursor.pop(req.request_id, None)
-                if getattr(req.sampling, "embed", False):
-                    embed_rows.append((slot, req))
-                    continue
-                req.state = RequestState.DECODE
-                self._vec_dirty = True
+                embed_rows.append((slot, req))
+            else:
                 self._obs_event(req, "decode")
         if embed_rows:
             # embedding BEFORE retirement: the epilogue reads the
@@ -2960,11 +3137,14 @@ class ServingEngine:
         spec_drafted = spec_accepted = 0
         spec_burst_sizes: List[int] = []
         for slot in decode_slots:
-            req = running.get(slot)
-            if req is None or req.state is not RequestState.DECODE:
+            req = plan.reqs[slot]
+            if not self._holds(slot, req) \
+                    or req.state is not RequestState.DECODE:
                 continue
             m = draft_grants.get(slot, 0)
             acc = min(int(accept[slot]), m) if m else 0
+            # the device moved the row past its accepted drafts too
+            self._pos_host[slot] += acc
             burst = [int(toks[slot])]
             if acc:
                 burst.extend(int(t) for t in proposals[slot][:acc])
@@ -3029,14 +3209,20 @@ class ServingEngine:
         succeed); an empty batch or a fault that doesn't track one
         request returns False and the original exception propagates as
         replica death. Assumes deterministic faults — the shape
-        `FaultInjector.poison` injects and real poison inputs show."""
+        `FaultInjector.poison` injects and real poison inputs show.
+        It runs on a quiet engine: the step in flight is committed
+        first (if that raises, the replica dies), and every probe
+        commits its own step before the next."""
+        self._round_serial = True
+        if self._inflight is not None:
+            self._complete(self._inflight, finished)
         candidates = sorted(self.scheduler.running)
         if not candidates:
             return False
         while len(candidates) > 1:
             half = frozenset(candidates[:len(candidates) // 2])
             try:
-                self._unified_step(finished, suppress=half)
+                self._unified_step(finished, suppress=half, serial=True)
             except Exception:
                 survivors = [s for s in candidates if s not in half]
             else:
@@ -3050,7 +3236,8 @@ class ServingEngine:
         if req is None:
             return False
         try:     # verdict check: the round must succeed without it
-            self._unified_step(finished, suppress=frozenset([slot]))
+            self._unified_step(finished, suppress=frozenset([slot]),
+                               serial=True)
         except Exception:
             return False
         req.error = PoisonedRequest(
@@ -3071,7 +3258,17 @@ class ServingEngine:
         poison quarantine (`_quarantine_poison`): if exactly one
         resident deterministically kills the step, it alone fails and
         the replica keeps serving; otherwise the exception propagates
-        (replica death). Returns requests that finished this round."""
+        (replica death). Returns requests that finished this round —
+        the rows of the step it committed, which is the step the round
+        before launched (`_unified_step`).
+
+        A round commits the step in flight BEFORE its admit, and then
+        runs as rounds ran before steps overlapped, when what it sees
+        asks for it: its plan needs that step's tokens on the host
+        (speculative drafts, grammar rows, draft-model seeding: such a
+        round also commits its own step before it returns), or its
+        admit would run programs on the pools or could use a slot that
+        step frees (`_needs_commit`)."""
         finished: List[RequestOutput] = []
         self._beat()
         self._step_idx += 1
@@ -3081,6 +3278,12 @@ class ServingEngine:
                              "reads_saved": 0, "collectives": 0,
                              "constrained_rows": 0,
                              "grammar_rejected": 0, "wall_s": 0.0}
+        hold = bool(self._drafters or self._grammars)
+        self._round_serial = hold
+        rec = self._inflight
+        if rec is not None and (hold or self._needs_commit(rec)):
+            self._round_serial = True
+            self._complete(rec, finished)
         now = self._clock()
         with self._phase(SPAN_ADMIT, "round_admit_s_total",
                          step=self._step_idx):
@@ -3088,7 +3291,7 @@ class ServingEngine:
             self._admit(now)
             self._preempt_for_overload(now)
         try:
-            self._unified_step(finished)
+            self._unified_step(finished, serial=hold)
         except Exception as exc:
             # the black box freezes BEFORE recovery runs: whatever
             # quarantine decides, the postmortem keeps the steps that
@@ -3110,8 +3313,11 @@ class ServingEngine:
                                          detail=repr(exc),
                                          step=self._step_idx,
                                          slo=self._slo_snap())
-        # what a round without a launch left pending
-        self._spill_leaf(self.host_pool.collect_pending)
+        # what a round that leaves no step running left pending (the
+        # copies set off beside a running step are collected after it)
+        if self._inflight is None:
+            self._spill_leaf(self.host_pool.collect_pending,
+                             self.host_pool.pending_pages)
         with self._phase(SPAN_REPORT, "round_report_s_total"):
             self._report_round()
         self.flush_host_phases()
@@ -3240,9 +3446,28 @@ class ServingEngine:
         resident — right now with `reason`, freeing their pages, without
         running another compiled step. Residents keep whatever tokens
         they already emitted (the HTTP layer uses reason
-        "replica_failure" to decide which are safe to retry)."""
+        "replica_failure" to decide which are safe to retry), the step
+        in flight committed first if its outputs arrive within
+        ABORT_SETTLE_S (a hung chip's step is left where it is)."""
         self._closed = True
         finished: List[RequestOutput] = []
+        rec = self._inflight
+        if rec is not None:
+            self._inflight = None
+            deadline = time.monotonic() + ABORT_SETTLE_S
+            while not (rec.fetched or (rec.toks.is_ready()
+                                       and rec.accept.is_ready())):
+                if time.monotonic() > deadline:
+                    break
+                time.sleep(0.001)
+            else:
+                try:
+                    self._complete(rec, finished)
+                except Exception as exc:
+                    # the step failed: its rows emit nothing more, and
+                    # every request still retires below
+                    if self.obs is not None:
+                        self.obs.flight.note("abort:step_lost", repr(exc))
         now = self._clock()
         for req in self.scheduler.pop_queued():
             self._finish_and_free(req, reason, now, finished)
@@ -3352,7 +3577,8 @@ class ServingEngine:
     # -- conveniences ------------------------------------------------------
     @property
     def has_work(self) -> bool:
-        return self.scheduler.has_work
+        """Requests queued or running, or a step still to commit."""
+        return self.scheduler.has_work or self._inflight is not None
 
     def run(self, max_steps: Optional[int] = None) -> List[RequestOutput]:
         """Pump steps until idle (or max_steps); returns everything that

@@ -99,6 +99,11 @@ HOST_PHASE_COUNTERS = (
     "inbox_s_total",            # serving::inbox
     "engine_wait_s_total",      # serving::wait
     "pump_s_total",             # the driver's pump loop, every iteration
+    # steps, not seconds: launched while the step before was still
+    # unfetched on the chip, and launched after a round committed or
+    # fetched that step first (engine `_needs_commit`, `_quiet_device`)
+    "overlapped_steps_total",
+    "serial_fallback_steps_total",
 )
 
 

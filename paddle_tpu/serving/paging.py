@@ -343,6 +343,12 @@ class HostPagePool:
         device, freed slots included)."""
         return sum(len(copy.slots) for copy in self._pending)
 
+    @property
+    def unstarted_pages(self) -> int:
+        """Pages of the pending copies not yet set off."""
+        return sum(len(copy.slots) for copy in self._pending
+                   if not copy.started)
+
     def _take_slot(self) -> int:
         if self._free:
             return self._free.pop()
@@ -425,6 +431,10 @@ class _PendingCopy:
         self._start = start
         self.fetch = fetch
         self.slots = slots
+
+    @property
+    def started(self) -> bool:
+        return self._start is None
 
     def start(self):
         """Sets the copy off, the first time it is called."""

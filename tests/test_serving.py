@@ -241,6 +241,7 @@ class TestLifecycleAndPolicy:
         t[0] = 6.0
         eng.step()           # qd's deadline passed while queued
         assert qd.finish_reason == "timeout"
+        eng.step()           # the first decode step committed
         t[0] = 11.0
         eng.step()           # run's deadline passed while decoding
         assert run.finish_reason == "timeout"

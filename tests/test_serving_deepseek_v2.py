@@ -109,7 +109,13 @@ def test_latent_counters_and_moe_counters():
     before = eng.metrics.snapshot()
     eng.step()                              # one decode row at position 19
     after = eng.metrics.snapshot()
-    delta = {k: after[k] - before[k] for k in STEP_WORK_COUNTERS}
+    # the host counts a step at its plan, the device's counts ride its
+    # fetch, one round after its launch
+    eng.step()
+    fetched = eng.metrics.snapshot()
+    delta = {k: after[k] - before[k] for k in LATENT_COUNTERS}
+    delta.update({k: fetched[k] - after[k] for k in STEP_WORK_COUNTERS
+                  if k.startswith("moe_")})
     assert delta["mla_rows_total"] == 5
     assert delta["mla_pairs_total"] == 5 * 20
     assert delta["mla_keys_distinct_total"] == 5 * 20

@@ -34,7 +34,10 @@ def engine(model, **kw):
 
 def serve_and_collect(eng, prompts, n_new):
     """Runs the requests to their end; returns for each (tokens, {p:
-    the logits the engine held for position p's successor})."""
+    the logits the engine held for position p's successor}). A round
+    commits the step before the one it launched, so a request is still
+    running when the step that fed its last token has run: the logits
+    past its last token are left out."""
     reqs = [eng.add_request(np.asarray(p), SamplingParams(
         max_new_tokens=n_new)) for p in prompts]
     held = [{} for _ in reqs]
@@ -44,7 +47,7 @@ def serve_and_collect(eng, prompts, n_new):
         logits = np.asarray(eng._last_logits)
         for slot, req in eng.scheduler.running.items():
             i = reqs.index(req)
-            if pos[slot] >= len(prompts[i]):
+            if len(prompts[i]) <= pos[slot] < len(prompts[i]) + n_new:
                 held[i][int(pos[slot]) - 1] = logits[slot].copy()
     return [(list(r.output_tokens), h) for r, h in zip(reqs, held)]
 
@@ -125,10 +128,13 @@ def test_decode_only_step_hits_fewer_experts_than_it_holds():
     model = tiny_laguna()
     eng = engine(model)
     eng.add_request(np.arange(1, 20), SamplingParams(max_new_tokens=4))
+    # what the device counts rides a step's fetch, one round after its
+    # launch
     eng.step()                              # 16 prompt tokens
     eng.step()                              # 3 prompt tokens
-    before = eng.metrics.snapshot()
     eng.step()                              # one decode row
+    before = eng.metrics.snapshot()
+    eng.step()                              # the decode row fetched
     after = eng.metrics.snapshot()
     delta = {k: after[k] - before[k] for k in STEP_WORK_COUNTERS}
     assert delta["moe_layer_steps_total"] == 4

@@ -306,6 +306,10 @@ class TestPreemptionOracle:
         assert lo.preemptions == 1
         pages = eng.metrics.swapped_out_pages
         assert pages == (6 if host_pages is None else 2)
+        # the copies cross beside the step launched after them and are
+        # collected after its fetch, in the next round
+        assert eng.host_pool.pending_pages == pages
+        eng.step()
         assert eng.host_pool.pending_pages == 0     # the step took it in
         eng.run()
         snap = eng.metrics.snapshot()

@@ -304,9 +304,12 @@ class TestCostCensus:
         assert busy["achieved_util"] == pytest.approx(
             (busy["prefill_tokens"] + busy["decode_tokens"]
              + busy["draft_tokens"]) / 16, abs=1e-4)
-        # metrics histogram agrees step-for-step
+        # metrics histogram agrees step-for-step: a round records the
+        # step it committed, the one the round before launched (the
+        # first round commits none)
         au = eng.metrics.snapshot()["achieved_util"]
-        assert au["count"] == len(steps)
+        assert packed[0] == 0
+        assert au["count"] == sum(p > 0 for p in packed) == len(steps) - 1
         # flight_dump renders the new columns
         from flight_dump import render_flight
         text = render_flight(eng.obs.flight.snapshot())

@@ -231,17 +231,24 @@ def test_chrome_trace_has_unified_step_and_request_spans(tmp_path):
     admits = [e for e in events if e["name"] == "serving::admit"]
     assert [e["args"]["step"] for e in admits] == \
         list(range(1, eng._step_idx + 1))
-    for phase in ("plan", "launch", "fetch", "commit", "report"):
+    # a round fetches and commits the step the round before launched,
+    # after it launched its own: the first round fetches none, the last
+    # launches none
+    for phase in ("plan", "report"):
         assert names.count(f"serving::{phase}") == len(admits), phase
+    for phase in ("launch", "fetch", "commit"):
+        assert names.count(f"serving::{phase}") == len(admits) - 1, phase
     # the prompt's three tokens, then one decoding row a step
     assert [e["args"]["tokens"] for e in events
             if e["name"] == "serving::launch"] == \
-        [3] + [1] * (len(admits) - 1)
-    first = {n: next(e for e in events if e["name"] == f"serving::{n}")
-             for n in ("admit", "plan", "launch", "fetch", "commit",
-                       "report")}
+        [3] + [1] * (len(admits) - 2)
+    second = [e for e in events if e["name"].startswith("serving::")
+              and admits[1]["ts"] <= e["ts"] < admits[2]["ts"]]
+    assert [e["name"] for e in second] == [
+        f"serving::{n}" for n in ("admit", "plan", "launch", "fetch",
+                                  "commit", "report")]
     # a row: each leaf ends before the next one starts
-    row = list(first.values())
+    row = second
     for a, b in zip(row, row[1:]):
         assert a["ts"] + a["dur"] <= b["ts"], (a["name"], b["name"])
     # no name is built per call: nothing carries an id in brackets
