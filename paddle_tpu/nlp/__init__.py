@@ -16,5 +16,7 @@ from .deepseek_v2 import (DeepseekV2Config, DeepseekV2Model,  # noqa: F401
                           DeepseekV2ForCausalLM)
 from .keye_vl2 import (KeyeVL2Config, KeyeVL2Model,  # noqa: F401
                        KeyeVL2ForCausalLM)
+from .mimo_v2 import (MiMoV2Config, MiMoV2Model,  # noqa: F401
+                      MiMoV2ForCausalLM)
 from .generation import (DecodeCache, init_decode_caches,  # noqa: F401
                          update_and_attend, CompiledGenerator)

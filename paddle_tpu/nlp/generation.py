@@ -43,6 +43,7 @@ from ..ops.pallas.paged_attention import (dequantize_paged_q8,
                                           ragged_paged_attention_q8,
                                           ragged_paged_attention_grouped,
                                           ragged_paged_attention_grouped_q8,
+                                          ragged_paged_attention_split,
                                           FP8_DTYPE,
                                           quantize_kv_rowwise,
                                           paged_scatter,
@@ -56,6 +57,7 @@ from ..ops.pallas.paged_attention import (dequantize_paged_q8,
 
 __all__ = ["DecodeCache", "init_decode_caches", "update_and_attend",
            "update_and_attend_latent", "update_and_attend_sparse",
+           "update_and_attend_split",
            "CompiledGenerator", "decode_model_step", "sample_logits",
            "resolve_paged_attn_impl", "PAGED_ATTN_IMPLS",
            "quantize_kv_rowwise"]
@@ -925,6 +927,49 @@ def update_and_attend_sparse(q, k_new, v_new, q_idx, w_idx, row_new,
                             page_table=cache.page_table,
                             attn_impl=cache.attn_impl, q_len=cache.q_len,
                             rows=rows)
+
+
+# The page walk over pools of split widths (pallas/paged_attention.py
+# `ragged_paged_attention_split`): a token's kv heads side by side, keys
+# and values of their own widths, and a learned sink where a layer has
+# one. The forms with and without a sink are one op, two signatures.
+register_op("ragged_paged_attention_split", ragged_paged_attention_split,
+            nondiff=True)
+
+
+def update_and_attend_split(q, k_new, v_new, cache: DecodeCache, *,
+                            window=None, sink=None):
+    """`update_and_attend` for a layer whose pools are of SPLIT widths
+    (the engine's cache-spec contract): `cache.k` / `cache.v` are
+    [num_pages, page_size, n_kv * Dk] and [num_pages, page_size,
+    n_kv * Dv], a token's kv heads side by side. Writes k_new
+    [B, l, n_kv, Dk] and v_new [B, l, n_kv, Dv] at cache.pos, then
+    attends q [B, l, H, Dk] over the keys at or below each query (the
+    last `window` of them in a sliding-window layer), with the layer's
+    learned `sink` logits (a Tensor [H] or None) in the softmax.
+    Returns (out [B, l, H, Dv], advanced cache). Served in the unified
+    ragged step only (paged, per-row q_len); the writes are the XLA row
+    scatter."""
+    if cache.page_table is None or cache.q_len is None \
+            or cache.k_scale is not None or cache.megakernel \
+            or cache.group is not None:
+        raise NotImplementedError(
+            "a layer of split K/V widths is served by the unified ragged "
+            "step over float paged pools (ServingEngine)")
+    b, l, hkv = (int(n) for n in k_new.shape[:3])
+    k_buf, v_buf = (
+        apply_op("kv_cache_update_paged", pool,
+                 new.reshape([b, l, int(pool.shape[2])]), cache.pos,
+                 cache.page_table)
+        for pool, new in ((cache.k, k_new), (cache.v, v_new)))
+    attrs = dict(heads=hkv, window=None if window is None else int(window))
+    args = [q, k_buf, v_buf, cache.page_table, cache.pos, cache.q_len]
+    if sink is not None:
+        args.append(sink)
+    out = apply_op("ragged_paged_attention_split", *args, attrs=attrs)
+    return out, DecodeCache(k_buf, v_buf, cache.pos + cache.q_len,
+                            page_table=cache.page_table,
+                            attn_impl=cache.attn_impl, q_len=cache.q_len)
 
 
 def _pack_caches(caches):

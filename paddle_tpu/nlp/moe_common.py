@@ -1,9 +1,9 @@
 """What the decoders with routed experts share (`laguna.py`,
-`deepseek_v2.py`): sublayers built in the configuration's dtype, the
-SwiGLU that is a dense MLP or a shared expert, the stacked experts'
-initialiser, the routed op, and the counts an expert layer hands to
-`ServingEngine` out of its step. A `cfg` here is either model's
-configuration: it has `dtype`, `initializer_range`, `hidden_size`.
+`deepseek_v2.py`, `keye_vl2.py`, `mimo_v2.py`): sublayers built in the
+configuration's dtype, the SwiGLU that is a dense MLP or a shared
+expert, the stacked experts' initialiser, the routed op, and the counts
+an expert layer hands to `ServingEngine` out of its step. A `cfg` here
+is any such model's configuration: it has `dtype`, `initializer_range`, `hidden_size`.
 """
 import jax
 import jax.numpy as jnp
@@ -16,14 +16,15 @@ from ..ops.pallas.moe import routed_experts
 from ..nn.initializer import Normal
 
 
-def _routed_experts_fwd(x, valid, router_w, w_gate, w_up, w_down, *,
-                        top_k, scale, norm_topk, first, n_group=1,
-                        topk_group=1):
+def _routed_experts_fwd(x, valid, router_w, w_gate, w_up, w_down,
+                        bias=None, *, top_k, scale, norm_topk, first,
+                        n_group=1, topk_group=1, scoring="softmax"):
     b, l, h = x.shape
     out, stats = routed_experts(
         x.reshape(b * l, h), valid.reshape(b * l), router_w, w_gate,
         w_up, w_down, top_k=top_k, scale=scale, norm_topk=norm_topk,
-        first=first, n_group=n_group, topk_group=topk_group)
+        first=first, n_group=n_group, topk_group=topk_group,
+        scoring=scoring, bias=bias)
     return out.reshape(b, l, h), stats
 
 
@@ -89,7 +90,8 @@ def moe_stats(layers):
     """int32 [4] Tensor over the expert layers of the latest call (the
     layers whose `mlp` holds `last_stats`): assignments routed (all
     experts), assignments computed here, local experts that received a
-    token, expert layers run. None without such a layer."""
+    token, (with a selection bias, [5]: the assignments the bias moved,)
+    expert layers run. None without such a layer."""
     stats = [layer.mlp.last_stats for layer in layers
              if getattr(layer.mlp, "last_stats", None) is not None]
     if not stats:

@@ -40,7 +40,9 @@ def trace32():
 # `metadata=` into `frontend_attributes={kernel_metadata=...}` of the
 # compiled instruction, which is the text a trace reader searches for
 # `ptk:<name>`. Names live in one `KERNELS` table at the top of each
-# kernel file (16 names: `ragged_walk`, `grouped_phase1`,
+# kernel file (18 names: `ragged_walk`, `split_walk`, `sink_walk` (the
+# same walk over pools of split widths, without and with a sink),
+# `grouped_phase1`,
 # `scatter_write`, `scatter_q8_write`, `lora_paged`, `argmax_epilogue`,
 # `flash_fwd`, `flash_dq`, `flash_dkv`, `layer_norm_fwd`,
 # `layer_norm_bwd`, `moe_experts`, `mla_walk`, `sparse_index`,

@@ -9,7 +9,9 @@ of the router's `n_experts` outputs), for the tokens marked `valid`:
     out[t] = sum over e in top_k(t), e held here, of w[t, e] * E_e(x[t])
 
 with w the router's softmax score (of the token's top-k, or of its
-top-k within its best groups of experts: `_top_experts`), renormalised
+top-k within its best groups of experts: `_top_experts`), or its sigmoid
+score, the top-k then chosen by that score plus a SELECTION BIAS that
+weighs nothing (DeepSeek-V3's `noaux_tc`: `scoring="sigmoid"`), renormalised
 over the token's whole top-k set where the model asks for it (the
 experts held elsewhere included), and scaled. No token is
 dropped and no capacity is fixed: the (token, expert) assignments are
@@ -41,7 +43,8 @@ T x top_k / TILE_ROWS + E_local tiles at most.
 
 Counts made on the device (`stats`, int32 [3]): assignments routed (all
 experts, valid tokens), assignments routed to experts held here, local
-experts that received at least one token.
+experts that received at least one token; with a selection bias a fourth,
+the assignments whose expert the unbiased scores would not have chosen.
 """
 from __future__ import annotations
 
@@ -119,11 +122,15 @@ def _top_experts(score, top_k, n_group, topk_group):
 
 
 def moe_route(x, router_w, valid, *, top_k, scale, norm_topk, first,
-              n_local, tile_rows=TILE_ROWS, n_group=1, topk_group=1):
+              n_local, tile_rows=TILE_ROWS, n_group=1, topk_group=1,
+              scoring="softmax", bias=None):
     """x [T, h]; router_w [h, n_experts]; valid bool [T] -> a dict of
     the routing's fixed-shape arrays (see the module doc). `n_group`,
     `topk_group`: the group limit on a token's choice (`_top_experts`;
-    1: none). Assignment
+    1: none). `scoring`: "softmax" over the router's outputs, or
+    "sigmoid" of each, where `bias` (f32 [n_experts] or None) is added
+    to the scores that CHOOSE the top-k and to none that weigh it.
+    Assignment
     a = k * T + t is token t's k-th expert (k-major, so that the sum
     over k at the end is over whole [T, h] slabs):
 
@@ -143,8 +150,17 @@ def moe_route(x, router_w, valid, *, top_k, scale, norm_topk, first,
         logits = jnp.dot(x.astype(jnp.float32),
                          router_w.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        top_v, top_i = _top_experts(jax.nn.softmax(logits, axis=-1),
-                                    top_k, n_group, topk_group)
+        if scoring == "softmax":
+            top_v, top_i = _top_experts(jax.nn.softmax(logits, axis=-1),
+                                        top_k, n_group, topk_group)
+        elif scoring == "sigmoid":
+            score = jax.nn.sigmoid(logits)
+            pick = score if bias is None \
+                else score + bias.astype(jnp.float32)[None, :]
+            _, top_i = _top_experts(pick, top_k, n_group, topk_group)
+            top_v = jnp.take_along_axis(score, top_i, axis=-1)
+        else:
+            raise ValueError(f"router scoring {scoring!r}")
         if norm_topk:
             top_v = top_v / jnp.sum(top_v, axis=-1, keepdims=True)
         weight = (top_v * jnp.float32(scale)).T.reshape(tk)
@@ -205,6 +221,13 @@ def moe_route(x, router_w, valid, *, top_k, scale, norm_topk, first,
         _, dest = jax.lax.sort((order, dest_sorted), num_keys=1)
         stats = jnp.stack([jnp.sum(live, dtype=jnp.int32), bounds[-1],
                            jnp.sum(gs > 0, dtype=jnp.int32)])
+        if bias is not None:
+            # the choices the bias changed: experts of the biased top-k
+            # that are not among the unbiased top-k
+            _, plain = _top_experts(score, top_k, n_group, topk_group)
+            moved = jnp.all(top_i[:, :, None] != plain[:, None, :], -1)
+            stats = jnp.concatenate([stats, jnp.sum(
+                moved & valid[:, None], dtype=jnp.int32)[None]])
     return dict(here=here, dest=dest, src=src, row_weight=row_weight,
                 tile_expert=tile_expert, n_tiles=tiles_end[-1],
                 group_sizes=gs, order=order, weight_sorted=weight_sorted,
@@ -314,18 +337,21 @@ def moe_experts_ragged_dot(x, route, w_gate, w_up, w_down):
 
 
 def routed_experts(x, valid, router_w, w_gate, w_up, w_down, *, top_k,
-                   scale, norm_topk, first, n_group=1, topk_group=1):
+                   scale, norm_topk, first, n_group=1, topk_group=1,
+                   scoring="softmax", bias=None):
     """The routed part of a mixture-of-experts block for the experts
     held here (the registered op's forward; module doc). x [T, h],
-    valid bool [T]; `n_group`, `topk_group`: `moe_route`'s; returns
-    (out [T, h] in x's dtype, stats int32 [3]).
+    valid bool [T]; `n_group`, `topk_group`, `scoring`, `bias`:
+    `moe_route`'s; returns (out [T, h] in x's dtype, stats int32 [3],
+    or [4] with a bias).
     The expert product is the Pallas kernel on a TPU (and in interpret
     mode) and the ragged_dot form elsewhere."""
     t, h = x.shape
     n_local = w_gate.shape[0]
     route = moe_route(x, router_w, valid, top_k=top_k, scale=scale,
                       norm_topk=norm_topk, first=first, n_local=n_local,
-                      n_group=n_group, topk_group=topk_group)
+                      n_group=n_group, topk_group=topk_group,
+                      scoring=scoring, bias=bias)
     if _use_kernel():
         with route_scope():
             xs = x[route["src"]]

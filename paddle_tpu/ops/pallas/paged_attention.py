@@ -197,7 +197,9 @@ __all__ = ["paged_decode_attention", "paged_attention_reference",
            "ragged_paged_attention_grouped",
            "ragged_paged_attention_grouped_q8",
            "count_page_block_reads", "count_window_page_reads",
-           "count_walk_grid_steps", "walk_grid_bounds",
+           "count_walk_grid_steps", "count_walk_pairs", "walk_grid_bounds",
+           "ragged_paged_attention_split",
+           "ragged_attention_reference_split",
            "FP8_DTYPE",
            "resolve_megakernel_flag", "MEGAKERNEL_ENV",
            "quantize_kv_rowwise", "paged_scatter", "paged_scatter_q8",
@@ -212,6 +214,8 @@ _INTERPRET = os.environ.get("PADDLE_TPU_PALLAS_INTERPRET", "0") == "1"
 # file (ops/pallas/__init__.py `kernel_id`); no name contains another
 KERNELS = {name: _kernel_id(name, fn) for name, fn in (
     ("ragged_walk", "_ragged_kernel"),
+    ("split_walk", "_ragged_kernel"),
+    ("sink_walk", "_ragged_kernel"),
     ("grouped_phase1", "_grouped_phase1_kernel"),
     ("scatter_write", "_scatter_write_kernel"),
     ("scatter_q8_write", "_scatter_q8_write_kernel"),
@@ -280,7 +284,7 @@ def _mask_to_additive(mask, b, h, lmax, lq=1):
 
 
 def _attend_block(q, k, v, ks, vs, live, mask, m_ref, l_ref, acc_ref, *,
-                  scale, fp8):
+                  scale, fp8, heads=None):
     """Fold ONE key block into the online-softmax partials of one row's
     query block, for every kv head at once. q [H_kv, R, D] with R query
     rows per kv head; k/v [kb, H_kv, D], the block's pages exactly as
@@ -290,7 +294,27 @@ def _attend_block(q, k, v, ks, vs, live, mask, m_ref, l_ref, acc_ref, *,
     additive f32 [H_kv, R, kb] or None. m/l [H_kv, R, 128] and acc
     [H_kv, R, D] are refs updated in place. The head axis is a batch
     dimension of both dots, so per head this is the flash_attention.py
-    recurrence with kb-wide key blocks."""
+    recurrence with kb-wide key blocks.
+
+    With `heads` (pools of SPLIT widths, `_ragged_attention_local`) k
+    is [kb, heads * Dk] and v [kb, heads * Dv], the heads side by side
+    on the lanes, and acc is [H_kv, R, Dv]: each head's keys and values
+    are its own run of lanes, and the two dots run a head at a time."""
+    if heads is not None:
+        dk, dv = k.shape[1] // heads, v.shape[1] // heads
+        prec = _prec(q.dtype)
+        for g in range(heads):
+            s = jax.lax.dot_general(
+                q[g], k[:, g * dk:(g + 1) * dk], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=prec) * jnp.float32(scale)     # [R, kb]
+            s = jnp.where(live, s, jnp.float32(_NEG_INF))
+            v_g = v[:, g * dv:(g + 1) * dv]
+            _fold(s, lambda p, v_g=v_g: jax.lax.dot_general(
+                p.astype(v_g.dtype), v_g, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec),
+                m_ref.at[g], l_ref.at[g], acc_ref.at[g])
+        return
     if ks is not None:
         # fused in-VMEM dequant: int8 codes x rowwise scale — the
         # dequantized block never round-trips through HBM
@@ -313,19 +337,27 @@ def _attend_block(q, k, v, ks, vs, live, mask, m_ref, l_ref, acc_ref, *,
     s = jnp.where(live[None], s, jnp.float32(_NEG_INF))
     if mask is not None:
         s = s + mask
-    m_prev = m_ref[:, :, :1]
-    l_prev = l_ref[:, :, :1]
-    m_cur = jnp.max(s, axis=2, keepdims=True)
+    _fold(s, lambda p: jax.lax.dot_general(
+        p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+        precision=prec), m_ref, l_ref, acc_ref)
+
+
+def _fold(s, pv, m_ref, l_ref, acc_ref):
+    """The online-softmax step over scores s [..., R, kb]: the running
+    max, the denominator and the accumulator (m/l [..., R, 128], acc
+    [..., R, D] refs) rescaled and `pv(p)`, the weights' product with
+    the block's values, added."""
+    m_prev = m_ref[..., :1]
+    l_prev = l_ref[..., :1]
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
     alpha = jnp.exp(m_prev - m_new)
     pexp = jnp.exp(s - m_new)
     l_ref[...] = jnp.broadcast_to(
-        alpha * l_prev + jnp.sum(pexp, axis=2, keepdims=True),
+        alpha * l_prev + jnp.sum(pexp, axis=-1, keepdims=True),
         l_ref.shape)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        pexp.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-        precision=prec)
+    acc_ref[...] = acc_ref[...] * alpha + pv(pexp)
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
 
@@ -529,7 +561,8 @@ def _walk_item(j, pre, *, ps, qblk, grouped, window):
 
 
 def _ragged_kernel(*refs, ps, ppb, qblk, rep, scale, has_mask, has_scale,
-                   fp8, grouped, in_place, window=None):
+                   fp8, grouped, in_place, window=None, heads=None,
+                   has_sink=False):
     """The per-row page walk — grid (work item, key block). A work item
     is one LIVE (row, query block) pair (`_work_items`), a key block
     `K_BLOCK` keys of the row's context, brought in from the pools in
@@ -545,7 +578,12 @@ def _ragged_kernel(*refs, ps, ppb, qblk, rep, scale, has_mask, has_scale,
     all. A query block whose live queries fit `_NARROW_ROWS` rows (a
     decoding row's one query; a verify row's few, where the heads of a
     group are few) runs its matmuls over those rows alone
-    (`_by_width`)."""
+    (`_by_width`). With `heads` the pools are of split widths (the
+    keys' and the values' lanes a head, `_attend_block`); with
+    `has_sink` a learned logit a query row (`sink_ref` [H_kv, rows,
+    128] f32) joins each row's softmax at the item's end: it takes its
+    share of the denominator and adds no value, so a row whose window
+    holds no live key leaves as zeros."""
     refs = list(refs)
     n_pre = 7 if grouped else 5
     pre, refs = refs[:n_pre], refs[n_pre:]
@@ -559,6 +597,8 @@ def _ragged_kernel(*refs, ps, ppb, qblk, rep, scale, has_mask, has_scale,
         refs = refs[2:]
     if has_mask:
         mask_ref, refs = refs[0], refs[1:]
+    if has_sink:
+        sink_ref, refs = refs[0], refs[1:]
     if grouped:
         gid_ref, gcnt_ref = pre[5:]
         parts_in, refs = refs[:3], refs[3:]
@@ -615,7 +655,8 @@ def _ragged_kernel(*refs, ps, ppb, qblk, rep, scale, has_mask, has_scale,
                             rep=rep, kb=kb, window=window,
                             lo_key=shared * ps if grouped else None),
                 mask_ref[0, 0, 0, :, :n] if has_mask else None,
-                *(p.at[:, :n] for p in parts), scale=scale, fp8=fp8)
+                *(p.at[:, :n] for p in parts), scale=scale, fp8=fp8,
+                heads=heads)
 
         by_width(attend)
 
@@ -628,6 +669,9 @@ def _ragged_kernel(*refs, ps, ppb, qblk, rep, scale, has_mask, has_scale,
     @pl.when(k == pl.num_programs(1) - 1)
     def _finalize():
         def store(n):
+            if has_sink:
+                _fold(sink_ref[:, :n, :1], lambda p: 0.0,
+                      *(p.at[:, :n] for p in parts))
             l = jnp.maximum(l_ref[:, :n, :1], jnp.float32(1e-30))
             o_ref[0, 0, :, :n] = (acc_ref[:, :n] / l).astype(o_ref.dtype)
 
@@ -802,7 +846,8 @@ def _zero_dead_queries(out, q_len):
 
 def _ragged_attention_kernel(q, k_pool, v_pool, page_table, pos, q_len,
                              mask, k_scale=None, v_scale=None,
-                             group=None, window=None):
+                             group=None, window=None, split_heads=None,
+                             sink=None):
     """`_ragged_attention_local` on every device of the kernel mesh
     (ops/pallas/__init__.py): under the tensor-parallel serving
     replica q, the pools, the scale pools and a user mask arrive
@@ -822,10 +867,14 @@ def _ragged_attention_kernel(q, k_pool, v_pool, page_table, pos, q_len,
         specs["k_scale"] = specs["v_scale"] = P(None, None, "heads")
     if group is not None:
         ops["group"], specs["group"] = tuple(group), (rows, rows, rows)
+    if sink is not None:
+        ops["sink"], specs["sink"] = sink, rows
     # what a trace of the walk reads beside its operands (tests set
     # them): a trace made under other values must not be reused
     extra = {"window": window,
              "traced_for": (K_BLOCK, _Q_ROWS, _INTERPRET)}
+    if split_heads is not None:
+        extra["heads"] = split_heads
     return _per_device(
         lambda o: _ragged_attention_local(**{"mask": None, **o}, **extra),
         (specs,), heads)(ops)
@@ -852,10 +901,12 @@ def _walk_scratch(pools, ppb):
     return in_place + [pltpu.SMEM((1,), jnp.int32)]
 
 
-@functools.partial(jax.jit, static_argnames=("window", "traced_for"))
+@functools.partial(jax.jit, static_argnames=("window", "traced_for",
+                                             "heads"))
 def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
                             mask, k_scale=None, v_scale=None,
-                            group=None, window=None, traced_for=None):
+                            group=None, window=None, traced_for=None,
+                            heads=None, sink=None):
     """q [B, lq, H, D]; pools [P, ps, H_kv, D]; page_table
     [B, max_pages] int32; pos/q_len [B] int32; mask None | additive f32
     [B, H, lq, lmax]. lq is padded up to a multiple of the query block
@@ -904,6 +955,20 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
     such layers is): only the pages of the window are ever read.
     Neither groups nor a user mask combine with it.
 
+    heads (SPLIT widths; None: the pools' own [P, ps, H_kv, D]): the
+    pools are [P, ps, heads * Dk] and [P, ps, heads * Dv], a token's kv
+    heads side by side on the lanes, keys Dk wide (q's D) and values Dv
+    wide, and the result is [B, lq, H, Dv]. A key width that is no
+    multiple of 128 lanes (192) would pad every head of a
+    [P, ps, H_kv, Dk] pool to whole tiles in HBM, and a few heads to a
+    whole tile of sublanes besides; side by side they pad nothing
+    (4 x 192 = 768 lanes), and a page is still one DMA. The kernel is
+    the same walk, its dots taken a head at a time (`_attend_block`),
+    under the trace name `split_walk`. sink (f32 [H], with `heads`
+    only): a learned logit a query head that joins the head's softmax
+    denominator and adds no value (a window layer's sink), under the
+    trace name `sink_walk`.
+
     The grid is as long as the step's rows ask, never as long as the
     step's SHAPE allows (a grid step with nothing to do still costs a
     grid step; PERF.md section 6, PR 28 and 30): its first axis runs
@@ -933,8 +998,19 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
         raise NotImplementedError(
             "the page walk of a sliding-window layer takes neither "
             "prefix-sharing groups nor a user attention mask")
+    if (heads is None) != (k_pool.ndim == 4) or (
+            heads is not None and (group is not None or mask is not None
+                                   or k_scale is not None)) or (
+            sink is not None and heads is None):
+        raise NotImplementedError(
+            "pools of split widths take neither groups, a user mask nor "
+            "an int8 lane, and a sink rides with split widths only")
     b, lq, h, d = q.shape
-    _, ps, hkv, _ = k_pool.shape
+    if heads is None:
+        _, ps, hkv, _ = k_pool.shape
+        dv = d
+    else:
+        ps, hkv, dv = k_pool.shape[1], heads, v_pool.shape[2] // heads
     mp = page_table.shape[1]
     rep = h // hkv
     scale = 1.0 / math.sqrt(d)
@@ -961,7 +1037,8 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
     # than that, and the int8 lane's [ps, H_kv] f32 scale pages (1/32
     # of the bytes the code pages are), come as each row's gathered
     # view instead (`_row_view`), a block of kb keys a grid step
-    in_place = d % _LANES == 0
+    in_place = d % _LANES == 0 if heads is None else \
+        k_pool.shape[2] % _LANES == 0 == v_pool.shape[2] % _LANES
     if not in_place:
         # a row's view holds its group's shared pages anyway: phase 1
         # has nothing to save (and its queries could not be cut either)
@@ -1007,8 +1084,19 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
             ((0, 0),) + pad_rows + ((0, 0),)))
         in_specs.append(pl.BlockSpec(
             (1, 1, 1, hkv, rows, kb), lambda *a: block(*a) + (0, 0, 0)))
+    if sink is not None:
+        # row r of kv head g's block is query head g * rep + r % rep
+        ops.append(jnp.broadcast_to(jnp.pad(
+            jnp.tile(sink.astype(jnp.float32).reshape(hkv, 1, rep),
+                     (1, qblk, 1)).reshape(hkv, qblk * rep),
+            ((0, 0), (0, rows - qblk * rep)))[:, :, None],
+            (hkv, rows, _LANES)))
+        in_specs.append(pl.BlockSpec((hkv, rows, _LANES),
+                                     lambda *a: (0, 0, 0)))
     part_shapes = [pltpu.VMEM((hkv, rows, w), jnp.float32)
-                   for w in (_LANES, _LANES, d)]
+                   for w in (_LANES, _LANES, dv)]
+    out_spec = q_spec if heads is None else pl.BlockSpec(
+        (1, 1, hkv, rows, dv), q_idx)
     with _trace32():
         ib, it = _work_items(q_len, qblk, nqb)
         n_items, n_kblk = walk_grid_bounds(
@@ -1031,24 +1119,26 @@ def _ragged_attention_local(q, k_pool, v_pool, page_table, pos, q_len,
             _ragged_kernel, ps=ps, ppb=ppb, qblk=qblk, rep=rep,
             scale=scale, has_mask=mask is not None, has_scale=has_scale,
             fp8=fp8, grouped=grouped, in_place=in_place,
-            **({} if window is None else {"window": window}))
+            **({} if window is None else {"window": window}),
+            heads=heads, has_sink=sink is not None)
         out = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(prefetch),
                 grid=(n_items, n_kblk),
                 in_specs=in_specs,
-                out_specs=q_spec,
+                out_specs=out_spec,
                 scratch_shapes=scratch),
-            out_shape=jax.ShapeDtypeStruct(q5.shape, q.dtype),
+            out_shape=jax.ShapeDtypeStruct(q5.shape[:4] + (dv,), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary"),
                 vmem_limit_bytes=_VMEM_LIMIT),
             interpret=_INTERPRET,
-            **KERNELS["ragged_walk"],
+            **KERNELS["ragged_walk" if heads is None else
+                      "sink_walk" if sink is not None else "split_walk"],
         )(*prefetch, *ops)
-    out = out[:, :, :, :qblk * rep].reshape(b, nqb, hkv, qblk, rep, d) \
-        .transpose(0, 1, 3, 2, 4, 5).reshape(b, lq_pad, h, d)[:, :lq]
+    out = out[:, :, :, :qblk * rep].reshape(b, nqb, hkv, qblk, rep, dv) \
+        .transpose(0, 1, 3, 2, 4, 5).reshape(b, lq_pad, h, dv)[:, :lq]
     return _zero_dead_queries(out, q_len)
 
 
@@ -1212,6 +1302,28 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, pos,
                                      posv, mask)
 
 
+def _gathered_view(pool, page_table, heads, width):
+    """A pool [P, ps, ...] gathered by the rows' page tables into their
+    dense logical views [B, max_pages * ps, heads, width]."""
+    tab = page_table.astype(jnp.int32)
+    return jnp.take(pool, tab, axis=0).reshape(
+        tab.shape[0], tab.shape[1] * pool.shape[1], heads, width)
+
+
+def _ragged_live(pos, q_len, lq, lmax, window=None):
+    """[B, lq, lmax] bool: the keys query i of row b sees under the
+    ragged causal window — j <= pos[b] + i (and, in a sliding-window
+    layer, j > pos[b] + i - window), none at i >= q_len[b]."""
+    i = jnp.arange(lq, dtype=jnp.int32)[None, :, None]
+    j = jnp.arange(lmax, dtype=jnp.int32)[None, None, :]
+    live = (i < q_len.astype(jnp.int32)[:, None, None]) & \
+        (j <= pos.astype(jnp.int32)[:, None, None] + i)
+    if window is not None:
+        live = live & (j > pos.astype(jnp.int32)[:, None, None] + i
+                       - window)
+    return live
+
+
 def _ragged_mask_attend(q, kf, vf, pos, q_len, mask, window=None):
     """Shared tail of the ragged references: grouped softmax over the
     dense logical K/V views under the ragged causal window — query i of
@@ -1220,13 +1332,7 @@ def _ragged_mask_attend(q, kf, vf, pos, q_len, mask, window=None):
     (their outputs are unspecified)."""
     b, lq, h, _ = q.shape
     lmax = kf.shape[1]
-    i = jnp.arange(lq, dtype=jnp.int32)[None, :, None]
-    j = jnp.arange(lmax, dtype=jnp.int32)[None, None, :]
-    live = (i < q_len.astype(jnp.int32)[:, None, None]) & \
-        (j <= pos.astype(jnp.int32)[:, None, None] + i)
-    if window is not None:
-        live = live & (j > pos.astype(jnp.int32)[:, None, None] + i
-                       - window)
+    live = _ragged_live(pos, q_len, lq, lmax, window)
     add = jnp.where(live, jnp.float32(0.0), jnp.float32(_NEG_INF))
     add = add[:, None]                            # [B, 1, lq, lmax]
     if mask is not None:
@@ -1243,12 +1349,9 @@ def ragged_attention_reference(q, k_pool, v_pool, page_table, pos,
     bit-identical to the gather path; for l > 1 rows the grouped unroll
     reproduces the dense repeat_interleave + SDPA oracle (the same
     per-group shape argument as gqa_attend_reference)."""
-    b, lq, h, d = q.shape
-    ps, hkv = k_pool.shape[1], k_pool.shape[2]
-    lmax = page_table.shape[1] * ps
-    tab = page_table.astype(jnp.int32)
-    kf = jnp.take(k_pool, tab, axis=0).reshape(b, lmax, hkv, d)
-    vf = jnp.take(v_pool, tab, axis=0).reshape(b, lmax, hkv, d)
+    d, hkv = q.shape[3], k_pool.shape[2]
+    kf = _gathered_view(k_pool, page_table, hkv, d)
+    vf = _gathered_view(v_pool, page_table, hkv, d)
     if _is_fp8(k_pool.dtype):
         # fp8 lane: pure-convert dequant of the gathered view
         kf = kf.astype(jnp.float32)
@@ -1321,6 +1424,61 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, pos, q_len,
             mask, window=window)
     return ragged_attention_reference(q, k_pool, v_pool, page_table,
                                       posv, qlv, mask, window)
+
+
+def ragged_attention_reference_split(q, k_pool, v_pool, page_table, pos,
+                                     q_len, sink=None, *, heads,
+                                     window=None):
+    """Pure-JAX form of the walk over pools of SPLIT widths
+    (`ragged_paged_attention_split`): the rows' pages gathered into
+    their dense views [B, lmax, heads, Dk | Dv], scores in float32 under
+    the ragged causal window, and a head's `sink` logit, where given,
+    one more term of the softmax's denominator. Queries at or past
+    q_len are unspecified but finite. The gather and the mask are the
+    accepted form's (`ragged_attention_reference`); the softmax is its
+    own, since `gqa_attend_reference` has one width for K and V and no
+    sink."""
+    b, lq, h, dk = q.shape
+    dv = v_pool.shape[2] // heads
+    rep = h // heads
+    kf = _gathered_view(k_pool, page_table, heads, dk)
+    vf = _gathered_view(v_pool, page_table, heads, dv)
+    live = _ragged_live(pos, q_len, lq, kf.shape[1], window)
+    s = jnp.einsum("blgrd,bmgd->bgrlm", q.reshape(b, lq, heads, rep, dk),
+                   kf, preferred_element_type=jnp.float32) \
+        * jnp.float32(1.0 / math.sqrt(dk))
+    s = jnp.where(live[:, None, None], s, jnp.float32(_NEG_INF))
+    m = jnp.max(s, axis=-1, keepdims=True)
+    if sink is not None:
+        sk = sink.astype(jnp.float32).reshape(1, heads, rep, 1, 1)
+        m = jnp.maximum(m, sk)
+    p = jnp.exp(s - m)
+    den = jnp.sum(p, axis=-1, keepdims=True)
+    if sink is not None:
+        den = den + jnp.exp(sk - m)
+    out = jnp.einsum("bgrlm,bmgd->blgrd", (p / den).astype(vf.dtype), vf,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, lq, h, dv).astype(q.dtype)
+
+
+def ragged_paged_attention_split(q, k_pool, v_pool, page_table, pos, q_len,
+                                 sink=None, *, heads, window=None):
+    """`ragged_paged_attention` over pools of SPLIT widths (the
+    registered op's forward): k/v pools [P, ps, heads * Dk] and
+    [P, ps, heads * Dv], a token's `heads` kv heads side by side; q
+    [B, lq, H, Dk]; returns [B, lq, H, Dv]. sink (f32 [H] or None): a
+    learned logit a query head in the softmax's denominator. On a TPU
+    the page walk (`_ragged_attention_local` with `heads`), elsewhere
+    `ragged_attention_reference_split`."""
+    posv = pos.astype(jnp.int32)
+    qlv = q_len.astype(jnp.int32)
+    if _use_kernel():
+        return _ragged_attention_kernel(
+            q, k_pool, v_pool, page_table.astype(jnp.int32), posv, qlv,
+            None, window=window, split_heads=heads, sink=sink)
+    return ragged_attention_reference_split(
+        q, k_pool, v_pool, page_table, posv, qlv, sink, heads=heads,
+        window=window)
 
 
 def ragged_paged_attention_q8(q, k_pool, v_pool, k_scale, v_scale,
@@ -1873,6 +2031,29 @@ def count_window_page_reads(pos, q_len, *, page_size, window):
     first = np.maximum(pos - (window - 1), 0) // page_size
     return (int(np.where(live, last - first + 1, 0).sum()),
             int(np.where(live, last + 1, 0).sum()))
+
+
+def count_walk_pairs(pos, q_len, window=None):
+    """Host-side (numpy) count over one layer's walk of a step: ((query,
+    key) pairs its live queries score, distinct keys they see, live
+    rows). Query i
+    of row b stands at position pos + i and sees its own key and those
+    below it, the last `window` of them in a sliding-window layer; a
+    row's queries share their keys, so what must be read at least once
+    is the keys any of them sees."""
+    pos = np.asarray(pos, np.int64)
+    q_len = np.asarray(q_len, np.int64)
+    if window is None:
+        pairs = (2 * pos + q_len + 1) * q_len // 2
+        keys = np.where(q_len > 0, pos + q_len, 0)
+    else:
+        # sum over i < q_len of min(pos + 1 + i, window)
+        under = np.clip(window - pos, 0, q_len)
+        pairs = (2 * pos + under + 1) * under // 2 \
+            + (q_len - under) * window
+        keys = np.where(q_len > 0, np.minimum(pos + q_len,
+                                              window - 1 + q_len), 0)
+    return int(pairs.sum()), int(keys.sum()), int((q_len > 0).sum())
 
 
 def count_walk_grid_steps(pos, q_len, *, lq, rep, page_size, max_pages):

@@ -214,6 +214,7 @@ from ..ops.pallas.sparse import count_sparse_work
 from ..ops.pallas.paged_attention import (count_page_block_reads,
                                           count_walk_grid_steps,
                                           count_window_page_reads,
+                                          count_walk_pairs,
                                           resolve_megakernel_flag)
 from .adapters import (AdapterStore, BASE_ADAPTER,
                        resolve_adapters_flag)
@@ -488,7 +489,24 @@ class ServingEngine:
         # and under the slot's ONE page table. A query attends the
         # `topk` positions its indexer scores highest against the rows.
         # The attention module writes all three and reads them through
-        # `update_and_attend_sparse`. `windows` must all be None.
+        # `update_and_attend_sparse`. `windows` must all be None; or
+        #   (n_layers, n_kv_heads, head_dim, windows, "split",
+        #    ((n_kv, k_dim, v_dim, sink), ...))
+        # for a model whose layers each state their OWN geometry
+        # (nlp/mimo_v2.py): layer i's kv heads, key width and value
+        # width, and whether a learned sink joins its softmax (what its
+        # walk is named in the trace, `ptk:sink_walk` with one and
+        # `ptk:split_walk` without; the sink itself is the model's
+        # weight). A layer's K and V pools are [pages, page_size,
+        # n_kv * k_dim] and [pages, page_size, n_kv * v_dim], its heads
+        # side by side on the lanes (a key width that is no multiple of
+        # 128 lanes pads nothing there), on the shared paged pool or,
+        # with a window, on the per-slot rings as in the second form;
+        # each kind's walk sizes its query block from its own query
+        # heads a kv head. `n_kv_heads` and `head_dim` are the
+        # full-attention layers' kv heads and key width, for what reads
+        # one pair. The attention module writes and reads through
+        # `update_and_attend_split`.
         if cache_spec is None:
             if not hasattr(model, "_decode_cache_spec"):
                 raise ValueError(
@@ -499,7 +517,10 @@ class ServingEngine:
                     "or (n_layers, 1, row, windows, 'latent') for one "
                     "latent row a token, or (n_layers, n_kv_heads, "
                     "head_dim, windows, 'sparse', (row, topk)) for keys, "
-                    "values and an indexer row a token, explicitly")
+                    "values and an indexer row a token, or (n_layers, "
+                    "n_kv_heads, head_dim, windows, 'split', geometry) "
+                    "with each layer's (n_kv, k_dim, v_dim, sink), "
+                    "explicitly")
             cache_spec = model._decode_cache_spec()
         self.model = model
         self.n_layers, self.n_kv, self.head_dim = cache_spec[:3]
@@ -514,7 +535,20 @@ class ServingEngine:
         # the latent kind: one pool a layer, no V; the sparse kind: a
         # third pool a layer, of indexer rows (the contract above)
         self.kv_sparse = len(cache_spec) > 4 and cache_spec[4] == "sparse"
-        self.kv_latent = len(cache_spec) > 4 and not self.kv_sparse
+        # the split kind: a geometry a layer (the contract above)
+        self.kv_split = len(cache_spec) > 4 and cache_spec[4] == "split"
+        self.kv_latent = len(cache_spec) > 4 and not (self.kv_sparse
+                                                      or self.kv_split)
+        self.kv_geometry = None
+        if self.kv_split:
+            if len(cache_spec) != 6 or len(cache_spec[5]) != self.n_layers:
+                raise ValueError(
+                    f"a split cache_spec is (n_layers, n_kv_heads, "
+                    f"head_dim, windows, 'split', one (n_kv, k_dim, "
+                    f"v_dim, sink) a layer), got {cache_spec}")
+            self.kv_geometry = tuple(
+                (int(h), int(dk), int(dv), bool(sink))
+                for h, dk, dv, sink in cache_spec[5])
         if self.kv_sparse and (len(cache_spec) != 6 or self.kv_windows):
             raise ValueError(
                 f"a sparse cache_spec is (n_layers, n_kv_heads, head_dim, "
@@ -525,6 +559,7 @@ class ServingEngine:
         # query heads a kv head serves in a full-attention layer: the
         # page walk sizes its query block from it, and the host counts
         # that walk's grid (`count_walk_grid_steps`)
+        # (the split kind states its full layers' kv heads at [1])
         self._walk_rep = max(1, int(getattr(
             getattr(model, "config", None), "num_attention_heads",
             self.n_kv)) // self.n_kv)
@@ -666,7 +701,8 @@ class ServingEngine:
         self.megakernel = (resolve_megakernel_flag(megakernel)
                            and self.attn_impl == "kernel"
                            and self.tp is None)
-        if self.kv_windows or self.kv_latent or self.kv_sparse:
+        if self.kv_windows or self.kv_latent or self.kv_sparse \
+                or self.kv_split:
             # WINDOW LAYERS, LATENT ROWS AND INDEXER ROWS: what the
             # engine does not do for them yet. (Latent rows, and the
             # indexer rows a sparse layer keeps BESIDE its K and V:
@@ -697,6 +733,8 @@ class ServingEngine:
                 ("spec", self.spec is not None)) if on]
             kind = (f"sliding-window layers {sorted(self.kv_windows)}"
                     if self.kv_windows else
+                    "keys and values of their own widths a layer"
+                    if self.kv_split else
                     f"indexer rows of {self.index_row} values in a lone "
                     f"pool beside K and V" if self.kv_sparse else
                     f"latent rows of {self.head_dim} values and no V pool")
@@ -852,6 +890,18 @@ class ServingEngine:
                 (jnp.zeros((self.num_pages, self.page_size,
                             self.head_dim), self._fp), None, None, None)
                 for _ in range(self.n_layers))
+        elif self.kv_split:
+            # each layer at its own geometry, heads side by side (the
+            # contract above); a window layer's on its rings
+            ring_total = self.num_slots * self.ring_pages + 1
+            self._ct = tuple(
+                (jnp.zeros((pages, self.page_size, h * dk), self._fp),
+                 jnp.zeros((pages, self.page_size, h * dv), self._fp),
+                 None, None)
+                for pages, (h, dk, dv, _) in zip(
+                    (ring_total if i in self.kv_windows
+                     else self.num_pages for i in range(self.n_layers)),
+                    self.kv_geometry))
         elif self.kv_sparse:
             # K and V as every full-attention layer has them, and the
             # indexer's rows behind (the contract above)
@@ -899,14 +949,21 @@ class ServingEngine:
         scale_bytes = 4 if self.kv_dtype == "int8" else 0
         # (a page of the shared pool: the full-attention layers only;
         # one pool a layer, not two, for latent rows; a sparse layer's
-        # indexer rows besides)
-        self.page_bytes = ((self.n_layers - len(self.kv_windows))
-                           * (1 if self.kv_latent else 2)
-                           * self.page_size * self.n_kv
-                           * (self.head_dim * kv_itemsize
-                              + scale_bytes)
-                           + self.n_layers * self.page_size
-                           * self.index_row * kv_itemsize)
+        # indexer rows besides; the split kind's, a layer at its own
+        # geometry)
+        if self.kv_split:
+            self.page_bytes = sum(
+                self.page_size * h * (dk + dv) * kv_itemsize
+                for i, (h, dk, dv, _) in enumerate(self.kv_geometry)
+                if i not in self.kv_windows)
+        else:
+            self.page_bytes = ((self.n_layers - len(self.kv_windows))
+                               * (1 if self.kv_latent else 2)
+                               * self.page_size * self.n_kv
+                               * (self.head_dim * kv_itemsize
+                                  + scale_bytes)
+                               + self.n_layers * self.page_size
+                               * self.index_row * kv_itemsize)
         self.metrics.kv_dtype = self.kv_dtype
         self.metrics.pool_bytes_per_page = self.page_bytes
         self.metrics.adapters_enabled = self.adapters is not None
@@ -2945,6 +3002,15 @@ class ServingEngine:
             for name, count in zip(SPARSE_COUNTERS, count_sparse_work(
                     pos_host, q_len, self.sparse_topk)):
                 self._host_phases[name] += count * self.n_layers
+        if self.kv_split:
+            # the pairs and keys of each layer's walk, by its trace name
+            for i, (_, _, _, sink) in enumerate(self.kv_geometry):
+                walk = "sink_walk" if sink else "split_walk"
+                for what, n in zip(("pairs", "keys", "rows"),
+                                   count_walk_pairs(
+                                       pos_host, q_len,
+                                       self.kv_windows.get(i))):
+                    self._host_phases[f"{walk}_{what}_total"] += n
         for window in self.kv_windows.values():
             walked, unwindowed = count_window_page_reads(
                 pos_host, q_len, page_size=self.page_size,

@@ -29,7 +29,7 @@ from typing import Optional, Sequence
 
 __all__ = ["Histogram", "ServingMetrics", "prometheus_render",
            "HOST_PHASE_COUNTERS", "STEP_WORK_COUNTERS", "LATENT_COUNTERS",
-           "SPARSE_COUNTERS",
+           "SPARSE_COUNTERS", "SPLIT_COUNTERS",
            "TTFT_BUCKETS", "LATENCY_BUCKETS", "PACKED_TOKEN_BUCKETS",
            "SPEC_TOKEN_BUCKETS", "GROUP_SIZE_BUCKETS", "UTIL_BUCKETS"]
 
@@ -134,7 +134,13 @@ HOST_PHASE_COUNTERS = (
 # the attention weighs; the distinct keys and values any form of the
 # attention must read, min(context, live queries x topk) a slot; the
 # indexer rows any form must read, a slot's context once; and the live
-# query rows.
+# query rows. `sink_walk_*`, `split_walk_*`: the same for a model of the
+# split kind (`paged_attention.count_walk_pairs`), summed over the layers
+# whose walk carries that trace name (with a sink and without): the
+# (query, key) pairs the live queries score, the distinct keys they see,
+# a row's once, and the live rows. `moe_bias_reranked_total`: made on the device by a
+# router whose selection takes a bias (`moe_route`): the assignments
+# whose expert the unbiased scores would not have chosen.
 LATENT_COUNTERS = (
     "mla_pairs_total",
     "mla_keys_distinct_total",
@@ -147,6 +153,14 @@ SPARSE_COUNTERS = (
     "sparse_keys_context_total",
     "sparse_rows_total",
 )
+SPLIT_COUNTERS = (
+    "sink_walk_pairs_total",
+    "sink_walk_keys_total",
+    "sink_walk_rows_total",
+    "split_walk_pairs_total",
+    "split_walk_keys_total",
+    "split_walk_rows_total",
+)
 STEP_WORK_COUNTERS = (
     "moe_assignments_total",
     "moe_assignments_here_total",
@@ -156,7 +170,8 @@ STEP_WORK_COUNTERS = (
     "kv_window_pages_skipped_total",
     "walk_grid_steps_total",
     "walk_grid_steps_full_total",
-) + LATENT_COUNTERS + SPARSE_COUNTERS
+) + LATENT_COUNTERS + SPARSE_COUNTERS + SPLIT_COUNTERS + (
+    "moe_bias_reranked_total",)
 
 
 class Histogram:

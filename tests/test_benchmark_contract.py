@@ -70,6 +70,8 @@ def _metric_files():
         if spec["reader"] == "sparse_roofline":
             counters += [*spec["args"]["flops"].values(),
                          *spec["args"]["bytes"].values()]
+        if spec["reader"] == "split_roofline":
+            counters += [spec["args"]["pairs"], spec["args"]["keys"]]
         spans = (_listed(spec["args"].get("spans"))
                  + _listed(spec["args"].get("excluding"))
                  if spec["reader"] in ("span_idle", "idle_outside",
@@ -114,12 +116,12 @@ def opened_spans():
 
 
 def test_the_yardstick_names_something():
-    """34 metrics read the engine's counters and histograms at the top
-    of their arguments, 2 more in an operand, 4 through the roofline
+    """35 metrics read the engine's counters and histograms at the top
+    of their arguments, 2 more in an operand, 6 through the roofline
     readers, 1 through `leaf_idle`; 14 read spans, 2 of them through
     `idle_outside` and 1 through `leaf_idle`."""
     cases = [p.values for p in _metric_files()]
-    assert sum(1 for c, s, sp in cases if c or s) == 41
+    assert sum(1 for c, s, sp in cases if c or s) == 44
     assert sum(1 for c, s, sp in cases if sp) == 14
 
 

@@ -965,6 +965,12 @@ ELSEWHERE = {
        for n in ["keye_layer_norm", "keye_published_attention"]},
     **{n: EW("test_serving_keye_vl2.py", "chunked_prefill|published")
        for n in ["keye_pad_last", "sparse_paged_attention"]},
+    # MiMo-V2-Flash's ops (nlp/mimo_v2.py, nlp/generation.py): against
+    # the plain float32 reference, eagerly and through the engine's
+    # pools of split widths
+    "mimo_sink_attend": EW("test_mimo_v2.py", "eager_forward"),
+    "ragged_paged_attention_split": EW("test_serving_mimo_v2.py",
+                                       "chunked_prefill"),
     # quantization — tests/test_inference_quant.py
     "fake_quantize_dequantize": EW("test_inference_quant.py",
                                    "quant"),

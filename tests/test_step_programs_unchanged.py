@@ -1,11 +1,12 @@
 """The unified step of the models the benchmark already measures is the
-PARENT's program, byte for byte: a small GPT, Laguna and DeepSeek-V2
-engine each serve one request on the CPU (the jnp forms: the text holds
-every operation of the step, its operands' shapes and the pytrees'
-arity), the lowered step's text is hashed, and the hashes are those of
-`tests/step_digests.json`, which was written by THIS file run on the
-parent commit's tree (ISSUE 36: a new model adds nothing to what the
-accepted cells trace and lower).
+PARENT's program, byte for byte: a small GPT, Laguna, DeepSeek-V2 and
+Keye-VL-2.0 engine each serve one request on the CPU (the jnp forms: the
+text holds every operation of the step, its operands' shapes and the
+pytrees' arity), the lowered step's text is hashed, and the hashes are
+those of `tests/step_digests.json`, which was written by THIS file run
+on the parent commit's tree (a new model adds nothing to what the
+accepted cells trace and lower). The router's softmax path, which every
+accepted expert model runs, is hashed the same way, lowered alone.
 
 A PR that changes one of these steps on purpose writes the file anew on
 its own tree and says so:
@@ -26,6 +27,7 @@ from paddle_tpu.nlp import GPTConfig, GPTForCausalLM
 from paddle_tpu.serving import SamplingParams, ServingEngine
 
 from test_deepseek_v2 import tiny_dsv2
+from test_keye_vl2 import tiny_keye
 from test_laguna import tiny_laguna
 
 
@@ -56,7 +58,8 @@ def _tiny_gpt():
     return model
 
 
-MODELS = {"gpt": _tiny_gpt, "laguna": tiny_laguna, "deepseek_v2": tiny_dsv2}
+MODELS = {"gpt": _tiny_gpt, "laguna": tiny_laguna, "deepseek_v2": tiny_dsv2,
+          "keye": tiny_keye}
 
 
 def step_text(name):
@@ -69,9 +72,26 @@ def step_text(name):
     return eng.lowered_unified_step().as_text()
 
 
-@pytest.mark.parametrize("name", sorted(MODELS))
+def route_text(top_k, n_group, topk_group):
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import moe
+    f = jax.jit(lambda x, w, v: moe.moe_route(
+        x, w, v, top_k=top_k, scale=2.5, norm_topk=True, first=8,
+        n_local=8, n_group=n_group, topk_group=topk_group))
+    return f.lower(jax.ShapeDtypeStruct((64, 32), jnp.float32),
+                   jax.ShapeDtypeStruct((32, 16), jnp.float32),
+                   jax.ShapeDtypeStruct((64,), jnp.bool_)).as_text()
+
+
+ROUTES = {"moe_route_softmax.k2g1": (2, 1, 1),
+          "moe_route_softmax.k6g4": (6, 4, 2)}
+
+
+@pytest.mark.parametrize("name", sorted(MODELS) + sorted(ROUTES))
 def test_lowered_step_is_the_parents(name):
-    digest = hashlib.sha256(step_text(name).encode()).hexdigest()
+    text = step_text(name) if name in MODELS else route_text(*ROUTES[name])
+    digest = hashlib.sha256(text.encode()).hexdigest()
     if os.environ.get("STEP_DIGESTS_WRITE"):
         have = {}
         if os.path.exists(DIGESTS):

@@ -154,6 +154,52 @@ def test_moe_routed_experts(one_chip, rows, monkeypatch):
     assert "ptk:moe_experts" in text
 
 
+# MiMo-V2-Flash's two walks at its serving shape: 32 slots x chunk 128
+# (and the decoding rows' one query), 64 query heads, keys of 192 and
+# values of 128 a head in pools of split widths ([pages, 16, n_kv x
+# width]: no lane or sublane of padding); full layers 4 kv heads over
+# 2,048 pages a slot, window layers 8 over the per-slot rings of 17
+# pages, a sink a query head
+MI = dict(slots=32, mp=2048, ring=17, ps=16, heads=64)
+
+
+@pytest.mark.parametrize("lq", [CHUNK, 1])
+@pytest.mark.parametrize("kind", ["sink_walk", "split_walk"])
+def test_paged_walk_mimo(one_chip, on_tpu_branch, lq, kind):
+    a = MI
+    sink = kind == "sink_walk"
+    hkv = 8 if sink else 4
+    pages = a["slots"] * (a["ring"] if sink else a["mp"]) + 1
+    shapes = [((a["slots"], lq, a["heads"], 192), BF16),
+              ((pages, a["ps"], hkv * 192), BF16),
+              ((pages, a["ps"], hkv * 128), BF16),
+              ((a["slots"], a["mp"]), I32), ((a["slots"],), I32),
+              ((a["slots"],), I32)]
+    if sink:
+        shapes.append(((a["heads"],), BF16))
+    text = _compiles_to_kernel(
+        lambda *ops: pa.ragged_paged_attention_split(
+            *ops, heads=hkv, window=128 if sink else None),
+        one_chip, *shapes)
+    assert f"ptk:{kind}" in text and "ptk:ragged_walk" not in text
+
+
+def test_moe_routed_experts_sigmoid(one_chip, monkeypatch):
+    """MiMo-V2-Flash's router over its decoding rows: sigmoid scores of
+    256 outputs, the top 8 chosen with a selection bias, this chip's 16
+    experts of width 2048."""
+    h, f, held, rows = 4096, 2048, 16, 32
+    monkeypatch.setattr(moe, "_use_kernel", lambda: True)
+    text = _compiles_to_kernel(
+        lambda x, v, wr, wg, wu, wd, b: moe.routed_experts(
+            x, v, wr, wg, wu, wd, top_k=8, scale=1.0, norm_topk=True,
+            first=0, scoring="sigmoid", bias=b),
+        one_chip, ((rows, h), BF16), ((rows,), jnp.bool_),
+        ((h, 256), BF16), ((held, h, f), BF16), ((held, h, f), BF16),
+        ((held, f, h), BF16), ((256,), F32))
+    assert "ptk:moe_experts" in text
+
+
 # DeepSeek-V2's latent rows at its serving shape: 16 slots x chunk 128
 # (and the decoding rows' one query), 128 heads over rows of 512 + 64
 # (640 in the cache: `DeepseekV2Config.cache_row`), rows of max_len
@@ -463,14 +509,17 @@ def test_kernel_names_are_distinct_and_on_every_site():
     import re
     tables = {mod: mod.KERNELS for mod in (pa, fa, pln, moe, mla, sp)}
     names = [n for t in tables.values() for n in t]
-    assert len(names) == len(set(names)) == 16
+    assert len(names) == len(set(names)) == 18
     assert not [(a, b) for a in names for b in names
                 if a != b and a in b]
     for mod, table in tables.items():
         with open(mod.__file__) as f:
             src = f.read()
-        sites = re.findall(r'\*\*KERNELS\["(\w+)"\]', src)
-        assert sorted(sites) == sorted(table)       # each name, one site
+        # a site names its kernel, or picks one of its variants' names
+        # (the page walk: `ragged_walk`, `split_walk`, `sink_walk`)
+        sites = re.findall(r'\*\*KERNELS\[([^\]]+)\]', src)
+        named = [n for site in sites for n in re.findall(r'"(\w+)"', site)]
+        assert sorted(named) == sorted(table)       # each name, one site
         assert src.count("pl.pallas_call(") == len(sites)
         for name, kw in table.items():
             assert kw["name"] == name
