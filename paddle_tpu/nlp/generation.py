@@ -58,7 +58,8 @@ from ..ops.pallas.paged_attention import (dequantize_paged_q8,
 __all__ = ["DecodeCache", "init_decode_caches", "update_and_attend",
            "update_and_attend_latent", "update_and_attend_sparse",
            "update_and_attend_split",
-           "CompiledGenerator", "decode_model_step", "sample_logits",
+           "CompiledGenerator", "decode_model_step", "head_columns",
+           "sample_logits",
            "resolve_paged_attn_impl", "PAGED_ATTN_IMPLS",
            "quantize_kv_rowwise"]
 
@@ -1040,6 +1041,18 @@ def decode_model_step(model, tokens, caches):
     inside one compiled program."""
     lg, caches = model(Tensor(tokens), caches=caches)
     return lg._value[:, -1, :].astype(jnp.float32), caches
+
+
+def head_columns(h, columns):
+    """What a causal-LM wrapper's head reads of the hidden states h
+    [B, L, H]: with `columns` (int [B, C] Tensor) the C columns it
+    names a row, [B, C, H]; without, h itself. The unified serving
+    step keeps one column a row of its W (1 + k with speculation), so
+    the vocabulary-wide matmul and its logits run on those alone."""
+    if columns is None:
+        return h
+    return Tensor(jnp.take_along_axis(h._value,
+                                      columns._value[:, :, None], axis=1))
 
 
 def sample_logits(logits, key, temperature=1.0, top_k=None, top_p=None,

@@ -20,6 +20,7 @@ from .. import nn
 from ..nn import functional as F
 from ..core.tensor import Tensor
 from ..nn.initializer import Normal, Constant
+from .generation import head_columns
 
 __all__ = ["GPTConfig", "GPTModel", "GPTForCausalLM",
            "GPTForCausalLMPipe"]
@@ -290,13 +291,14 @@ class GPTForCausalLM(nn.Layer):
         self._qhead_group = None
 
     def forward(self, input_ids, position_ids=None, labels=None,
-                caches=None):
+                caches=None, columns=None):
         from ..ops import linalg
         if caches is not None:
             h, new_caches = self.gpt(input_ids, position_ids,
                                      caches=caches)
         else:
             h = self.gpt(input_ids, position_ids)
+        h = head_columns(h, columns)
         if self._qhead_algo is not None:
             # weight-only quantized LM head (nn.quant): the vocab-sized
             # matmul streams int8/int4 from HBM — the decode hot spot

@@ -51,6 +51,7 @@ from ..ops._helpers import apply_op
 from ..ops.pallas.sparse import LANES
 from ..nn.initializer import Constant, Normal
 from .laguna import rotary_frequencies
+from .generation import head_columns
 from .moe_common import (MOE_STEP_STAT_COUNTERS, NormalByExpert, cast,
                          linear, moe_stats, valid_columns)
 
@@ -367,11 +368,11 @@ class KeyeVL2ForCausalLM(nn.Layer):
         self.lm_head = linear(cfg.hidden_size, cfg.vocab_size, cfg)
         self.config = cfg
 
-    def forward(self, input_ids, caches=None):
+    def forward(self, input_ids, caches=None, columns=None):
         if caches is not None:
             h, new_caches = self.model(input_ids, caches=caches)
-            return self.lm_head(h), new_caches
-        return self.lm_head(self.model(input_ids))
+            return self.lm_head(head_columns(h, columns)), new_caches
+        return self.lm_head(head_columns(self.model(input_ids), columns))
 
     def _decode_cache_spec(self):
         """The six-entry form of `ServingEngine`'s cache-spec contract:

@@ -44,6 +44,7 @@ from ..core.tensor import Tensor
 from ..core.dispatch import register_op
 from ..ops._helpers import apply_op
 from ..nn.initializer import Normal
+from .generation import head_columns
 from .moe_common import (MOE_STEP_STAT_COUNTERS, NormalByExpert, SwiGLU,
                          cast, linear, moe_stats, valid_columns)
 
@@ -380,11 +381,11 @@ class LagunaForCausalLM(nn.Layer):
         self.lm_head = linear(cfg.hidden_size, cfg.vocab_size, cfg)
         self.config = cfg
 
-    def forward(self, input_ids, caches=None):
+    def forward(self, input_ids, caches=None, columns=None):
         if caches is not None:
             h, new_caches = self.laguna(input_ids, caches=caches)
-            return self.lm_head(h), new_caches
-        return self.lm_head(self.laguna(input_ids))
+            return self.lm_head(head_columns(h, columns)), new_caches
+        return self.lm_head(head_columns(self.laguna(input_ids), columns))
 
     def _decode_cache_spec(self):
         """(layers, kv heads, head size, each layer's sliding window or

@@ -17,6 +17,7 @@ from ..core.tensor import Tensor
 from ..core.dispatch import register_op
 from ..ops._helpers import apply_op, as_tensor
 from ..nn.initializer import Normal
+from .generation import head_columns
 from .gpt import _make_linear, _mp_active, _sep_active
 
 __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM"]
@@ -281,12 +282,12 @@ class LlamaForCausalLM(nn.Layer):
                                     parallel="column", gather_output=True)
         self.config = cfg
 
-    def forward(self, input_ids, labels=None, caches=None):
+    def forward(self, input_ids, labels=None, caches=None, columns=None):
         if caches is not None:
             h, new_caches = self.llama(input_ids, caches=caches)
-            return self.lm_head(h), new_caches
+            return self.lm_head(head_columns(h, columns)), new_caches
         h = self.llama(input_ids)
-        logits = self.lm_head(h)
+        logits = self.lm_head(head_columns(h, columns))
         if labels is not None:
             return F.cross_entropy(logits, labels)
         return logits

@@ -55,6 +55,7 @@ from ..core.dispatch import register_op
 from ..ops._helpers import apply_op
 from ..nn.initializer import Normal
 from .laguna import rotary_frequencies
+from .generation import head_columns
 from .moe_common import (NormalByExpert, SwiGLU, cast, linear, moe_stats,
                          valid_columns)
 
@@ -375,11 +376,11 @@ class MiMoV2ForCausalLM(nn.Layer):
         self.lm_head = linear(cfg.hidden_size, cfg.vocab_size, cfg)
         self.config = cfg
 
-    def forward(self, input_ids, caches=None):
+    def forward(self, input_ids, caches=None, columns=None):
         if caches is not None:
             h, new_caches = self.model(input_ids, caches=caches)
-            return self.lm_head(h), new_caches
-        return self.lm_head(self.model(input_ids))
+            return self.lm_head(head_columns(h, columns)), new_caches
+        return self.lm_head(head_columns(self.model(input_ids), columns))
 
     def _decode_cache_spec(self):
         """(layers, the full layers' kv heads and key width, each

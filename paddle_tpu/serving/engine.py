@@ -1141,6 +1141,11 @@ class ServingEngine:
         self._last_walk_bytes: Optional[dict] = None
         self.step_capacity_tokens = self.num_slots * self.chunk_len
         self.metrics.step_capacity_tokens = self.step_capacity_tokens
+        # the columns of a row the step's LM head computes: the one a
+        # row keeps, and with speculation the columns a decoding row's
+        # drafts are verified against (drafts are capped at W - 1)
+        self._head_cols = (1 + min(self.spec.k, self.chunk_len - 1)
+                           if self.spec is not None else 1)
         # engine step counter (timeline/flight step index) + the
         # running round's token-split stats the flight record reads
         self._step_idx = 0
@@ -1332,15 +1337,19 @@ class ServingEngine:
         it is ever attended), and its held logits come from column
         `accept` so the next step's sample is the model's own
         correction token. Prefill rows: pos advances by q_len, held
-        logits from the last real column.
+        logits from the last real column. The LM head computes those
+        columns alone (`_head_cols` a row), gathered from the final
+        hidden states, not the step's whole width.
         With speculation off decode rows simply ride at q_len 1,
-        where accept is 0 by construction — SAME program, same trace,
-        zero cost; enabling speculation changes only the host-side
-        q_len/tokens values (the retrace probe asserts this). ONE
-        trace serves every prefill/decode/verify mix, membership
-        change and packing decision."""
+        where accept is 0 by construction; the gate sets only, at
+        build time, how many columns the head computes (1, or 1 + k).
+        Drafting changes only the host-side q_len/tokens values (the
+        retrace probe asserts this): ONE trace serves every
+        prefill/decode/verify mix, membership change and packing
+        decision."""
         model = self.model
         state_vals = self._state_vals
+        C = self._head_cols
 
         def ustep(state_vals, ct, pos, last_logits, page_table, tokens,
                   q_len, is_decode, key, temps, top_k, top_p, greedy,
@@ -1402,8 +1411,16 @@ class ServingEngine:
                                       lora=lora_layers,
                                       lora_paged=lora_paged_layers,
                                       megakernel=self.megakernel)
-                logits_t, caches = model(Tensor(toks), caches=caches)
-                lg = logits_t._value.astype(jnp.float32)   # [S, W, V]
+                # the LM head runs on the C columns the epilogue reads
+                # (`_head_cols`), not on all W: a decoding row's
+                # columns 0..C-1 (its token and drafts), any other
+                # row's last real column (q_len - 1) C times over
+                ccol = jnp.arange(C, dtype=jnp.int32)[None, :]
+                cols = jnp.where(is_decode[:, None], ccol,
+                                 jnp.maximum(q_len - 1, 0)[:, None])
+                logits_t, caches = model(Tensor(toks), caches=caches,
+                                         columns=Tensor(cols))
+                lg = logits_t._value.astype(jnp.float32)   # [S, C, V]
                 # greedy draft verification: column i's argmax is the
                 # token sequential decode would commit after column i;
                 # accept = longest prefix of draft columns 1..q_len-1
@@ -1417,7 +1434,8 @@ class ServingEngine:
                 # and is rejected by this same fused greedy acceptance
                 # — no second program. Only `preds` sees the bias;
                 # row_last below reads the unbiased lg.
-                lg_v = lg if gver is None else lg + gver
+                lg_v = lg if gver is None else lg + gver[:, :C]
+                toks_v = toks[:, :C]
                 if self.megakernel:
                     # fused acceptance epilogue: the registered op is
                     # the SAME expressions as the inline branch below
@@ -1426,22 +1444,21 @@ class ServingEngine:
                     # whole accept chain is ONE dispatched op the
                     # launch census can count
                     accept = self._dispatch("spec_verify_accept",
-                                            lg_v, toks, q_len,
+                                            lg_v, toks_v, q_len,
                                             is_decode)
                 else:
                     preds = jnp.argmax(lg_v, axis=-1).astype(jnp.int32)
-                    match = (toks[:, 1:] == preds[:, :-1])
-                    dcol = jnp.arange(tokens.shape[1] - 1,
-                                      dtype=jnp.int32)[None, :]
+                    match = (toks_v[:, 1:] == preds[:, :-1])
+                    dcol = jnp.arange(C - 1, dtype=jnp.int32)[None, :]
                     valid = dcol < (q_len - 1)[:, None]
                     accept = jnp.cumprod(
                         jnp.where(match & valid, 1, 0), axis=1
                     ).sum(axis=1).astype(jnp.int32)
                     accept = jnp.where(is_decode, accept, 0)
-                last_idx = jnp.where(is_decode, accept,
-                                     jnp.maximum(q_len - 1, 0))
+                # a decoding row keeps column `accept`, any other row
+                # column 0 (its last real one)
                 row_last = jnp.take_along_axis(
-                    lg, last_idx[:, None, None], axis=1)[:, 0]
+                    lg, accept[:, None, None], axis=1)[:, 0]
                 live = (q_len > 0)[:, None]
                 new_last = jnp.where(live, row_last, last_logits)
                 new_pos = pos + jnp.where(is_decode, 1 + accept,
@@ -1463,7 +1480,8 @@ class ServingEngine:
         # trace-build time from the engine's gates — the 3 adapter
         # operands (pool pytree, per-slot page, per-slot scale), the
         # 3 grouped-walk operands, the [S, V] grammar sample bias and
-        # (with spec also on) the [S, W, V] grammar verify bias.
+        # (with spec also on) the [S, W, V] grammar verify bias, of which
+        # the step reads the first `_head_cols` columns.
         # Adapter pools/pages, groups and grammar masks are DATA next
         # to pos/q_len: churn never retraces, and with the grammar
         # gate OFF the program carries no bias operand at all —
@@ -3018,6 +3036,10 @@ class ServingEngine:
             self._host_phases["kv_window_pages_walked_total"] += walked
             self._host_phases["kv_window_pages_skipped_total"] += \
                 unwindowed - walked
+        # the rows the step carries, and those its LM head computes
+        self._host_phases["step_rows_total"] += self.num_slots * W
+        self._host_phases["lm_head_rows_total"] += \
+            self.num_slots * self._head_cols
         # per-layer walk bytes -> whole-step modeled bytes: every
         # layer's attention issues the same walk over its own pools
         self._last_walk_bytes = {

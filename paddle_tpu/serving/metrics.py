@@ -141,6 +141,10 @@ HOST_PHASE_COUNTERS = (
 # a row's once, and the live rows. `moe_bias_reranked_total`: made on the device by a
 # router whose selection takes a bias (`moe_route`): the assignments
 # whose expert the unbiased scores would not have chosen.
+# `step_rows_total`, `lm_head_rows_total`: made on the host at the plan,
+# a step: the rows the step carries (slots x its width W), and those of
+# them its LM head computes (slots x one column, 1 + k with
+# speculation), so the head's share of the step's columns reads off.
 LATENT_COUNTERS = (
     "mla_pairs_total",
     "mla_keys_distinct_total",
@@ -171,7 +175,7 @@ STEP_WORK_COUNTERS = (
     "walk_grid_steps_total",
     "walk_grid_steps_full_total",
 ) + LATENT_COUNTERS + SPARSE_COUNTERS + SPLIT_COUNTERS + (
-    "moe_bias_reranked_total",)
+    "moe_bias_reranked_total", "step_rows_total", "lm_head_rows_total")
 
 
 class Histogram:

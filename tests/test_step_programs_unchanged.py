@@ -6,7 +6,9 @@ pytrees' arity), the lowered step's text is hashed, and the hashes are
 those of `tests/step_digests.json`, which was written by THIS file run
 on the parent commit's tree (a new model adds nothing to what the
 accepted cells trace and lower). The router's softmax path, which every
-accepted expert model runs, is hashed the same way, lowered alone.
+accepted expert model runs, is hashed the same way, lowered alone, and so
+is the training step of a small GPT-2 built as the pretraining cell
+builds its own (bf16 weights, AdamW, `jit.compile_train_step`).
 
 A PR that changes one of these steps on purpose writes the file anew on
 its own tree and says so:
@@ -88,9 +90,37 @@ ROUTES = {"moe_route_softmax.k2g1": (2, 1, 1),
           "moe_route_softmax.k6g4": (6, 4, 2)}
 
 
-@pytest.mark.parametrize("name", sorted(MODELS) + sorted(ROUTES))
+def train_text():
+    import jax
+    import paddle_tpu.optimizer as opt
+    from paddle_tpu import jit
+    before = jax.config.jax_default_matmul_precision
+    paddle.set_matmul_precision("default")
+    try:
+        model = _tiny_gpt()
+        model.to(dtype="bfloat16")
+        optimizer = opt.AdamW(learning_rate=1e-4,
+                              parameters=model.parameters(),
+                              weight_decay=0.01)
+        step = jit.compile_train_step(
+            lambda ids, labels: model(ids, labels=labels), model,
+            optimizer)
+        ids = paddle.to_tensor(np.arange(32, dtype=np.int64)
+                               .reshape(2, 16) % 97)
+        return step.compile_info(ids, ids).as_text()
+    finally:
+        paddle.set_matmul_precision(before)
+
+
+@pytest.mark.parametrize("name", sorted(MODELS) + sorted(ROUTES)
+                         + ["gpt2_train"])
 def test_lowered_step_is_the_parents(name):
-    text = step_text(name) if name in MODELS else route_text(*ROUTES[name])
+    if name in MODELS:
+        text = step_text(name)
+    elif name in ROUTES:
+        text = route_text(*ROUTES[name])
+    else:
+        text = train_text()
     digest = hashlib.sha256(text.encode()).hexdigest()
     if os.environ.get("STEP_DIGESTS_WRITE"):
         have = {}
